@@ -99,10 +99,6 @@ def main(argv: list[str] | None = None) -> int:
     )
     args = ap.parse_args(argv)
 
-    from fast_tffm_tpu.utils.platform import apply_platform_env
-
-    apply_platform_env()
-
     cfg = load_config(args.config)
     if args.metrics_path is not None:
         cfg.metrics_path = args.metrics_path
@@ -113,14 +109,16 @@ def main(argv: list[str] | None = None) -> int:
 
         parse_profile_steps(args.profile_steps)  # fail fast on a bad spec
         cfg.telemetry_profile_steps = args.profile_steps
-    if cfg.telemetry_compilation_cache_dir:
-        # Before any driver import compiles a program: repeated runs (and
-        # serving cold starts) then read their XLA programs back from the
-        # on-disk cache instead of recompiling — the compile sentinel
-        # reports the hits distinctly (kind=compile cache_hits).
-        from fast_tffm_tpu.telemetry import enable_compilation_cache
+    # Before any driver import compiles a program: repeated runs (and
+    # serving cold starts) read their XLA programs back from the on-disk
+    # cache instead of recompiling — the compile sentinel reports the hits
+    # distinctly (kind=compile cache_hits).  serving/replica.main makes the
+    # same call, so the workers land on the same directory.  (Configures
+    # jax; initialises no backend — the supervisor and the socket front
+    # end stay off the device.)
+    from fast_tffm_tpu.telemetry import enable_compilation_cache
 
-        enable_compilation_cache(cfg.telemetry_compilation_cache_dir)
+    enable_compilation_cache(cfg.telemetry_compilation_cache_dir)
     if args.legacy:
         print(
             f"note: ignoring legacy cluster args {args.legacy!r} — the SPMD mesh "

@@ -84,8 +84,9 @@ class Config:
     #   the bit-parity reference pipeline; auto picks dense/compact by size)
     tail: str = "auto"  # sparse Adagrad tail: xla (the gather/scatter program
     #   chain) | pallas (ops/pallas_tail.py one-pass gather→update→scatter
-    #   kernel, double-buffered row DMA) | auto (pallas on TPU, xla
-    #   elsewhere — off-TPU the kernel would run interpreted).  pallas with
+    #   kernel, double-buffered row DMA) | auto (= xla: the kernel does not
+    #   compile on the chip yet, so auto never selects it; an explicit
+    #   pallas raises the compiler's error on a TPU).  pallas with
     #   table_layout=packed requires adagrad_accumulator=fused (the kernel's
     #   merged layout); incompatible with dedup_gather_rows (the kernel
     #   dedups internally)
@@ -136,9 +137,10 @@ class Config:
     #   stacks + prefetch depth as kind=stall when no step completes for
     #   this many seconds (0 = watchdog off)
     telemetry_compilation_cache_dir: str = ""  # persistent XLA compilation
-    #   cache directory (jax_compilation_cache_dir): serving cold-start
-    #   warmup and repeated bench runs skip recompiles across processes;
-    #   the compile sentinel marks cache hits distinctly ("" = off)
+    #   cache directory.  The cache is always on (CLI runs and replica
+    #   workers share it; the compile sentinel marks cache hits
+    #   distinctly).  Precedence: JAX_COMPILATION_CACHE_DIR in the
+    #   environment, else this key, else "" = <checkout>/.jax_cache
     telemetry_profile_steps: str = ""  # "A:B" captures a jax.profiler trace
     #   over steps [A, B) (rounded to dispatch boundaries under step
     #   fusion) into <model_file>.profile (trace_dir overrides); start/

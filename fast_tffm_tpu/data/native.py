@@ -16,6 +16,7 @@ import ctypes
 import os
 import shutil
 import subprocess
+import sys
 
 import numpy as np
 
@@ -46,15 +47,21 @@ def _try_build() -> None:
 
     The reference shipped its kernels as a compile-it-yourself Makefile; here
     the build is a sub-second g++ invocation, so running it lazily on first
-    use keeps the fast path on by default without a packaging step.  Any
-    failure (no make/g++, read-only tree, concurrent writer) just leaves the
-    pure-Python parser in place.
+    use keeps the fast path on by default without a packaging step.  A
+    failure (no make/g++, read-only tree, compile error) leaves the
+    pure-Python parser in place and says so ONCE on stderr — a run that
+    silently parses 10x slower looks like an input-bound device.
     """
     global _BUILD_ATTEMPTED
     if _BUILD_ATTEMPTED:
         return
     _BUILD_ATTEMPTED = True
     if _CSRC_DIR is None or not shutil.which("make"):
+        print(
+            "native libsvm parser not built (no csrc/Makefile or no `make` "
+            "on PATH) — using the pure-Python parser",
+            file=sys.stderr,
+        )
         return
     # Build to a process-unique name, then atomically rename into place:
     # concurrent processes (multi-host pods share the filesystem) must never
@@ -68,8 +75,13 @@ def _try_build() -> None:
             timeout=120,
         )
         os.replace(tmp, _SO_PATH)
-    except (subprocess.SubprocessError, OSError):
-        pass
+    except (subprocess.SubprocessError, OSError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        print(
+            f"native libsvm parser build failed ({e!r}) — using the "
+            "pure-Python parser\n" + detail.decode(errors="replace")[-2000:],
+            file=sys.stderr,
+        )
     finally:
         if os.path.exists(tmp):
             try:
@@ -516,6 +528,12 @@ def load_native_parser(threads: int = 0) -> NativeParser | None:
         # AttributeError: a stale pre-fm_parse_mt .so — rebuild next process.
         return None
     return NativeParser(lib, threads)
+
+
+def parser_name() -> str:
+    """``native`` when the C++ parser loads (building it if needed),
+    ``python`` otherwise — named on every run's device line."""
+    return "python" if load_native_parser() is None else "native"
 
 
 def best_parser(threads: int = 0):

@@ -1,8 +1,8 @@
 """Packed wire format: one contiguous H2D buffer per (super)batch.
 
-The streamed input path is PCIe/DMA-bound on real TPU hosts (DESIGN §8
-item 2; PROBE_INPUT_r05 measured 501k step-rate vs 44k end-to-end with
-H2D as the entire gap), and the classic staging ships every batch as
+The streamed input path pays a host→device transfer per batch (what it
+costs on a plain TPU host is ROADMAP S3 — not measured yet), and the
+classic staging ships every batch as
 five separate host arrays (labels/ids/vals/fields/weights — one
 ``device_put`` each).  This module cuts the wire two ways:
 

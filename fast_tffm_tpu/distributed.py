@@ -754,20 +754,14 @@ class GenerationWatcher:
 # ---------------------------------------------------------------------------
 
 
-def enable_cpu_collectives() -> bool:
+def enable_cpu_collectives() -> None:
     """Switch the CPU backend's cross-process collectives on (gloo) —
     without this a multi-process CPU mesh fails every computation with
     "Multiprocess computations aren't implemented on the CPU backend".
-    Must run before backend init; no-op (False) when this jax predates
-    the knob or the backend is already up."""
-    try:
-        import jax
+    Must run before backend init (a TPU backend ignores it)."""
+    import jax
 
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        return True
-    # analysis: ok exception-hygiene capability probe: False means "no gloo on this jax", the caller proceeds single-process
-    except Exception:
-        return False
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def initialize_runtime(cfg, log=print, argv: list[str] | None = None):

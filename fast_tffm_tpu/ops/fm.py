@@ -202,7 +202,10 @@ def fm_score(
       order: interaction order ≥ 2.  order=2 uses the fused (Σv)²−Σv² path;
              order≥3 the ANOVA dynamic program.  Both carry hand-written VJPs.
       use_pallas: route the order≥3 interaction DP through the Pallas TPU
-             kernel (ops/pallas_anova.py).  None = auto (TPU backend only).
+             kernel (ops/pallas_anova.py).  None = auto: on a TPU backend
+             only — the kernel compiled and matched this path there (PR 22,
+             TPU v5 lite, B=16384 N=11 k=8 order 3).  True is honored
+             anywhere and never drops back: a compiler refusal raises.
 
     Returns:
       [batch] raw (pre-sigmoid) scores.
@@ -219,9 +222,8 @@ def fm_score(
 
         # Only the DP carries a hand-written (kernel) VJP; the linear term
         # and z = v·x are cheap elementwise ops XLA autodiff handles best.
-        # Off-TPU the kernel runs in the Pallas interpreter
-        # (ops.pallas_common), keeping this public path testable on the
-        # CPU mesh.
+        # On the CPU test mesh an explicit use_pallas=True runs in the
+        # Pallas interpreter (ops.pallas_common).
         linear = jnp.sum(rows[..., 0] * vals, axis=-1)
         z = rows[..., 1:] * vals[..., None]
         return linear + anova_inter(z, order, default_interpret())

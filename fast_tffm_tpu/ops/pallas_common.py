@@ -1,13 +1,12 @@
-"""Shared Pallas kernel plumbing: interpret-mode resolution.
+"""Shared Pallas kernel plumbing: interpret-mode and tail resolution.
 
 Every Pallas kernel in this package takes an ``interpret`` flag so the
 CPU tier-1 suite can run it in the Pallas interpreter.  The detection
-used to be duplicated at each call site (``jax.default_backend() !=
-"tpu"``); it lives here once so (a) production modules never spell
-``interpret=True`` (the static-analysis suite flags the literal outside
-this module — a compiled path silently running interpreted is a
-throughput bug, not an error), and (b) tests need no per-test plumbing:
-off-TPU the kernels interpret themselves automatically.
+lives here once so (a) production modules never spell ``interpret=True``
+(the static-analysis suite flags the literal outside this module — a
+compiled path silently running interpreted is a throughput bug, not an
+error), and (b) tests need no per-test plumbing: on the test mesh the
+kernels interpret themselves automatically.
 """
 
 from __future__ import annotations
@@ -18,16 +17,23 @@ __all__ = ["default_interpret", "resolve_interpret", "resolve_tail"]
 
 
 def default_interpret() -> bool:
-    """True off-TPU: run Pallas kernels in the interpreter (CPU tests)."""
-    return jax.default_backend() != "tpu"
+    """True only where the CPU was ASKED for (``JAX_PLATFORMS=cpu`` — the
+    tier-1 test mesh): there Pallas kernels run in the interpreter.
+
+    A CPU backend nobody asked for (libtpu failed to initialise and jax
+    dropped to the CPU with a warning) is NOT a reason to interpret: the
+    kernel is then lowered for the backend it finds and the lowering's own
+    error surfaces, instead of a whole run proceeding interpreted."""
+    requested = (jax.config.jax_platforms or "").split(",")[0]
+    return requested == "cpu" and jax.default_backend() == "cpu"
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
     """``None`` (the wrapper default) → auto-detect; a bool is explicit.
 
     Tests pass ``interpret=True`` explicitly; production call sites pass
-    ``None`` and inherit the backend detection — the one CPU branch the
-    analysis suite sanctions.
+    ``None`` and inherit the detection — the one CPU branch the analysis
+    suite sanctions.
     """
     return default_interpret() if interpret is None else bool(interpret)
 
@@ -35,12 +41,12 @@ def resolve_interpret(interpret: bool | None) -> bool:
 def resolve_tail(tail: str) -> str:
     """``[Train] tail`` → effective sparse-tail implementation.
 
-    ``auto`` picks the Pallas tail on TPU and the XLA tail elsewhere —
-    off-TPU the kernel would run interpreted (orders of magnitude slower
-    than compiled XLA), so auto never selects it there.  An explicit
-    ``pallas`` is honored anywhere (off-TPU it interprets — that is what
-    the tier-1 parity tests run).
+    ``auto`` is the XLA tail on every backend: ``auto`` only ever selects
+    a program that has compiled on the chip, and neither Pallas tail
+    kernel does (TPU v5 lite, jax 0.9.0 / libtpu 0.0.34 — Mosaic refuses
+    the sub-tile row DMA; ops/pallas_tail.py quotes the message).  An
+    explicit ``pallas`` is honored anywhere and never drops back: on a TPU
+    it raises the compiler's error, on the CPU test mesh it interprets
+    (what the tier-1 parity tests run).
     """
-    if tail == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    return tail
+    return "xla" if tail == "auto" else tail

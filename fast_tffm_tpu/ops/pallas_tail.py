@@ -2,10 +2,8 @@
 
 The XLA sparse tail is a CHAIN of programs — grad lane-spread, bitmap/
 cumsum (or sort) compaction, RMW gather, RMW scatter — each of which
-walks its own descriptor stream over the same touched rows (~16 ns/row
-each; BENCH_r05's 201M-row rung spends its step there, at ~3% of nominal
-HBM bandwidth).  This module replaces the tail with ONE Pallas TPU
-kernel per table layout:
+walks its own descriptor stream over the same touched rows.  This module
+replaces the tail with ONE Pallas TPU kernel per table layout:
 
   * dedup ONCE at **logical-row** granularity (optim.dedup_rows — the
     sort/segment-sum pipeline the rows-layout classic update already
@@ -43,7 +41,27 @@ Layouts served:
     shape the kernel wants — remapped slot ids against a compact table).
 
 Both run under ``interpret=`` for CPU tier-1 (ops.pallas_common resolves
-the flag off the backend, same pattern as ops/pallas_anova.py).
+the flag, same pattern as ops/pallas_anova.py).
+
+STATUS ON THE CHIP (PR 22; TPU v5 lite, jax 0.9.0, libtpu 0.0.34, at
+baseline #1's width — M = 16384×39 ids, D = 9 / D+1 = 9 lanes): neither
+kernel compiles.  Mosaic refuses the per-row DMA both are built on::
+
+    INTERNAL: Mosaic failed to compile TPU kernel: Slice shape along
+    dimension 1 must be aligned to tiling (128), but is 9.
+      "tpu.memref_slice"(...) : (memref<1048576x128xf32,
+      #tpu.tiled<(1,128),[1,1]>, #tpu.memory_space<hbm>>, i32, i32)
+      -> memref<1x9xf32, #tpu.tiled<(1,128),[1,1]>, ...<hbm>>
+
+(rows layout: ``table_ref.at[row]``; fused layout: ``pl.ds(lane0, d+1)``,
+same message against ``memref<74904x128xf32>``).  An HBM row is stored
+128 lanes wide and a DMA window must cover whole tiles, so a 9-lane
+window does not exist; a whole-tile-row window would make two logical
+rows of one tile row overwrite each other — a different algorithm (dedup
+at tile-row granularity), not a repair.  So ``tail = auto`` resolves to
+the XLA tail (ops.pallas_common.resolve_tail), ``tail = pallas`` raises
+the message above on a TPU, and ROADMAP S4 decides whether this module
+stays.  The kernels still run interpreted on the CPU test mesh.
 """
 
 from __future__ import annotations
@@ -186,9 +204,9 @@ def _fused_rmw(fused, uids, nrows, gsum, *, lr, decay, p, d, interpret, blk):
         grid=(nblocks,),
         in_specs=[
             pl.BlockSpec((blk, d), lambda i, *_: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((2, blk, d + 1), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
@@ -352,12 +370,12 @@ def rows_tail_adagrad_update(
         grid=(nblocks,),
         in_specs=[
             pl.BlockSpec((blk, d), lambda i, *_: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, blk, d), jnp.float32),

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import numpy as np
 import jax
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
@@ -31,15 +32,6 @@ __all__ = [
 
 DATA_AXIS = "data"
 ROW_AXIS = "row"
-
-try:  # JAX >= 0.4.31 exports lax.axis_size
-    from jax.lax import axis_size
-except ImportError:  # older JAX: psum of the literal 1 constant-folds to
-    # the same STATIC int at trace time, so `axis_size(ax) == 1` branches
-    # still resolve while tracing (the mesh=1 fast paths depend on that).
-    def axis_size(name):
-        """Static size of mesh axis ``name`` inside a shard_map body."""
-        return jax.lax.psum(1, name)
 
 
 def make_mesh(

@@ -25,26 +25,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # JAX >= 0.6: top-level export, replication check spelled check_vma
-    from jax import shard_map as _shard_map
-
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except ImportError:  # older JAX: experimental module, kwarg spelled check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """shard_map across the JAX compat break: one callsite spelling
-    (``check_vma``), routed to whichever kwarg the installed JAX uses."""
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_SHARD_MAP_CHECK_KW: check_vma},
-    )
 
 from fast_tffm_tpu.models.base import Batch
 from fast_tffm_tpu.optim import AdagradState, dense_adagrad_update

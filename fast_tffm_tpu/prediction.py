@@ -17,7 +17,7 @@ import numpy as np
 from fast_tffm_tpu.checkpoint import restore_checkpoint
 from fast_tffm_tpu.config import Config, build_model
 from fast_tffm_tpu.models.base import Batch
-from fast_tffm_tpu.telemetry import RunMonitor
+from fast_tffm_tpu.telemetry import RunMonitor, log_device
 from fast_tffm_tpu.training import _batch_converter, _stream, scan_max_nnz
 from fast_tffm_tpu.trainer import init_state, make_predict_step
 
@@ -168,6 +168,7 @@ def _run_predict(
         stall_timeout_s=cfg.telemetry_stall_timeout_s,
         mem_every_s=cfg.telemetry_mem_every_s,
         log=log,
+        device=log_device(log, "predict"),
     )
     # Measured cost ledger (profiling.py): ONE kind=profile record for
     # the predict program — bytes accessed / FLOPs from XLA cost

@@ -32,36 +32,51 @@ chaos.py --serve`` kills/slows/corrupts replicas under live traffic and
 pins the no-hung-client + bit-identical-scores acceptance.
 """
 
-from fast_tffm_tpu.serving.admission import AdmissionQueue
-from fast_tffm_tpu.serving.buckets import BucketLadder, validate_buckets
-from fast_tffm_tpu.serving.engine import (
-    DeadlineExceeded,
-    EngineClosed,
-    OverloadError,
-    ServingEngine,
-    serve_lines,
-)
-from fast_tffm_tpu.serving.metrics import LatencyHistogram, ServingMetrics
-from fast_tffm_tpu.serving.protocol import (
-    BadRequest,
-    Overloaded,
-    Unavailable,
-    WireError,
-)
+import importlib
 
-__all__ = [
-    "AdmissionQueue",
-    "BadRequest",
-    "BucketLadder",
-    "DeadlineExceeded",
-    "EngineClosed",
-    "LatencyHistogram",
-    "Overloaded",
-    "OverloadError",
-    "ServingEngine",
-    "ServingMetrics",
-    "Unavailable",
-    "WireError",
-    "serve_lines",
-    "validate_buckets",
-]
+# PEP 562 lazy exports (same pattern as the package root): the engine pulls
+# in jax, but the jax-free members of this package — protocol.py,
+# client.py, and through them chip_smoke.py's parent process — must be
+# importable without it.  A process that holds no chip should not load the
+# library that takes one.
+_EXPORTS = {
+    "AdmissionQueue": "fast_tffm_tpu.serving.admission",
+    "BadRequest": "fast_tffm_tpu.serving.protocol",
+    "BucketLadder": "fast_tffm_tpu.serving.buckets",
+    "DeadlineExceeded": "fast_tffm_tpu.serving.engine",
+    "EngineClosed": "fast_tffm_tpu.serving.engine",
+    "LatencyHistogram": "fast_tffm_tpu.serving.metrics",
+    "Overloaded": "fast_tffm_tpu.serving.protocol",
+    "OverloadError": "fast_tffm_tpu.serving.engine",
+    "ServingEngine": "fast_tffm_tpu.serving.engine",
+    "ServingMetrics": "fast_tffm_tpu.serving.metrics",
+    "Unavailable": "fast_tffm_tpu.serving.protocol",
+    "WireError": "fast_tffm_tpu.serving.protocol",
+    "serve_lines": "fast_tffm_tpu.serving.engine",
+    "validate_buckets": "fast_tffm_tpu.serving.buckets",
+}
+
+__all__ = sorted(_EXPORTS)
+
+
+def __getattr__(name: str):
+    mod = _EXPORTS.get(name)
+    if mod is not None:
+        value = getattr(importlib.import_module(mod), name)
+    else:
+        # Submodules used to be bound by the eager imports
+        # (`serving.engine.X`-style access) — keep that working lazily.
+        try:
+            value = importlib.import_module(f"{__name__}.{name}")
+        except ModuleNotFoundError as e:
+            if e.name != f"{__name__}.{name}":
+                raise  # the submodule EXISTS but one of its deps is missing
+            raise AttributeError(
+                f"module {__name__!r} has no attribute {name!r}"
+            ) from None
+    globals()[name] = value  # cache: resolve each name once
+    return value
+
+
+def __dir__():
+    return sorted(set(globals()) | set(_EXPORTS))

@@ -471,7 +471,6 @@ def spawn_serve(
     its first output fails at the deadline, not never); returns (proc,
     announced port).  Caller owns terminate()."""
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.Popen(
         [sys.executable, os.path.join(_REPO, "fast_tffm.py"), "serve",

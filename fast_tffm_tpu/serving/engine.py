@@ -51,8 +51,7 @@ from fast_tffm_tpu.serving.admission import AdmissionQueue
 from fast_tffm_tpu.serving.buckets import BucketLadder
 from fast_tffm_tpu.serving.metrics import ServingMetrics
 from fast_tffm_tpu.serving.protocol import FRAME_STATUS_CODES, DeadlineExceeded
-from fast_tffm_tpu.telemetry import log_quietly
-from fast_tffm_tpu.telemetry import RunMonitor
+from fast_tffm_tpu.telemetry import RunMonitor, log_device, log_quietly
 
 __all__ = [
     "ServingEngine",
@@ -222,6 +221,7 @@ class ServingEngine:
             mem_every_s=cfg.telemetry_mem_every_s,
             replica=replica,
             log=log,
+            device=log_device(log, "serving"),
         )
         self._flush_seq = 0  # telemetry step for serving = flush ordinal
         self._metrics_every = cfg.serve_metrics_every_s
@@ -321,6 +321,12 @@ class ServingEngine:
         """Telemetry run id of this engine's monitor — the join key
         bench/probe artifacts stamp so they are joinable to the JSONL."""
         return self._monitor.run_id
+
+    @property
+    def device(self) -> dict:
+        """``platform`` / ``device_kind`` / ``device_count`` this engine
+        scores on (telemetry.log_device) — what REPLICA_READY announces."""
+        return self._monitor.device
 
     def compile_count(self) -> int | None:
         return self._ladder.compile_count()

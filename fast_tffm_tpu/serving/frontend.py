@@ -14,7 +14,7 @@ stays a thin multiplexer that never holds state a failover would lose.
 On startup it spawns the router (which spawns and warms the replicas)
 BEFORE binding, then announces::
 
-    SERVE_READY port=<port> pid=<pid> replicas=<n>
+    SERVE_READY port=<port> pid=<pid> replicas=<n> platform=<platform>
 
 on stdout — the line tools/loadgen.py --spawn and tools/chaos.py --serve
 block on.  Ops: ``ping`` (cheap router snapshot), ``stats`` (router +
@@ -224,7 +224,8 @@ def run_frontend(cfg, config_path: str, *, port: int | None = None, log=None) ->
             f"({n} replica(s), run_id {router.run_id})"
         )
         print(
-            f"{SERVE_READY_PREFIX}port={fe.port} pid={os.getpid()} replicas={n}",
+            f"{SERVE_READY_PREFIX}port={fe.port} pid={os.getpid()} "
+            f"replicas={n} platform={','.join(router.platforms()) or None}",
             flush=True,
         )
         stop.wait()

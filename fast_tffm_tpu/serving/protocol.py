@@ -46,7 +46,7 @@ order, for ``count``=n rows of ``width``=w features:
 
 SCORES payload: req_ids n×u32 | status n×u8 | scores n×f32 — status 0
 is a delivered score, anything else indexes ``FRAME_STATUS_CODES`` (the
-typed wire codes, so the per-row error taxonomy survives the binary
+typed wire codes, so the per-row error classification survives the binary
 hop).  ERROR payload (count=0): u8 code idx | u16 len | utf-8 detail —
 the typed answer to a frame the peer could not decode, preserving the
 no-dropped-connection invariant on the binary wire too.

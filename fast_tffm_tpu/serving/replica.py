@@ -11,10 +11,15 @@ serving/router.py (or by hand for debugging):
 On startup it binds (``--port 0`` = ephemeral), warms the bucket ladder,
 and only THEN prints the readiness line the router blocks on::
 
-    REPLICA_READY port=<port> pid=<pid>
+    REPLICA_READY port=<port> pid=<pid> platform=<platform> chip=<chip>
 
 so a replica is never routed to before its compile ladder is warm (a
-cold replica would pay XLA compiles at p99).  Ops beyond ``score``:
+cold replica would pay XLA compiles at p99), and the jax-free router can
+say which platform — and which chip — each of its workers landed on
+(``chip`` is the ``TPU_VISIBLE_CHIPS`` the router pinned it with, ``all``
+when unpinned: a pinned worker sees a one-device world in which every
+chip calls itself device 0 at (0,0,0), so jax cannot tell them apart).
+Ops beyond ``score``:
 
   * ``ping``   → engine.health() (queue depth, oldest queued wait — the
     router's wedge signal — last flush age, steady compiles);
@@ -295,7 +300,9 @@ def run_replica(
     engine = ServingEngine(cfg, log=log, replica=replica)
     actual = srv.getsockname()[1]
     print(
-        f"{REPLICA_READY_PREFIX}port={actual} pid={os.getpid()}",
+        f"{REPLICA_READY_PREFIX}port={actual} pid={os.getpid()} "
+        f"platform={engine.device['platform']} "
+        f"chip={os.environ.get('TPU_VISIBLE_CHIPS') or 'all'}",
         file=ready_out,
         flush=True,
     )
@@ -351,12 +358,13 @@ def main(argv=None) -> int:
     ap.add_argument("--metrics-path", default=None, metavar="PATH")
     args = ap.parse_args(argv)
 
-    from fast_tffm_tpu.utils.platform import apply_platform_env
-
-    apply_platform_env()
     from fast_tffm_tpu.config import load_config
+    from fast_tffm_tpu.telemetry import enable_compilation_cache
 
     cfg = load_config(args.config)
+    # Same helper, same precedence as cli.main: the replica's ladder warmup
+    # reads the programs an earlier replica (or CLI run) already compiled.
+    enable_compilation_cache(cfg.telemetry_compilation_cache_dir)
     if args.metrics_path is not None:
         cfg.metrics_path = args.metrics_path
     if args.run_id is not None:

@@ -33,12 +33,17 @@ router owns everything cross-replica:
     is applied exactly once per replica (N independent watchers would
     race the filesystem N times per write).
 
-The router itself is device-free — it relays bytes and stats; jax lives
-only in the replica workers.
+The router itself is device-free — it relays bytes and stats and never
+initialises a jax backend; the devices belong to the replica workers.  A
+TPU chip belongs to one process at a time, so on a TPU host the router
+pins replica *i* to chip *i* through the worker's environment
+(``TPU_VISIBLE_CHIPS``) and refuses, at start-up, more replicas than the
+host has chips.
 """
 
 from __future__ import annotations
 
+import glob
 import itertools
 import os
 import signal
@@ -62,15 +67,56 @@ from fast_tffm_tpu.serving.protocol import (
 __all__ = ["Router", "ReplicaProcess", "spawn_replica"]
 
 
+def tpu_chips() -> list[str]:
+    """The chips this host offers its replica workers, as the values
+    ``TPU_VISIBLE_CHIPS`` takes — found WITHOUT jax (initialising a
+    backend here would take every chip away from the workers).
+
+    Empty when the environment keeps jax off the TPU (``JAX_PLATFORMS``
+    set and not naming ``tpu``) or the host has no TPU device node: the
+    workers are then CPU processes, unpinned and unlimited.  A
+    ``TPU_VISIBLE_CHIPS`` already in the environment narrows the offer to
+    those chips; otherwise libtpu numbers the chips 0..n-1 in the order of
+    their device nodes (``/dev/vfio/<group>`` on v5e and later,
+    ``/dev/accel<n>`` before)."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return []
+    visible = os.environ.get("TPU_VISIBLE_CHIPS", "")
+    if visible:
+        return [c for c in visible.split(",") if c]
+    nodes = glob.glob("/dev/accel[0-9]*") + glob.glob("/dev/vfio/[0-9]*")
+    return [str(i) for i in range(len(nodes))]
+
+
+# What makes one libtpu process own exactly one chip of a multi-chip host
+# (established on a four-chip TPU v5 lite host, libtpu 0.0.34 — CHANGES.md
+# PR 22): the chip it may open AND a one-chip process topology.  All three
+# are needed: with TPU_VISIBLE_CHIPS alone the second concurrent process
+# dies on libtpu's multi-process lockfile.
+def _one_chip_env(chip: str) -> dict:
+    return {
+        "TPU_VISIBLE_CHIPS": chip,
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
+
 class ReplicaProcess:
     """Handle for one spawned replica worker: the Popen, its announced
-    port, and liveness/kill.  Tests substitute a duck-typed fake (a
-    thread-backed socket server) via Router(launcher=...)."""
+    port, the platform/chip its READY line named, and liveness/kill.
+    Tests substitute a duck-typed fake (a thread-backed socket server)
+    via Router(launcher=...)."""
 
-    def __init__(self, proc: subprocess.Popen, port: int, pid: int):
+    def __init__(
+        self, proc: subprocess.Popen, port: int, pid: int,
+        platform: str | None = None, chip: str | None = None,
+    ):
         self.proc = proc
         self.port = port
         self.pid = pid
+        self.platform = platform
+        self.chip = chip
 
     def alive(self) -> bool:
         return self.proc.poll() is None
@@ -99,13 +145,16 @@ def spawn_replica(
     run_id: str = "",
     metrics_path: str | None = None,
     env: dict | None = None,
+    chip: str | None = None,
     log=print,
     ready_timeout_s: float = 180.0,
 ) -> ReplicaProcess:
     """Default launcher: start ``python -m fast_tffm_tpu.serving.replica``
     and block until its REPLICA_READY line (the ladder is warm — a
-    replica is never routed to cold).  stderr passes through; stdout is
-    drained to ``log`` after the readiness line."""
+    replica is never routed to cold).  ``chip`` (a ``tpu_chips()`` entry)
+    pins the worker to that one TPU chip; None leaves the environment as
+    inherited.  stderr passes through; stdout is drained to ``log`` after
+    the readiness line."""
     cmd = [
         sys.executable, "-m", "fast_tffm_tpu.serving.replica",
         config_path, "--replica", str(index), "--port", "0",
@@ -121,6 +170,8 @@ def spawn_replica(
         if child_env.get("PYTHONPATH")
         else pkg_root
     )
+    if chip is not None:
+        child_env.update(_one_chip_env(chip))
     proc = subprocess.Popen(
         cmd, stdout=subprocess.PIPE, stderr=None, text=True, env=child_env
     )
@@ -128,7 +179,7 @@ def spawn_replica(
     # stdout line would park a plain readline forever — the deadline must
     # bound silence, not just the gaps between lines.
     ready = threading.Event()
-    port_box: list[int | None] = [None]
+    ready_fields: list[dict | None] = [None]  # the READY line's key=value pairs
 
     def wait_ready():
         try:
@@ -139,7 +190,8 @@ def spawn_replica(
                         kv.split("=", 1)
                         for kv in line[len(_READY_PREFIX):].split()
                     )
-                    port_box[0] = int(fields["port"])
+                    fields["port"] = int(fields["port"])
+                    ready_fields[0] = fields
                     ready.set()
                     return
                 if line:
@@ -156,8 +208,8 @@ def spawn_replica(
     )
     waiter.start()
     ready.wait(ready_timeout_s)
-    port = port_box[0]
-    if port is None:
+    fields = ready_fields[0]
+    if fields is None:
         proc.kill()
         raise Unavailable(
             f"replica {index} never announced readiness within "
@@ -176,7 +228,10 @@ def spawn_replica(
             log_quietly(log, f"replica {index}: drain error: {e!r}")
 
     threading.Thread(target=drain, name=f"replica-{index}-drain", daemon=True).start()
-    return ReplicaProcess(proc, port, proc.pid)
+    return ReplicaProcess(
+        proc, fields["port"], proc.pid,
+        platform=fields.get("platform"), chip=fields.get("chip"),
+    )
 
 
 class _Pending:
@@ -236,6 +291,18 @@ class Router:
     ):
         if launcher is None and config_path is None:
             raise ValueError("Router needs config_path (or a custom launcher)")
+        n_replicas = max(1, cfg.serve_replicas)
+        # One process per chip: more replicas than chips is a start-up
+        # error, not a worker that waits out ready_timeout_s for a chip
+        # its peer already holds.  (A custom launcher places its own.)
+        chips = tpu_chips() if launcher is None else []
+        if chips and n_replicas > len(chips):
+            raise ValueError(
+                f"serve_replicas = {n_replicas} but this host offers "
+                f"{len(chips)} TPU chip(s) (chips {','.join(chips)}): a chip "
+                "belongs to one process at a time, so a TPU host serves at "
+                "most one replica per chip"
+            )
         self._cfg = cfg
         self._log = log
         self._health_interval = float(health_interval_s)
@@ -258,6 +325,7 @@ class Router:
                 i,
                 run_id=self.run_id,
                 metrics_path=cfg.metrics_path or None,
+                chip=chips[i] if chips else None,
                 log=self._log,
             )
         )
@@ -311,7 +379,7 @@ class Router:
             from fast_tffm_tpu.checkpoint import checkpoint_signature
 
             self._watch_baseline = checkpoint_signature(cfg.model_file)
-        self.slots = [_Slot(i) for i in range(max(1, cfg.serve_replicas))]
+        self.slots = [_Slot(i) for i in range(n_replicas)]
         # Parallel bring-up: replica warmup is seconds of jax import +
         # ladder compiles; serial would multiply it by N.
         errs: list[BaseException] = []
@@ -352,6 +420,11 @@ class Router:
 
     def _launch_into(self, slot: _Slot) -> None:
         handle = self._launcher(slot.index)
+        self._log(
+            f"replica {slot.index}: ready on port {handle.port} "
+            f"platform={getattr(handle, 'platform', None)} "
+            f"chip={getattr(handle, 'chip', None)}"
+        )
         # Two connections: DATA carries scores; CONTROL carries
         # ping/reload/slow/stats so health checking never queues behind a
         # score backlog (an overloaded replica must read as overloaded,
@@ -387,6 +460,18 @@ class Router:
 
     def healthy_replicas(self) -> list[_Slot]:
         return [s for s in self.slots if s.state == "healthy"]
+
+    def platforms(self) -> list[str]:
+        """Distinct platforms the live workers announced (REPLICA_READY
+        ``platform=``), sorted — what SERVE_READY repeats so a jax-free
+        parent can refuse a tier that came up on the wrong device."""
+        return sorted(
+            {
+                str(p)
+                for p in (getattr(s.handle, "platform", None) for s in self.slots)
+                if p
+            }
+        )
 
     def assign(self) -> tuple[int, int]:
         """Placement for an affinity-pinned DATA connection (ISSUE 16):

@@ -35,7 +35,7 @@ from fast_tffm_tpu.resilience import (
     drain_fault_counters,
     drain_fault_events,
 )
-from fast_tffm_tpu.telemetry import RunMonitor
+from fast_tffm_tpu.telemetry import RunMonitor, log_device
 from fast_tffm_tpu.trainer import init_state, make_predict_step, make_train_step
 from fast_tffm_tpu.utils.prefetch import PrefetchError, prefetch
 from fast_tffm_tpu.utils.tracing import WindowTracer, step_trace
@@ -661,6 +661,7 @@ def _run_training(
         stall_timeout_s=cfg.telemetry_stall_timeout_s,
         mem_every_s=cfg.telemetry_mem_every_s,
         log=log,
+        device=log_device(log, "train"),
     )
     # Deep observability (profiling.py): the on-demand step-window trace,
     # the per-compiled-program measured cost ledger (kind=profile — the
@@ -1268,12 +1269,13 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
         state = init_state(
             model, jax.random.key(0), cfg.init_accumulator_value, cfg.adagrad_accumulator
         )
-    # [Train] tail: resolve auto → pallas-on-TPU / xla-elsewhere ONCE, up
-    # front, so every step factory below (packed, rows, scanned, device
-    # cache) sees the same resolved choice.  The Pallas tail applies to
-    # the fused packed layout and the rows layout; auto quietly keeps xla
-    # where the kernel has no contract (split packed accumulators,
-    # dedup_gather_rows) — an EXPLICIT pallas there is a config error.
+    # [Train] tail: resolve auto ONCE, up front, so every step factory
+    # below (packed, rows, scanned, device cache) sees the same resolved
+    # choice.  auto = the XLA tail (the only one that compiles on the
+    # chip — ops.pallas_common.resolve_tail); an EXPLICIT pallas builds
+    # the kernel step and lets the compiler's error raise — it never
+    # drops back to xla — and is a config error where the kernel has no
+    # contract (split packed accumulators, dedup_gather_rows).
     from fast_tffm_tpu.ops.pallas_common import resolve_tail
 
     tail = resolve_tail(cfg.tail)

@@ -493,15 +493,19 @@ def test_delta_config_validation():
         Config(checkpoint_chunk_mb=0).validate()
 
 
-def test_compilation_cache_enable_and_compile_record_cache_hits(tmp_path):
-    """[Telemetry] compilation_cache_dir satellite: the knob points jax's
-    persistent cache at the dir, and kind=compile records carry the
-    cache_hits count distinctly (0 on a cold compile)."""
+def test_compilation_cache_enable_and_compile_record_cache_hits(tmp_path, monkeypatch):
+    """[Telemetry] compilation_cache_dir: with no JAX_COMPILATION_CACHE_DIR
+    in the environment the key points jax's persistent cache at the dir
+    (the full precedence is pinned in tests/test_chip_smoke.py), and
+    kind=compile records carry the cache_hits count distinctly (0 on a
+    cold compile)."""
     from fast_tffm_tpu import telemetry
 
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")  # conftest sets it
     cc = str(tmp_path / "cc")
-    assert telemetry.enable_compilation_cache(cc)
     try:
+        assert telemetry.enable_compilation_cache(cc) == cc
         assert jax.config.jax_compilation_cache_dir == cc
         mon = telemetry.RunMonitor(str(tmp_path / "m.jsonl"))
         import jax.numpy as jnp
@@ -514,7 +518,7 @@ def test_compilation_cache_enable_and_compile_record_cache_hits(tmp_path):
         assert comp, "expected the fresh program to fire the compile sentinel"
         assert all("cache_hits" in r for r in comp)
     finally:
-        jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_report_renders_ckpt_and_gates_stall_share(tmp_path):

@@ -286,7 +286,13 @@ def test_streamed_train_telemetry_schema_and_zero_steady_compiles(dataset):
     yields kind ∈ {train, input, compile, mem} records sharing one
     run_id, with ZERO steady-state kind=compile events after warmup."""
     cfg = _train_cfg(dataset, telemetry_stall_timeout_s=30.0)
-    train(cfg, log=lambda *_: None)
+    logged = []
+    train(cfg, log=logged.append)
+    # ...and says so once at start, in the one format a jax-free parent
+    # (chip_smoke.py) parses
+    (line,) = [l for l in logged if "device platform=" in l]
+    assert line.startswith("train: device platform=cpu device_kind=")
+    assert " pallas=interpreted parser=" in line
     records = _read(cfg.metrics_path)
     kinds = {r["kind"] for r in records}
     assert {"train", "input", "compile", "mem", "summary"} <= kinds
@@ -300,6 +306,12 @@ def test_streamed_train_telemetry_schema_and_zero_steady_compiles(dataset):
     assert summary["steady_compiles"] == 0
     assert summary["total_compiles"] >= 1  # warmup compile was seen
     assert summary["stalls"] == 0 and summary["anomalies"] == 0
+    # the run names its device: what jax reported to the driver
+    import jax
+
+    assert summary["platform"] == "cpu"
+    assert summary["device_kind"] == jax.devices()[0].device_kind
+    assert summary["device_count"] == jax.device_count()
     # the windowed meter fed real rates into the telemetry field
     assert all(
         r["examples_per_sec"] > 0 for r in records if r["kind"] == "train"
@@ -493,6 +505,10 @@ def test_report_compare_gates_throughput_regression(tmp_path):
     # cannot pass the gate)...
     empty = tmp_path / "empty.jsonl"
     RunMonitor(str(empty)).close()  # mem + summary only
+    # a monitor no driver handed a device (router, supervisor) says null
+    (summ,) = [x for x in _read(str(empty)) if x["kind"] == "summary"]
+    assert summ["platform"] is None and summ["device_count"] is None
+    assert all(k in summ for k in SCHEMAS["summary"])
     r = run(str(empty), "--compare", base)
     assert r.returncode == 1 and "no train throughput" in r.stdout
     # ...and appended back-to-back runs report only the LAST run

@@ -114,8 +114,15 @@ metrics_path = {d}/run.jsonl
     return cfg
 
 
+# A correctness harness, not a measurement: every child (trainers, the
+# serve tier) is forced onto the CPU on purpose — kill/corrupt/relaunch
+# trials need N cheap processes, not the one chip — and every artifact
+# this tool writes says so.
+PLATFORM = "cpu"
+
+
 def _env(processes: int = 1) -> dict:
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS=PLATFORM)
     if processes > 1:
         # One virtual device per pod host: the mesh spans the processes.
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
@@ -361,6 +368,7 @@ def _serve_chaos(args) -> int:
 
     result: dict = {
         "probe": "SERVE_CHAOS",
+        "platform": PLATFORM,
         # Envelope join keys (run_id + schema_version): this probe is
         # joinable to the telemetry JSONL its serve tier wrote.
         **artifact_stamp(),
@@ -582,6 +590,7 @@ def main(argv=None) -> int:
                     "@N is the replica index)")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+    os.environ["JAX_PLATFORMS"] = PLATFORM  # spawn_serve children inherit
     if args.serve:
         return _serve_chaos(args)
     pod = args.processes > 1
@@ -602,6 +611,7 @@ def main(argv=None) -> int:
         # in per-trial tempdirs, so this stamp names the probe invocation;
         # the serve probe's tier ADOPTS its run_id (see _serve_chaos).
         **artifact_stamp(),
+        "platform": PLATFORM,
         "steps_total": STEPS,
         "delta_every_steps": DELTA_EVERY,
         "seed": args.seed,

@@ -542,7 +542,6 @@ def run_multiprocess(args, host, port, res: Results) -> dict:
     if args.input:
         cmd_base += ["--input", args.input]
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
     procs = [

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """A/B probe: packed sparse-tail strategies at the giant-vocab scale rung.
 
-Measures, each config in its OWN subprocess (a failed rung leaks device
-buffers for the life of the process on this backend — bench.py:_probe_rung):
+Measures, each config in its OWN subprocess (one state per process: the
+element-accumulator variants are expected to exhaust device memory):
 
   rows      the r4 scale-rung step (rows layout, row accumulator) — baseline
   compact   lane-packed table + sort-free touched-row compaction

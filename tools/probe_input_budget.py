@@ -15,7 +15,7 @@ make_global_batch) driven by 1 vs 2 real OS processes over a localhost
 jax.distributed CPU mesh, NO train step — the measured quantity is
 parse+assembly throughput, which must scale with processes.
 
-Writes PROBE_INPUT_r05.json.  Usage:
+Writes PROBE_INPUT.json.  Usage:
   python tools/probe_input_budget.py [--skip-tpu] [--rows 400000]
 """
 
@@ -229,7 +229,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=1 << 19)
     ap.add_argument("--skip-tpu", action="store_true")
-    ap.add_argument("--out", default=os.path.join(REPO, "PROBE_INPUT_r05.json"))
+    ap.add_argument("--out", default=os.path.join(REPO, "PROBE_INPUT.json"))
     args = ap.parse_args(argv)
 
     res = {"batch": BATCH, "nnz": NNZ, "vocab": VOCAB, "fmb_rows": args.rows}

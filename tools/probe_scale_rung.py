@@ -3,8 +3,8 @@
 
 BENCH_r02 measured a 234,881,024-row table; BENCH_r03's fresh-subprocess
 probe got RESOURCE_EXHAUSTED at the same size.  This tool isolates WHICH
-stage fails, each stage in its OWN fresh subprocess (a failed big
-allocation poisons the process — bench._probe_rung):
+stage fails, each stage in its OWN fresh subprocess (one allocation
+attempt per process, so no stage inherits another's buffers):
 
   alloc      build the [V, 9] table + [V, 1] row accumulator, value-sync
   alloc_el   same with the ELEMENT [V, 9] accumulator (2.2 GB more)

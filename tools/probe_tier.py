@@ -66,7 +66,6 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=os.path.join(REPO, "PROBE_TIER_r12.json"))
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import bench  # repo-root module: the scale workload's one source of truth
     from fast_tffm_tpu.config import Config
     from fast_tffm_tpu.data.binary import open_fmb

@@ -382,7 +382,6 @@ def main(argv=None) -> int:
 
         tcfg = _train_cfg(d, run_id, args)
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
         tcmd = [
             sys.executable, os.path.join(REPO, "fast_tffm.py"), "train", tcfg,
