@@ -1,0 +1,277 @@
+"""The train window, for ``train`` and ``dist_train`` mixes alike: the
+program's own entry point (``training.train`` / ``training.dist_train``, what
+``fast_tffm.py`` calls) runs in this process on FMB input made from the seed.
+
+The program fetches the losses every ``log_every`` steps, a host wait on the
+device, and then calls ``log``.  Those calls are the sync boundaries: the
+window opens at the first one at or after ``warm_steps`` and closes at the
+first one ``--seconds`` later, so it holds whole steps only.  The same compiled
+step and state serve steps 1-3, which the reference follows, and the window.
+"""
+
+from __future__ import annotations
+
+import gc
+import os
+import sys
+import time
+import traceback
+
+import numpy as np
+
+from . import cells, common, gen, peaks, readers, reference
+
+CHECK_STEPS = 3
+
+
+class _WindowClosed(Exception):
+    """Raised from ``step_hook`` to end the run without its closing save."""
+
+
+class SyncWindow:
+    """The window between two sync boundaries.  ``boundary(now, step, loss)``
+    is called at each of the program's loss fetches; the window opens at the
+    first one at or after ``warm_steps`` and closes at the first one
+    ``seconds`` or more later.  Steps between boundaries are never counted."""
+
+    def __init__(self, seconds: float, warm_steps: int, log_every: int):
+        self.seconds, self.warm_steps, self.log_every = seconds, warm_steps, log_every
+        self.open = self.close = None  # (time, step)
+        self.bad_steps = 0
+
+    def boundary(self, now: float, step: int, loss: float) -> str | None:
+        """'open' / 'close' when this boundary is that edge, else None."""
+        if self.open is None:
+            if step >= self.warm_steps:
+                self.open = (now, step)
+                return "open"
+        elif self.close is None:
+            if not np.isfinite(loss):
+                self.bad_steps += self.log_every
+            if now - self.open[0] >= self.seconds:
+                self.close = (now, step)
+                return "close"
+        return None
+
+    @property
+    def steps(self) -> int:
+        return self.close[1] - self.open[1]
+
+    def rate(self, examples_per_step: int, chips: int) -> float:
+        return self.steps * examples_per_step / (self.close[0] - self.open[0]) / chips
+
+
+def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cells.CHECKOUT, keep_events=None):
+    import jax
+
+    from fast_tffm_tpu import training
+
+    device = common.device_info(cell["chips"], require_chip)
+    phase = common.phases(t_start)
+    tr, ini = cell["traffic"], cell["ini"]
+    batch, nnz = int(ini["Train"]["batch_size"]), int(ini["Train"]["max_nnz"])
+    hyper = _hyper(ini)
+    vocab, k = hyper["vocab"], hyper["k"]
+    log_every = int(ini["Train"]["log_every"])
+    n_batches = int(tr["file_batches"])
+    runtime_start_s = phase("device found")
+    labels, ids, vals = gen.rows_from_seed(seed, n_batches * batch, nnz, vocab, tr.get("zipf_alpha", 2.5))
+    phase("rows drawn")
+    work, cfg = common.configured(cell, cell["name"], workroot, train_file="train.fmb")
+    gen.write_fmb(cfg.train_files[0], labels, ids, vals, vocab)
+    phase("input made")
+
+    # The rows the first three steps touch: what the check reads back.
+    first = ids[: CHECK_STEPS * batch].reshape(CHECK_STEPS, batch, nnz)
+    u = np.unique(first)
+    u1 = np.unique(first[0])
+    take = jax.jit(lambda t, i: t[i])
+    u_dev, u1_dev = _pad(u, first.size), _pad(u1, first[0].size)
+    phase("check rows found")
+
+    cap = {"losses": []}
+    win = SyncWindow(seconds, int(tr["warm_steps"]), log_every)
+    wall_open = [None]
+    trace_dir = os.path.join(work, "trace")
+
+    def hook(step_num):
+        if win.close is not None:
+            raise _WindowClosed
+        if step_num <= CHECK_STEPS:
+            # ``step_hook`` is handed the step number only; the state and the
+            # loss of that step are the caller's locals.
+            frame = sys._getframe(1).f_locals
+            state = frame["state"]
+            cap["losses"].append(frame["loss"])
+            if step_num == 1:
+                cap["t1"] = take(state.table, u1_dev)
+                cap["a1"] = take(state.table_opt.accum, u1_dev)
+            if step_num == CHECK_STEPS:
+                cap["t3"] = take(state.table, u_dev)
+
+    def log(msg):
+        msg = str(msg)
+        if not msg.startswith("step "):
+            print(msg, file=sys.stderr)
+            return
+        parts = msg.split()
+        step, loss = int(parts[1]), float(parts[5])
+        if win.open is None and step >= win.warm_steps and do_trace:
+            common.start_trace(trace_dir)  # before the clock is read
+        edge = win.boundary(time.perf_counter(), step, loss)
+        if edge == "open":
+            wall_open[0] = time.time()
+        elif edge == "close" and do_trace:
+            jax.profiler.stop_trace()
+
+    entry = training.dist_train if cell["kind"] == "dist_train" else training.train
+    try:
+        entry(cfg, log=log, step_hook=hook)
+        raise SystemExit("the input ended before the window closed: raise epoch_num")
+    except _WindowClosed as e:
+        traceback.clear_frames(e.__traceback__)
+    peak = common.memory_peak_bytes()
+    phase("window")
+    steps, s_open, s_close = win.steps, win.open[1], win.close[1]
+    rate = win.rate(batch, cell["chips"])
+
+    got = {
+        "losses": [float(x) for x in cap["losses"][:CHECK_STEPS]],
+        "t1": np.asarray(cap["t1"])[: u1.size],
+        "a1": np.asarray(cap["a1"])[: u1.size],
+        "t3": np.asarray(cap["t3"])[: u.size],
+    }
+    cap.clear()
+    gc.collect()
+    phase("state read back")
+    n3 = CHECK_STEPS * batch
+    ref = followed(hyper, first, vals[:n3].reshape(first.shape), labels[:n3].reshape(CHECK_STEPS, batch), u, u1)
+    numbers = compare(got, ref, hyper["lr"])
+    correct, compared = common.decide(numbers, tr["limits"])
+    phase("reference followed and compared")
+
+    result = {
+        "correct": correct,
+        "attempted": steps,
+        "failed": win.bad_steps,
+        "metrics": {},
+        "device": dict(device, memory_peak_bytes=peak),
+    }
+    if do_trace:
+        red = common.reduce_trace(result, trace_dir, keep_events)
+        m = min(4, n_batches)
+        work_bytes = np.mean([peaks.modeled_step_bytes(ids[i * batch : (i + 1) * batch], k + 1, k + 1) for i in range(m)], axis=0)
+        ctx = {
+            "records": readers.read_jsonl(cfg.metrics_path),
+            "steps": (s_open, s_close),
+            "trace": red,
+            "n_steps": steps,
+            "device_kind": device["kind"],
+            "chips": cell["chips"],
+            "values": {"runtime_start_s": runtime_start_s},
+            "step_bytes": float(work_bytes[0]),
+            "step_flops": peaks.modeled_step_flops(batch * nnz, int(work_bytes[1]), k + 1),
+        }
+        result["metrics"] = readers.read_all(cells.load_metrics(cell["kind"], cell["bench_dir"]), ctx)
+    else:
+        result["metrics"] = {
+            "train_examples_per_s_per_chip": common.metric(rate, "examples/s/chip"),
+            "setup_s": common.metric(wall_open[0] - t_start, "s"),
+        }
+    result["compared"] = compared
+    common.remove_tree(work)
+    return result
+
+
+def _pad(rows, n):
+    """``rows`` padded to the most rows the batches can touch, so that every
+    seed compiles the same shapes; the padding repeats a row and is never read."""
+    return np.concatenate([rows, np.full(n - rows.size, rows[0], rows.dtype)])
+
+
+def followed(h, first, vals, labels, u, u1, dtype=None, shards=0):
+    """The reference over the first three batches (``first`` ids [3, B, N],
+    ``vals`` [3, B, N], ``labels`` [3, B]), on the compact table of the rows
+    ``u`` they touch.  Returns what ``compare`` reads, as numpy."""
+    import jax.numpy as jnp
+
+    t0 = reference.init_rows(h["vocab"], h["k"], h["init_range"], _pad(u, CHECK_STEPS * h["rows_per_step"]))
+    idx = np.searchsorted(u, first).astype(np.int32)
+    # Padding rows of the compact table are never read; shard 0 may own them.
+    owner = _pad(u // (h["vocab"] // shards), t0.shape[0]).astype(np.int32) if shards else None
+    outs = reference.train_steps(
+        t0, list(zip(idx, vals, labels)), h["lr"], h["accum0"], h["bias_lambda"], h["factor_lambda"],
+        dtype=dtype or jnp.float32, owner=owner,
+    )
+    at1 = np.searchsorted(u, u1)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))[: u.size]
+    return {
+        "losses": [float(o[0]) for o in outs],
+        "t0": f32(t0), "at1": at1,
+        "t1": f32(outs[0][1])[at1], "a1": f32(outs[0][2])[at1],
+        "t3": f32(outs[-1][1]),
+    }
+
+
+def planted(cell, seed, what):
+    """The control or a fault, planted in the reference put in the program's
+    place, at the cell's own size: ``control`` follows the three steps in
+    bfloat16; ``half_batch`` leaves half of each batch out and takes the mean
+    over the rest; ``no_exchange`` (cells on several chips) leaves out the
+    exchange of gradients between the row shards.  Returns the numbers ``compare`` gives against the sound
+    float32 reference."""
+    import jax.numpy as jnp
+
+    ini = cell["ini"]
+    batch, nnz = int(ini["Train"]["batch_size"]), int(ini["Train"]["max_nnz"])
+    h = _hyper(ini)
+    labels, ids, vals = gen.rows_from_seed(seed, CHECK_STEPS * batch, nnz, h["vocab"], cell["traffic"].get("zipf_alpha", 2.5))
+    first, vals, labels = ids.reshape(CHECK_STEPS, batch, nnz), vals.reshape(CHECK_STEPS, batch, nnz), labels.reshape(CHECK_STEPS, batch)
+    u, u1 = np.unique(first), np.unique(first[0])
+    ref = followed(h, first, vals, labels, u, u1)
+    if what == "control":
+        bad = followed(h, first, vals, labels, u, u1, dtype=jnp.bfloat16)
+    elif what == "half_batch":
+        half = batch // 2
+        bad = followed(h, first[:, :half], vals[:, :half], labels[:, :half], u, u1)
+    elif what == "no_exchange":
+        bad = followed(h, first, vals, labels, u, u1, shards=cell["chips"])
+    else:
+        raise ValueError(what)
+    return compare(bad, ref, h["lr"])
+
+
+def _hyper(ini):
+    return {
+        "vocab": int(ini["General"]["vocabulary_size"]), "k": int(ini["General"]["factor_num"]),
+        "rows_per_step": int(ini["Train"]["batch_size"]) * int(ini["Train"]["max_nnz"]),
+        "init_range": float(ini["Train"].get("init_value_range", 0.01)),
+        "lr": float(ini["Train"]["learning_rate"]),
+        "accum0": float(ini["Train"].get("init_accumulator_value", 0.1)),
+        "bias_lambda": float(ini["Train"].get("bias_lambda", 0.0)),
+        "factor_lambda": float(ini["Train"].get("factor_lambda", 0.0)),
+    }
+
+
+def _leaf_norms(rows):
+    """The two leaves of a row table: biases (column 0), factors (the rest)."""
+    rows = rows.astype(np.float64)
+    return np.array([np.sqrt((rows[:, 0] ** 2).sum()), np.sqrt((rows[:, 1:] ** 2).sum())])
+
+
+def compare(got, ref, lr):
+    """Gap of each step's loss; gap between the program's and the reference's
+    norm of the first gradient, worked out on both sides from the state after
+    one step (g = (p0 - p1) * sqrt(accum1) / lr); gap of the norm of the
+    parameters' change after three steps.  Norms by leaf, the worst leaf
+    counts, each against the reference's norm of that leaf."""
+    t0_1 = ref["t0"][ref["at1"]]
+    grad = lambda s: _leaf_norms((t0_1 - s["t1"]) * np.sqrt(s["a1"]) / lr)
+    delta = lambda s: _leaf_norms(s["t3"] - ref["t0"])
+    worst = lambda p, r: float(np.max(np.abs(p - r) / r))
+    lp, lr_ = np.array(got["losses"]), np.array(ref["losses"])
+    return {
+        "loss_gap": float(np.max(np.abs(lp - lr_) / np.abs(lr_))) if lp.shape == lr_.shape else float("inf"),
+        "grad1_norm_gap": worst(grad(got), grad(ref)),
+        "delta3_norm_gap": worst(delta(got), delta(ref)),
+    }
