@@ -54,6 +54,8 @@ from typing import NamedTuple
 
 import numpy as np
 
+from fast_tffm_tpu.utils.tracing import span
+
 __all__ = [
     "WireSpec",
     "make_spec",
@@ -304,6 +306,7 @@ def make_unpacker(spec: WireSpec):
         return u.astype(jnp.int32)
 
     @jax.jit
+    @jax.named_scope("score.unpack")
     def unpack(buf):
         *lead, length = buf.shape
         lead = tuple(lead)
@@ -441,18 +444,28 @@ class InputStats:
         self.wire_bytes = 0
         self.q_depth_sum = 0
         self.q_samples = 0
+        self.wait_s = 0.0  # the consumer blocked in prefetch's q.get
 
     def timed(self, raw, convert):
         """Wrap the (parsed, w) stream, timing production and conversion.
         ``convert`` None keeps conversion in the consumer (text input) —
-        parse time and queue depth still get measured."""
-        t0 = time.perf_counter()
-        for p, w in raw:
+        parse time and queue depth still get measured.  Both stages are
+        spans on the producer's thread too (``input.parse``, ``input.h2d``)."""
+        raw = iter(raw)
+        done = object()
+        while True:
+            t0 = time.perf_counter()
+            with span("input.parse"):
+                item = next(raw, done)
+            if item is done:
+                return
+            p, w = item
             t1 = time.perf_counter()
             if convert is None:
                 b, nbytes, t2 = None, 0, t1
             else:
-                b = convert(p, w)
+                with span("input.h2d"):
+                    b = convert(p, w)
                 t2 = time.perf_counter()
                 nbytes = getattr(convert, "last_nbytes", 0)
                 if not nbytes:  # arrays converter: estimate from the host arrays
@@ -484,13 +497,17 @@ class InputStats:
                 self.convert_s += t2 - t1
                 self.wire_bytes += nbytes
             yield b, p, w
-            t0 = time.perf_counter()
 
     def on_queue_depth(self, depth: int) -> None:
         with self._lock:
             self.last_depth = depth
             self.q_depth_sum += depth
             self.q_samples += 1
+
+    def on_wait(self, seconds: float) -> None:
+        """The consumer's pop blocked this long (``input.wait``)."""
+        with self._lock:
+            self.wait_s += seconds
 
     def drain(self) -> dict:
         """Snapshot-and-reset; {} when nothing flowed since last drain."""
@@ -518,6 +535,13 @@ class InputStats:
                 ),
                 "prefetch_queue_depth": (
                     round(self.q_depth_sum / self.q_samples, 2)
+                    if self.q_samples
+                    else None
+                ),
+                # Mean a pop the consumer blocked; None with no prefetch
+                # queue in front of this stream.
+                "wait_ms": (
+                    round(1e3 * self.wait_s / self.q_samples, 3)
                     if self.q_samples
                     else None
                 ),

@@ -69,6 +69,7 @@ class FFMModel:
     def init_dense(self, key: jax.Array):
         return {}
 
+    @jax.named_scope("fm.interaction")
     def score(self, rows: jax.Array, dense, batch: Batch) -> jax.Array:
         del dense
         B, N = batch.vals.shape

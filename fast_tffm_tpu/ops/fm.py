@@ -212,19 +212,22 @@ def fm_score(
     """
     if order < 2:
         raise ValueError(f"FM order must be >= 2, got {order}")
-    if order == 2:
-        return _fm_score_order2(rows, vals)
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas:
-        from fast_tffm_tpu.ops.pallas_anova import anova_inter
-        from fast_tffm_tpu.ops.pallas_common import default_interpret
+    # Every caller's interaction (train step, scorer, any layout, the
+    # sharded step) carries this name in the compiled program.
+    with jax.named_scope("fm.interaction"):
+        if order == 2:
+            return _fm_score_order2(rows, vals)
+        if use_pallas is None:
+            use_pallas = jax.default_backend() == "tpu"
+        if use_pallas:
+            from fast_tffm_tpu.ops.pallas_anova import anova_inter
+            from fast_tffm_tpu.ops.pallas_common import default_interpret
 
-        # Only the DP carries a hand-written (kernel) VJP; the linear term
-        # and z = v·x are cheap elementwise ops XLA autodiff handles best.
-        # On the CPU test mesh an explicit use_pallas=True runs in the
-        # Pallas interpreter (ops.pallas_common).
-        linear = jnp.sum(rows[..., 0] * vals, axis=-1)
-        z = rows[..., 1:] * vals[..., None]
-        return linear + anova_inter(z, order, default_interpret())
-    return _fm_score_anova(rows, vals, order)
+            # Only the DP carries a hand-written (kernel) VJP; the linear term
+            # and z = v·x are cheap elementwise ops XLA autodiff handles best.
+            # On the CPU test mesh an explicit use_pallas=True runs in the
+            # Pallas interpreter (ops.pallas_common).
+            linear = jnp.sum(rows[..., 0] * vals, axis=-1)
+            z = rows[..., 1:] * vals[..., None]
+            return linear + anova_inter(z, order, default_interpret())
+        return _fm_score_anova(rows, vals, order)

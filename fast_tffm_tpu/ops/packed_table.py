@@ -174,13 +174,14 @@ def packed_gather(packed: jax.Array, ids: jax.Array, d: int) -> jax.Array:
     slices sum into the [..., D] result (each id has exactly one live
     slot, so the sum just selects)."""
     p = rows_per_tile(d)
-    phys = ids // p
-    slot = ids % p
-    rows128 = packed[phys]  # [..., 128] full-tile-row gather
-    out = jnp.zeros(ids.shape + (d,), packed.dtype)
-    for s in range(p):
-        piece = rows128[..., s * d : (s + 1) * d]
-        out = out + jnp.where((slot == s)[..., None], piece, 0)
+    with jax.named_scope("fm.gather"):
+        phys = ids // p
+        slot = ids % p
+        rows128 = packed[phys]  # [..., 128] full-tile-row gather
+        out = jnp.zeros(ids.shape + (d,), packed.dtype)
+        for s in range(p):
+            piece = rows128[..., s * d : (s + 1) * d]
+            out = out + jnp.where((slot == s)[..., None], piece, 0)
     return out
 
 
@@ -636,13 +637,14 @@ def fused_gather(fused: jax.Array, ids: jax.Array, d: int) -> jax.Array:
     static masked slot extraction, accumulator lanes skipped)."""
     p = fused_rows_per_tile(d)
     d1 = d + 1
-    phys = ids // p
-    slot = ids % p
-    rows128 = fused[phys]
-    out = jnp.zeros(ids.shape + (d,), fused.dtype)
-    for s in range(p):
-        piece = rows128[..., s * d1 : s * d1 + d]
-        out = out + jnp.where((slot == s)[..., None], piece, 0)
+    with jax.named_scope("fm.gather"):
+        phys = ids // p
+        slot = ids % p
+        rows128 = fused[phys]
+        out = jnp.zeros(ids.shape + (d,), fused.dtype)
+        for s in range(p):
+            piece = rows128[..., s * d1 : s * d1 + d]
+            out = out + jnp.where((slot == s)[..., None], piece, 0)
     return out
 
 

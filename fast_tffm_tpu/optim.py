@@ -109,18 +109,19 @@ def dedup_rows(ids: jax.Array, row_grads: jax.Array, num_rows: int):
       (out of range → scattered with mode='drop') and zero gradients.
     """
     m = ids.shape[0]
-    order = jnp.argsort(ids)
-    sid = ids[order]
-    sg = row_grads[order]
-    is_new = jnp.concatenate([jnp.ones((1,), bool), sid[1:] != sid[:-1]])
-    seg = jnp.cumsum(is_new) - 1  # [M] segment index per occurrence
-    gsum = jax.ops.segment_sum(sg, seg, num_segments=m)
-    # Segment representative via scatter-SET, not segment_max (measured
-    # ~9 ms slower as a 1-D scatter-max on this backend): every
-    # occurrence in a segment writes the SAME sid, so any duplicate
-    # winning is correct; unwritten trailing slots keep the sentinel
-    # ``num_rows`` (out of range → scattered with mode='drop').
-    uids = jnp.full((m,), num_rows, sid.dtype).at[seg].set(sid)
+    with jax.named_scope("fm.dedup"):
+        order = jnp.argsort(ids)
+        sid = ids[order]
+        sg = row_grads[order]
+        is_new = jnp.concatenate([jnp.ones((1,), bool), sid[1:] != sid[:-1]])
+        seg = jnp.cumsum(is_new) - 1  # [M] segment index per occurrence
+        gsum = jax.ops.segment_sum(sg, seg, num_segments=m)
+        # Segment representative via scatter-SET, not segment_max (measured
+        # ~9 ms slower as a 1-D scatter-max on this backend): every
+        # occurrence in a segment writes the SAME sid, so any duplicate
+        # winning is correct; unwritten trailing slots keep the sentinel
+        # ``num_rows`` (out of range → scattered with mode='drop').
+        uids = jnp.full((m,), num_rows, sid.dtype).at[seg].set(sid)
     return uids, gsum
 
 
@@ -147,11 +148,12 @@ def sparse_adagrad_update(
     paths)."""
     D = table.shape[-1]
     uids, gsum = dedup_rows(ids.reshape(-1), row_grads.reshape(-1, D), table.shape[0])
-    acc_prev = state.accum[uids]
-    if decay != 1.0:
-        acc_prev = decay * acc_prev
-    acc_rows = acc_prev + accum_sq(state.accum, gsum)  # sentinel lanes
-    upd_rows = table[uids] - lr * gsum / jnp.sqrt(acc_rows)  # dropped below
-    accum = state.accum.at[uids].set(acc_rows, mode="drop")
-    table = table.at[uids].set(upd_rows, mode="drop")
+    with jax.named_scope("fm.tail"):
+        acc_prev = state.accum[uids]
+        if decay != 1.0:
+            acc_prev = decay * acc_prev
+        acc_rows = acc_prev + accum_sq(state.accum, gsum)  # sentinel lanes
+        upd_rows = table[uids] - lr * gsum / jnp.sqrt(acc_rows)  # dropped below
+        accum = state.accum.at[uids].set(acc_rows, mode="drop")
+        table = table.at[uids].set(upd_rows, mode="drop")
     return table, AdagradState(accum)

@@ -36,6 +36,7 @@ These functions run INSIDE a shard_map body (parallel/train_step.py).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -98,6 +99,7 @@ def _bucketize(owner: jnp.ndarray, n_buckets: int, capacity: int):
     return order, sorted_owner, send_pos, in_cap, overflow
 
 
+@jax.named_scope("fm.gather")
 def routed_gather(
     table_shard: jnp.ndarray,
     ids: jnp.ndarray,
@@ -161,6 +163,7 @@ def routed_gather(
     return out.reshape(B, N, -1)
 
 
+@jax.named_scope("fm.tail")
 def routed_update(
     table_shard: jnp.ndarray,
     accum_shard: jnp.ndarray,

@@ -58,6 +58,7 @@ def owned_local_ids(global_ids, shard_logical_rows: int, sentinel: int):
     return jnp.where(owned, local, sentinel), owned
 
 
+@jax.named_scope("fm.tail")
 def apply_shard_adagrad(table_shard, accum_shard, guids, ggsum, lr, base, decay=1.0):
     """Adagrad on this shard's rows from globally-combined unique grads.
 
@@ -85,6 +86,7 @@ def apply_shard_adagrad(table_shard, accum_shard, guids, ggsum, lr, base, decay=
     return table_shard, accum_shard
 
 
+@jax.named_scope("fm.gather")
 def sharded_gather(table_shard: jax.Array, ids: jax.Array) -> jax.Array:
     """Assemble this chip's parameter rows from the row-sharded table.
 

@@ -619,15 +619,16 @@ def make_sharded_train_step(
 
         def loss_fn(rows, dense):
             scores = model.score(rows, dense, batch)
-            per = (
-                jnp.maximum(scores, 0.0)
-                - scores * batch.labels
-                + jnp.log1p(jnp.exp(-jnp.abs(scores)))
-            )
-            denom = jnp.maximum(lax.psum(jnp.sum(batch.weights), _BOTH), 1.0)
-            data_loss = jnp.sum(per * batch.weights) / denom
-            reg = model.regularization(rows, dense, batch)
-            return data_loss + reg, data_loss
+            with jax.named_scope("fm.loss"):
+                per = (
+                    jnp.maximum(scores, 0.0)
+                    - scores * batch.labels
+                    + jnp.log1p(jnp.exp(-jnp.abs(scores)))
+                )
+                denom = jnp.maximum(lax.psum(jnp.sum(batch.weights), _BOTH), 1.0)
+                data_loss = jnp.sum(per * batch.weights) / denom
+                reg = model.regularization(rows, dense, batch)
+                return data_loss + reg, data_loss
 
         grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)
 
@@ -644,10 +645,11 @@ def make_sharded_train_step(
                 )
                 (_, dl), (g_rows, g_dense) = grad_fn(rows, dense)
                 fmode = resolve_fused_update(packed_update, table.shape[0])
-                t2 = fused_sharded_update(
-                    table, batch.ids, g_rows, learning_rate,
-                    shard_logical_rows, mode=fmode, k_cap=compact_cap,
-                )
+                with jax.named_scope("fm.tail"):
+                    t2 = fused_sharded_update(
+                        table, batch.ids, g_rows, learning_rate,
+                        shard_logical_rows, mode=fmode, k_cap=compact_cap,
+                    )
                 return t2, accum, g_dense, dl
             if packed:
                 from fast_tffm_tpu.ops.packed_table import resolve_packed_update
@@ -664,16 +666,17 @@ def make_sharded_train_step(
                 mode = resolve_packed_update(
                     packed_update, table.shape[0], accum.shape[-1]
                 )
-                if mode in ("dense", "compact"):
-                    t2, a2 = packed_sharded_dense_update(
-                        table, accum, batch.ids, g_rows, learning_rate,
-                        shard_logical_rows, mode=mode,
-                    )
-                else:
-                    t2, a2 = packed_sharded_update(
-                        table, accum, batch.ids, g_rows, learning_rate,
-                        num_rows_global, shard_logical_rows,
-                    )
+                with jax.named_scope("fm.tail"):
+                    if mode in ("dense", "compact"):
+                        t2, a2 = packed_sharded_dense_update(
+                            table, accum, batch.ids, g_rows, learning_rate,
+                            shard_logical_rows, mode=mode,
+                        )
+                    else:
+                        t2, a2 = packed_sharded_update(
+                            table, accum, batch.ids, g_rows, learning_rate,
+                            num_rows_global, shard_logical_rows,
+                        )
                 return t2, a2, g_dense, dl
             rows = sharded_gather(table, batch.ids)
             (_, dl), (g_rows, g_dense) = grad_fn(rows, dense)

@@ -52,6 +52,7 @@ from fast_tffm_tpu.serving.buckets import BucketLadder
 from fast_tffm_tpu.serving.metrics import ServingMetrics
 from fast_tffm_tpu.serving.protocol import FRAME_STATUS_CODES, DeadlineExceeded
 from fast_tffm_tpu.telemetry import RunMonitor, log_device, log_quietly
+from fast_tffm_tpu.utils.tracing import span
 
 __all__ = [
     "ServingEngine",
@@ -100,7 +101,9 @@ class _Block:
     """A whole decoded REQUEST frame admitted as ONE unit: one queue
     slot, one decode, one coalesced placement, one response.  ``future``
     resolves to ``(statuses u8[n], scores f32[n])`` — nonzero statuses
-    index FRAME_STATUS_CODES, so per-row typed errors survive batching.
+    index FRAME_STATUS_CODES, so per-row typed errors survive batching;
+    once a flush has claimed the block, ``future.flush_seq`` is the flush
+    it rode (``serve.flush`` carries the same number in the trace).
     Tier is the MINIMUM over its rows: under tiered overload a mixed
     frame sheds as its weakest member (a frame is one delivery unit; a
     caller who needs gold treatment must not staple gold rows to std
@@ -225,7 +228,11 @@ class ServingEngine:
         )
         self._flush_seq = 0  # telemetry step for serving = flush ordinal
         self._metrics_every = cfg.serve_metrics_every_s
-        self._last_metrics_log = time.perf_counter()
+        self._wait_s = 0.0  # collector: blocked in q.get since the last flush
+        # Held by the collector from a flush's start until it is accounted:
+        # ``metrics_snapshot`` takes it, so a caller whose future resolved
+        # (on the collector, mid-flush) reads counters that hold its flush.
+        self._flush_lock = threading.Lock()
         self._closed = False  # no new submits (set by close AND by a
         #   collector crash — see _collect's exception handler)
         self._close_done = False  # close() finalization ran (separate
@@ -667,10 +674,14 @@ class ServingEngine:
                 elif draining:
                     # Close requested and everything flushed: done.
                     return
+                t_wait = time.perf_counter()
                 try:
-                    item = self._q.get(timeout=timeout)
+                    with span("serve.collect_wait"):
+                        item = self._q.get(timeout=timeout)
                 except queue.Empty:
                     continue
+                finally:
+                    self._wait_s += time.perf_counter() - t_wait
                 if item is _CLOSE:
                     # Flush what's pending plus anything still queued, in
                     # max_batch groups, then exit.
@@ -745,17 +756,36 @@ class ServingEngine:
         chunk_rows = 0
         for item in pending:
             if chunk and chunk_rows + item.n_rows > self.max_batch:
-                self._flush_units(chunk, deadline_fired)
+                self._flush_units(chunk, chunk_rows, deadline_fired)
                 chunk = []
                 chunk_rows = 0
             chunk.append(item)
             chunk_rows += item.n_rows
         if chunk:
-            self._flush_units(chunk, deadline_fired)
+            self._flush_units(chunk, chunk_rows, deadline_fired)
 
     def _flush_units(
-        self, pending: "list[_Request | _Block]", deadline_fired: bool
+        self, pending: "list[_Request | _Block]", rows: int, deadline_fired: bool
     ) -> None:
+        """One dispatch: ``serve.flush`` with its four stages as children,
+        then the bookkeeping (which is the collector's time but no part of
+        any request's latency, so outside the span)."""
+        with self._flush_lock:
+            t_start = time.perf_counter()
+            with span(
+                "serve.flush",
+                flush_seq=self._flush_seq + 1,
+                rows=rows,
+                deadline_fired=int(deadline_fired),
+            ):
+                scored = self._score_units(pending, t_start)
+            if scored is not None:
+                self._account_flush(t_start, deadline_fired, *scored)
+
+    def _shed(self, pending: "list[_Request | _Block]", now: float):
+        """Claim the futures and shed what has already missed its own
+        deadline; (live per-row requests in order, (block, alive idx)
+        pairs, live rows)."""
         # Claim the futures: a pending Future is always cancellable, and
         # resolving a cancelled one raises InvalidStateError — which,
         # unguarded, would kill the collector over ONE impatient caller.
@@ -768,12 +798,13 @@ class ServingEngine:
         # nobody is waiting for.  Shedding first can also shrink the
         # bucket the survivors pad to (the bucket is picked AFTER the
         # shed, over the whole coalesced flush).
-        now = time.perf_counter()
-        reqs: list[_Request] = []  # live per-row requests, in order
-        blocks: list[tuple[_Block, np.ndarray]] = []  # (block, alive idx)
+        seq = self._flush_seq + 1
+        reqs: list[_Request] = []
+        blocks: list[tuple[_Block, np.ndarray]] = []
         n_alive = 0
         for r in pending:
             if isinstance(r, _Block):
+                r.future.flush_seq = seq
                 st = r.statuses
                 expired = (now >= r.deadline_t) & (st == _ST_OK)
                 if expired.any():
@@ -795,60 +826,90 @@ class ServingEngine:
             else:
                 reqs.append(r)
                 n_alive += 1
-        if n_alive == 0:
-            # Every row shed — blocks still owe their ONE response (the
-            # shed rows' typed codes travel in it).  Still PROGRESS: the
-            # collector drained (and answered) work — an all-shed flush
-            # must advance the liveness clock or a tight-deadline
-            # overload reads as a wedged collector to the router's
-            # health checks.
-            for b, _ in blocks:
-                b.future.set_result((b.statuses, np.zeros(b.n_rows, np.float32)))
-            self._last_flush_t = time.perf_counter()
-            return
-        if self._slow_flushes > 0:  # injected latency (chaos replica_slow)
-            self._slow_flushes -= 1
-            time.sleep(self._slow_ms / 1e3)
-        t_start = time.perf_counter()
-        try:
-            parts = [(r.row[0][None], r.row[1][None], r.row[2][None]) for r in reqs]
-            parts += [
-                (
-                    b.ids[alive],
-                    b.vals[alive],
-                    b.fields[alive] if b.fields is not None else None,
-                )
-                for b, alive in blocks
-            ]
-            batch, bucket = self._ladder.assemble_parts(parts)
-            t_dispatch = time.perf_counter()
-            scores = np.asarray(self._ladder.score(self._state, batch))
-            t_done = time.perf_counter()
-        except BaseException as e:
-            for r in reqs:
-                if not r.future.done():
-                    r.future.set_exception(e)
-            for b, alive in blocks:
-                if not b.future.done():
-                    # Blocks resolve, never raise: already-decided rows
-                    # (deadline/bad_request) keep their codes; only the
-                    # would-have-scored rows become unavailable.
-                    st = b.statuses.copy()
-                    st[alive] = _ST_UNAVAILABLE
-                    b.future.set_result((st, np.zeros(b.n_rows, np.float32)))
-            log_quietly(self._log, f"serving: flush failed: {e!r}")
-            self._last_flush_t = time.perf_counter()  # answered = progress
-            return
-        pos = 0
+        return reqs, blocks, n_alive
+
+    def _fail_flush(self, reqs, blocks, e: BaseException) -> None:
+        """A flush that raised is answered typed, never stranded."""
         for r in reqs:
-            r.future.set_result(float(scores[pos]))
-            pos += 1
+            if not r.future.done():
+                r.future.set_exception(e)
         for b, alive in blocks:
-            out = np.zeros(b.n_rows, np.float32)
-            out[alive] = scores[pos : pos + alive.size]
-            pos += int(alive.size)
-            b.future.set_result((b.statuses, out))
+            if not b.future.done():
+                # Blocks resolve, never raise: already-decided rows
+                # (deadline/bad_request) keep their codes; only the
+                # would-have-scored rows become unavailable.
+                st = b.statuses.copy()
+                st[alive] = _ST_UNAVAILABLE
+                b.future.set_result((st, np.zeros(b.n_rows, np.float32)))
+        log_quietly(self._log, f"serving: flush failed: {e!r}")
+        self._last_flush_t = time.perf_counter()  # answered = progress
+
+    def _score_units(self, pending: "list[_Request | _Block]", t_start: float):
+        """Shed and assemble, dispatch, fetch, reply: four stages that tile
+        ``t_start`` → ``t_resolved``, each a span and a clock.  Returns what
+        ``_account_flush`` records, or None when nothing was scored (all
+        rows shed, or the flush failed and was answered typed)."""
+        # The shed, the chaos sleep, packing, the one H2D put and the unpack
+        # program's dispatch (packed wire: buckets._finalize).
+        with span("serve.assemble"):
+            reqs, blocks, n_alive = self._shed(pending, t_start)
+            if n_alive == 0:
+                # Every row shed — blocks still owe their ONE response (the
+                # shed rows' typed codes travel in it).  Still PROGRESS: the
+                # collector drained (and answered) work — an all-shed flush
+                # must advance the liveness clock or a tight-deadline
+                # overload reads as a wedged collector to the router's
+                # health checks.
+                for b, _ in blocks:
+                    b.future.set_result((b.statuses, np.zeros(b.n_rows, np.float32)))
+                self._last_flush_t = time.perf_counter()
+                return None
+            if self._slow_flushes > 0:  # injected latency (chaos replica_slow)
+                self._slow_flushes -= 1
+                time.sleep(self._slow_ms / 1e3)
+            try:
+                parts = [(r.row[0][None], r.row[1][None], r.row[2][None]) for r in reqs]
+                parts += [
+                    (
+                        b.ids[alive],
+                        b.vals[alive],
+                        b.fields[alive] if b.fields is not None else None,
+                    )
+                    for b, alive in blocks
+                ]
+                batch, bucket = self._ladder.assemble_parts(parts)
+            except BaseException as e:
+                return self._fail_flush(reqs, blocks, e)
+        t_dispatch = time.perf_counter()
+        try:
+            with span("serve.dispatch", bucket=bucket, rows=n_alive):
+                on_device = self._ladder.score(self._state, batch)
+            t_fetch = time.perf_counter()
+            with span("serve.fetch"):
+                scores = np.asarray(on_device)
+                del on_device  # the device buffer goes now, not after the reply
+        except BaseException as e:
+            return self._fail_flush(reqs, blocks, e)
+        t_done = time.perf_counter()
+        # Resolving a future runs its done-callbacks HERE, on this thread:
+        # for a replica that is the SCORES packing and the socket send.
+        with span("serve.reply"):
+            pos = 0
+            for r in reqs:
+                r.future.set_result(float(scores[pos]))
+                pos += 1
+            for b, alive in blocks:
+                out = np.zeros(b.n_rows, np.float32)
+                out[alive] = scores[pos : pos + alive.size]
+                pos += int(alive.size)
+                b.future.set_result((b.statuses, out))
         t_resolved = time.perf_counter()
+        stages = (t_dispatch - t_start, t_fetch - t_dispatch, t_done - t_fetch, t_resolved - t_done)
+        return reqs, blocks, n_alive, bucket, t_resolved, stages
+
+    def _account_flush(
+        self, t_start, deadline_fired, reqs, blocks, n_alive, bucket, t_resolved, stages
+    ) -> None:
         self._flush_seq += 1
         if self._pending_fresh is not None:
             self._emit_freshness()
@@ -868,18 +929,25 @@ class ServingEngine:
             n_alive,
             queue_waits=[t_start - r.t_submit for r in reqs]
             + [t_start - b.t_submit for b, _ in blocks],
-            compute_s=t_done - t_dispatch,
+            compute_s=stages[1] + stages[2],
             total_s=[t_resolved - r.t_submit for r in reqs]
             + [t_resolved - b.t_submit for b, _ in blocks],
             deadline_fired=deadline_fired,
             classes=[r.klass for r in reqs] + [b.klass for b, _ in blocks],
             counts=[1] * len(reqs) + [int(alive.size) for _, alive in blocks],
+            t_start=t_start,
+            t_resolved=t_resolved,
+            stages=stages,
+            wait_s=self._wait_s,
         )
+        self._wait_s = 0.0
+        # A record is due ``serve_metrics_every_s`` after its interval
+        # opened (the first flush since the previous record), so a record
+        # never holds just the one row that woke an idle server.
         if (
             self._metrics_every > 0
-            and t_resolved - self._last_metrics_log >= self._metrics_every
+            and self.metrics.interval_age(t_resolved) >= self._metrics_every
         ):
-            self._last_metrics_log = t_resolved
             try:
                 self.metrics.log_to(self._monitor)
             except (OSError, ValueError):
@@ -1157,7 +1225,8 @@ class ServingEngine:
     # -- shutdown --------------------------------------------------------
 
     def metrics_snapshot(self) -> dict:
-        return self.metrics.snapshot()
+        with self._flush_lock:
+            return self.metrics.snapshot()
 
     def close(self, timeout: float = 30.0) -> None:
         """Stop accepting, flush everything already admitted, stop the

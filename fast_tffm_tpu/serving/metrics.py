@@ -11,6 +11,7 @@ for utils.tracing.MetricsLogger.
 
 from __future__ import annotations
 
+import math
 import threading
 
 import numpy as np
@@ -31,7 +32,9 @@ class LatencyHistogram:
         if not (0 < lo < hi) or bins < 2:
             raise ValueError(f"bad histogram spec lo={lo} hi={hi} bins={bins}")
         self._edges = np.geomspace(lo, hi, bins + 1)
-        self._counts = np.zeros(bins, np.int64)
+        self._lo = lo
+        self._bins_per_log = bins / math.log(hi / lo)
+        self._counts = [0] * bins  # a list: adds are per flush, reads are rare
         self._n = 0
         self._sum = 0.0
         self._min = float("inf")
@@ -45,8 +48,11 @@ class LatencyHistogram:
         whole-frame flush records its rows without k searchsorted calls."""
         if k <= 0:
             return
-        i = int(np.searchsorted(self._edges, seconds, side="right")) - 1
-        self._counts[min(max(i, 0), self._counts.size - 1)] += k
+        # The bin by arithmetic, not by a search of the edges: a flush adds a
+        # dozen samples on the collector's thread, and there a microsecond
+        # is four of latency (PERF.md, PR 26).
+        i = int(math.log(seconds / self._lo) * self._bins_per_log) if seconds > self._lo else 0
+        self._counts[min(i, len(self._counts) - 1)] += k
         self._n += k
         self._sum += seconds * k
         self._min = min(self._min, seconds)
@@ -56,22 +62,42 @@ class LatencyHistogram:
     def count(self) -> int:
         return self._n
 
+    def _interpolated(self, counts: np.ndarray, n: int, q: float) -> float:
+        """Quantile ``q`` of ``n`` samples binned as ``counts``,
+        log-interpolated inside the hit bin."""
+        target = q * n
+        cum = np.cumsum(counts)
+        i = int(np.searchsorted(cum, target, side="left"))
+        i = min(i, counts.size - 1)
+        prev = float(cum[i - 1]) if i > 0 else 0.0
+        inbin = float(counts[i])
+        frac = (target - prev) / inbin if inbin > 0 else 0.0
+        lo, hi = self._edges[i], self._edges[i + 1]
+        return float(lo * (hi / lo) ** min(max(frac, 0.0), 1.0))
+
     def quantile(self, q: float) -> float:
         """Value at quantile ``q`` (log-interpolated inside the hit bin);
         nan when empty.  Clamped by the exact min/max so a one-sample
         histogram reports the sample, not its bin edge."""
         if self._n == 0:
             return float("nan")
-        target = q * self._n
-        cum = np.cumsum(self._counts)
-        i = int(np.searchsorted(cum, target, side="left"))
-        i = min(i, self._counts.size - 1)
-        prev = float(cum[i - 1]) if i > 0 else 0.0
-        inbin = float(self._counts[i])
-        frac = (target - prev) / inbin if inbin > 0 else 0.0
-        lo, hi = self._edges[i], self._edges[i + 1]
-        v = float(lo * (hi / lo) ** min(max(frac, 0.0), 1.0))
+        v = self._interpolated(self.counts(), self._n, q)
         return min(max(v, self._min), self._max)
+
+    def counts(self) -> np.ndarray:
+        """The bin counts as an array of their own: what ``quantile_since``
+        differences."""
+        return np.array(self._counts, np.int64)
+
+    def quantile_since(self, prev_counts: np.ndarray, q: float) -> float:
+        """Quantile ``q`` over the samples added since ``prev_counts`` was
+        taken; nan when none were.  The exact min/max are cumulative, so
+        here only the bin edges bound the answer (~13% a bin)."""
+        counts = self.counts() - prev_counts
+        n = int(counts.sum())
+        if n == 0:
+            return float("nan")
+        return self._interpolated(counts, n, q)
 
     def snapshot(self) -> dict:
         """{count, mean, p50, p95, p99, max} in MILLISECONDS (the unit
@@ -99,7 +125,19 @@ class ServingMetrics:
     Stages: ``queue`` (submit → flush start: micro-batching wait +
     deadline), ``compute`` (device dispatch → scores on host, whole
     flush), ``total`` (submit → future resolved, what a caller feels).
+
+    Everything is cumulative from construction, and ``snapshot()`` only
+    reads.  ``log_to()`` also differences: beside the counters it keeps
+    their state at the previous record (``_mark``), and each record adds
+    flat fields over the interval since — the window's own p50s, the
+    stage clocks of engine.py and replica.py (``serve.*`` spans, same
+    names on the profiler's host plane) and the collector's busy share.
+    An interval opens at the first flush after a record and ends at the
+    newest flush, so idle time before, between and after traffic is in
+    no interval and a record with no flush behind it carries no fields.
     """
+
+    STAGES = ("assemble", "dispatch", "fetch", "reply")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -140,6 +178,30 @@ class ServingMetrics:
         # so cross-host skew is the documented error bar.
         self.fresh_applied = LatencyHistogram()
         self.fresh_scored = LatencyHistogram()
+        # Stage clocks: seconds summed where the work happens.
+        self.frames = 0  # REQUEST frames a replica reader took in
+        self.frame_in_s = 0.0  # header read → submit_block returned
+        self.stage_s = [0.0] * len(self.STAGES)  # per flush, STAGES order
+        # The open interval: its first flush's start, its newest flush's
+        # end, and the collector's q.get waits between those two.
+        self._open_t = None
+        self._last_t = 0.0
+        self._wait_s = 0.0
+        self._mark = self._totals()
+
+    def _totals(self) -> dict:
+        """What ``log_to`` differences (caller holds the lock, or is
+        ``__init__``)."""
+        return {
+            "flushes": self.flushes,
+            "flushes_deadline": self.flushes_deadline,
+            "frames": self.frames,
+            "frame_in_s": self.frame_in_s,
+            "stage_s": tuple(self.stage_s),
+            "queue": self.queue.counts(),
+            "compute": self.compute.counts(),
+            "total": self.total.counts(),
+        }
 
     @staticmethod
     def _class_key(klass: str) -> str:
@@ -189,12 +251,27 @@ class ServingMetrics:
         deadline_fired: bool,
         classes: list[str] | None = None,
         counts: list[int] | None = None,
+        *,
+        t_start: float,
+        t_resolved: float,
+        stages: tuple[float, ...],
+        wait_s: float,
     ) -> None:
         """``queue_waits``/``total_s``/``classes`` are parallel per-GROUP
         lists; ``counts[i]`` is how many rows share entry i (a whole
         frame's rows enter as one group — None = every group is 1 row,
-        the per-request path)."""
+        the per-request path).  ``stages`` are this flush's seconds in
+        STAGES order (they tile ``t_start`` → ``t_resolved``); ``wait_s``
+        is what the collector spent blocked in ``q.get`` since the
+        previous flush."""
         with self._lock:
+            if self._open_t is None:
+                self._open_t = t_start  # the wait before it is idle time
+            else:
+                self._wait_s += wait_s
+            self._last_t = t_resolved
+            for i, s in enumerate(stages):
+                self.stage_s[i] += s
             self.flushes += 1
             if deadline_fired:
                 self.flushes_deadline += 1
@@ -220,6 +297,19 @@ class ServingMetrics:
                     if h is None:
                         h = self.class_total[k] = LatencyHistogram()
                     h.add_many(t, c)
+
+    def on_frame_in(self, seconds: float) -> None:
+        """One REQUEST frame taken in by a replica reader thread (header
+        read → ``submit_block`` returned, admitted or not)."""
+        with self._lock:
+            self.frames += 1
+            self.frame_in_s += seconds
+
+    def interval_age(self, now: float) -> float:
+        """Seconds since the open interval's first flush began; 0.0 when
+        no flush has followed the previous record."""
+        t = self._open_t
+        return 0.0 if t is None else now - t
 
     def on_reload(self, ok: bool) -> None:
         with self._lock:
@@ -261,6 +351,7 @@ class ServingMetrics:
                 "class_total_ms": {
                     k: h.snapshot() for k, h in sorted(self.class_total.items())
                 },
+                "frames": self.frames,
                 "flushes": self.flushes,
                 "flushes_deadline": self.flushes_deadline,
                 "flushes_full": self.flushes_full,
@@ -291,12 +382,51 @@ class ServingMetrics:
                 "freshness_scored_ms": self.fresh_scored.snapshot(),
             }
 
+    def _close_interval(self) -> dict:
+        """The flat fields over the interval since the previous record
+        ({} when no flush followed it), and the new mark."""
+        with self._lock:
+            cur = self._totals()
+            prev, self._mark = self._mark, cur
+            open_t, self._open_t = self._open_t, None
+            wait_s, self._wait_s = self._wait_s, 0.0
+            if open_t is None:
+                return {}
+            interval_s = self._last_t - open_t
+            p50 = {
+                f"{k}_ms_p50_interval": round(1e3 * h.quantile_since(prev[k], 0.5), 4)
+                for k, h in (("queue", self.queue), ("compute", self.compute), ("total", self.total))
+            }
+        flushes = cur["flushes"] - prev["flushes"]
+        frames = cur["frames"] - prev["frames"]
+        mean_ms = lambda s, n: round(1e3 * s / n, 4) if n else None
+        return {
+            "interval_s": round(interval_s, 4),
+            "interval_flushes": flushes,
+            "interval_frames": frames,
+            **p50,
+            "frame_in_ms": mean_ms(cur["frame_in_s"] - prev["frame_in_s"], frames),
+            **{
+                f"{name}_ms": mean_ms(c - p, flushes)
+                for name, c, p in zip(self.STAGES, cur["stage_s"], prev["stage_s"])
+            },
+            # The collector's time outside q.get: flushes AND their
+            # bookkeeping (histograms, compile sentinel, this record).
+            "collector_busy_share": (
+                round(1.0 - wait_s / interval_s, 4) if interval_s > 0 else None
+            ),
+            "deadline_flush_share": round(
+                (cur["flushes_deadline"] - prev["flushes_deadline"]) / flushes, 4
+            ),
+        }
+
     def log_to(self, sink) -> None:
-        """Append the snapshot as a ``kind=serving`` record.  ``sink`` is
+        """Append the snapshot as a ``kind=serving`` record, with the
+        interval's fields, and close the interval.  ``sink`` is
         a telemetry.RunMonitor (the engine's — records get the shared
         envelope) or, for bare callers, a utils.tracing.MetricsLogger
         (no-op logger ⇒ no-op here)."""
-        snap = self.snapshot()
+        snap = {**self.snapshot(), **self._close_interval()}
         emit = getattr(sink, "emit", None)
         if emit is not None:
             emit("serving", **snap)

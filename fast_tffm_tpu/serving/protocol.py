@@ -78,6 +78,8 @@ __all__ = [
     "encode",
     "decode",
     "read_frame",
+    "read_frame_header",
+    "read_frame_payload",
     "pack_request_frame",
     "unpack_request_frame",
     "pack_scores_frame",
@@ -197,16 +199,11 @@ def _read_exact(reader, n: int) -> bytes:
     return buf
 
 
-def read_frame(reader):
-    """Read one frame from a buffered binary reader.
-
-    Returns ``(kind, flags, count, width, payload)``; ``None`` on clean
-    EOF at a frame boundary.  Raises BadRequest for anything torn: a
-    truncated header, wrong magic/version (framing is lost — the caller
-    should answer with an ERROR frame and close), an absurd payload
-    length, or EOF mid-payload.  Never hangs on a well-formed header:
-    at most ``payload`` more bytes are awaited.
-    """
+def read_frame_header(reader):
+    """Block for one frame header.  Returns ``(kind, flags, count, width,
+    payload_len)``; ``None`` on clean EOF at a frame boundary; raises
+    BadRequest for a torn or foreign header (framing is lost — the caller
+    should answer with an ERROR frame and close)."""
     hdr = _read_exact(reader, FRAME_HEADER.size)
     if not hdr:
         return None
@@ -219,10 +216,32 @@ def read_frame(reader):
         raise BadRequest(f"unsupported frame version {version} (want {FRAME_VERSION})")
     if payload_len > FRAME_MAX_PAYLOAD:
         raise BadRequest(f"frame payload {payload_len} exceeds max {FRAME_MAX_PAYLOAD}")
+    return kind, flags, count, width, payload_len
+
+
+def read_frame_payload(reader, payload_len: int) -> bytes:
+    """The ``payload_len`` bytes a header announced; BadRequest on EOF
+    mid-payload.  Never hangs on a well-formed header: at most
+    ``payload_len`` more bytes are awaited."""
     payload = _read_exact(reader, payload_len)
     if len(payload) < payload_len:
         raise BadRequest(f"truncated frame payload ({len(payload)}/{payload_len} bytes)")
-    return kind, flags, count, width, payload
+    return payload
+
+
+def read_frame(reader):
+    """Read one frame from a buffered binary reader.
+
+    Returns ``(kind, flags, count, width, payload)``; ``None`` on clean
+    EOF at a frame boundary.  Raises BadRequest for anything torn: a
+    truncated header, wrong magic/version, an absurd payload length, or
+    EOF mid-payload.  (The replica's reader takes the two halves apart:
+    the wait for a header is idle time, the rest is ``serve.frame_in``.)
+    """
+    hdr = read_frame_header(reader)
+    if hdr is None:
+        return None
+    return (*hdr[:4], read_frame_payload(reader, hdr[4]))
 
 
 def _header(kind: int, flags: int, count: int, width: int, payload: bytes) -> bytes:

@@ -48,6 +48,7 @@ import os
 import socket
 import sys
 import threading
+import time
 
 import numpy as np
 
@@ -62,9 +63,11 @@ from fast_tffm_tpu.serving.protocol import (
     exc_code,
     pack_error_frame,
     pack_scores_frame,
-    read_frame,
+    read_frame_header,
+    read_frame_payload,
     unpack_request_frame,
 )
+from fast_tffm_tpu.utils.tracing import span
 
 __all__ = ["run_replica", "main"]
 
@@ -222,49 +225,78 @@ class _Conn:
         """Binary DATA loop (post-hello).  Torn input never hangs or
         silently drops the socket: an undecodable PAYLOAD (header intact,
         stream still synced) gets an ERROR frame and the loop continues;
-        a broken HEADER (framing lost — resync is impossible on a byte
-        stream) gets an ERROR frame and THEN the connection closes."""
+        a broken HEADER or a payload cut short (framing lost — resync is
+        impossible on a byte stream) gets an ERROR frame and THEN the
+        connection closes.
+
+        The wait for a header is idle time; from the header on the frame
+        is ``serve.frame_in`` (payload read, decode, admission), a span
+        and a clock of the engine's metrics."""
         while True:
             try:
-                fr = read_frame(buf)
+                hdr = read_frame_header(buf)
             except BadRequest as e:
                 self.send_bytes(pack_error_frame("bad_request", str(e)))
                 return False
-            if fr is None:
+            if hdr is None:
                 return False  # clean EOF at a frame boundary
-            kind, flags, count, width, payload = fr
-            if kind != FRAME_KIND_REQUEST:
-                self.send_bytes(
-                    pack_error_frame("bad_request", f"unexpected frame kind {kind}")
-                )
-                continue
-            try:
-                d = unpack_request_frame(flags, count, width, payload)
-            except BadRequest as e:
-                self.send_bytes(pack_error_frame("bad_request", str(e)))
-                continue
-            req_ids = d["req_ids"]
-            try:
-                fut = self._engine.submit_block(
-                    d["ids"],
-                    d["vals"],
-                    d["fields"],
-                    deadlines_ms=d["deadlines_ms"],
-                    classes=d["classes"],
-                )
-            except Exception as e:
-                self._answer_all(req_ids, exc_code(e))
-                continue
+            t_in = time.perf_counter()
+            with span("serve.frame_in", req_id=_first_req_id(buf, hdr[4]), rows=hdr[2]):
+                synced = self._take_frame(buf, *hdr)
+            self._engine.metrics.on_frame_in(time.perf_counter() - t_in)
+            if not synced:
+                return False
 
-            def done(f, req_ids=req_ids):
-                exc = f.exception()
-                if exc is None:
-                    statuses, scores = f.result()
-                    self.send_bytes(pack_scores_frame(req_ids, statuses, scores))
-                else:
-                    self._answer_all(req_ids, exc_code(exc))
+    def _take_frame(self, buf, kind, flags, count, width, payload_len) -> bool:
+        """Read, decode and admit one frame; False when framing is lost."""
+        try:
+            payload = read_frame_payload(buf, payload_len)
+        except BadRequest as e:
+            self.send_bytes(pack_error_frame("bad_request", str(e)))
+            return False
+        if kind != FRAME_KIND_REQUEST:
+            self.send_bytes(
+                pack_error_frame("bad_request", f"unexpected frame kind {kind}")
+            )
+            return True
+        try:
+            d = unpack_request_frame(flags, count, width, payload)
+        except BadRequest as e:
+            self.send_bytes(pack_error_frame("bad_request", str(e)))
+            return True
+        req_ids = d["req_ids"]
+        try:
+            fut = self._engine.submit_block(
+                d["ids"],
+                d["vals"],
+                d["fields"],
+                deadlines_ms=d["deadlines_ms"],
+                classes=d["classes"],
+            )
+        except Exception as e:
+            self._answer_all(req_ids, exc_code(e))
+            return True
 
-            fut.add_done_callback(done)
+        def done(f):  # on the collector's thread, inside ``serve.reply``
+            exc = f.exception()
+            if exc is None:
+                statuses, scores = f.result()
+                self.send_bytes(pack_scores_frame(req_ids, statuses, scores))
+            else:
+                self._answer_all(req_ids, exc_code(exc))
+
+        fut.add_done_callback(done)
+        return True
+
+
+def _first_req_id(buf, payload_len: int) -> int:
+    """The first row's request id, read ahead of the payload (its first
+    four bytes) so that ``serve.frame_in`` can carry it from its start;
+    -1 when the reader has not buffered that far."""
+    if payload_len < 4:
+        return -1
+    head = buf.peek(4)
+    return int.from_bytes(head[:4], "little") if len(head) >= 4 else -1
 
 
 def run_replica(

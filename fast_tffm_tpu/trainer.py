@@ -76,12 +76,23 @@ def init_state(
     )
 
 
+def gather_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
+    """``table[ids]``: the rows-layout gather of touched rows only, under
+    the step's ``fm.gather`` scope (the packed and sharded gathers carry
+    the same name where they live)."""
+    with jax.named_scope("fm.gather"):
+        return table[ids]
+
+
 def batch_loss(model, table_rows, dense, batch: Batch):
-    """(total loss with L2, plain data loss) — shared with the sharded step."""
+    """(total loss with L2, plain data loss).  The scorer names itself
+    ``fm.interaction`` (ops/fm.py); its backward arrives in the compiled
+    step as ``transpose(jvp(fm.interaction))``."""
     scores = model.score(table_rows, dense, batch)
-    data_loss = logistic_loss(scores, batch.labels, batch.weights)
-    reg = model.regularization(table_rows, dense, batch)
-    return data_loss + reg, data_loss
+    with jax.named_scope("fm.loss"):
+        data_loss = logistic_loss(scores, batch.labels, batch.weights)
+        reg = model.regularization(table_rows, dense, batch)
+        return data_loss + reg, data_loss
 
 
 def train_step_body(
@@ -96,7 +107,7 @@ def train_step_body(
     ``decay`` is the online-learning ``[Online] adagrad_decay`` γ (lazy
     touched-row accumulator decay — optim.sparse_adagrad_update); γ=1.0
     branches back to the exact classic program at trace time."""
-    rows = state.table[batch.ids]  # [B, N, D] gather of touched rows only
+    rows = gather_rows(state.table, batch.ids)  # [B, N, D]
 
     grad_fn = jax.value_and_grad(
         partial(batch_loss, model), argnums=(0, 1), has_aux=True
@@ -171,21 +182,22 @@ def make_pallas_tail_body(decay: float = 1.0, interpret: bool | None = None):
     def body(model, learning_rate, state: TrainState, batch: Batch):
         from fast_tffm_tpu.ops.pallas_tail import rows_tail_adagrad_update
 
-        rows = state.table[batch.ids]
+        rows = gather_rows(state.table, batch.ids)
         grad_fn = jax.value_and_grad(
             partial(batch_loss, model), argnums=(0, 1), has_aux=True
         )
         (_, data_loss), (g_rows, g_dense) = grad_fn(rows, state.dense, batch)
 
-        table, accum = rows_tail_adagrad_update(
-            state.table,
-            state.table_opt.accum,
-            batch.ids,
-            g_rows,
-            learning_rate,
-            decay=decay,
-            interpret=interpret,
-        )
+        with jax.named_scope("fm.tail"):
+            table, accum = rows_tail_adagrad_update(
+                state.table,
+                state.table_opt.accum,
+                batch.ids,
+                g_rows,
+                learning_rate,
+                decay=decay,
+                interpret=interpret,
+            )
         dense, dense_opt = state.dense, state.dense_opt
         if jax.tree.leaves(state.dense):
             dense, dense_opt = dense_adagrad_update(
@@ -225,10 +237,11 @@ def make_dedup_body(cap: int, decay: float = 1.0):
         flat = batch.ids.reshape(-1)
         # Sorted unique ids padded with the out-of-range sentinel ``v``
         # (the gather clamps it to a row whose value is never used).
-        uids = jnp.unique(flat, size=cap, fill_value=v)
-        compact = state.table[jnp.minimum(uids, v - 1)]
-        inv = jnp.searchsorted(uids, flat)
-        rows = compact[inv].reshape(*batch.ids.shape, d)
+        with jax.named_scope("fm.gather"):
+            uids = jnp.unique(flat, size=cap, fill_value=v)
+            compact = state.table[jnp.minimum(uids, v - 1)]
+            inv = jnp.searchsorted(uids, flat)
+            rows = compact[inv].reshape(*batch.ids.shape, d)
 
         grad_fn = jax.value_and_grad(
             partial(batch_loss, model), argnums=(0, 1), has_aux=True
@@ -319,7 +332,7 @@ def make_predict_step(model):
 
     @jax.jit
     def predict(state: TrainState, batch: Batch):
-        rows = state.table[batch.ids]
+        rows = gather_rows(state.table, batch.ids)
         return jax.nn.sigmoid(model.score(rows, state.dense, batch))
 
     return predict
@@ -424,29 +437,32 @@ def packed_train_step_body(
     )
     (_, data_loss), (g_rows, g_dense) = grad_fn(rows, state.dense, batch)
 
-    if fused:
-        if tail == "pallas":
-            from fast_tffm_tpu.ops.pallas_tail import fused_tail_adagrad_update
+    # Every packed tail (dense / compact / sorted / fused / Pallas) runs
+    # under the rows layout's name for the same stage.
+    with jax.named_scope("fm.tail"):
+        if fused:
+            if tail == "pallas":
+                from fast_tffm_tpu.ops.pallas_tail import fused_tail_adagrad_update
 
-            table = fused_tail_adagrad_update(
-                state.table, batch.ids, g_rows, learning_rate,
-                k_cap=compact_cap,
-            )
+                table = fused_tail_adagrad_update(
+                    state.table, batch.ids, g_rows, learning_rate,
+                    k_cap=compact_cap,
+                )
+            else:
+                from fast_tffm_tpu.ops.packed_table import apply_fused_update
+
+                mode = resolve_fused_update(update, state.table.shape[0])
+                table = apply_fused_update(
+                    state.table, batch.ids, g_rows, learning_rate, mode,
+                    compact_cap,
+                )
+            accum = acc
         else:
-            from fast_tffm_tpu.ops.packed_table import apply_fused_update
-
-            mode = resolve_fused_update(update, state.table.shape[0])
-            table = apply_fused_update(
-                state.table, batch.ids, g_rows, learning_rate, mode,
-                compact_cap,
+            mode = resolve_packed_update(update, state.table.shape[0], acc.shape[-1])
+            update_fn = PACKED_UPDATE_FNS[mode]
+            table, accum = update_fn(
+                state.table, acc, batch.ids, g_rows, learning_rate
             )
-        accum = acc
-    else:
-        mode = resolve_packed_update(update, state.table.shape[0], acc.shape[-1])
-        update_fn = PACKED_UPDATE_FNS[mode]
-        table, accum = update_fn(
-            state.table, acc, batch.ids, g_rows, learning_rate
-        )
     dense, dense_opt = state.dense, state.dense_opt
     if jax.tree.leaves(state.dense):
         dense, dense_opt = dense_adagrad_update(

@@ -38,7 +38,7 @@ from fast_tffm_tpu.resilience import (
 from fast_tffm_tpu.telemetry import RunMonitor, log_device
 from fast_tffm_tpu.trainer import init_state, make_predict_step, make_train_step
 from fast_tffm_tpu.utils.prefetch import PrefetchError, prefetch
-from fast_tffm_tpu.utils.tracing import WindowTracer, step_trace
+from fast_tffm_tpu.utils.tracing import span, step_trace
 
 __all__ = ["train", "dist_train", "scan_max_nnz"]
 
@@ -526,6 +526,41 @@ def _resolve_cursor(cfg: Config, cursor, log) -> tuple[int, int]:
     return e, b
 
 
+def _timed_next(stream, clocks: dict):
+    """Iterate ``stream``, adding the time the caller spends inside its
+    ``next()`` — blocked on the input — to ``clocks["wait_s"]``."""
+    it = iter(stream)
+    while True:
+        t0 = time.perf_counter()
+        try:
+            item = next(it)
+        except StopIteration:
+            return
+        finally:
+            clocks["wait_s"] += time.perf_counter() - t0
+        yield item
+
+
+def _window_clocks(clocks: dict, steps: int, sync_s: float) -> dict:
+    """The kind=train record's stage fields, and the next window opened.
+    Per step of the log window: ``wait_ms`` (in the input's ``next()``),
+    ``dispatch_ms`` (inside ``step_fn``), ``host_ms`` (the rest of the
+    loop thread's time); ``sync_ms`` is the one loss fetch at the
+    boundary — the host's slack, since it waits for the device there.
+    wait + dispatch + host + sync/steps is the wall time a step."""
+    now = time.perf_counter()
+    wall = now - clocks["t0"]
+    wait_s, dispatch_s = clocks["wait_s"], clocks["dispatch_s"]
+    clocks.update(wait_s=0.0, dispatch_s=0.0, t0=now)
+    per_step = lambda s: round(1e3 * s / steps, 4)
+    return {
+        "wait_ms": per_step(wait_s),
+        "dispatch_ms": per_step(dispatch_s),
+        "host_ms": per_step(wall - wait_s - dispatch_s - sync_s),
+        "sync_ms": round(1e3 * sync_s, 4),
+    }
+
+
 def _run_training(
     cfg: Config,
     state,
@@ -639,7 +674,6 @@ def _run_training(
             "note: multi-host npz checkpoints — process 0 is the sole "
             "writer; peers barrier on each publish's content signature"
         )
-    tracer = WindowTracer(cfg.trace_dir if is_lead else None, count=cfg.trace_steps)
     # Unified telemetry: every record (train/input/validation/compile/mem/
     # stall/anomaly/summary) shares one run_id and the envelope schema
     # (telemetry.SCHEMAS); the compile sentinel drains per dispatch, the
@@ -668,7 +702,7 @@ def _run_training(
     # evidence column next to the modeled HBM floor), and the sampled
     # id-traffic statistics (kind=datastats — the dedup/heavy-hitter
     # numbers ROADMAP item 3 sizes against).  All compiles these issue
-    # attribute as warmup; the trace is lead-host-only like WindowTracer.
+    # attribute as warmup; the trace is lead-host-only.
     from fast_tffm_tpu.profiling import (
         CostLedger,
         DataStatsCollector,
@@ -676,8 +710,13 @@ def _run_training(
         modeled_step_bytes,
     )
 
+    profile_steps = cfg.telemetry_profile_steps
+    if cfg.trace_dir and not profile_steps:
+        # ``trace_dir`` alone asks for the default window: ``trace_steps``
+        # steps, past the compile and five steps of warm-up.
+        profile_steps = f"{start_step + 5}:{start_step + 5 + max(1, cfg.trace_steps)}"
     profiler = StepProfiler(
-        cfg.telemetry_profile_steps if is_lead else "",
+        profile_steps if is_lead else "",
         cfg.trace_dir or (cfg.model_file + ".profile"),
         monitor=monitor,
         log=log,
@@ -884,6 +923,11 @@ def _run_training(
 
         for sig in (signal.SIGTERM, signal.SIGINT):
             restore_handlers[sig] = signal.signal(sig, _on_signal)
+    # The loop thread's clocks over the open log window (kind=train):
+    # blocked on the input, inside step_fn, and the window's start; what
+    # is left of the wall time is the host's own (hooks, sentinel drain,
+    # checkpoint notes, validation).
+    clocks = {"wait_s": 0.0, "dispatch_s": 0.0, "t0": time.perf_counter()}
     try:
         for epoch in range(start_epoch, cfg.epoch_num):
             if stop_requested.is_set():
@@ -905,16 +949,17 @@ def _run_training(
                 getattr(epoch_stream, "producer_alive", None)
             )
             monitor.set_stream_idle_fn(getattr(epoch_stream, "stream_idle", None))
-            for b, parsed, w in epoch_stream:
+            for b, parsed, w in _timed_next(epoch_stream, clocks):
                 if b is None:
                     b = to_batch(parsed, w)
-                tracer.on_step()
                 if ledger is not None and ledger.want("train_step"):
                     # Abstract shapes must be captured BEFORE the dispatch
                     # donates the state buffers.
                     _stage_step_profile(b, parsed)
+                t_dispatch = time.perf_counter()
                 with step_trace("train", step_num):
                     state, loss = step_fn(state, b)
+                clocks["dispatch_s"] += time.perf_counter() - t_dispatch
                 # A fused call returns per-micro-step losses [K]; K=1
                 # returns the classic scalar.  The shape is static — no
                 # device sync happens here.
@@ -985,15 +1030,23 @@ def _run_training(
                 if stop_requested.is_set():
                     break
                 if pending_steps >= cfg.log_every:
-                    pending_steps = 0
                     rate = meter.rate()
-                    mean_loss = float(
-                        np.mean(
-                            np.concatenate(
-                                [np.atleast_1d(np.asarray(l)) for l in losses]
+                    t_sync = time.perf_counter()
+                    with span("train.sync"):
+                        mean_loss = float(
+                            np.mean(
+                                np.concatenate(
+                                    [np.atleast_1d(np.asarray(l)) for l in losses]
+                                )
                             )
                         )
+                    # The window's clocks close here, at the fetch: what
+                    # follows (the caller's ``log``, the records) is the
+                    # next window's host time.
+                    stage_ms = _window_clocks(
+                        clocks, pending_steps, time.perf_counter() - t_sync
                     )
+                    pending_steps = 0
                     _check_finite(
                         mean_loss, cfg, monitor=monitor,
                         step=int(state.step), state=state,
@@ -1016,6 +1069,7 @@ def _run_training(
                         loss=round(float(mean_loss), 6),
                         examples_per_sec=round(rate, 1),
                         examples_per_sec_per_chip=round(rate / n_chips, 1),
+                        **stage_ms,
                         **extra,
                     )
                     if input_stats is not None:
@@ -1146,7 +1200,6 @@ def _run_training(
         if paramstore is not None:
             summary_extra.update(paramstore.summary())
         profiler.close(step_num)
-        tracer.close()
         if host_monitor is not None:
             host_monitor.close()
         monitor.close(**summary_extra)

@@ -14,7 +14,10 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from collections.abc import Iterable, Iterator
+
+from fast_tffm_tpu.utils.tracing import span
 
 __all__ = ["prefetch", "chunk", "grouped_pairs", "InputStream", "PrefetchError"]
 
@@ -101,7 +104,9 @@ def prefetch(it: Iterable, depth: int = 8, stats=None) -> Iterator:
     ``stats`` (an object with ``on_queue_depth(int)``) samples the queue
     occupancy at every consumer pop — the overlap-efficiency signal the
     kind=input metrics records carry (depth ~0 = producer-bound, depth at
-    the cap = consumer-bound).  The queue — and the producer THREAD —
+    the cap = consumer-bound) — and, with ``on_wait(seconds)``, is told how
+    long each pop BLOCKED (the ``input.wait`` span; ``wait_ms`` of the
+    same records: what the proxy above stands for).  The queue — and the producer THREAD —
     are also bound onto ``stats`` (``bind_queue`` / ``bind_producer``)
     so the telemetry watchdog can read the LIVE depth and the thread's
     liveness from its own thread while the consumer is wedged.
@@ -139,6 +144,7 @@ def prefetch(it: Iterable, depth: int = 8, stats=None) -> Iterator:
         e.__cause__ = err[0] if err else None
         return e
 
+    on_wait = getattr(stats, "on_wait", None)
     need_sample = True
     while True:
         if stats is not None and need_sample:
@@ -149,9 +155,13 @@ def prefetch(it: Iterable, depth: int = 8, stats=None) -> Iterator:
             # producer-bound signal.
             stats.on_queue_depth(q.qsize())
             need_sample = False
+        t_wait = time.perf_counter()
         try:
-            item = q.get(timeout=1.0)
+            with span("input.wait"):
+                item = q.get(timeout=1.0)
         except queue.Empty:
+            if on_wait is not None:
+                on_wait(time.perf_counter() - t_wait)
             if not t.is_alive() and q.empty():
                 # Died without its sentinel: the finally was never
                 # reached (teardown/kill).  Without this check the
@@ -160,6 +170,8 @@ def prefetch(it: Iterable, depth: int = 8, stats=None) -> Iterator:
                     f"raised {err[0]!r}" if err else "died without signaling"
                 )
             continue
+        if on_wait is not None:
+            on_wait(time.perf_counter() - t_wait)
         need_sample = True
         if item is _SENTINEL:
             if err:

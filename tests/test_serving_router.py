@@ -729,6 +729,11 @@ class _StubBlockEngine:
     max_nnz = 6
     uses_fields = False
 
+    def __init__(self):
+        from fast_tffm_tpu.serving.metrics import ServingMetrics
+
+        self.metrics = ServingMetrics()  # the reader's frame_in clock lands here
+
     def submit_block(self, ids, vals, fields=None, *, deadlines_ms=None, classes=None):
         import concurrent.futures
 
@@ -814,7 +819,8 @@ def test_conn_torn_frame_typed_error_never_hung():
         unpack_error_frame,
     )
 
-    client, rf, _ = _conn_pair(_StubBlockEngine())
+    engine = _StubBlockEngine()
+    client, rf, _ = _conn_pair(engine)
     client.sendall(encode({"id": 1, "op": "hello", "wire": "binary"}))
     decode(rf.readline())
     # Header says count=9 rows but the payload bytes can't hold them.
@@ -845,6 +851,9 @@ def test_conn_torn_frame_typed_error_never_hung():
     assert unpack_error_frame(payload)[0] == "bad_request"
     assert read_frame(rf) is None
     client.close()
+    # Two frames had a header (the torn payload, the good one), each timed
+    # from it; the garbage never became a frame.
+    assert engine.metrics.frames == 2 and engine.metrics.frame_in_s > 0.0
 
 
 def test_frame_connection_wire_refused_falls_back():

@@ -7,7 +7,8 @@ import numpy as np
 
 from fast_tffm_tpu.config import load_config
 from fast_tffm_tpu.training import train
-from fast_tffm_tpu.utils.tracing import MetricsLogger, maybe_trace, step_trace
+from fast_tffm_tpu.profiling import StepProfiler
+from fast_tffm_tpu.utils.tracing import MetricsLogger, span, step_trace
 from tests.test_e2e import _write_cfg, _write_dataset
 
 
@@ -27,10 +28,18 @@ def test_metrics_logger_noop_without_path():
         m.log(step=1)  # must not raise or create files
 
 
-def test_step_trace_and_maybe_trace_noop():
-    with maybe_trace(None):
-        with step_trace("train", 3):
-            pass
+def test_step_trace_and_step_profiler_noop(tmp_path):
+    """No window asked for: the one tracer call a step does nothing, and
+    the annotations cost nothing outside a profiler session."""
+    prof = StepProfiler("", str(tmp_path / "trace"))
+    assert not prof.enabled
+    for step in range(1, 4):
+        with step_trace("train", step):
+            with span("input.wait", item=step):
+                pass
+        prof.on_step(step)
+    prof.close(3)
+    assert not (tmp_path / "trace").exists()
 
 
 def test_train_emits_trace_and_metrics(tmp_path):
@@ -52,6 +61,10 @@ def test_train_emits_trace_and_metrics(tmp_path):
     rows = [json.loads(l) for l in (tmp_path / "metrics.jsonl").read_text().splitlines()]
     assert any("loss" in r for r in rows)
     assert any("validation_auc" in r for r in rows)
+    # ``trace_dir`` alone is StepProfiler's default window (past five
+    # steps of warm-up): its start and stop are kind=profile events.
+    events = [r["event"] for r in rows if r.get("kind") == "profile" and r.get("program") == "trace"]
+    assert events == ["trace_start", "trace_stop"]
     # jax.profiler.trace wrote its TensorBoard plugin layout.
     assert os.path.isdir(tmp_path / "trace")
     found = []
