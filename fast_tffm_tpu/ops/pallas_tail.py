@@ -243,9 +243,12 @@ def fused_tail_adagrad_update(
     accumulator): dedup to unique logical rows, ``acc ← γ·acc + ‖g‖²``,
     ``w ← w − lr·g/√acc``.  The dedup is ``optim.dedup_rows`` — the SAME
     sort/segment pipeline the rows-layout classic update uses, so at
-    γ=1.0 the result is bit-identical to ``sparse_adagrad_update`` with
-    a row accumulator on the logical arrays (test-pinned); against the
-    scatter-add-built XLA fused tails it is allclose (summation order).
+    γ=1.0 the result is ``sparse_adagrad_update``'s with a row accumulator
+    on the logical arrays, bit for bit where both run the same
+    expressions and within a few ULP inside the ``k_cap`` fallback
+    (test-pinned; the classic tail scatter-ADDS ``-lr·g/√acc`` since
+    PR 27); against the scatter-add-built XLA fused tails it is allclose
+    (summation order).
 
     ``k_cap`` mirrors ``packed_compact_cap``: cap the kernel's deduped
     row span, with an exact full-span ``lax.cond`` fallback when a batch
@@ -351,10 +354,13 @@ def rows_tail_adagrad_update(
 ) -> tuple[jax.Array, jax.Array]:
     """``optim.sparse_adagrad_update`` as one kernel pass.
 
-    Same dedup (``optim.dedup_rows``), same update expressions, same
-    lazy-decay semantics — bit-identical at γ=1.0 AND γ<1 (test-pinned);
-    the only change is HOW the unique rows move: one double-buffered
-    DMA pass instead of the gather program + scatter program pair.
+    Same dedup (``optim.dedup_rows``), same accumulator expressions, same
+    lazy-decay semantics — the accumulator bit-identical, the table
+    bit-identical with the row accumulator and within a few ULP with the
+    element one (test-pinned: the classic tail rounds ``-lr·g/√acc``
+    before its one scatter-add, the kernel's ``w − lr·g/√acc`` fuses);
+    what changes is HOW the unique rows move: one double-buffered DMA
+    pass instead of the gather program + scatter program pair.
     """
     interpret = resolve_interpret(interpret)
     v, d = table.shape

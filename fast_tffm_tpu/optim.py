@@ -11,10 +11,23 @@ parameter variables).  Semantics mirror TF Adagrad:
 The sparse step is the BASELINE.json "dense-over-sparse optimizer step":
 gradients arrive per *gathered occurrence* ``[batch, nnz, D]``; occurrences
 of the same row id are summed on device (sort + segment-sum — static
-shapes, no `jnp.unique`), then a single gather→update→scatter touches each
-unique row exactly once.  Touching each row once matters: Adagrad is not
-linear in g (accum += g² must see the *summed* gradient, and duplicate
-scatter targets would race).
+shapes, no `jnp.unique`), then each unique row is touched exactly once:
+the accumulator's row is gathered, updated and scatter-set, and the
+table's row takes ``-lr·g/√accum`` by ONE scatter-add (its old value is
+never gathered).  Touching each row once matters: Adagrad is not linear in
+g (accum += g² must see the *summed* gradient, and duplicate scatter
+targets would race).
+
+Row descriptors (PR 27; PERF.md §6 has the chip's readings).  On the TPU a
+row narrower than a 128-lane tile is laid out with the ROW INDEX along the
+lanes, so a scatter of one such row is a masked read-modify-write of single
+lanes, four times a gather of the same row.  Where this module chooses the
+buffer's shape (``dedup_rows``' segment sum) it therefore works on rows
+padded to whole tiles; where it does not (the ``[V, D]`` table and
+accumulator of the rows layout, whose shapes checkpoints and the serving
+replica share) it issues the fewest row operations the math allows and
+declares what it knows about the indices — ascending, unique — which costs
+nothing on narrow rows and spares XLA a hidden sort on wide ones.
 
 Accumulator granularity: the accumulator array's trailing dim selects the
 variant — ``[V, D]`` is TF-Adagrad's per-element accumulator (parity
@@ -35,8 +48,18 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
-__all__ = ["AdagradState", "init_adagrad", "dense_adagrad_update", "sparse_adagrad_update", "dedup_rows"]
+__all__ = [
+    "AdagradState",
+    "init_adagrad",
+    "dense_adagrad_update",
+    "sparse_adagrad_update",
+    "dedup_rows",
+    "distinct_sentinels",
+    "segment_sum_lanes",
+    "describe_rows_tail",
+]
 
 
 class AdagradState(NamedTuple):
@@ -95,33 +118,99 @@ def dense_adagrad_update(param, state: AdagradState, grad, lr: float, decay: flo
     return new_param, AdagradState(accum)
 
 
+# One vreg row: a [m, w] f32 buffer with w a multiple of this is laid out
+# row-major on the TPU and a row is a whole-tile descriptor; any narrower w
+# is laid out with m along the lanes, and a scatter of one row is a masked
+# read-modify-write of single lanes (PERF.md §6, PR 27: the segment sum of
+# 2.56M rows took 280 ms at 9 lanes, 284 / 333 / 457 at 16 / 32 / 64, and
+# 25 at 128).
+_LANES = 128
+# The wide segment sum holds two [m, lanes] temporaries (the padded rows
+# and their sums).  A quarter of the smallest HBM this runs on (16 GiB, one
+# v5e chip) is what a step's temporaries may take beside a table and an
+# accumulator that fill it by half; past that the narrow form stays.
+_WIDE_SEGMENT_SUM_MAX_BYTES = 4 << 30
+
+
+def segment_sum_lanes(m: int, d: int) -> int:
+    """Row width ``dedup_rows`` sums ``m`` float32 rows of ``d`` at: ``d``
+    padded to whole 128-lane rows, or ``d`` itself where the rows are
+    tile-wide already or the two padded temporaries would pass the ceiling
+    above."""
+    lanes = -(-d // _LANES) * _LANES
+    if lanes == d or 2 * m * lanes * 4 > _WIDE_SEGMENT_SUM_MAX_BYTES:
+        return d
+    return lanes
+
+
+def distinct_sentinels(num_rows: int, m: int, dtype=jnp.int32) -> bool:
+    """Whether ``dedup_rows``' trailing slots can each carry a drop id of
+    their own (``num_rows + slot`` must fit the id type).  Only then are its
+    ``uids`` UNIQUE as well as ascending, which is what a tail may tell its
+    scatters (``unique_indices=True`` over repeated sentinels would be a
+    false statement, dropped rows or not)."""
+    return num_rows + m - 1 <= jnp.iinfo(dtype).max
+
+
+def describe_rows_tail(num_rows: int, m: int, d: int) -> str:
+    """The form ``sparse_adagrad_update`` takes at these shapes, for the
+    trainer's start-up log (it is a trace-time choice, so it is said once)."""
+    lanes = segment_sum_lanes(m, d)
+    hints = "sorted+unique" if distinct_sentinels(num_rows, m) else "sorted"
+    return (
+        f"segment sum on {lanes}-lane rows (row width {d}), "
+        f"table updated by one scatter-add, row ops declared {hints}"
+    )
+
+
 def dedup_rows(ids: jax.Array, row_grads: jax.Array, num_rows: int):
     """Sum per-occurrence row gradients over duplicate ids.
 
     Args:
-      ids:       [M] int row ids (flattened batch×nnz), may repeat.
+      ids:       [M] int row ids (flattened batch×nnz), may repeat; any id
+                 ``>= num_rows`` is a caller's drop sentinel (the sharded
+                 updates dedup all-gathered ``uids`` a second time).
       row_grads: [M, D] gradient per occurrence.
-      num_rows:  table row count V (used as the drop sentinel).
+      num_rows:  table row count V (the first drop id).
 
     Returns:
-      (uids [M], gsum [M, D]): unique ids with their summed gradients in the
-      leading segments; trailing slots carry the sentinel id ``num_rows``
-      (out of range → scattered with mode='drop') and zero gradients.
+      (uids [M], gsum [M, D]): unique ids, ASCENDING, with their summed
+      gradients in the leading segments.  Trailing slots carry zero
+      gradients and out-of-range ids (→ scattered with mode='drop'):
+      ``num_rows + slot``, distinct and still ascending, where that fits
+      the id type (``distinct_sentinels``), else ``num_rows`` repeated.  The
+      callers' own sentinels collapse into ONE segment with id ``num_rows``,
+      which sorts before every trailing slot's id, so the whole of ``uids``
+      is sorted and (where distinct) unique.
+
+    No row operation here is a partial-lane scatter: the segment sum runs on
+    rows padded to whole 128-lane tiles (``segment_sum_lanes``; same op, same
+    addends in the same order as the ``D``-wide form, so the sums are the
+    same floats), and ``uids`` comes from a second sort of the ids, not from
+    a scatter-set by segment (5 ms against 13, PERF.md §6, PR 27).
     """
-    m = ids.shape[0]
+    m, d = row_grads.shape
     with jax.named_scope("fm.dedup"):
-        order = jnp.argsort(ids)
-        sid = ids[order]
+        # One stable sort hands back the sorted ids beside the order
+        # (``ids[argsort(ids)]`` is an 18 ms gather of 2.56M ints more).
+        sid, order = lax.sort_key_val(
+            jnp.minimum(ids, num_rows), jnp.arange(m, dtype=jnp.int32), is_stable=True
+        )
         sg = row_grads[order]
         is_new = jnp.concatenate([jnp.ones((1,), bool), sid[1:] != sid[:-1]])
         seg = jnp.cumsum(is_new) - 1  # [M] segment index per occurrence
-        gsum = jax.ops.segment_sum(sg, seg, num_segments=m)
-        # Segment representative via scatter-SET, not segment_max (measured
-        # ~9 ms slower as a 1-D scatter-max on this backend): every
-        # occurrence in a segment writes the SAME sid, so any duplicate
-        # winning is correct; unwritten trailing slots keep the sentinel
-        # ``num_rows`` (out of range → scattered with mode='drop').
-        uids = jnp.full((m,), num_rows, sid.dtype).at[seg].set(sid)
+        lanes = segment_sum_lanes(m, d)
+        if lanes != d:
+            sg = jnp.pad(sg, ((0, 0), (0, lanes - d)))
+        gsum = jax.ops.segment_sum(
+            sg, seg, num_segments=m, indices_are_sorted=True
+        )[:, :d]
+        # Each segment's first occurrence keeps its id and every other slot
+        # takes a drop id above all of them: sorted, the unique ids lead.
+        drop = jnp.asarray(num_rows, sid.dtype)
+        if distinct_sentinels(num_rows, m, sid.dtype):
+            drop = drop + jnp.arange(m, dtype=sid.dtype)
+        uids = jnp.sort(jnp.where(is_new, sid, drop))
     return uids, gsum
 
 
@@ -136,7 +225,12 @@ def sparse_adagrad_update(
     """Sparse Adagrad step on a ``[V, D]`` table.
 
     ids: [...] int ids; row_grads: [..., D] matching occurrence grads.
-    Only the unique touched rows are read and written.
+    Only the unique touched rows are read and written: one gather and one
+    scatter-set of the accumulator's rows (the new value sets the step
+    size), ONE scatter-add into the table (``p + (-x)`` is ``p - x``; the
+    old row is never gathered).  The three declare what ``dedup_rows``
+    guarantees about ``uids`` — ascending, and unique where the trailing
+    drop ids are distinct (a trace-time test on shapes).
 
     ``decay`` γ < 1 decays the accumulator LAZILY — only the rows a step
     touches pay ``accum = γ·accum + g²`` (an untouched row's history is
@@ -147,13 +241,18 @@ def sparse_adagrad_update(
     XLA program, bit-identical results (test-pinned on all three train
     paths)."""
     D = table.shape[-1]
-    uids, gsum = dedup_rows(ids.reshape(-1), row_grads.reshape(-1, D), table.shape[0])
+    flat = ids.reshape(-1)
+    uids, gsum = dedup_rows(flat, row_grads.reshape(-1, D), table.shape[0])
+    known = dict(
+        indices_are_sorted=True,
+        unique_indices=distinct_sentinels(table.shape[0], flat.shape[0], uids.dtype),
+    )
     with jax.named_scope("fm.tail"):
-        acc_prev = state.accum[uids]
+        acc_prev = state.accum.at[uids].get(mode="clip", **known)
         if decay != 1.0:
             acc_prev = decay * acc_prev
         acc_rows = acc_prev + accum_sq(state.accum, gsum)  # sentinel lanes
-        upd_rows = table[uids] - lr * gsum / jnp.sqrt(acc_rows)  # dropped below
-        accum = state.accum.at[uids].set(acc_rows, mode="drop")
-        table = table.at[uids].set(upd_rows, mode="drop")
+        step = -(lr * gsum / jnp.sqrt(acc_rows))  # dropped below
+        accum = state.accum.at[uids].set(acc_rows, mode="drop", **known)
+        table = table.at[uids].add(step, mode="drop", **known)
     return table, AdagradState(accum)

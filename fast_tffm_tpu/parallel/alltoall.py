@@ -227,7 +227,7 @@ def routed_update(
     base = lax.axis_index(ROW_AXIS) * shard_rows
     R = axis_size(ROW_AXIS)
     uids, gsum = dedup_rows(ids.reshape(-1), row_grads.reshape(-1, D), num_rows_global)
-    # Sentinel uids (== num_rows_global) route to owner R: excluded from
+    # Sentinel uids (>= num_rows_global) route to owner R: excluded from
     # counts (bincount length R) and dropped by the out-of-range scatter.
     owner = jnp.where(uids >= num_rows_global, R, uids // shard_rows)
     order, sorted_owner, send_pos, _in_cap, overflow = _bucketize(owner, R, capacity)

@@ -153,8 +153,9 @@ def sharded_sparse_adagrad_update(
     uids, gsum = dedup_rows(ids.reshape(-1), row_grads.reshape(-1, D), num_rows_global)
     all_uids = lax.all_gather(uids, (DATA_AXIS, ROW_AXIS), tiled=True)  # [P*M]
     all_gsum = lax.all_gather(gsum, (DATA_AXIS, ROW_AXIS), tiled=True)  # [P*M, D]
-    # Sentinel ids (num_rows_global) from short shards collapse into one
-    # segment and are dropped again below.
+    # Drop ids (>= num_rows_global, one per trailing slot of each peer's
+    # dedup) collapse into one segment inside dedup_rows and are dropped
+    # again below.
     guids, ggsum = dedup_rows(all_uids, all_gsum, num_rows_global)
 
     base = lax.axis_index(ROW_AXIS) * table_shard.shape[0]

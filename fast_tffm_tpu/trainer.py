@@ -3,8 +3,11 @@
 The TPU-native analog of the reference's session step loop
 (`renyi533/fast_tffm` :: local trainer: sess.run(train_op) over the graph
 parser → gather → scorer → loss → Adagrad scatter-add).  Here one jitted
-function fuses gather → fused scorer (custom VJP) → loss → dedup →
-sparse Adagrad scatter; XLA compiles the whole step into a single program.
+function fuses gather → fused scorer (custom VJP) → loss → dedup (sort,
+segment sum on tile-wide rows) → sparse Adagrad tail (accumulator gather
+and scatter-set, table scatter-add); XLA compiles the whole step into a
+single program whose ops carry the stage's name (``fm.gather``,
+``fm.interaction``, ``fm.loss``, ``fm.dedup``, ``fm.tail``).
 
 The mesh-sharded variant lives in parallel/train_step.py and reuses these
 loss pieces; this module is also its single-shard reference semantics.
@@ -99,10 +102,16 @@ def train_step_body(
     model, learning_rate: float, state: TrainState, batch: Batch,
     decay: float = 1.0,
 ):
-    """The (unjitted) single-device step: gather → fused scorer → loss →
-    dedup → sparse Adagrad.  Shared verbatim by ``make_train_step`` and the
-    device-cache step (data/device_cache.py) so the two paths are the SAME
-    math on the same values — the bit-identity their parity test pins.
+    """The (unjitted) single-device step, by the scope its ops carry:
+    ``fm.gather`` (the batch's rows) → ``fm.interaction`` (fused scorer and
+    its backward) → ``fm.loss`` → ``fm.dedup`` (one sort for ids and order,
+    permutation gather, segment sum on 128-lane rows, unique ids by a second
+    sort) → ``fm.tail`` (one gather and one scatter-set of the accumulator,
+    one scatter-add into the table, all declared sorted and unique;
+    optim.sparse_adagrad_update).
+    Shared verbatim by ``make_train_step`` and the device-cache step
+    (data/device_cache.py) so the two paths are the SAME math on the same
+    values — the bit-identity their parity test pins.
 
     ``decay`` is the online-learning ``[Online] adagrad_decay`` γ (lazy
     touched-row accumulator decay — optim.sparse_adagrad_update); γ=1.0

@@ -1371,6 +1371,18 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
             step_body = make_decayed_body(decay)
         else:
             step_body = None
+        if tail != "pallas":
+            from fast_tffm_tpu.optim import describe_rows_tail
+
+            log(
+                "sparse tail: xla rows ("
+                + describe_rows_tail(
+                    state.table.shape[0],
+                    cfg.batch_size * cfg.max_nnz,
+                    state.table.shape[1],
+                )
+                + ")"
+            )
         step_fn = make_train_step(
             model, cfg.learning_rate, decay=decay, body=step_body
         )

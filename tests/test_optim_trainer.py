@@ -1,5 +1,7 @@
 """Sparse Adagrad correctness vs a dense oracle; end-to-end training smoke."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,7 +9,15 @@ import pytest
 
 from fast_tffm_tpu.metrics import auc
 from fast_tffm_tpu.models import Batch, DeepFMModel, FFMModel, FMModel
-from fast_tffm_tpu.optim import AdagradState, dedup_rows, init_adagrad, sparse_adagrad_update
+from fast_tffm_tpu.optim import (
+    AdagradState,
+    dedup_rows,
+    distinct_sentinels,
+    init_adagrad,
+    init_table_adagrad,
+    segment_sum_lanes,
+    sparse_adagrad_update,
+)
 from fast_tffm_tpu.trainer import init_state, make_predict_step, make_train_step
 
 
@@ -42,6 +52,165 @@ def test_sparse_adagrad_matches_dense_oracle():
         np.asarray(new_table)[~touched], np.asarray(table)[~touched]
     )
     np.testing.assert_allclose(np.asarray(new_state.accum)[touched], accum[touched], rtol=1e-5)
+
+
+# --- the rows tail's row descriptors (PR 27) ------------------------------
+#
+# Widths: FM k=8 and k=16 rows, an FFM row, a row that is tile-wide already
+# (takes the narrow segment sum) and one past a tile (pads to 256 lanes).
+_WIDTHS = [1 + 8, 1 + 16, 89, 128, 130]
+_V = 2000
+
+
+def _pattern_ids(pattern: str) -> np.ndarray:
+    rng = np.random.default_rng(7)
+    if pattern == "all_unique":
+        return rng.permutation(_V)[:96].astype(np.int32)
+    if pattern == "one_id":
+        return np.full((64,), 7, np.int32)
+    if pattern == "run_over_256":  # deeper than any doubling bound guessed from the cell (250)
+        ids = np.concatenate([np.full((300,), 5), rng.integers(0, _V, 400)])
+        return rng.permutation(ids).astype(np.int32)
+    if pattern == "runs_at_both_ends":  # sorted, a run opens the array and another closes it
+        ids = np.concatenate([np.zeros(3), np.full((5,), _V - 1), rng.integers(1, _V - 1, 42)])
+        return rng.permutation(ids).astype(np.int32)
+    assert pattern == "m_not_pow2"
+    return rng.integers(0, 300, 1037).astype(np.int32)  # every id about 3.5 times
+
+
+_PATTERNS = ["all_unique", "one_id", "run_over_256", "runs_at_both_ends", "m_not_pow2"]
+
+
+def _dense_grad(ids, g, d):
+    dense = np.zeros((_V, d), np.float64)
+    np.add.at(dense, ids, np.asarray(g, np.float64))
+    return dense
+
+
+@pytest.mark.parametrize("pattern", _PATTERNS)
+@pytest.mark.parametrize("d", _WIDTHS)
+def test_dedup_rows_matches_dense_oracle(d, pattern):
+    ids = _pattern_ids(pattern)
+    g = np.random.default_rng(d).normal(size=(ids.size, d)).astype(np.float32)
+    uids, gsum = jax.jit(lambda i, r: dedup_rows(i, r, _V))(ids, g)
+    uids, gsum = np.asarray(uids), np.asarray(gsum)
+    want_ids = np.unique(ids)
+    n = want_ids.size
+    np.testing.assert_array_equal(uids[:n], want_ids)
+    np.testing.assert_allclose(gsum[:n], _dense_grad(ids, g, d)[want_ids], rtol=1e-5, atol=1e-5)
+    assert (uids[n:] >= _V).all() and (np.diff(uids) > 0).all()
+    assert not gsum[n:].any()
+
+
+@pytest.mark.parametrize("pattern", _PATTERNS)
+@pytest.mark.parametrize("decay", [1.0, 0.9])
+@pytest.mark.parametrize("accumulator", ["element", "row"])
+@pytest.mark.parametrize("d", _WIDTHS)
+def test_sparse_adagrad_matches_dense_oracle_over_widths(d, accumulator, decay, pattern):
+    ids = _pattern_ids(pattern)
+    rng = np.random.default_rng(d)
+    g = rng.normal(size=(ids.size, d)).astype(np.float32)
+    table = rng.normal(size=(_V, d)).astype(np.float32)
+    state = init_table_adagrad(jnp.asarray(table), 0.1, accumulator)
+    lr = 0.5
+    new_table, new_state = jax.jit(
+        lambda t, s, i, r: sparse_adagrad_update(t, s, i, r, lr, decay=decay)
+    )(jnp.asarray(table), state, ids, g)
+
+    dense = _dense_grad(ids, g, d)
+    sq = dense**2 if accumulator == "element" else (dense**2).sum(-1, keepdims=True)
+    touched = np.zeros(_V, bool)
+    touched[ids] = True
+    acc = np.full(sq.shape, 0.1)
+    acc[touched] = decay * acc[touched] + sq[touched]
+    want = table.astype(np.float64) - lr * dense / np.sqrt(acc)
+    # float32 sums of up to 300 addends that cancel (a sum of 0.8 from terms
+    # of size 1) are good to about 1e-5 of the sum, and g² doubles that.
+    np.testing.assert_allclose(np.asarray(new_table)[touched], want[touched], rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(new_state.accum), acc, rtol=1e-4)
+    np.testing.assert_array_equal(np.asarray(new_table)[~touched], table[~touched])
+
+
+def _hlo_ops(lowered, kind):
+    """(scope, line) of every ``kind`` op in the lowered step, the scope being
+    the ``jax.named_scope`` path in the op's ``op_name``."""
+    out = []
+    for line in lowered.as_text(dialect="hlo", debug_info=True).splitlines():
+        if re.search(rf" {kind}\(", line):
+            out.append((re.search(r'op_name="([^"]*)"', line).group(1), line))
+    return out
+
+
+def _lower_rows_tail(v, d, m):
+    sd = jax.ShapeDtypeStruct
+    return jax.jit(
+        lambda t, a, i, r: sparse_adagrad_update(t, AdagradState(a), i, r, 0.05)
+    ).lower(sd((v, d), jnp.float32), sd((v, d), jnp.float32), sd((m,), jnp.int32), sd((m, d), jnp.float32))
+
+
+def test_drop_ids_are_distinct_ascending_and_guarded_by_int32():
+    ids = jnp.asarray([3, 1, 3, 7, 1, 3], jnp.int32)
+    uids, _ = dedup_rows(ids, jnp.ones((6, 2)), num_rows=10)
+    uids = np.asarray(uids)
+    np.testing.assert_array_equal(uids[:3], [1, 3, 7])
+    assert (uids[3:] >= 10).all() and (np.diff(uids) > 0).all()
+    # A caller's own drop ids (the sharded updates dedup all-gathered uids a
+    # second time) collapse into ONE segment below every trailing slot's id.
+    again, gsum = dedup_rows(jnp.asarray([12, 1, 15, 10, 1, 13], jnp.int32), jnp.ones((6, 2)), num_rows=10)
+    again = np.asarray(again)
+    np.testing.assert_array_equal(again[:2], [1, 10])
+    assert (again[1:] >= 10).all() and (np.diff(again) > 0).all()
+    np.testing.assert_array_equal(np.asarray(gsum)[0], [2.0, 2.0])
+
+    # num_rows + slot must fit the id type: the cell's shapes do, a table a
+    # few rows under 2^31 does not, and its tail then says "sorted" only.
+    assert distinct_sentinels(2**26, 65536 * 39)
+    v = 2**31 - 4
+    assert distinct_sentinels(v, 4) and not distinct_sentinels(v, 8)
+    tail = [line for scope, line in _hlo_ops(_lower_rows_tail(v, 1, 8), "scatter") if "fm.tail" in scope]
+    assert len(tail) == 2
+    assert all("indices_are_sorted=true" in line and "unique_indices=true" not in line for line in tail)
+
+
+def test_rows_step_declares_its_row_descriptors():
+    """The lowered rows step (the benchmark's train cell at a small size):
+    no partial-lane scatter under ``fm.dedup``, and under ``fm.tail`` two
+    table-shaped scatters that say sorted + unique and ONE table-shaped
+    gather (the accumulator's; the table's old rows are never read)."""
+    v, k, b, n = 4096, 8, 64, 5
+    model = FMModel(vocabulary_size=v, factor_num=k, order=2)
+    sd = jax.ShapeDtypeStruct
+    state = jax.eval_shape(lambda: init_state(model, jax.random.key(0)))
+    batch = Batch(
+        labels=sd((b,), jnp.float32), ids=sd((b, n), jnp.int32), vals=sd((b, n), jnp.float32),
+        fields=sd((b, 0), jnp.int32), weights=sd((b,), jnp.float32),
+    )
+    lowered = make_train_step(model, 0.05).lower(state, batch)
+    table_shape = f"f32[{v},{1 + k}]"
+
+    scatters = _hlo_ops(lowered, "scatter")
+    tail = [line for scope, line in scatters if "fm.tail" in scope]
+    assert len(tail) == 2 and all(line.split(" = ")[1].startswith(table_shape) for line in tail)
+    assert all("indices_are_sorted=true" in line and "unique_indices=true" in line for line in tail)
+    dedup = [line for scope, line in scatters if "fm.dedup" in scope]
+    assert len(dedup) == 1 and dedup[0].split(" = ")[1].startswith(f"f32[{b * n},128]")
+    assert "indices_are_sorted=true" in dedup[0]
+    assert {scope.split("/")[1] for scope, _ in scatters} == {"fm.dedup", "fm.tail"}
+
+    from_table = [
+        (scope, line) for scope, line in _hlo_ops(lowered, "gather")
+        if "slice_sizes={1,9}" in line and "fm.dedup" not in scope
+    ]
+    assert sorted(scope.split("/")[1] for scope, _ in from_table) == ["fm.gather", "fm.tail"]
+    assert all("indices_are_sorted=true" in line for scope, line in from_table if "fm.tail" in scope)
+
+
+def test_segment_sum_width_is_chosen_from_shapes():
+    assert [segment_sum_lanes(16384 * 39, d) for d in _WIDTHS] == [128, 128, 128, 128, 256]
+    # Two padded temporaries past a quarter of a v5e's memory: the narrow form.
+    m = 65536 * 39  # the train cell's batch: 2.6e9 bytes at 128 lanes
+    assert [segment_sum_lanes(m, 9), segment_sum_lanes(m, 130)] == [128, 130]
+    assert [segment_sum_lanes(k * m, 9) for k in (1, 2, 4)] == [128, 9, 9]
 
 
 def _synthetic_batches(rng, model_cls_hint, n_batches=30, B=64, N=6, V=100, F=4):

@@ -5,9 +5,13 @@ auto-detects the backend, so no per-test plumbing); real-TPU compilation
 of the same kernels is exercised by bench.py / the driver.
 
 Parity contract (acceptance criteria):
-  * γ=1.0 — BIT-IDENTICAL to the classic XLA program (same
-    optim.dedup_rows front + same update expressions, compared inside
-    jax.jit exactly as training runs them);
+  * γ=1.0 — the classic XLA program (same optim.dedup_rows front, same
+    update expressions, compared inside jax.jit exactly as training runs
+    them): the accumulator BIT-IDENTICAL; the table bit-identical with the
+    row accumulator and within a few float32 ULP with the element one
+    since PR 27 — the classic tail now hands ``-(lr·g/√acc)`` to one
+    scatter-add, a rounded operand, where the kernel's fused
+    ``w − lr·g/√acc`` contracts into an FMA on the CPU (``_assert_few_ulp``);
   * γ<1 — row accumulator stays bitwise, element accumulator is
     rtol-pinned (XLA fuses the decayed expressions into different FMA
     clusters — 1-ULP table drift);
@@ -49,6 +53,18 @@ def _operands(seed=0, m=40, v=V, d=D):
         jnp.asarray(rng.standard_normal((v, d)), jnp.float32),
         jnp.asarray(rng.uniform(0.05, 2.0, (v, 1)), jnp.float32),
         jnp.asarray(rng.uniform(0.05, 2.0, (v, d)), jnp.float32),
+    )
+
+
+def _assert_few_ulp(got, want, scale=1.0, ulps=4):
+    """``got`` within ``ulps`` float32 ULP of ``want`` at magnitude ``scale``
+    (the larger operand of the tail's ``w + (−x)``: |x| < lr, |w| ≲ 4 for
+    the standard-normal tables here).  The one difference allowed between
+    the classic tail and a path that does not share its table update: one
+    rounds ``x`` before the add, the other fuses multiply and subtract."""
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=0,
+        atol=ulps * float(np.finfo(np.float32).eps) * scale,
     )
 
 
@@ -101,7 +117,11 @@ def test_rows_tail_bit_identical_to_classic(acc_kind):
     acc = accum_row if acc_kind == "row" else accum_elem
     rt, rs = _classic(table, acc, ids, g, 0.13)
     kt, ka = _kernel(table, acc, ids, g, 0.13)
-    assert jnp.all(kt == rt) and jnp.all(ka == rs.accum)
+    assert jnp.all(ka == rs.accum)
+    if acc_kind == "row":
+        assert jnp.all(kt == rt)
+    else:
+        _assert_few_ulp(kt, rt)
 
 
 @pytest.mark.parametrize("acc_kind", ["row", "element"])
@@ -174,7 +194,17 @@ def test_fused_k_cap_edge(k_cap):
         lambda f: fused_tail_adagrad_update(f, ids, g, 0.13, k_cap=k_cap)
     )(fused)
     tu, au = unpack_fused(f2, V, D)
-    assert jnp.all(tu == rt) and jnp.all(au == rs.accum)
+    if k_cap >= ids.shape[0]:
+        assert jnp.all(tu == rt) and jnp.all(au == rs.accum)
+    else:
+        # Inside the lax.cond fallback the row accumulator's Σg² over the
+        # classic dedup's sums (since PR 27 a slice of 128-lane rows) is
+        # associated differently from the classic program's own: 1-2 ULP
+        # of the accumulator, and the table follows (module docstring).
+        np.testing.assert_allclose(
+            au, rs.accum, rtol=4 * float(np.finfo(np.float32).eps)
+        )
+        _assert_few_ulp(tu, rt)
 
 
 def test_remainder_tail_small_blocks():
@@ -219,12 +249,14 @@ def test_train_step_pallas_body_bit_identical():
     s1 = tr.init_state(model, jax.random.key(0), 0.1, "element")
     step_x = tr.make_train_step(model, 0.05)
     step_p = tr.make_train_step(model, 0.05, body=tr.make_pallas_tail_body())
-    for b in _batches():
+    for i, b in enumerate(_batches()):
         s0, l0 = step_x(s0, b)
         s1, l1 = step_p(s1, b)
-        assert l0 == l1
-    assert jnp.all(s0.table == s1.table)
-    assert jnp.all(s0.table_opt.accum == s1.table_opt.accum)
+        # The first loss sees the same table; later ones a table a few ULP
+        # apart (module docstring), and the gap compounds over the steps.
+        np.testing.assert_allclose(l1, l0, rtol=0 if i == 0 else 1e-6)
+    _assert_few_ulp(s1.table, s0.table, ulps=16)
+    np.testing.assert_allclose(s1.table_opt.accum, s0.table_opt.accum, rtol=1e-6)
 
 
 def test_packed_fused_step_tail_pallas():
