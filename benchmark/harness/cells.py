@@ -4,6 +4,7 @@ metrics are found by name from data files; adding one edits no file here."""
 from __future__ import annotations
 
 import configparser
+import importlib
 import json
 import os
 import shutil
@@ -17,9 +18,23 @@ WINDOW_OF_KIND = {"train": "train", "dist_train": "train", "serve": "serve"}
 
 
 def window_module(kind: str):
-    import importlib
-
     return importlib.import_module(f"harness.{WINDOW_OF_KIND[kind]}")
+
+
+def harness_model(config: dict, ini: dict):
+    """The ``Model`` of the module ``models/<name>.py`` that the configuration's
+    file names under ``harness_model``, built from the cell's INI.  A file
+    that names none, or one that is not there, is an error, never a default."""
+    name = config.get("harness_model")
+    if not name:
+        raise SystemExit(f"configuration {config.get('name')!r} names no harness_model (a module of harness/models/)")
+    try:
+        module = importlib.import_module(f"harness.models.{name}")
+    except ModuleNotFoundError as e:
+        if e.name != f"harness.models.{name}":
+            raise
+        raise SystemExit(f"configuration {config.get('name')!r} names the harness_model {name!r}: there is no harness/models/{name}.py")
+    return module.Model(ini)
 
 
 def _load(path: str) -> dict:
@@ -28,7 +43,7 @@ def _load(path: str) -> dict:
 
 
 def load_cell(workload: str, bench_dir: str = BENCH_DIR) -> dict:
-    """``<config>.<mix>`` -> {name, config, traffic, kind, chips, ini}.
+    """``<config>.<mix>`` -> {name, config, traffic, kind, chips, ini, model}.
 
     The config name may itself hold dots; the mix is what follows the last
     one whose two halves both name a file."""
@@ -47,6 +62,7 @@ def load_cell(workload: str, bench_dir: str = BENCH_DIR) -> dict:
                 "kind": traffic["kind"],
                 "chips": int(config.get("chips", 1)),
                 "ini": ini,
+                "model": harness_model(config, ini),
                 "bench_dir": bench_dir,
             }
     raise SystemExit(f"unknown workload {workload!r}: no configs/<config>.json + traffic/<mix>.json")

@@ -55,18 +55,25 @@ def rows_from_seed(seed: int, n_rows: int, fields: int, vocab: int, alpha: float
     return tuple(np.concatenate([p[i] for p in parts]) for i in range(3))
 
 
-def write_fmb(path: str, labels, ids, vals, vocab: int) -> int:
-    """One FMB v2 file; returns the bytes written."""
+def column_fields(ids: np.ndarray) -> np.ndarray:
+    """The field of every id of ``rows_from_seed``: column f is field f."""
+    return np.broadcast_to(np.arange(ids.shape[-1], dtype=np.int32), ids.shape)
+
+
+def write_fmb(path: str, labels, ids, vals, vocab: int, fields=None) -> int:
+    """One FMB v2 file; returns the bytes written.  Without ``fields`` (a
+    model that reads none) every field id is zero and the header says so."""
     n, width = ids.shape
     sections = [
         labels.astype("<f4"),
         np.full(n, width, "<i4"),
         ids.astype("<i4"),
         vals.astype("<f4"),
-        np.zeros((n, width), "<i4"),
+        np.zeros((n, width), "<i4") if fields is None else fields.astype("<i4"),
     ]
+    flags = _FLAG_FIELDS_ALL_ZERO if fields is None else 0
     with open(path, "wb") as f:
-        f.write(_HEADER.pack(b"FMB1", 2, n, width, vocab, 0, 4, _FLAG_FIELDS_ALL_ZERO, 0, 0, width))
+        f.write(_HEADER.pack(b"FMB1", 2, n, width, vocab, 0, 4, flags, 0, 0, width))
         for a in sections:
             f.seek(-(-f.tell() // _ALIGN) * _ALIGN)
             f.write(a.tobytes())

@@ -1,8 +1,14 @@
-"""The few generic readers a per-layer metric file can name.  A reader that
-finds nothing to read returns None and the metric is left out of the line."""
+"""The few generic readers a per-layer metric file can name, and the look-up
+of one that a later file brings: ``"reader": "<module>:<function>"`` is that
+function of ``harness/<module>.py``, called as these are, ``(metric file,
+context)``; the context is what the window gathered: the program's
+``records``, the ``trace`` reduction and the ``trace_dir`` it was read from, the
+window's ``steps`` and ``values``, the cell's ``model``.  A reader that finds nothing to read returns None and the
+metric is left out of the line."""
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import statistics
@@ -89,10 +95,19 @@ READERS = {
 }
 
 
+def reader(name: str):
+    if name in READERS:
+        return READERS[name]
+    module, _, function = name.partition(":")
+    if not function:
+        raise SystemExit(f"no reader {name!r}: not one of {sorted(READERS)} and not <module>:<function>")
+    return getattr(importlib.import_module(f"harness.{module}"), function)
+
+
 def read_all(metrics: list[dict], ctx: dict) -> dict:
     out = {}
     for m in metrics:
-        v = READERS[m["reader"]](m, ctx)
+        v = reader(m["reader"])(m, ctx)
         if v is not None:
             out[m["name"]] = common.metric(v, m["unit"])
     return out

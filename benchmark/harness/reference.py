@@ -1,12 +1,11 @@
-"""The plain reference: a 2nd-order factorization machine in straightforward
+"""The plain reference, what every model shares of it: straightforward
 ``jax.numpy``, float32, no kernels, no dedup, no packing.  It imports nothing
-of the program and takes nothing the program has made: the initial table is
-drawn here by the recipe the program documents (uniform factors from
-``split(key(0))[0]``, zero biases), the rows come from the harness's generator.
+of the program and takes nothing the program has made: the initial rows and
+the score of gathered rows are the cell's ``model`` (``models/<name>.py``), the
+rows come from the harness's generator.
 
-Row layout: column 0 the bias w_i, columns 1: the factors v_i.
+Row layout: column 0 the bias w_i, the other columns the factors v_i.
 
-    score = sum_i w_i x_i + 1/2 sum_f [(sum_i v_if x_i)^2 - sum_i (v_if x_i)^2]
     loss  = mean log(1 + exp(-y' score)) + bias_lambda |w|^2 + factor_lambda |v|^2
             (L2 over the gathered occurrences, padding masked)
     Adagrad: accum += g^2 ; param -= lr * g / sqrt(accum), g summed per row.
@@ -17,37 +16,15 @@ float32 the configurations state.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
-def init_rows(vocab: int, factor_num: int, init_range: float, rows: np.ndarray) -> jax.Array:
-    """Rows ``rows`` of the initial [vocab, 1 + factor_num] table."""
-    k1, _ = jax.random.split(jax.random.key(0))
-
-    @jax.jit
-    def draw(idx):
-        factors = jax.random.uniform(
-            k1, (vocab, factor_num), minval=-init_range, maxval=init_range, dtype=jnp.float32
-        )
-        f = factors[idx]
-        return jnp.concatenate([jnp.zeros((f.shape[0], 1), jnp.float32), f], axis=-1)
-
-    return draw(jnp.asarray(rows, jnp.int32))
-
-
-def fm_score(rows, vals):
-    bias, v = rows[..., 0], rows[..., 1:]
-    vx = v * vals[..., None]
-    s1 = jnp.sum(vx, axis=1)
-    s2 = jnp.sum(vx * vx, axis=1)
-    return jnp.sum(bias * vals, axis=-1) + 0.5 * jnp.sum(s1 * s1 - s2, axis=-1)
-
-
-def _loss(table, idx, vals, labels, bias_lambda, factor_lambda, batch=None):
+def _loss(score, table, idx, vals, fields, labels, bias_lambda, factor_lambda, batch=None):
     rows = table[idx]
-    s = fm_score(rows, vals)
+    s = score(rows, vals, fields)
     per = jnp.maximum(s, 0) - s * labels + jnp.log1p(jnp.exp(-jnp.abs(s)))
     data = jnp.sum(per) / (batch or labels.shape[0])
     masked = rows * (vals != 0).astype(rows.dtype)[..., None]
@@ -55,8 +32,8 @@ def _loss(table, idx, vals, labels, bias_lambda, factor_lambda, batch=None):
     return data + reg, data
 
 
-def train_steps(table0, batches, lr, accum0, bias_lambda, factor_lambda, dtype=jnp.float32, owner=None):
-    """Follow ``batches`` = [(idx[B,N] into table0, vals, labels)] with dense
+def train_steps(score, table0, batches, lr, accum0, bias_lambda, factor_lambda, dtype=jnp.float32, owner=None):
+    """Follow ``batches`` = [(idx[B,N] into table0, vals, fields, labels)] with dense
     autodiff and dense Adagrad over the compact table.  Returns per step
     (data_loss, table, accum).
 
@@ -65,20 +42,21 @@ def train_steps(table0, batches, lr, accum0, bias_lambda, factor_lambda, dtype=j
     batch is cut into as many micro-batches as there are shards, and shard r
     applies to the rows it owns only what its own micro-batch contributes."""
     lam = (jnp.asarray(bias_lambda, dtype), jnp.asarray(factor_lambda, dtype))
+    loss = jax.value_and_grad(functools.partial(_loss, score), has_aux=True)
 
-    def grad(table, idx, vals, labels):
+    def grad(table, idx, vals, fields, labels):
         if owner is None:
-            return jax.value_and_grad(_loss, has_aux=True)(table, idx, vals, labels, *lam)
+            return loss(table, idx, vals, fields, labels, *lam)
         shards, n = int(owner.max()) + 1, labels.shape[0]
         data, g = 0.0, jnp.zeros_like(table)
-        for r, part in enumerate(zip(*(jnp.split(a, shards) for a in (idx, vals, labels)))):
-            (_, d), gr = jax.value_and_grad(_loss, has_aux=True)(table, *part, *lam, batch=n)
+        for r, part in enumerate(zip(*(jnp.split(a, shards) for a in (idx, vals, fields, labels)))):
+            (_, d), gr = loss(table, *part, *lam, batch=n)
             data, g = data + d, g + jnp.where((jnp.asarray(owner) == r)[:, None], gr, 0)
         return (None, data), g
 
     @jax.jit
-    def step(table, accum, idx, vals, labels):
-        (_, data), g = grad(table, idx, vals.astype(dtype), labels.astype(dtype))
+    def step(table, accum, idx, vals, fields, labels):
+        (_, data), g = grad(table, idx, vals.astype(dtype), fields, labels.astype(dtype))
         accum = accum + g * g
         return data, table - jnp.asarray(lr, dtype) * g / jnp.sqrt(accum), accum
 
@@ -86,15 +64,15 @@ def train_steps(table0, batches, lr, accum0, bias_lambda, factor_lambda, dtype=j
     accum = jnp.full_like(table, accum0)
     out = []
     with jax.default_matmul_precision("highest"):
-        for idx, vals, labels in batches:
-            data, table, accum = step(table, accum, jnp.asarray(idx), jnp.asarray(vals), jnp.asarray(labels))
+        for batch in batches:
+            data, table, accum = step(table, accum, *map(jnp.asarray, batch))
             out.append((data, table, accum))
     return out
 
 
-def score_rows(table_rows, idx, vals, dtype=jnp.float32):
-    """Served score of each row: sigmoid(fm_score) over rows gathered from the
+def score_rows(score, table_rows, idx, vals, fields, dtype=jnp.float32):
+    """Served score of each row: sigmoid(score) over rows gathered from the
     compact ``table_rows`` by ``idx``."""
     with jax.default_matmul_precision("highest"):
         rows = jnp.asarray(table_rows).astype(dtype)[jnp.asarray(idx)]
-        return jax.nn.sigmoid(fm_score(rows, jnp.asarray(vals).astype(dtype))).astype(jnp.float32)
+        return jax.nn.sigmoid(score(rows, jnp.asarray(vals).astype(dtype), jnp.asarray(fields))).astype(jnp.float32)

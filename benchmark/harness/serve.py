@@ -23,7 +23,7 @@ import time
 
 import numpy as np
 
-from . import cells, common, loadgen, peaks, readers, reference
+from . import cells, common, gen, loadgen, peaks, readers, reference
 
 _POOL_BLOCK = 1 << 16
 
@@ -79,28 +79,41 @@ def seed_table(seed: int, vocab: int, row_dim: int):
     return jax.jit(lambda k: jax.random.uniform(k, (vocab, row_dim), minval=-0.08, maxval=0.08))(key)
 
 
-def write_model_file(cfg, seed: int) -> None:
+def served_model(cell):
+    """The cell's model, where this window can serve it: the load generator's
+    FMD1 frames carry no field ids."""
+    model = cell["model"]
+    if model.reads_fields:
+        raise SystemExit(
+            f"{cell['name']}: the configuration's model reads field ids and the serve window's FMD1 frames "
+            "(harness/loadgen.py) carry none; it would be scored with every feature in field 0"
+        )
+    return model
+
+
+def write_model_file(cfg, seed: int, row_dim: int) -> None:
     import jax.numpy as jnp
 
     from fast_tffm_tpu.checkpoint import save_checkpoint
     from fast_tffm_tpu.optim import AdagradState
     from fast_tffm_tpu.trainer import TrainState
 
-    table = seed_table(seed, cfg.vocabulary_size, 1 + cfg.factor_num)
+    table = seed_table(seed, cfg.vocabulary_size, row_dim)
     cols = table.shape[1] if cfg.adagrad_accumulator == "element" else 1
     accum = np.broadcast_to(np.float32(cfg.init_accumulator_value), (table.shape[0], cols))
     state = TrainState(table, AdagradState(accum), {}, AdagradState({}), jnp.zeros((), jnp.int32))
     save_checkpoint(cfg.model_file, state, "npz")
 
 
-def pool_reference(seed, spec, vocab, row_dim, dtype=None):
+def pool_reference(seed, spec, model, dtype=None):
     """The reference's score of every row of the frame pool, [pool, rows]."""
     import jax.numpy as jnp
 
     _, ids, vals = loadgen.pool_rows(seed, spec)
-    table = seed_table(seed, vocab, row_dim)
+    fields = gen.column_fields(ids)
+    table = seed_table(seed, spec["vocab"], model.row_dim)
     out = [
-        np.asarray(reference.score_rows(table, ids[i : i + _POOL_BLOCK], vals[i : i + _POOL_BLOCK], dtype or jnp.float32))
+        np.asarray(reference.score_rows(model.score, table, *(a[i : i + _POOL_BLOCK] for a in (ids, vals, fields)), dtype or jnp.float32))
         for i in range(0, ids.shape[0], _POOL_BLOCK)
     ]
     return np.concatenate(out).reshape(spec["pool_frames"], spec["frame_rows"])
@@ -123,10 +136,9 @@ def planted(cell, seed, what):
 
     if what != "control":
         raise ValueError(what)
-    spec = _spec(cell, seed, 1.0, 0, "")
-    row_dim = 1 + int(cell["ini"]["General"]["factor_num"])
-    ref = pool_reference(seed, spec, spec["vocab"], row_dim)
-    low = pool_reference(seed, spec, spec["vocab"], row_dim, jnp.bfloat16)
+    spec, model = _spec(cell, seed, 1.0, 0, ""), served_model(cell)
+    ref = pool_reference(seed, spec, model)
+    low = pool_reference(seed, spec, model, jnp.bfloat16)
     return {"score_gap": float(np.max(np.abs(low - ref))), "unanswered_rows": 0.0}
 
 
@@ -249,11 +261,11 @@ def population(res, seconds):
     }
 
 
-def _model_and_config(cell, seed, name, workroot, phase):
+def _model_and_config(cell, model, seed, name, workroot, phase):
     """An emptied work directory with the cell's INI file and the model file
     made from the seed; (directory, loaded Config)."""
     work, cfg = common.configured(cell, name, workroot)
-    write_model_file(cfg, seed)
+    write_model_file(cfg, seed, model.row_dim)
     phase("model file written")
     # The model file's dirty pages go to disk now, not under the window, and
     # what this process has built so far is kept out of later collections:
@@ -268,9 +280,10 @@ def _model_and_config(cell, seed, name, workroot, phase):
 def sweep(cell, seed, rates, seconds, t_start):
     """The knee, once: one server, one short window at each rate.  Prints a
     line a rate; run by ``tests/chip_readings.py --what sweep``."""
+    model = served_model(cell)
     common.device_info(cell["chips"])
     phase = common.phases(t_start)
-    work, cfg = _model_and_config(cell, seed, cell["name"] + ".sweep", cells.CHECKOUT, phase)
+    work, cfg = _model_and_config(cell, model, seed, cell["name"] + ".sweep", cells.CHECKOUT, phase)
     with serving(cfg) as (router, fe):
         for rate in rates:
             spec = _spec(cell, seed, seconds, fe.port, os.path.join(work, f"loadgen_{rate}"))
@@ -292,12 +305,12 @@ def sweep(cell, seed, rates, seconds, t_start):
 
 
 def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cells.CHECKOUT, keep_events=None):
+    model = served_model(cell)
     device = common.device_info(cell["chips"], require_chip)
     phase = common.phases(t_start)
     tr = cell["traffic"]
     runtime_start_s = phase("device found")
-    work, cfg = _model_and_config(cell, seed, cell["name"], workroot, phase)
-    row_dim = 1 + cfg.factor_num
+    work, cfg = _model_and_config(cell, model, seed, cell["name"], workroot, phase)
 
     trace_dir = os.path.join(work, "trace")
     with serving(cfg) as (router, fe):
@@ -309,7 +322,7 @@ def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cell
 
     pop = population(res, seconds)
     # Every answer due in the window against the reference's score of its row.
-    ref = pool_reference(seed, spec, cfg.vocabulary_size, row_dim)
+    ref = pool_reference(seed, spec, model)
     w = slice(int(res["n_warm"]), None)
     answered = res["status"][w] == 1
     gaps = np.abs(res["score"][w] - ref[res["which"][w]])[answered]
@@ -336,9 +349,9 @@ def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cell
             **{k: pop[k] for k in ("latency_ms_p95", "latency_ms_p99", "latency_ms_p99_by_1s", "late_ms_p99")},
         }
         if red and red["busy_s"]:
-            least, _ = peaks.least_seconds(0.0, peaks.modeled_score_bytes(d("go", "close", "rows"), cfg.max_nnz, row_dim), device["kind"])
+            least, _ = peaks.least_seconds(0.0, model.score_bytes(d("go", "close", "rows"), cfg.max_nnz), device["kind"])
             values["score_mfu"] = 100.0 * least / red["busy_s"]
-        ctx = {"records": readers.read_jsonl(cfg.metrics_path + ".r0"), "steps": "warmup_flag", "values": values, "trace": red}
+        ctx = {"records": readers.read_jsonl(cfg.metrics_path + ".r0"), "steps": "warmup_flag", "values": values, "trace": red, "trace_dir": trace_dir, "model": model}
         result["metrics"] = readers.read_all(cells.load_metrics(cell["kind"], cell["bench_dir"]), ctx)
     else:
         result["metrics"] = {

@@ -19,7 +19,7 @@ import traceback
 
 import numpy as np
 
-from . import cells, common, gen, peaks, readers, reference
+from . import cells, common, gen, readers, reference
 
 CHECK_STEPS = 3
 
@@ -70,15 +70,16 @@ def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cell
     phase = common.phases(t_start)
     tr, ini = cell["traffic"], cell["ini"]
     batch, nnz = int(ini["Train"]["batch_size"]), int(ini["Train"]["max_nnz"])
-    hyper = _hyper(ini)
-    vocab, k = hyper["vocab"], hyper["k"]
+    hyper, model = _hyper(ini), cell["model"]
+    vocab = hyper["vocab"]
     log_every = int(ini["Train"]["log_every"])
     n_batches = int(tr["file_batches"])
     runtime_start_s = phase("device found")
     labels, ids, vals = gen.rows_from_seed(seed, n_batches * batch, nnz, vocab, tr.get("zipf_alpha", 2.5))
     phase("rows drawn")
     work, cfg = common.configured(cell, cell["name"], workroot, train_file="train.fmb")
-    gen.write_fmb(cfg.train_files[0], labels, ids, vals, vocab)
+    fields = gen.column_fields(ids)
+    gen.write_fmb(cfg.train_files[0], labels, ids, vals, vocab, fields if model.reads_fields else None)
     phase("input made")
 
     # The rows the first three steps touch: what the check reads back.
@@ -145,7 +146,7 @@ def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cell
     gc.collect()
     phase("state read back")
     n3 = CHECK_STEPS * batch
-    ref = followed(hyper, first, vals[:n3].reshape(first.shape), labels[:n3].reshape(CHECK_STEPS, batch), u, u1)
+    ref = followed(hyper, model, first, vals[:n3].reshape(first.shape), fields[:n3].reshape(first.shape), labels[:n3].reshape(CHECK_STEPS, batch), u, u1)
     numbers = compare(got, ref, hyper["lr"])
     correct, compared = common.decide(numbers, tr["limits"])
     phase("reference followed and compared")
@@ -160,17 +161,19 @@ def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cell
     if do_trace:
         red = common.reduce_trace(result, trace_dir, keep_events)
         m = min(4, n_batches)
-        work_bytes = np.mean([peaks.modeled_step_bytes(ids[i * batch : (i + 1) * batch], k + 1, k + 1) for i in range(m)], axis=0)
+        work_bytes = np.mean([model.step_bytes(ids[i * batch : (i + 1) * batch]) for i in range(m)], axis=0)
         ctx = {
             "records": readers.read_jsonl(cfg.metrics_path),
             "steps": (s_open, s_close),
             "trace": red,
+            "trace_dir": trace_dir,
             "n_steps": steps,
             "device_kind": device["kind"],
             "chips": cell["chips"],
+            "model": model,
             "values": {"runtime_start_s": runtime_start_s},
             "step_bytes": float(work_bytes[0]),
-            "step_flops": peaks.modeled_step_flops(batch * nnz, int(work_bytes[1]), k + 1),
+            "step_flops": model.step_flops(batch, nnz, int(work_bytes[1])),
         }
         result["metrics"] = readers.read_all(cells.load_metrics(cell["kind"], cell["bench_dir"]), ctx)
     else:
@@ -189,18 +192,18 @@ def _pad(rows, n):
     return np.concatenate([rows, np.full(n - rows.size, rows[0], rows.dtype)])
 
 
-def followed(h, first, vals, labels, u, u1, dtype=None, shards=0):
+def followed(h, model, first, vals, fields, labels, u, u1, dtype=None, shards=0):
     """The reference over the first three batches (``first`` ids [3, B, N],
-    ``vals`` [3, B, N], ``labels`` [3, B]), on the compact table of the rows
-    ``u`` they touch.  Returns what ``compare`` reads, as numpy."""
+    ``vals`` and ``fields`` [3, B, N], ``labels`` [3, B]), on the compact table
+    of the rows ``u`` they touch.  Returns what ``compare`` reads, as numpy."""
     import jax.numpy as jnp
 
-    t0 = reference.init_rows(h["vocab"], h["k"], h["init_range"], _pad(u, CHECK_STEPS * h["rows_per_step"]))
+    t0 = model.init_rows(_pad(u, CHECK_STEPS * h["rows_per_step"]))
     idx = np.searchsorted(u, first).astype(np.int32)
     # Padding rows of the compact table are never read; shard 0 may own them.
     owner = _pad(u // (h["vocab"] // shards), t0.shape[0]).astype(np.int32) if shards else None
     outs = reference.train_steps(
-        t0, list(zip(idx, vals, labels)), h["lr"], h["accum0"], h["bias_lambda"], h["factor_lambda"],
+        model.score, t0, list(zip(idx, vals, fields, labels)), h["lr"], h["accum0"], h["bias_lambda"], h["factor_lambda"],
         dtype=dtype or jnp.float32, owner=owner,
     )
     at1 = np.searchsorted(u, u1)
@@ -224,18 +227,19 @@ def planted(cell, seed, what):
 
     ini = cell["ini"]
     batch, nnz = int(ini["Train"]["batch_size"]), int(ini["Train"]["max_nnz"])
-    h = _hyper(ini)
+    h, model = _hyper(ini), cell["model"]
     labels, ids, vals = gen.rows_from_seed(seed, CHECK_STEPS * batch, nnz, h["vocab"], cell["traffic"].get("zipf_alpha", 2.5))
     first, vals, labels = ids.reshape(CHECK_STEPS, batch, nnz), vals.reshape(CHECK_STEPS, batch, nnz), labels.reshape(CHECK_STEPS, batch)
+    fields = gen.column_fields(first)
     u, u1 = np.unique(first), np.unique(first[0])
-    ref = followed(h, first, vals, labels, u, u1)
+    ref = followed(h, model, first, vals, fields, labels, u, u1)
     if what == "control":
-        bad = followed(h, first, vals, labels, u, u1, dtype=jnp.bfloat16)
+        bad = followed(h, model, first, vals, fields, labels, u, u1, dtype=jnp.bfloat16)
     elif what == "half_batch":
         half = batch // 2
-        bad = followed(h, first[:, :half], vals[:, :half], labels[:, :half], u, u1)
+        bad = followed(h, model, first[:, :half], vals[:, :half], fields[:, :half], labels[:, :half], u, u1)
     elif what == "no_exchange":
-        bad = followed(h, first, vals, labels, u, u1, shards=cell["chips"])
+        bad = followed(h, model, first, vals, fields, labels, u, u1, shards=cell["chips"])
     else:
         raise ValueError(what)
     return compare(bad, ref, h["lr"])
@@ -243,7 +247,7 @@ def planted(cell, seed, what):
 
 def _hyper(ini):
     return {
-        "vocab": int(ini["General"]["vocabulary_size"]), "k": int(ini["General"]["factor_num"]),
+        "vocab": int(ini["General"]["vocabulary_size"]),
         "rows_per_step": int(ini["Train"]["batch_size"]) * int(ini["Train"]["max_nnz"]),
         "init_range": float(ini["Train"].get("init_value_range", 0.01)),
         "lr": float(ini["Train"]["learning_rate"]),

@@ -59,7 +59,8 @@ def main(argv=None) -> int:
     state = jax.eval_shape(init)
     batch = Batch(
         labels=jax.ShapeDtypeStruct((b,), jnp.float32), ids=jax.ShapeDtypeStruct((b, n), jnp.int32),
-        vals=jax.ShapeDtypeStruct((b, n), jnp.float32), fields=jax.ShapeDtypeStruct((b, 0), jnp.int32),
+        vals=jax.ShapeDtypeStruct((b, n), jnp.float32),
+        fields=jax.ShapeDtypeStruct((b, n if cell["model"].reads_fields else 0), jnp.int32),
         weights=jax.ShapeDtypeStruct((b,), jnp.float32),
     )
     place = lambda tree, sh: jax.tree.map(

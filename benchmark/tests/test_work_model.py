@@ -3,15 +3,15 @@
 import numpy as np
 import pytest
 
-from harness import peaks
+from harness import models, peaks
 
 
-@pytest.mark.parametrize("seed,row_dim,accum_cols", [(0, 9, 9), (1, 17, 17), (2, 9, 1)])
+@pytest.mark.parametrize("seed,row_dim,accum_cols", [(0, 9, 9), (1, 17, 17), (2, 9, 1), (3, 157, 157)])
 def test_step_bytes_equal_the_programs(seed, row_dim, accum_cols):
     from fast_tffm_tpu.profiling import modeled_step_bytes
 
     ids = np.random.default_rng(seed).integers(0, 5000, size=(256, 39))
-    assert peaks.modeled_step_bytes(ids, row_dim, accum_cols) == modeled_step_bytes(ids, row_dim, accum_cols)
+    assert models.sparse_step_bytes(ids, row_dim, accum_cols) == modeled_step_bytes(ids, row_dim, accum_cols)
 
 
 def test_least_time_names_its_bound_and_unknown_chips_are_errors():
