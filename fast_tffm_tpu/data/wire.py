@@ -289,11 +289,22 @@ def make_unpacker(spec: WireSpec):
     rb = spec.row_bytes
 
     def combine(x, k):
-        # uint8 [..., m*k] -> uint32 [..., m], little-endian.
-        x = x.reshape(*x.shape[:-1], -1, k).astype(jnp.uint32)
-        out = x[..., 0]
+        # uint8 [..., m*k] -> uint32 [..., m], little-endian: byte i of every
+        # word by one strided slice.  Written as a reshape to [..., m, k] the
+        # combine is hoisted by XLA over the whole wire buffer, and with
+        # k = 3 (ids of a table of up to 2^24 rows) the TPU compiler takes
+        # minutes over it where m is a million (12.7 min for 32,768 x 39 ids
+        # with fields).  On a v5e, both forms at 65,536 x 39 4-byte ids and
+        # values: reshape 8.9 ms and 1.25 GiB of temporaries, slices 6.5 ms
+        # and none; at the serving buckets 0.8 ms either way (PERF.md §6,
+        # PR 29).
+        planes = [
+            jax.lax.slice_in_dim(x, i, x.shape[-1], stride=k, axis=-1)
+            for i in range(k)
+        ]
+        out = planes[0].astype(jnp.uint32)
         for i in range(1, k):
-            out = out | (x[..., i] << (8 * i))
+            out = out | (planes[i].astype(jnp.uint32) << (8 * i))
         return out
 
     def as_f32(x):

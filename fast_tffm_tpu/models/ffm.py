@@ -17,16 +17,29 @@ tensor  T[a, b] = Σ_{i: fᵢ=a} z_i[b]  (z = v·x) so the double sum becomes
 O(N²) gather loop.  Padding (x=0) contributes z=0 and is exactly neutral.
 
 The vals factor x folds into the ONE-HOT operand (w[b,n,a] = x·1[f=a]),
-not into v: z = v·x as a separate [B, N, F, k] array is ~0.5 GB written
-+ read per direction at the benchmark shape (B=65536, 22 fields), and
-the fold removes that HBM round-trip while computing the identical
-per-term products (measured r5 — the cfg3p gap driver, VERDICT r4 #4).
+not into v, so that z = v·x is never a [B, N, F, k] array of its own.
 
-``compute_dtype='bfloat16'`` additionally runs the interaction einsums
-with bf16 INPUTS and f32 MXU accumulation (preferred_element_type):
-halves the bytes of the dominant [B, N, F, k] reads.  Scores move by
-O(1e-3) relative — fine for CTR ranking, so it is the bench's choice —
-while the default stays float32 (bit-parity with the oracle tests).
+In the compiled step the three parts carry scopes of their own under the
+``fm.interaction`` the scorer shares with the order-2 model: ``ffm.fieldsum``
+(the one-hot operand and the einsum into T), ``ffm.pairdot`` (⟨T[a,b],
+T[b,a]⟩) and ``ffm.diag`` (the i = j term); the backward arrives as
+``transpose(jvp(ffm.fieldsum))`` and so on.  What the chip read of each at
+libffm's Criteo shapes (39 fields, k = 4, rows of 157 floats, B = 32,768) is
+PERF.md §5, ``ffm4_criteo.train_fmb_fields``.
+
+Precision.  With ``compute_dtype = float32`` (the default) the three
+contractions ask for ``Precision.HIGHEST``: on a TPU a float32 matmul at
+the default precision is ONE bfloat16 pass, which made the float32
+configuration compute what ``compute_dtype = bfloat16`` states (the values x
+in the one-hot operand are not bfloat16 numbers): before PR 29 the score
+was 6e-3 of its size off the plain pair sum on the chip, with it 1e-9 in the
+benchmark's check.  It costs 2% of the step there (a bare loop over the
+compiled step reads 197.6 ms at the default precision, 201.5-201.7 at
+``HIGH`` and ``HIGHEST`` alike; a fused multiply-and-reduce off the MXU reads
+210.0), and on the CPU the setting changes nothing.
+``compute_dtype = bfloat16`` runs the contractions with bfloat16 INPUTS and
+float32 accumulation (preferred_element_type): scores move by O(1e-3)
+relative, and the benchmark's check tells the two apart.
 """
 
 from __future__ import annotations
@@ -35,8 +48,20 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from fast_tffm_tpu.models.base import Batch, masked_l2
+
+
+def _dot_inputs(a: jax.Array, b: jax.Array):
+    """The operands of a batched contraction as the backend can take them.
+    XLA's CPU backend has no batched bfloat16 x bfloat16 -> float32 dot
+    (``Unsupported element type for DotThunk``), so there the inputs, rounded
+    to bfloat16 already, go in as float32: the same products, each exact in
+    float32, summed in float32."""
+    if a.dtype == jnp.bfloat16 and jax.default_backend() == "cpu":
+        return a.astype(jnp.float32), b.astype(jnp.float32)
+    return a, b
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,22 +103,30 @@ class FFMModel:
         v = rows[..., 1:].reshape(B, N, F, k)  # v[b, i, partner_field, :]
         linear = jnp.sum(bias * batch.vals, axis=-1)
         dt = jnp.dtype(self.compute_dtype)
+        # A float32 contraction at the TPU's default precision is one
+        # bfloat16 pass (module doc); bfloat16 inputs need no more than that.
+        prec = lax.Precision.HIGHEST if dt == jnp.float32 else None
         vc = v.astype(dt)
-        # x folds into the one-hot operand (w = x·1[f=a]) so z = v·x never
-        # materializes as [B, N, F, k]; same per-term products (module doc).
-        woh = jax.nn.one_hot(batch.fields, F, dtype=dt) * batch.vals[
-            ..., None
-        ].astype(dt)
-        # T[b, a, g, :] = Σ_{i: field_i = a} x_i · v[b, i, g, :]
-        T = jnp.einsum(
-            "bna,bngk->bagk", woh, vc, preferred_element_type=jnp.float32
-        )
-        cross = jnp.einsum("bagk,bgak->b", T, T)
-        # Diagonal (i == j) correction: z_i[f_i] per nonzero.
-        z_self = jnp.einsum(
-            "bnfk,bnf->bnk", vc, woh, preferred_element_type=jnp.float32
-        )
-        diag = jnp.sum(z_self * z_self, axis=(1, 2))
+        with jax.named_scope("ffm.fieldsum"):
+            # x folds into the one-hot operand (w = x·1[f=a]) so z = v·x never
+            # materializes as [B, N, F, k]; same per-term products (module doc).
+            woh = jax.nn.one_hot(batch.fields, F, dtype=dt) * batch.vals[
+                ..., None
+            ].astype(dt)
+            # T[b, a, g, :] = Σ_{i: field_i = a} x_i · v[b, i, g, :]
+            T = jnp.einsum(
+                "bna,bngk->bagk", *_dot_inputs(woh, vc), precision=prec,
+                preferred_element_type=jnp.float32,
+            )
+        with jax.named_scope("ffm.pairdot"):
+            cross = jnp.einsum("bagk,bgak->b", T, T, precision=prec)
+        with jax.named_scope("ffm.diag"):
+            # Diagonal (i == j) correction: z_i[f_i] per nonzero.
+            z_self = jnp.einsum(
+                "bnfk,bnf->bnk", vc, woh, precision=prec,
+                preferred_element_type=jnp.float32,
+            )
+            diag = jnp.sum(z_self * z_self, axis=(1, 2))
         return linear + 0.5 * (cross - diag)
 
     def regularization(self, rows: jax.Array, dense, batch: Batch) -> jax.Array:

@@ -246,7 +246,8 @@ class CostLedger:
     after a dispatch completes: the lowering runs inside the monitor's
     warmup window (it compiles nothing, but any concurrent stats/unpack
     compile must not read as steady-state) and the record lands with
-    measured bytes/FLOPs next to whatever modeled floor the driver
+    measured bytes/FLOPs (null where the backend cannot analyse a
+    lowering) next to whatever modeled floor the driver
     supplied.  Each name measures once per run; un-lowerable callables
     (driver closures that chose not to expose ``.lower``) are skipped
     silently — measurement is additive, never required."""
@@ -292,9 +293,12 @@ class CostLedger:
         with (ctx() if ctx is not None else contextlib.nullcontext()):
             for name, (fn, absargs, examples, modeled, meta) in pending.items():
                 self._done.add(name)
-                cost = program_cost(fn, absargs)
-                if cost is None:
-                    continue
+                # None where the backend has no cost analysis of a lowering
+                # (the TPU's PJRT client): the record is written all the
+                # same, measured fields null, so that what was dispatched
+                # (examples, the modeled floor, the driver's ``meta``) is on
+                # record on the chip too.
+                cost = program_cost(fn, absargs) or {}
                 body = dict(
                     program=name,
                     flops=cost.get("flops"),

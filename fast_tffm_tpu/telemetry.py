@@ -134,7 +134,8 @@ SCHEMAS: dict[str, tuple[str, ...]] = {
     ),
     # Deep observability (profiling.py).  profile: one record per
     # measured compiled program (XLA cost analysis — bytes/flops null
-    # only for trace start/stop event records, program="trace");
+    # for trace start/stop event records, program="trace", and where the
+    # backend has no analysis of a lowering, as the TPU's PJRT client);
     # datastats: sampled device-side id-traffic statistics (dedup ratio,
     # heavy-hitter sketch mass, cumulative rows seen); freshness: the
     # publish→applied / publish→first-scored-with-new-rows SLO measured

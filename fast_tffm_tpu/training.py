@@ -576,6 +576,7 @@ def _run_training(
     saveable=None,
     step_hook=None,
     row_dim=0,
+    tail_lanes=None,
     mark_touched=None,
     start_cursor=None,
     rollback=None,
@@ -616,6 +617,9 @@ def _run_training(
     optional custom touched-row bitmap marker — the device-cache drivers
     mark from their resident id arrays) parameterize the async/delta
     checkpoint subsystem (checkpoint_async.AsyncCheckpointer).
+    ``tail_lanes`` (the row width the rows layout's XLA tail sums segments
+    at, ``optim.segment_sum_lanes``; None on every other tail) rides the
+    step's ``kind=profile`` record beside ``row_dim``.
 
     ``datastats_ids`` (optional ``batch -> device ids``) lets the sampled
     id-statistics collector read a device-cache batch's ids straight off
@@ -770,6 +774,7 @@ def _run_training(
                     modeled = None
         ledger.stage(
             "train_step", step_fn, (state, b), examples=ex, modeled_bytes=modeled,
+            row_dim=max(1, row_dim), segment_sum_lanes=tail_lanes,
         )
 
     # Pod liveness: this host's heartbeat (armed at bring-up) starts
@@ -1332,6 +1337,7 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
     from fast_tffm_tpu.ops.pallas_common import resolve_tail
 
     tail = resolve_tail(cfg.tail)
+    tail_lanes = None  # only the rows layout's XLA tail has a segment sum
     if packed:
         predict_step = make_packed_predict_step(model, fused=fused)
         packed_tail = tail if fused else "xla"
@@ -1372,15 +1378,13 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
         else:
             step_body = None
         if tail != "pallas":
-            from fast_tffm_tpu.optim import describe_rows_tail
+            from fast_tffm_tpu.optim import describe_rows_tail, segment_sum_lanes
 
+            m_ids = cfg.batch_size * cfg.max_nnz
+            tail_lanes = segment_sum_lanes(m_ids, state.table.shape[1])
             log(
                 "sparse tail: xla rows ("
-                + describe_rows_tail(
-                    state.table.shape[0],
-                    cfg.batch_size * cfg.max_nnz,
-                    state.table.shape[1],
-                )
+                + describe_rows_tail(state.table.shape[0], m_ids, state.table.shape[1])
                 + ")"
             )
         step_fn = make_train_step(
@@ -1396,7 +1400,7 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
     to_batch = _batch_converter(model.uses_fields)
     run_kwargs = dict(
         to_batch=to_batch, saveable=saveable, step_hook=step_hook,
-        row_dim=model.row_dim,
+        row_dim=model.row_dim, tail_lanes=tail_lanes,
     )
     if cfg.online_accum_restart_steps > 0:
         from fast_tffm_tpu.trainer import make_accum_restart

@@ -115,6 +115,43 @@ def test_streamed_profile_and_datastats(dataset):
     assert summary["profile_train_bytes_per_example"] == prof["bytes_per_example"]
 
 
+@pytest.mark.parametrize(
+    "kw, row_dim, lanes",
+    [
+        (dict(model="fm", factor_num=4), 5, 128),  # under a tile: padded to one
+        (dict(model="ffm", factor_num=4, num_fields=39), 157, 256),  # libffm's Criteo row: two tiles
+        (dict(model="fm", factor_num=127), 128, 128),  # tile-wide already
+        (dict(model="fm", factor_num=4, table_layout="packed"), 5, None),  # no rows-layout segment sum
+    ],
+    ids=["fm_k4", "ffm_39x4", "fm_k127", "packed"],
+)
+def test_the_steps_profile_record_says_the_row_width_and_the_tails_lanes(dataset, kw, row_dim, lanes):
+    """What only the start-up log line said (``describe_rows_tail``): the row
+    width and the lanes ``dedup_rows`` sums segments at, a trace-time choice."""
+    cfg = _cfg(dataset, tag="lanes", epoch_num=1, **kw)
+    train(cfg, log=lambda *_: None)
+    (prof,) = [r for r in _read(cfg.metrics_path) if r["kind"] == "profile" and r["program"] == "train_step"]
+    assert (prof["row_dim"], prof["segment_sum_lanes"]) == (row_dim, lanes)
+
+
+def test_a_backend_without_cost_analysis_still_records_what_was_dispatched(dataset, monkeypatch):
+    """The TPU's PJRT client analyses no lowering (``Lowered.cost_analysis``
+    is None there): the record is written with the measured fields null."""
+    from fast_tffm_tpu import profiling
+
+    monkeypatch.setattr(profiling, "program_cost", lambda fn, args: None)
+    cfg = _cfg(dataset, tag="nocost", epoch_num=1)
+    train(cfg, log=lambda *_: None)
+    records = _read(cfg.metrics_path)
+    _assert_schema(records)
+    (prof,) = [r for r in records if r["kind"] == "profile" and r["program"] == "train_step"]
+    assert prof["flops"] is None and prof["bytes_accessed"] is None and prof["bytes_per_example"] is None
+    assert prof["examples"] == cfg.batch_size and prof["modeled_hbm_bytes"] > 0
+    assert (prof["row_dim"], prof["segment_sum_lanes"]) == (5, 128)
+    (summary,) = [r for r in records if r["kind"] == "summary"]
+    assert "profile_train_bytes_per_example" not in summary
+
+
 def test_device_cache_profile_and_datastats(dataset):
     """The device-cached path (scan-fused): the cached step closures
     delegate .lower to the inner jit, so the ledger still measures, and

@@ -40,17 +40,17 @@ from fast_tffm_tpu.training import train
 VOCAB = 1000
 
 
-def _random_parsed(rng, rows=9, width=8, ones=False, with_fields=False):
+def _random_parsed(rng, rows=9, width=8, ones=False, with_fields=False, vocab=VOCAB):
     lines = []
     for _ in range(rows):
         nnz = int(rng.integers(1, width - 1))
         toks = []
         for _ in range(nnz):
             val = 1 if ones else round(float(rng.normal()), 4)
-            fid = rng.integers(0, VOCAB)
+            fid = rng.integers(vocab // 2, vocab)  # the id's top byte is in use
             toks.append(f"{rng.integers(0, 4)}:{fid}:{val}" if with_fields else f"{fid}:{val}")
         lines.append(f"{rng.integers(0, 2)} {' '.join(toks)}")
-    return parse_lines(lines, vocabulary_size=VOCAB, max_nnz=width)
+    return parse_lines(lines, vocabulary_size=vocab, max_nnz=width)
 
 
 def _assert_batches_equal(got: Batch, ref: Batch):
@@ -65,16 +65,20 @@ def _assert_batches_equal(got: Batch, ref: Batch):
 
 @pytest.mark.parametrize("with_fields", [False, True])
 @pytest.mark.parametrize("with_weights", [False, True])
-def test_roundtrip_explicit_vals(with_fields, with_weights):
+@pytest.mark.parametrize("vocab", [VOCAB, 1 << 20, 1 << 26], ids=["ids2", "ids3", "ids4"])
+def test_roundtrip_explicit_vals(with_fields, with_weights, vocab):
+    """Every id width: 3 bytes (a table of up to 2^24 rows) is read on the
+    device like the others, byte planes by strided slices (wire.make_unpacker)."""
     rng = np.random.default_rng(0)
-    p = _random_parsed(rng, with_fields=with_fields)
+    p = _random_parsed(rng, with_fields=with_fields, vocab=vocab)
     w = np.ones((p.batch_size,), np.float32)
     if with_weights:
         w[:] = 0.25  # non-uniform per-file weight
     spec = make_spec(
-        VOCAB, p.max_nnz, with_vals=True, with_fields=with_fields,
+        vocab, p.max_nnz, with_vals=True, with_fields=with_fields,
         with_weights=with_weights,
     )
+    assert spec.id_bytes == {VOCAB: 2, 1 << 20: 3, 1 << 26: 4}[vocab]
     got = WireConverter(spec)(p, w)
     ref = Batch.from_parsed(p, w, with_fields=with_fields)
     _assert_batches_equal(got, ref)
