@@ -572,8 +572,8 @@ def main():
     # (the per-tail lower-bound formula) — so tools/report.py can render
     # the two tails side by side against the HBM roof.  A leg the
     # compiler refuses records its error and fails the exit code (the
-    # Pallas tail does not compile on a TPU v5 lite today —
-    # ops/pallas_tail.py; ROADMAP S4 decides the kernel).
+    # fused layout's Pallas tail does not compile on a TPU v5 lite today —
+    # ops/pallas_tail.py; ROADMAP D2 decides the kernel).
     from fast_tffm_tpu.profiling import program_cost
 
     tail_modes = [

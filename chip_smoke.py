@@ -60,6 +60,11 @@ SERVE_ATOL = 2e-6
 # TPU v5 lite at B=16384 N=11 k=8: value identical, gradient 2.7e-7 of its
 # scale.  Relative to the largest magnitude, not elementwise.
 KERNEL_RTOL = 1e-5
+# The rows sweep against the XLA row operations on the same deduped
+# gradients: table and accumulator came out bit-equal at fm8_criteo's size
+# on a TPU v5 lite (PERF.md §6, PR 30); on the CPU the interpreted kernel
+# is another fusion, a few ULP off.  Steps here are about 1e-3, accumulators 0.1.
+ROWS_SWEEP_ATOL = 1e-7
 # Sharded first-step loss against the one-chip step on the same batch:
 # the bound __graft_entry__.py asserts on the CPU mesh.
 DIST_LOSS_ATOL = 1e-5
@@ -533,21 +538,32 @@ class Smoke:
             raise SmokeFailure("kernels", "anova_inter ran interpreted on the chip")
         if res["auto_tail"] != "xla":
             raise SmokeFailure(
-                "kernels", f"tail = auto resolves to {res['auto_tail']!r}, which "
-                "never compiled on the chip"
+                "kernels", f"tail = auto resolves to {res['auto_tail']!r} for the "
+                "packed layouts, whose kernel never compiled on the chip"
             )
-        for name in ("rows_tail", "fused_tail"):
-            refused = res[name].get("refused")
-            if on_chip and not refused:
-                raise SmokeFailure(
-                    "kernels", f"{name} now compiles on the chip: auto could select "
-                    "it again — redo the ROADMAP S4 decision"
-                )
-            self.echo(
-                f"chip_smoke: kernels {name}: "
-                + (f"refused by the compiler ({refused[:160]}); not selected by auto"
-                   if refused else "interpreted (not a TPU)")
+        rows = res["rows_tail"]
+        if rows.get("refused"):
+            raise SmokeFailure("kernels", f"the rows sweep did not run: {rows['refused']}")
+        if on_chip and not rows["compiled"]:
+            raise SmokeFailure("kernels", "the rows sweep ran interpreted on the chip")
+        if not rows["max_abs_diff"] <= ROWS_SWEEP_ATOL:
+            raise SmokeFailure("kernels", f"the rows sweep disagrees with the XLA row operations: {rows}")
+        self.echo(
+            f"chip_smoke: kernels rows_tail: {'compiled' if rows['compiled'] else 'interpreted'}"
+            f"+matched (max_abs_diff={rows['max_abs_diff']:.2g}); what the rows layout's "
+            "auto takes where optim.rows_tail_form says so"
+        )
+        refused = res["fused_tail"].get("refused")
+        if on_chip and not refused:
+            raise SmokeFailure(
+                "kernels", "fused_tail now compiles on the chip: auto could select "
+                "it again — redo the ROADMAP D2 decision"
             )
+        self.echo(
+            "chip_smoke: kernels fused_tail: "
+            + (f"refused by the compiler ({refused[:160]}); not selected by auto"
+               if refused else "interpreted (not a TPU)")
+        )
         self.echo(
             f"chip_smoke: kernels ok platform={res['platform']} "
             f"anova_inter={'compiled' if a['compiled'] else 'interpreted'}+matched "
@@ -699,9 +715,11 @@ def kernels_child(b: int, n: int, k: int) -> None:
     }
 
     # The two tail kernels at BASELINE #1's row width (D = 9 lanes; few
-    # rows — the compiler's objection is to the row DMA's shape, which no
-    # row count changes).  On a TPU an explicit request must raise the
-    # compiler's message; on the CPU test mesh they interpret.
+    # rows).  The rows sweep (PR 30) compiles on a TPU and must match the
+    # XLA row operations; the fused kernel's per-row DMA does not (the
+    # compiler's objection is to the DMA's shape, which no row count
+    # changes), so an explicit request must raise the compiler's message.
+    # On the CPU test mesh both interpret.
     v, m = 4096, 512
     ids = jnp.asarray(rng.integers(0, v, (m,)), jnp.int32)
 
@@ -714,10 +732,23 @@ def kernels_child(b: int, n: int, k: int) -> None:
         return {"refused": None}
 
     g9 = jnp.asarray(rng.standard_normal((m, 9)) * 1e-2, jnp.float32)
-    out["rows_tail"] = attempt(
-        jax.jit(lambda t, a: rows_tail_adagrad_update(t, a, ids, g9, 0.05)),
-        jnp.zeros((v, 9), jnp.float32), jnp.full((v, 9), 0.1, jnp.float32),
-    )
+    t9, a9 = jnp.zeros((v, 9), jnp.float32), jnp.full((v, 9), 0.1, jnp.float32)
+    from fast_tffm_tpu.optim import AdagradState, sparse_adagrad_update
+
+    tail = {  # the same update in its two forms
+        "sweep": jax.jit(lambda t, a: rows_tail_adagrad_update(t, a, ids, g9, 0.05)),
+        "rows": jax.jit(
+            lambda t, a: sparse_adagrad_update(t, AdagradState(a), ids, g9, 0.05, form="rows")
+        ),
+    }
+    out["rows_tail"] = attempt(tail["sweep"], t9, a9)
+    if not out["rows_tail"]["refused"]:
+        want_t, want_s = tail["rows"](t9, a9)
+        got_t, got_a = tail["sweep"](t9, a9)
+        out["rows_tail"]["compiled"] = "tpu_custom_call" in tail["sweep"].lower(t9, a9).as_text()
+        out["rows_tail"]["max_abs_diff"] = float(
+            jnp.maximum(jnp.max(jnp.abs(got_t - want_t)), jnp.max(jnp.abs(got_a - want_s.accum)))
+        )
     fused = pack_fused(
         jnp.zeros((v, 8), jnp.float32), jnp.full((v, 1), 0.1, jnp.float32), 0.1
     )

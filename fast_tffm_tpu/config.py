@@ -83,13 +83,15 @@ class Config:
     #   touched-row compaction, O(M) buffers — the giant-vocab path; sorted =
     #   the bit-parity reference pipeline; auto picks dense/compact by size)
     tail: str = "auto"  # sparse Adagrad tail: xla (the gather/scatter program
-    #   chain) | pallas (ops/pallas_tail.py one-pass gather→update→scatter
-    #   kernel, double-buffered row DMA) | auto (= xla: the kernel does not
-    #   compile on the chip yet, so auto never selects it; an explicit
-    #   pallas raises the compiler's error on a TPU).  pallas with
-    #   table_layout=packed requires adagrad_accumulator=fused (the kernel's
-    #   merged layout); incompatible with dedup_gather_rows (the kernel
-    #   dedups internally)
+    #   chain) | pallas (ops/pallas_tail.py: on the rows layout one in-place
+    #   sweep over table and accumulator, on the fused layout the per-row
+    #   DMA kernel, which does not compile on the chip and raises there) |
+    #   auto (rows layout: the sweep on a TPU where optim.rows_tail_form
+    #   reckons it cheaper than the batch's row operations — sub-tile rows,
+    #   a batch that touches most of the table — else xla; packed layouts:
+    #   xla).  pallas with table_layout=packed requires
+    #   adagrad_accumulator=fused (the kernel's merged layout); incompatible
+    #   with dedup_gather_rows (no contract there; auto still chooses)
     thread_num: int = 0  # host-side parse workers; 0 = all cores (reference: queue threads)
     binary_cache: bool = False  # parse text once into <file>.fmb, stream that
     binary_cache_wait: float = 600.0  # multi-host: non-lead wait for lead's build (s)

@@ -1,67 +1,73 @@
-"""Fused Pallas sparse tail: one-pass gather→Adagrad→scatter.
+"""Pallas sparse tails: the Adagrad update of the touched rows as one kernel.
 
-The XLA sparse tail is a CHAIN of programs — grad lane-spread, bitmap/
-cumsum (or sort) compaction, RMW gather, RMW scatter — each of which
-walks its own descriptor stream over the same touched rows.  This module
-replaces the tail with ONE Pallas TPU kernel per table layout:
+The XLA sparse tail is a CHAIN of programs — dedup (sort, segment sum), an
+accumulator gather, two table-shaped scatters — each of which walks its own
+descriptor stream over the same touched rows.  This module holds two
+kernels that take the tail's place after the SAME dedup (optim.dedup_rows —
+the sort/segment-sum pipeline the rows-layout classic update uses, so the
+summed gradients are bit-identical to it):
 
-  * dedup ONCE at **logical-row** granularity (optim.dedup_rows — the
-    sort/segment-sum pipeline the rows-layout classic update already
-    uses, so the compacted gradients are bit-identical to it), then
-  * a single kernel pass: per deduped row, DMA **only the touched
-    lanes** HBM→VMEM (for the fused ``[VPf, 128]`` layout that is the
-    row's own ``D+1``-lane slot — params + its in-row accumulator — not
-    the whole 128-lane tile row), apply the Adagrad update in VMEM, and
-    DMA the result straight back.  Gather and scatter ride the same
-    pass, double-buffered two row-blocks deep: block ``i+1``'s gather
-    DMAs issue while block ``i`` computes, and block ``i``'s scatter
-    DMAs drain while ``i+1`` computes.
-  * the output aliases the table operand (``input_output_aliases``), so
-    the update is in place — untouched rows are never read or written.
+  * ``rows_tail_adagrad_update`` / ``sweep_adagrad_update`` — the **rows
+    sweep** (PR 30) for a plain ``[V, D]`` table with a separate ``[V, D]``
+    (element) or ``[V, 1]`` (row) accumulator: the resident rows layout AND
+    the tiered paramstore's compact ``[C, D]`` device table.  It never
+    addresses a row.  A ``[V, D]`` float32 buffer with ``D < 128`` is held
+    lane-major on the TPU (``{0,1:T(8,128)}``: the row index along the
+    lanes), which IS the row-major layout of its transpose, so the kernel
+    takes ``table.T`` / ``accum.T`` (bitcasts in the compiled step), walks
+    them block by block IN PLACE (``input_output_aliases``) and writes whole
+    tile columns.  The batch's dense delta never exists in HBM: a work list
+    computed from the sorted unique ids (scalar prefetch) pairs every block
+    with the 256-id chunks that fall in it, and the kernel builds the
+    block's gradient in VMEM as a one-hot of the ids against the row index,
+    contracted with the gradients on the MXU — exact in float32, because
+    every output has one non-zero term and the gradient goes in as three
+    bfloat16 parts that sum back to it bit for bit; a row of ones in the
+    gradients returns the hit mask.  Adagrad is then the classic
+    expressions on the block, SELECTED by the hit mask: an untouched row
+    comes out bit for bit whatever its accumulator holds (0 included), and a
+    lazily decayed accumulator decays only where touched.  Blocks no id
+    falls in are not visited.  A non-finite gradient spreads NaN over the
+    touched rows of its chunk's tiles (0·inf in the contraction); the step's
+    loss is non-finite then and the trainer's ``on_nan`` policy has it.
+  * ``fused_tail_adagrad_update`` — the resident fused layout
+    (``ops.packed_table.pack_fused``, ``[VPf, 128]``, P = 128//(D+1)
+    logical rows per tile row; accumulator in lane ``s·(D+1)+D``): per
+    deduped row, DMA **only the touched lanes** HBM→VMEM (the row's own
+    ``D+1``-lane slot), apply the update in VMEM, DMA the result back,
+    double-buffered two row-blocks deep (``_schedule``); the output aliases
+    the table operand, so untouched rows are never read or written.
 
 Decay-γ (``[Online] adagrad_decay``) threads through exactly like
 ``trainer.make_decayed_body``: γ=1.0 is a TRACE-TIME branch back to the
-classic expression (``accum += g²``), so the default program — and its
-bits — are untouched; γ<1 decays lazily, and *only the deduped touched
-rows* ever reach the kernel, which is precisely the lazy-decay contract.
-Correctness of the slot-slice RMW rests on the zero-grad identity: a row
-(or lane) with zero summed gradient maps to exactly itself
-(``acc+0 = acc``; ``w − lr·0/√acc = w``), so rows the batch doesn't
-touch can simply never enter the kernel.
-
-Layouts served:
-
-  * ``fused_tail_adagrad_update`` — the resident fused layout
-    (``ops.packed_table.pack_fused``, ``[VPf, 128]``, P = 128//(D+1)
-    logical rows per tile row; accumulator in lane ``s·(D+1)+D``).
-  * ``rows_tail_adagrad_update`` — a plain ``[V, D]`` table with a
-    separate ``[V, D]`` (element) or ``[V, 1]`` (row) accumulator: the
-    resident rows layout AND the tiered paramstore's compact ``[C, D]``
-    device table (the staging region already holds exactly the operand
-    shape the kernel wants — remapped slot ids against a compact table).
+classic expression (``accum += g²``); γ<1 decays lazily, touched rows only.
 
 Both run under ``interpret=`` for CPU tier-1 (ops.pallas_common resolves
 the flag, same pattern as ops/pallas_anova.py).
 
-STATUS ON THE CHIP (PR 22; TPU v5 lite, jax 0.9.0, libtpu 0.0.34, at
-baseline #1's width — M = 16384×39 ids, D = 9 / D+1 = 9 lanes): neither
-kernel compiles.  Mosaic refuses the per-row DMA both are built on::
+STATUS ON THE CHIP (TPU v5 lite, jax 0.9.0, libtpu 0.0.34).  The rows sweep
+compiles and runs (PR 30; tests/test_pallas_tail_chip_compile.py compiles
+it for a described v5e at the train cell's shapes): at ``fm8_criteo``'s
+shapes (2^26 rows of 9, 2,555,904 ids a step, 2.2M distinct) the kernel
+takes 38 ms inside the step (45 alone, with its work list) where the XLA
+row operations took 570, and table and accumulator come out as theirs
+(PERF.md §6 has the bitwise reading).  ``tail = auto`` takes it on a TPU where ``optim.rows_tail_form`` says
+the sweep costs less than the batch's row operations.  The fused kernel
+does NOT compile (PR 22): Mosaic refuses the per-row DMA it is built on::
 
     INTERNAL: Mosaic failed to compile TPU kernel: Slice shape along
     dimension 1 must be aligned to tiling (128), but is 9.
-      "tpu.memref_slice"(...) : (memref<1048576x128xf32,
+      "tpu.memref_slice"(...) : (memref<74904x128xf32,
       #tpu.tiled<(1,128),[1,1]>, #tpu.memory_space<hbm>>, i32, i32)
       -> memref<1x9xf32, #tpu.tiled<(1,128),[1,1]>, ...<hbm>>
 
-(rows layout: ``table_ref.at[row]``; fused layout: ``pl.ds(lane0, d+1)``,
-same message against ``memref<74904x128xf32>``).  An HBM row is stored
-128 lanes wide and a DMA window must cover whole tiles, so a 9-lane
-window does not exist; a whole-tile-row window would make two logical
-rows of one tile row overwrite each other — a different algorithm (dedup
-at tile-row granularity), not a repair.  So ``tail = auto`` resolves to
-the XLA tail (ops.pallas_common.resolve_tail), ``tail = pallas`` raises
-the message above on a TPU, and ROADMAP S4 decides whether this module
-stays.  The kernels still run interpreted on the CPU test mesh.
+An HBM row is stored 128 lanes wide and a DMA window must cover whole
+tiles, so a 9-lane window does not exist (the per-row DMA kernel that stood
+behind ``rows_tail_adagrad_update`` until PR 30 died of the same message
+and is gone).  So for the packed layouts ``tail = auto`` resolves to the
+XLA tail (ops.pallas_common.resolve_tail), ``tail = pallas`` on the fused
+layout raises the message above on a TPU, and ROADMAP D2 decides whether
+the fused kernel stays.  It still runs interpreted on the CPU test mesh.
 """
 
 from __future__ import annotations
@@ -80,10 +86,47 @@ from fast_tffm_tpu.ops.pallas_common import resolve_interpret
 __all__ = [
     "fused_tail_adagrad_update",
     "rows_tail_adagrad_update",
+    "sweep_adagrad_update",
+    "sweep_block_lanes",
+    "sweep_fits",
     "DEFAULT_BLOCK_ROWS",
 ]
 
 DEFAULT_BLOCK_ROWS = 256  # rows per grid step; 2 buffers × 256 × ≤128 lanes
+# The rows sweep (readings: PERF.md §6, PR 30, at fm8_criteo's shapes).
+# A block of the table is this many bytes in VMEM (its rows padded to whole
+# sublanes); eight such buffers are in flight (table and accumulator, in
+# and out, double-buffered).  512 KiB is 8,192 rows of 9 floats: blocks of
+# 4,096 / 8,192 / 16,384 rows read 57.1 / 55.9 / 55.8 ms (tiles of 512).
+_BLOCK_BYTES = 512 << 10
+# Table rows one one-hot contraction covers.  The loop over a chunk's tiles
+# costs more than the one-hot wasted at its ends: 128 / 256 / 512 / 1,024
+# rows read 105 / 73 / 57 / 50 ms (chunks of 128).
+_TILE = 1024
+# Updates one contraction runs over (two passes of the MXU's depth of 128):
+# fewer, longer work items; 128 / 256 read 50.4 / 46.3 ms.
+_CHUNK = 256
+
+
+def sweep_block_lanes(v: int, d: int, block_lanes: int | None = None) -> int:
+    """Table rows (lanes of the transposed view) a block of the sweep holds:
+    ``block_lanes`` if given, else what ``_BLOCK_BYTES`` holds of rows ``d``
+    wide in whole tiles of ``_TILE``; never more than the table itself
+    rounded up to whole 128-lane tiles."""
+    if block_lanes is None:
+        fit = _BLOCK_BYTES // (4 * -(-d // 8) * 8)
+        block_lanes = max(_TILE, fit // _TILE * _TILE)
+    return min(block_lanes, -(-v // 128) * 128)
+
+
+def sweep_fits(v: int, d: int, m: int) -> bool:
+    """Whether the sweep's work list for ``m`` ids on ``v`` rows of ``d`` —
+    three int32 a (block, chunk) item, scalar-prefetched — fits the scalar
+    memory: 1 MiB on a v5e ("Used 1.92M of 1.00M smem", the compiler on a
+    list of 176K items), of which three quarters are taken as the room:
+    65,536 items, about 15M ids a batch on 2^26 rows of 9."""
+    items = -(-v // sweep_block_lanes(v, d)) + -(-m // _CHUNK)
+    return 12 * items <= 768 << 10
 
 
 def _nblocks(k: int, blk: int) -> int:
@@ -281,64 +324,206 @@ def fused_tail_adagrad_update(
 
 # --------------------------------------------------------------------------
 # rows [V, D] (+ separate [V, A] accumulator) layout — resident rows path
-# and the tiered paramstore's compact [C, D] device table
+# and the tiered paramstore's compact [C, D] device table: one in-place
+# sweep over table and accumulator in their own lane-major layout
 # --------------------------------------------------------------------------
 
 
-def _rows_kernel(
-    uids_ref, nrows_ref, g_ref, table_ref, accum_ref, t_out_ref, a_out_ref,
-    tbuf, abuf, tin_sem, ain_sem, tout_sem, aout_sem,
-    *, lr: float, decay: float, d: int, a: int, blk: int, nblocks: int,
-    vmax: int,
+def _sweep_plan(uids, v: int, tb: int, tile: int):
+    """The sweep's work list, from the sorted ``uids`` alone (XLA, a few
+    arrays of ``nb + nchunks`` ints).
+
+    One item is one (block of ``tb`` table rows, chunk of ``_CHUNK``
+    updates) pair that overlap: block ``b`` owns ``uids[off[b]:off[b+1]]``
+    (``off = searchsorted(uids, b·tb)``), which lies in the chunks
+    ``off[b] // _CHUNK .. (off[b+1]-1) // _CHUNK``.  Blocks no update falls
+    in get no item — they are never read or written.  The list has a static
+    length (every block once plus every chunk boundary once); slots past the
+    real items repeat the last one's block and chunk with no tiles and no
+    flags, so the pipeline moves nothing and the kernel does nothing for
+    them.  At least one item is real (block and chunk of its own, possibly
+    matching nothing), so the output block the grid ends on has always been
+    written.
+
+    Returns int32 ``[w]`` arrays: the item's block, its chunk, and
+    ``meta`` = first tile | last tile << 10 | first-of-block << 20 |
+    last-of-block << 21, the tiles (``tile`` rows) of the block that the
+    chunk's ids in it span (none: first 1, last 0).
+    """
+    m_pad = uids.shape[0]
+    nb, nchunks = -(-v // tb), m_pad // _CHUNK
+    w = nb + nchunks
+    bounds = jnp.minimum(jnp.arange(nb + 1, dtype=jnp.int32) * tb, v)
+    off = jnp.searchsorted(uids, bounds, method="scan_unrolled").astype(jnp.int32)
+    lo, hi = off[:-1], off[1:]
+    c0 = lo // _CHUNK
+    n_items = jnp.where(hi > lo, (hi - 1) // _CHUNK - c0 + 1, 0)
+    end = jnp.cumsum(n_items)
+    start = end - n_items
+    total = jnp.maximum(end[-1], 1)
+    i = jnp.arange(w, dtype=jnp.int32)
+    real = i < total
+    i_eff = jnp.minimum(i, total - 1)
+    blk = jnp.minimum(
+        jnp.searchsorted(end, i_eff, side="right", method="scan_unrolled").astype(jnp.int32),
+        nb - 1,
+    )
+    ch = jnp.minimum(c0[blk] + i_eff - start[blk], nchunks - 1)
+    first = real & (i_eff == start[blk])
+    last = real & (i_eff >= end[blk] - 1)
+    s = jnp.maximum(lo[blk], ch * _CHUNK)
+    e = jnp.minimum(hi[blk], (ch + 1) * _CHUNK) - 1
+    some = real & (e >= s)  # a real item lacks ids only if the batch drops all
+    t0 = jnp.where(some, (uids[jnp.minimum(s, m_pad - 1)] - blk * tb) // tile, 1)
+    t1 = jnp.where(some, (uids[jnp.clip(e, 0, m_pad - 1)] - blk * tb) // tile, 0)
+    meta = (
+        t0 | (t1 << 10) | (first.astype(jnp.int32) << 20)
+        | (last.astype(jnp.int32) << 21)
+    )
+    return blk, ch, meta.astype(jnp.int32)
+
+
+def _split3(gt: jax.Array) -> jax.Array:
+    """``[dp, m]`` float32 → ``[3·dp, m]`` bfloat16 whose three row groups
+    (top 8 significand bits, the next 8, the last 8) sum back to the float32
+    values exactly.  By masks, not by convert pairs: the TPU compiler may
+    drop a float32 → bfloat16 → float32 round trip as excess precision, and
+    ``g − hi`` would then be zero.  (Gradients under about 1e-33, whose last
+    part is subnormal, lose it to the flush.)"""
+
+    def top(x):
+        bits = lax.bitcast_convert_type(x, jnp.uint32) & jnp.uint32(0xFFFF0000)
+        return lax.bitcast_convert_type(bits, jnp.float32)
+
+    hi = top(gt)
+    r = gt - hi
+    mid = top(r)
+    return jnp.concatenate([hi, mid, r - mid], axis=0).astype(jnp.bfloat16)
+
+
+def _sweep_kernel(
+    blk_ref, ch_ref, meta_ref, u_ref, g3_ref, t_ref, a_ref, t_out, a_out, gacc,
+    *, lr: float, decay: float, d: int, dp: int, tb: int, tile: int,
 ):
     i = pl.program_id(0)
-    nrows = nrows_ref[0]
+    meta = meta_ref[i]
+    base = blk_ref[i] * tb
 
-    def _run(block, slot, *, outward, wait):
-        base = block * blk
+    @pl.when(((meta >> 20) & 1) == 1)
+    def _():
+        gacc[...] = jnp.zeros_like(gacc)
 
-        def body(j, _):
-            @pl.when(base + j < nrows)
-            def _():
-                row = jnp.minimum(uids_ref[base + j], vmax - 1)
-                for hbm_in, hbm_out, vbuf, isem, osem in (
-                    (table_ref, t_out_ref, tbuf, tin_sem, tout_sem),
-                    (accum_ref, a_out_ref, abuf, ain_sem, aout_sem),
-                ):
-                    vref = vbuf.at[slot, j]
-                    href = (hbm_out if outward else hbm_in).at[row]
-                    src, dst = (vref, href) if outward else (href, vref)
-                    cp = pltpu.make_async_copy(
-                        src, dst, (osem if outward else isem).at[slot]
-                    )
-                    cp.wait() if wait else cp.start()
-            return 0
+    u = u_ref[...]  # [1, _CHUNK] ids, ascending
+    g3 = g3_ref[...]  # [3·dp, _CHUNK] the gradients' three parts
+    row = lax.broadcasted_iota(jnp.int32, (tile, _CHUNK), 0)
 
-        @pl.when(base < nrows)
-        def _():
-            lax.fori_loop(0, blk, body, 0)
+    def body(t, carry):
+        # One-hot of the chunk's ids against this tile's rows; ids of other
+        # tiles, other blocks and the drop ids match no row.
+        hot = jnp.where(row == u - (base + t * tile), 1.0, 0.0)
+        r = lax.dot_general(
+            g3, hot.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [3·dp, tile]: one non-zero term an output, so exact
+        gacc[t] += (r[:dp] + r[dp:2 * dp]) + r[2 * dp:]
+        return carry
 
-    def compute(slot):
-        w = tbuf[slot]  # [blk, d]
-        acc = abuf[slot]  # [blk, a]
-        g = g_ref[...]  # [blk, d]
-        if a == 1:  # row-granularity accumulator
-            asq = jnp.sum(g * g, axis=-1, keepdims=True)
-        else:  # element granularity (TF-Adagrad parity)
+    lax.fori_loop(meta & 1023, ((meta >> 10) & 1023) + 1, body, 0)
+
+    @pl.when(((meta >> 21) & 1) == 1)
+    def _():
+        for t in range(tb // tile):
+            cols = slice(t * tile, (t + 1) * tile)
+            g = gacc[t, :d, :]
+            hit = gacc[t, d:d + 1, :] > 0.5  # the row of ones
+            w, acc = t_ref[:, cols], a_ref[:, cols]
             asq = g * g
-        acc_prev = acc if decay == 1.0 else decay * acc
-        acc2 = acc_prev + asq
-        tbuf[slot] = w - lr * g / jnp.sqrt(acc2)
-        abuf[slot] = acc2
+            if acc.shape[0] == 1 and d != 1:  # row-granularity accumulator
+                asq = jnp.sum(asq, axis=0, keepdims=True)
+            acc2 = (acc if decay == 1.0 else decay * acc) + asq
+            # ``lr·g/√acc'`` as XLA compiles it on the TPU: a multiply by the
+            # reciprocal root.  Mosaic's own divide and root are XLA's bit
+            # for bit, but ``x / sqrt(y)`` kept as written rounds differently
+            # from the classic tail in two elements of three (PERF.md §6).
+            t_out[:, cols] = jnp.where(hit, w - lr * g * lax.rsqrt(acc2), w)
+            a_out[:, cols] = jnp.where(hit, acc2, acc)
 
-    _schedule(
-        i, nblocks,
-        start_in=lambda b, s: _run(b, s, outward=False, wait=False),
-        wait_in=lambda b, s: _run(b, s, outward=False, wait=True),
-        start_out=lambda b, s: _run(b, s, outward=True, wait=False),
-        wait_out=lambda b, s: _run(b, s, outward=True, wait=True),
-        compute=compute,
+
+def sweep_adagrad_update(
+    table: jax.Array,
+    accum: jax.Array,
+    uids: jax.Array,
+    gsum: jax.Array,
+    lr: float,
+    *,
+    decay: float = 1.0,
+    interpret: bool | None = None,
+    block_lanes: int | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """The sweep on ``optim.dedup_rows``' output: ``uids`` ascending and
+    unique below ``V`` (anything from ``V`` up is dropped), ``gsum`` their
+    summed gradients.
+
+    A ``[V, D]`` float32 array with ``D < 128`` is held lane-major on the
+    TPU (the row index along the lanes), which is the row-major layout of
+    its transpose: the kernel takes ``table.T``, ``accum.T`` (bitcasts) in
+    blocks of ``block_lanes`` rows, aliased to its outputs, builds each
+    block's dense gradient in VMEM from the slice of the ids that falls in
+    it (a one-hot against the row index, contracted with the gradients on
+    the MXU, exact in float32) and writes ``w − lr·g/√acc'`` and ``acc'``
+    where the one-hot hit, the old values elsewhere.  Blocks without an
+    update are not visited.
+    """
+    interpret = resolve_interpret(interpret)
+    v, d = table.shape
+    a = accum.shape[-1]
+    m = uids.shape[0]
+    tb = sweep_block_lanes(v, d, block_lanes)
+    tile = _TILE
+    while tb % tile:  # a table shorter than a block: the tile that divides it
+        tile //= 2
+    if tb // tile > 1024:
+        raise ValueError(f"block_lanes {tb} is more than 1024 tiles of {tile} rows")
+    dp = -(-(d + 1) // 16) * 16  # whole bfloat16 tiles, room for the ones
+    m_pad = -(-m // _CHUNK) * _CHUNK
+    uids = jnp.pad(
+        uids.astype(jnp.int32), (0, m_pad - m),
+        constant_values=jnp.iinfo(jnp.int32).max,
     )
+    gt = jnp.pad(gsum.T, ((0, dp - d), (0, m_pad - m))).at[d].set(1.0)
+    blk, ch, meta = _sweep_plan(uids, v, tb, tile)
+    by_block = lambda i, blk, ch, meta: (0, blk[i])
+    by_chunk = lambda i, blk, ch, meta: (0, ch[i])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(blk.shape[0],),
+        in_specs=[
+            pl.BlockSpec((1, _CHUNK), by_chunk),
+            pl.BlockSpec((3 * dp, _CHUNK), by_chunk),
+            pl.BlockSpec((d, tb), by_block),
+            pl.BlockSpec((a, tb), by_block),
+        ],
+        out_specs=[
+            pl.BlockSpec((d, tb), by_block),
+            pl.BlockSpec((a, tb), by_block),
+        ],
+        scratch_shapes=[pltpu.VMEM((tb // tile, dp, tile), jnp.float32)],
+    )
+    kernel = functools.partial(
+        _sweep_kernel, lr=float(lr), decay=float(decay), d=d, dp=dp, tb=tb,
+        tile=tile,
+    )
+    table_t, accum_t = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=(
+            jax.ShapeDtypeStruct((d, v), table.dtype),
+            jax.ShapeDtypeStruct((a, v), accum.dtype),
+        ),
+        input_output_aliases={5: 0, 6: 1},  # table and accum in place
+        interpret=interpret,
+    )(blk, ch, meta, uids[None, :], _split3(gt), table.T, accum.T)
+    return table_t.T, accum_t.T
 
 
 def rows_tail_adagrad_update(
@@ -350,59 +535,14 @@ def rows_tail_adagrad_update(
     *,
     decay: float = 1.0,
     interpret: bool | None = None,
-    block_rows: int = DEFAULT_BLOCK_ROWS,
+    block_lanes: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """``optim.sparse_adagrad_update`` as one kernel pass.
-
-    Same dedup (``optim.dedup_rows``), same accumulator expressions, same
-    lazy-decay semantics — the accumulator bit-identical, the table
-    bit-identical with the row accumulator and within a few ULP with the
-    element one (test-pinned: the classic tail rounds ``-lr·g/√acc``
-    before its one scatter-add, the kernel's ``w − lr·g/√acc`` fuses);
-    what changes is HOW the unique rows move: one double-buffered DMA
-    pass instead of the gather program + scatter program pair.
-    """
-    interpret = resolve_interpret(interpret)
-    v, d = table.shape
-    a = accum.shape[-1]
-    uids, gsum = dedup_rows(ids.reshape(-1), row_grads.reshape(-1, d), v)
-    m = uids.shape[0]
-    nrows = jnp.sum(uids < v).astype(jnp.int32)[None]
-    blk = max(8, min(block_rows, m))
-    nblocks = _nblocks(m, blk)
-    uids = _pad_ids(uids.astype(jnp.int32), nblocks * blk, v)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((blk, d), lambda i, *_: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, blk, d), jnp.float32),
-            pltpu.VMEM((2, blk, a), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+    """``optim.sparse_adagrad_update`` as one in-place sweep: same dedup
+    (``optim.dedup_rows``), same accumulator expressions, same lazy-decay
+    semantics, through ``sweep_adagrad_update``."""
+    d = table.shape[-1]
+    uids, gsum = dedup_rows(ids.reshape(-1), row_grads.reshape(-1, d), table.shape[0])
+    return sweep_adagrad_update(
+        table, accum, uids, gsum, lr, decay=decay, interpret=interpret,
+        block_lanes=block_lanes,
     )
-    kernel = functools.partial(
-        _rows_kernel, lr=float(lr), decay=float(decay), d=d, a=a, blk=blk,
-        nblocks=nblocks, vmax=v,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct(table.shape, table.dtype),
-            jax.ShapeDtypeStruct(accum.shape, accum.dtype),
-        ),
-        input_output_aliases={3: 0, 4: 1},  # table and accum in place
-        interpret=interpret,
-    )(uids, nrows, gsum, table, accum)

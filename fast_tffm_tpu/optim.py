@@ -16,7 +16,9 @@ the accumulator's row is gathered, updated and scatter-set, and the
 table's row takes ``-lr·g/√accum`` by ONE scatter-add (its old value is
 never gathered).  Touching each row once matters: Adagrad is not linear in
 g (accum += g² must see the *summed* gradient, and duplicate scatter
-targets would race).
+targets would race).  Where the batch touches so many sub-tile rows that
+streaming the whole table costs less (``rows_tail_form``), the same update
+runs as one in-place kernel sweep instead (ops/pallas_tail.py, PR 30).
 
 Row descriptors (PR 27; PERF.md §6 has the chip's readings).  On the TPU a
 row narrower than a 128-lane tile is laid out with the ROW INDEX along the
@@ -25,9 +27,14 @@ lanes, four times a gather of the same row.  Where this module chooses the
 buffer's shape (``dedup_rows``' segment sum) it therefore works on rows
 padded to whole tiles; where it does not (the ``[V, D]`` table and
 accumulator of the rows layout, whose shapes checkpoints and the serving
-replica share) it issues the fewest row operations the math allows and
-declares what it knows about the indices — ascending, unique — which costs
-nothing on narrow rows and spares XLA a hidden sort on wide ones.
+replica share) it has two forms.  Row by row it issues the fewest row
+operations the math allows and declares what it knows about the indices —
+ascending, unique — which costs nothing on narrow rows (100 ns a scatter
+whatever is declared) and spares XLA a hidden sort on wide ones.  As a
+sweep (PR 30) it never addresses a row: the lane-major ``[V, D]`` buffer IS
+its transpose ``[D, V]`` row-major, a Pallas kernel walks that view block
+by block in place and writes whole tile columns, and the choice between
+the two is ``rows_tail_form``'s, from the shapes.
 
 Accumulator granularity: the accumulator array's trailing dim selects the
 variant — ``[V, D]`` is TF-Adagrad's per-element accumulator (parity
@@ -58,6 +65,7 @@ __all__ = [
     "dedup_rows",
     "distinct_sentinels",
     "segment_sum_lanes",
+    "rows_tail_form",
     "describe_rows_tail",
 ]
 
@@ -152,14 +160,70 @@ def distinct_sentinels(num_rows: int, m: int, dtype=jnp.int32) -> bool:
     return num_rows + m - 1 <= jnp.iinfo(dtype).max
 
 
-def describe_rows_tail(num_rows: int, m: int, d: int) -> str:
+# What the rows layout's tail costs in its two forms on a TPU v5e, read on
+# 2^26 rows of 9 floats under batches of 80K to 2.6M ids (PERF.md §6, PR 30;
+# PR 27 read the row primitives alone: scatter-set 100 ns, scatter-add 100,
+# gather 23 a row).  Row by row the tail costs 208 ns an id more than the
+# sweep does (19 / 37 / 75 / 150 / 303 ms against 33 / 36 / 41 / 49 / 68 at
+# 80K / 160K / 319K / 639K / 1.28M ids, dedup included on both sides).  The
+# sweep pays for the table instead: both arrays read and written once in
+# their padded lane-major layout, 17.2 GB in 31 ms with few ids (550 GB/s;
+# an elementwise XLA pass over the same bytes takes 27, the HBM's 819 GB/s
+# would take 21).
+_ROWS_OVER_SWEEP_NS = 208
+_SWEEP_BYTES_PER_S = 550e9
+
+
+def rows_tail_form(
+    num_rows: int, m: int, d: int, accum_cols: int, backend: str | None = None
+) -> str:
+    """Which form ``sparse_adagrad_update`` takes at these shapes when
+    nobody says: ``"sweep"`` (ops.pallas_tail.sweep_adagrad_update, one
+    in-place pass over table and accumulator) or ``"rows"`` (the XLA row
+    operations).  A trace-time function of the shapes and the backend:
+
+      * only a TPU takes the sweep (anywhere else the kernel would run
+        interpreted inside every train step);
+      * only rows under one 128-lane tile: those are held lane-major, a row
+        operation on them is a masked single-lane access, and the transposed
+        view the kernel takes is a bitcast.  From 128 lanes up a row is a
+        whole-tile descriptor at 12-15 ns and the row operations stay;
+      * only where the sweep's bytes (both arrays, read and written, rows
+        padded to whole sublanes of 8) take less time at the rate the sweep
+        reaches than the batch's ``m`` ids pay for going row by row.
+        ``fm8_criteo`` (2^26 rows of 9, 65,536 x 39 ids): 31 ms against 530,
+        the sweep; the same table under 1,024 x 39 ids: 31 against 8, the
+        rows; the two cross at 150K ids, as read;
+      * only where the sweep's work list fits the scalar memory
+        (ops.pallas_tail.sweep_fits: past about 15M ids a batch it does not).
+    """
+    if (backend or jax.default_backend()) != "tpu" or d >= _LANES:
+        return "rows"
+    from fast_tffm_tpu.ops.pallas_tail import sweep_fits
+
+    if not sweep_fits(num_rows, d, m):
+        return "rows"
+    sublanes = lambda c: -(-c // 8) * 8
+    sweep_s = 2 * num_rows * 4 * (sublanes(d) + sublanes(accum_cols)) / _SWEEP_BYTES_PER_S
+    return "sweep" if sweep_s < m * _ROWS_OVER_SWEEP_NS * 1e-9 else "rows"
+
+
+def describe_rows_tail(num_rows: int, m: int, d: int, form: str = "rows") -> str:
     """The form ``sparse_adagrad_update`` takes at these shapes, for the
     trainer's start-up log (it is a trace-time choice, so it is said once)."""
     lanes = segment_sum_lanes(m, d)
+    if form == "sweep":
+        from fast_tffm_tpu.ops.pallas_tail import sweep_block_lanes
+
+        block = sweep_block_lanes(num_rows, d)
+        return (
+            f"pallas rows sweep (block {block} lanes, {-(-num_rows // block)} "
+            f"blocks; segment sum on {lanes}-lane rows, row width {d})"
+        )
     hints = "sorted+unique" if distinct_sentinels(num_rows, m) else "sorted"
     return (
-        f"segment sum on {lanes}-lane rows (row width {d}), "
-        f"table updated by one scatter-add, row ops declared {hints}"
+        f"xla rows (segment sum on {lanes}-lane rows (row width {d}), "
+        f"table updated by one scatter-add, row ops declared {hints})"
     )
 
 
@@ -221,16 +285,20 @@ def sparse_adagrad_update(
     row_grads: jax.Array,
     lr: float,
     decay: float = 1.0,
+    form: str | None = None,
 ):
     """Sparse Adagrad step on a ``[V, D]`` table.
 
     ids: [...] int ids; row_grads: [..., D] matching occurrence grads.
-    Only the unique touched rows are read and written: one gather and one
-    scatter-set of the accumulator's rows (the new value sets the step
-    size), ONE scatter-add into the table (``p + (-x)`` is ``p - x``; the
-    old row is never gathered).  The three declare what ``dedup_rows``
-    guarantees about ``uids`` — ascending, and unique where the trailing
-    drop ids are distinct (a trace-time test on shapes).
+    ``form`` ``"rows"``: only the unique touched rows are read and written:
+    one gather and one scatter-set of the accumulator's rows (the new value
+    sets the step size), ONE scatter-add into the table (``p + (-x)`` is
+    ``p - x``; the old row is never gathered).  The three declare what
+    ``dedup_rows`` guarantees about ``uids`` — ascending, and unique where
+    the trailing drop ids are distinct (a trace-time test on shapes).
+    ``form`` ``"sweep"``: the same update as one in-place kernel pass over
+    table and accumulator (ops.pallas_tail.sweep_adagrad_update).  ``None``
+    (every caller but an explicit ``[Train] tail``): ``rows_tail_form``.
 
     ``decay`` γ < 1 decays the accumulator LAZILY — only the rows a step
     touches pay ``accum = γ·accum + g²`` (an untouched row's history is
@@ -243,6 +311,18 @@ def sparse_adagrad_update(
     D = table.shape[-1]
     flat = ids.reshape(-1)
     uids, gsum = dedup_rows(flat, row_grads.reshape(-1, D), table.shape[0])
+    if form is None:
+        form = rows_tail_form(
+            table.shape[0], flat.shape[0], D, state.accum.shape[-1]
+        )
+    if form == "sweep":
+        from fast_tffm_tpu.ops.pallas_tail import sweep_adagrad_update
+
+        with jax.named_scope("fm.tail"):
+            table, accum = sweep_adagrad_update(
+                table, state.accum, uids, gsum, lr, decay=decay
+            )
+        return table, AdagradState(accum)
     known = dict(
         indices_are_sorted=True,
         unique_indices=distinct_sentinels(table.shape[0], flat.shape[0], uids.dtype),

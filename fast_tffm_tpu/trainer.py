@@ -4,9 +4,11 @@ The TPU-native analog of the reference's session step loop
 (`renyi533/fast_tffm` :: local trainer: sess.run(train_op) over the graph
 parser → gather → scorer → loss → Adagrad scatter-add).  Here one jitted
 function fuses gather → fused scorer (custom VJP) → loss → dedup (sort,
-segment sum on tile-wide rows) → sparse Adagrad tail (accumulator gather
-and scatter-set, table scatter-add); XLA compiles the whole step into a
-single program whose ops carry the stage's name (``fm.gather``,
+segment sum on tile-wide rows) → sparse Adagrad tail (row by row:
+accumulator gather and scatter-set, table scatter-add; or, where the batch
+touches most of a table of sub-tile rows on a TPU, one in-place kernel
+sweep: optim.rows_tail_form); XLA compiles the whole step into a single
+program whose ops carry the stage's name (``fm.gather``,
 ``fm.interaction``, ``fm.loss``, ``fm.dedup``, ``fm.tail``).
 
 The mesh-sharded variant lives in parallel/train_step.py and reuses these
@@ -100,15 +102,16 @@ def batch_loss(model, table_rows, dense, batch: Batch):
 
 def train_step_body(
     model, learning_rate: float, state: TrainState, batch: Batch,
-    decay: float = 1.0,
+    decay: float = 1.0, tail_form: str | None = None,
 ):
     """The (unjitted) single-device step, by the scope its ops carry:
     ``fm.gather`` (the batch's rows) → ``fm.interaction`` (fused scorer and
     its backward) → ``fm.loss`` → ``fm.dedup`` (one sort for ids and order,
     permutation gather, segment sum on 128-lane rows, unique ids by a second
     sort) → ``fm.tail`` (one gather and one scatter-set of the accumulator,
-    one scatter-add into the table, all declared sorted and unique;
-    optim.sparse_adagrad_update).
+    one scatter-add into the table, all declared sorted and unique; or the
+    in-place sweep; optim.sparse_adagrad_update, whose ``form`` is
+    ``tail_form``: None leaves the choice to ``optim.rows_tail_form``).
     Shared verbatim by ``make_train_step`` and the device-cache step
     (data/device_cache.py) so the two paths are the SAME math on the same
     values — the bit-identity their parity test pins.
@@ -125,7 +128,7 @@ def train_step_body(
 
     table, table_opt = sparse_adagrad_update(
         state.table, state.table_opt, batch.ids, g_rows, learning_rate,
-        decay=decay,
+        decay=decay, form=tail_form,
     )
     dense, dense_opt = state.dense, state.dense_opt
     if jax.tree.leaves(state.dense):
@@ -161,69 +164,36 @@ def make_train_step(model, learning_rate: float, decay: float = 1.0, body=None):
     return step
 
 
-def make_decayed_body(decay: float):
-    """``train_step_body`` with ``[Online] adagrad_decay`` γ baked in — the
-    ``body`` shape the scanned and device-cache step factories take."""
+def make_decayed_body(decay: float, tail_form: str | None = None):
+    """``train_step_body`` with ``[Online] adagrad_decay`` γ and the tail's
+    form (an explicit ``[Train] tail``; None: ``optim.rows_tail_form``) baked
+    in — the ``body`` shape the scanned and device-cache step factories take."""
 
     def body(model, learning_rate, state, batch):
-        return train_step_body(model, learning_rate, state, batch, decay)
+        return train_step_body(
+            model, learning_rate, state, batch, decay, tail_form
+        )
 
     return body
 
 
-def make_pallas_tail_body(decay: float = 1.0, interpret: bool | None = None):
-    """``train_step_body`` with the sparse Adagrad tail swapped for the
-    one-pass Pallas kernel (``ops.pallas_tail.rows_tail_adagrad_update``):
-    same gather → fused scorer → loss → dedup front, but the deduped rows
-    move through ONE double-buffered DMA gather→update→scatter pass
-    instead of the XLA gather program + scatter program pair.
+def make_pallas_tail_body(decay: float = 1.0):
+    """``train_step_body`` with the sparse Adagrad tail as the Pallas rows
+    sweep whatever the shapes (``[Train] tail = pallas``;
+    ops.pallas_tail.sweep_adagrad_update): same gather → fused scorer → loss
+    → dedup front, then ONE in-place kernel pass over table and accumulator
+    instead of the XLA row gather and the two row scatters.
 
     Same ``(model, lr, state, batch)`` body contract as the scanned /
     device-cache / tiered factories, so it plugs into
     ``make_train_step(body=...)``, ``make_scanned_train_step(body=...)``,
     and the tiered paramstore's ``wrap_step`` unchanged — the tiered
-    compact ``[C, D]`` staging table is exactly the operand shape the
-    kernel takes.  γ threads through like ``make_decayed_body`` (γ=1.0
-    is a trace-time branch to the classic expressions — bit-identical,
-    test-pinned).  ``interpret=None`` auto-resolves off the backend
-    (ops.pallas_common); tests pass ``interpret=True`` explicitly."""
-
-    def body(model, learning_rate, state: TrainState, batch: Batch):
-        from fast_tffm_tpu.ops.pallas_tail import rows_tail_adagrad_update
-
-        rows = gather_rows(state.table, batch.ids)
-        grad_fn = jax.value_and_grad(
-            partial(batch_loss, model), argnums=(0, 1), has_aux=True
-        )
-        (_, data_loss), (g_rows, g_dense) = grad_fn(rows, state.dense, batch)
-
-        with jax.named_scope("fm.tail"):
-            table, accum = rows_tail_adagrad_update(
-                state.table,
-                state.table_opt.accum,
-                batch.ids,
-                g_rows,
-                learning_rate,
-                decay=decay,
-                interpret=interpret,
-            )
-        dense, dense_opt = state.dense, state.dense_opt
-        if jax.tree.leaves(state.dense):
-            dense, dense_opt = dense_adagrad_update(
-                state.dense, state.dense_opt, g_dense, learning_rate,
-                decay=decay,
-            )
-        return (
-            TrainState(
-                table, AdagradState(accum), dense, dense_opt, state.step + 1
-            ),
-            data_loss,
-        )
-
-    return body
+    compact ``[C, D]`` staging table is the operand shape the kernel takes.
+    On the CPU test mesh the kernel interprets itself (ops.pallas_common)."""
+    return make_decayed_body(decay, "sweep")
 
 
-def make_dedup_body(cap: int, decay: float = 1.0):
+def make_dedup_body(cap: int, decay: float = 1.0, tail_form: str | None = None):
     """Device-side dedup-before-gather (ROADMAP item 2(a)): the forward
     gather reads each of the batch's ≤ ``cap`` UNIQUE rows from the
     [V, D] table exactly once; per-slot re-reads index a compact
@@ -237,7 +207,8 @@ def make_dedup_body(cap: int, decay: float = 1.0):
     VERIFIES that per batch before shipping (training._stream's dedup
     guard), so a too-small cap is a loud error, never silent truncation
     (``jnp.unique(size=...)`` would otherwise drop the largest ids).
-    Same ``body`` contract as the scanned/device-cache factories."""
+    Same ``body`` contract as the scanned/device-cache factories;
+    ``tail_form`` as in ``make_decayed_body``."""
 
     def body(model, learning_rate, state: TrainState, batch: Batch):
         import jax.numpy as jnp
@@ -259,7 +230,7 @@ def make_dedup_body(cap: int, decay: float = 1.0):
 
         table, table_opt = sparse_adagrad_update(
             state.table, state.table_opt, batch.ids, g_rows, learning_rate,
-            decay=decay,
+            decay=decay, form=tail_form,
         )
         dense, dense_opt = state.dense, state.dense_opt
         if jax.tree.leaves(state.dense):

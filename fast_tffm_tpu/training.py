@@ -576,7 +576,7 @@ def _run_training(
     saveable=None,
     step_hook=None,
     row_dim=0,
-    tail_lanes=None,
+    tail_profile=None,
     mark_touched=None,
     start_cursor=None,
     rollback=None,
@@ -617,9 +617,10 @@ def _run_training(
     optional custom touched-row bitmap marker — the device-cache drivers
     mark from their resident id arrays) parameterize the async/delta
     checkpoint subsystem (checkpoint_async.AsyncCheckpointer).
-    ``tail_lanes`` (the row width the rows layout's XLA tail sums segments
-    at, ``optim.segment_sum_lanes``; None on every other tail) rides the
-    step's ``kind=profile`` record beside ``row_dim``.
+    ``tail_profile`` (the rows layout's trace-time choices: the row width
+    its tail sums segments at, ``segment_sum_lanes``; ``tail_form``
+    ``sweep`` | ``rows``; the sweep's ``tail_block_lanes``; empty on every
+    other layout) rides the step's ``kind=profile`` record beside ``row_dim``.
 
     ``datastats_ids`` (optional ``batch -> device ids``) lets the sampled
     id-statistics collector read a device-cache batch's ids straight off
@@ -774,7 +775,11 @@ def _run_training(
                     modeled = None
         ledger.stage(
             "train_step", step_fn, (state, b), examples=ex, modeled_bytes=modeled,
-            row_dim=max(1, row_dim), segment_sum_lanes=tail_lanes,
+            row_dim=max(1, row_dim),
+            **{
+                "segment_sum_lanes": None, "tail_form": None,
+                "tail_block_lanes": None, **(tail_profile or {}),
+            },
         )
 
     # Pod liveness: this host's heartbeat (armed at bring-up) starts
@@ -1329,15 +1334,18 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
         )
     # [Train] tail: resolve auto ONCE, up front, so every step factory
     # below (packed, rows, scanned, device cache) sees the same resolved
-    # choice.  auto = the XLA tail (the only one that compiles on the
-    # chip — ops.pallas_common.resolve_tail); an EXPLICIT pallas builds
-    # the kernel step and lets the compiler's error raise — it never
-    # drops back to xla — and is a config error where the kernel has no
-    # contract (split packed accumulators, dedup_gather_rows).
+    # choice.  For the packed / fused layouts auto = the XLA tail (their
+    # kernel does not compile on the chip — ops.pallas_common.resolve_tail);
+    # for the rows layout auto leaves the form to the shapes
+    # (optim.rows_tail_form: the Pallas sweep or the XLA row operations).
+    # An EXPLICIT pallas builds the kernel step and lets the compiler's
+    # error raise — it never drops back to xla — and is a config error
+    # where the kernel has no contract (split packed accumulators,
+    # dedup_gather_rows); an explicit xla keeps the row operations.
     from fast_tffm_tpu.ops.pallas_common import resolve_tail
 
     tail = resolve_tail(cfg.tail)
-    tail_lanes = None  # only the rows layout's XLA tail has a segment sum
+    tail_profile = {}  # what the rows layout's tail says of itself, once
     if packed:
         predict_step = make_packed_predict_step(model, fused=fused)
         packed_tail = tail if fused else "xla"
@@ -1358,35 +1366,39 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
         # bit-identity the online tests pin).  Packed layouts reject
         # γ < 1 at config.validate, so the packed bodies stay untouched.
         decay = float(cfg.online_adagrad_decay)
-        from fast_tffm_tpu.trainer import (
-            make_decayed_body,
-            make_dedup_body,
-            make_pallas_tail_body,
+        from fast_tffm_tpu.optim import (
+            describe_rows_tail,
+            rows_tail_form,
+            segment_sum_lanes,
         )
+        from fast_tffm_tpu.trainer import make_decayed_body, make_dedup_body
 
+        m_ids = cfg.batch_size * cfg.max_nnz
+        num_rows, row_dim = state.table.shape
+        tail_form = {"xla": "rows", "pallas": "sweep"}.get(cfg.tail) or (
+            rows_tail_form(
+                num_rows, m_ids, row_dim, state.table_opt.accum.shape[-1]
+            )
+        )
         if cfg.dedup_gather_rows > 0:
             # Device-side dedup-before-gather (ROADMAP item 2(a)): the
             # forward gather touches each unique row once; the stream's
             # host-side guard (_dedup_cap_guard) pins the cap.  Values —
             # and therefore losses — are bit-identical (test-pinned).
-            step_body = make_dedup_body(cfg.dedup_gather_rows, decay)
-        elif tail == "pallas":
-            step_body = make_pallas_tail_body(decay)
-            log("sparse tail: pallas (rows one-pass gather→Adagrad→scatter)")
-        elif decay != 1.0:
-            step_body = make_decayed_body(decay)
+            step_body = make_dedup_body(cfg.dedup_gather_rows, decay, tail_form)
+        elif decay != 1.0 or cfg.tail != "auto":
+            step_body = make_decayed_body(decay, tail_form)
         else:
-            step_body = None
-        if tail != "pallas":
-            from fast_tffm_tpu.optim import describe_rows_tail, segment_sum_lanes
+            step_body = None  # train_step_body: the same rule at trace time
+        tail_profile = dict(
+            segment_sum_lanes=segment_sum_lanes(m_ids, row_dim),
+            tail_form=tail_form,
+        )
+        if tail_form == "sweep":
+            from fast_tffm_tpu.ops.pallas_tail import sweep_block_lanes
 
-            m_ids = cfg.batch_size * cfg.max_nnz
-            tail_lanes = segment_sum_lanes(m_ids, state.table.shape[1])
-            log(
-                "sparse tail: xla rows ("
-                + describe_rows_tail(state.table.shape[0], m_ids, state.table.shape[1])
-                + ")"
-            )
+            tail_profile["tail_block_lanes"] = sweep_block_lanes(num_rows, row_dim)
+        log("sparse tail: " + describe_rows_tail(num_rows, m_ids, row_dim, tail_form))
         step_fn = make_train_step(
             model, cfg.learning_rate, decay=decay, body=step_body
         )
@@ -1400,7 +1412,7 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
     to_batch = _batch_converter(model.uses_fields)
     run_kwargs = dict(
         to_batch=to_batch, saveable=saveable, step_hook=step_hook,
-        row_dim=model.row_dim, tail_lanes=tail_lanes,
+        row_dim=model.row_dim, tail_profile=tail_profile,
     )
     if cfg.online_accum_restart_steps > 0:
         from fast_tffm_tpu.trainer import make_accum_restart
@@ -1507,11 +1519,9 @@ def _tiered_train(cfg: Config, *, resume: bool, log=print, step_hook=None):
     "what fits in HBM" to "what fits on the host": 2^30+ rows on one
     chip, bit-identical to the resident path at overlapping vocab."""
     from fast_tffm_tpu.data.wire import make_spec
-    from fast_tffm_tpu.ops.pallas_common import resolve_tail
     from fast_tffm_tpu.paramstore import TieredConverter, open_tiered_run
     from fast_tffm_tpu.trainer import (
         make_decayed_body,
-        make_pallas_tail_body,
         make_scanned_train_step,
         make_train_step,
     )
@@ -1522,14 +1532,14 @@ def _tiered_train(cfg: Config, *, resume: bool, log=print, step_hook=None):
         cfg, model, max_nnz, resume=resume, log=log
     )
     decay = float(cfg.online_adagrad_decay)
-    if resolve_tail(cfg.tail) == "pallas":
-        # The tiered inner step already runs over the compact [C, D]
-        # staging table with remapped slot ids — exactly the rows-layout
-        # operands the kernel takes, so the SAME body serves both tiers.
-        body = make_pallas_tail_body(decay)
-        log("sparse tail: pallas (one-pass kernel over the compact tier)")
-    else:
-        body = make_decayed_body(decay) if decay != 1.0 else None
+    # The tiered inner step already runs over the compact [C, D] staging
+    # table with remapped slot ids — exactly the rows-layout operands, so
+    # the SAME bodies serve both tiers: an explicit tail fixes the form,
+    # auto leaves it to the shapes (optim.rows_tail_form).
+    tail_form = {"xla": "rows", "pallas": "sweep"}.get(cfg.tail)
+    if tail_form == "sweep":
+        log("sparse tail: pallas (rows sweep over the compact tier)")
+    body = make_decayed_body(decay, tail_form) if decay != 1.0 or tail_form else None
     if cfg.steps_per_call > 1:
         inner = make_scanned_train_step(model, cfg.learning_rate, body=body)
     else:

@@ -116,22 +116,31 @@ def test_streamed_profile_and_datastats(dataset):
 
 
 @pytest.mark.parametrize(
-    "kw, row_dim, lanes",
+    "kw, row_dim, lanes, form, block",
     [
-        (dict(model="fm", factor_num=4), 5, 128),  # under a tile: padded to one
-        (dict(model="ffm", factor_num=4, num_fields=39), 157, 256),  # libffm's Criteo row: two tiles
-        (dict(model="fm", factor_num=127), 128, 128),  # tile-wide already
-        (dict(model="fm", factor_num=4, table_layout="packed"), 5, None),  # no rows-layout segment sum
+        (dict(model="fm", factor_num=4), 5, 128, "rows", None),  # under a tile: padded to one
+        (dict(model="ffm", factor_num=4, num_fields=39), 157, 256, "rows", None),  # libffm's Criteo row: two tiles
+        (dict(model="fm", factor_num=127), 128, 128, "rows", None),  # tile-wide already
+        (dict(model="fm", factor_num=4, tail="pallas"), 5, 128, "sweep", 256),  # asked for: 200 rows, two tiles
+        (dict(model="fm", factor_num=4, table_layout="packed"), 5, None, None, None),  # no rows-layout tail
     ],
-    ids=["fm_k4", "ffm_39x4", "fm_k127", "packed"],
+    ids=["fm_k4", "ffm_39x4", "fm_k127", "fm_k4_sweep", "packed"],
 )
-def test_the_steps_profile_record_says_the_row_width_and_the_tails_lanes(dataset, kw, row_dim, lanes):
+def test_the_steps_profile_record_says_the_row_width_and_the_tails_lanes(dataset, kw, row_dim, lanes, form, block):
     """What only the start-up log line said (``describe_rows_tail``): the row
-    width and the lanes ``dedup_rows`` sums segments at, a trace-time choice."""
+    width, the lanes ``dedup_rows`` sums segments at and the form the tail
+    took (off a TPU the rows unless asked), trace-time choices all."""
     cfg = _cfg(dataset, tag="lanes", epoch_num=1, **kw)
-    train(cfg, log=lambda *_: None)
+    logs = []
+    train(cfg, log=lambda *a: logs.append(" ".join(map(str, a))))
     (prof,) = [r for r in _read(cfg.metrics_path) if r["kind"] == "profile" and r["program"] == "train_step"]
     assert (prof["row_dim"], prof["segment_sum_lanes"]) == (row_dim, lanes)
+    assert (prof["tail_form"], prof["tail_block_lanes"]) == (form, block)
+    said = [l for l in logs if l.startswith("sparse tail: ")]
+    if form == "sweep":
+        assert said == [f"sparse tail: pallas rows sweep (block 256 lanes, 1 blocks; segment sum on 128-lane rows, row width {row_dim})"]
+    elif form == "rows":
+        assert len(said) == 1 and said[0].startswith(f"sparse tail: xla rows (segment sum on {lanes}-lane rows")
 
 
 def test_a_backend_without_cost_analysis_still_records_what_was_dispatched(dataset, monkeypatch):

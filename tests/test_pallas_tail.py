@@ -1,20 +1,22 @@
-"""Fused Pallas sparse tail (ISSUE 18 tentpole) vs its XLA oracles.
+"""Pallas sparse tails (the fused kernel of ISSUE 18, the rows sweep of
+ISSUE 30) vs their XLA oracles.
 
 Runs the kernels in the Pallas interpreter on the CPU mesh (resolve
-auto-detects the backend, so no per-test plumbing); real-TPU compilation
-of the same kernels is exercised by bench.py / the driver.
+auto-detects the backend, so no per-test plumbing); the sweep's compile
+for the chip at the cell's shapes and its chip readings are PERF.md's.
 
 Parity contract (acceptance criteria):
-  * γ=1.0 — the classic XLA program (same optim.dedup_rows front, same
-    update expressions, compared inside jax.jit exactly as training runs
-    them): the accumulator BIT-IDENTICAL; the table bit-identical with the
-    row accumulator and within a few float32 ULP with the element one
-    since PR 27 — the classic tail now hands ``-(lr·g/√acc)`` to one
-    scatter-add, a rounded operand, where the kernel's fused
-    ``w − lr·g/√acc`` contracts into an FMA on the CPU (``_assert_few_ulp``);
-  * γ<1 — row accumulator stays bitwise, element accumulator is
-    rtol-pinned (XLA fuses the decayed expressions into different FMA
-    clusters — 1-ULP table drift);
+  * the rows sweep against the classic XLA program (same optim.dedup_rows
+    front, same update expressions, compared inside jax.jit exactly as
+    training runs them): the dense gradient it builds in VMEM is the summed
+    gradient BIT FOR BIT (``test_the_blocks_gradient_is_the_summed_gradient``
+    and ``_split3``'s own test), untouched rows come out bit for bit, and
+    touched rows within a few float32 ULP, γ = 1 or not, either
+    accumulator: on the CPU XLA contracts ``acc + g·g`` and
+    ``w − lr·g/√acc`` into FMAs in one program and not in the other (the
+    interpreted kernel body is a different fusion), and the row
+    accumulator's Σg² runs over sublanes in the kernel (``_assert_few_ulp``;
+    on the chip the accumulator read bit-equal, PERF.md §6, PR 30);
   * fused layout vs the scatter-add-built XLA fused tails — allclose
     (summation order), and BITWISE vs the rows-classic program on the
     unpacked logical arrays (the structural oracle);
@@ -99,8 +101,8 @@ def test_rows_tail_matches_numpy_oracle():
         want = np.asarray(table, np.float64) - lr * dense_g / np.sqrt(accn)
         touched = np.zeros(V, bool)
         touched[np.unique(np.asarray(ids))] = True
-        np.testing.assert_allclose(
-            np.asarray(t2)[touched], want[touched], rtol=1e-5
+        np.testing.assert_allclose(  # atol: entries where w − step nearly cancels
+            np.asarray(t2)[touched], want[touched], rtol=1e-5, atol=1e-7
         )
         np.testing.assert_allclose(
             np.asarray(a2)[touched], accn[touched], rtol=1e-5
@@ -111,17 +113,24 @@ def test_rows_tail_matches_numpy_oracle():
         )
 
 
+def _assert_accum_few_ulp(got, want, ulps=4):
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want),
+        rtol=ulps * float(np.finfo(np.float32).eps),
+    )
+
+
 @pytest.mark.parametrize("acc_kind", ["row", "element"])
 def test_rows_tail_bit_identical_to_classic(acc_kind):
     ids, g, table, accum_row, accum_elem = _operands(1)
     acc = accum_row if acc_kind == "row" else accum_elem
     rt, rs = _classic(table, acc, ids, g, 0.13)
     kt, ka = _kernel(table, acc, ids, g, 0.13)
-    assert jnp.all(ka == rs.accum)
-    if acc_kind == "row":
-        assert jnp.all(kt == rt)
-    else:
-        _assert_few_ulp(kt, rt)
+    _assert_accum_few_ulp(ka, rs.accum)
+    _assert_few_ulp(kt, rt)
+    untouched = np.setdiff1d(np.arange(V), np.asarray(ids))
+    assert jnp.all(kt[untouched] == table[untouched])
+    assert jnp.all(ka[untouched] == acc[untouched])
 
 
 @pytest.mark.parametrize("acc_kind", ["row", "element"])
@@ -130,24 +139,178 @@ def test_rows_tail_decay_parity(acc_kind):
     acc = accum_row if acc_kind == "row" else accum_elem
     rt, rs = _classic(table, acc, ids, g, 0.13, decay=0.9)
     kt, ka = _kernel(table, acc, ids, g, 0.13, decay=0.9)
-    if acc_kind == "row":
-        # Row mode keeps bitwise even under decay.
-        assert jnp.all(kt == rt) and jnp.all(ka == rs.accum)
-    else:
-        # Element mode: decayed expressions land in different XLA fusion
-        # clusters (FMA contraction) — 1-ULP table drift, rtol-pinned
-        # (atol floors the near-zero entries where 1 ULP is a big ratio).
-        np.testing.assert_allclose(kt, rt, rtol=1e-5, atol=1e-7)
-        np.testing.assert_allclose(ka, rs.accum, rtol=1e-5, atol=1e-7)
+    # Decayed expressions land in different XLA fusion clusters (FMA
+    # contraction) — ULP drift, rtol-pinned (atol floors the near-zero
+    # entries where 1 ULP is a big ratio).
+    np.testing.assert_allclose(kt, rt, rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(ka, rs.accum, rtol=1e-5, atol=1e-7)
 
 
 def test_zero_grad_rows_are_exact_fixed_points():
     ids, g, table, accum_row, _ = _operands(3)
     z = jnp.zeros_like(g)
     kt, ka = _kernel(table, accum_row, ids, z, 0.13)
-    # acc + 0 = acc and w − lr·0/√acc = w: the zero-grad identity that
-    # lets untouched rows skip the kernel entirely.
+    # acc + 0 = acc and w − lr·0/√acc = w: a touched row whose gradients
+    # sum to nothing is written back as it was.
     assert jnp.all(kt == table) and jnp.all(ka == accum_row)
+
+
+# -- the sweep's own cases (ISSUE 30) ---------------------------------------
+
+
+def _oracle(table, acc, ids, g, lr, decay=1.0):
+    """The dense NumPy oracle of tests/test_optim_trainer.py, float64: (table,
+    accumulator, touched) with ids >= V dropped."""
+    v, d = table.shape
+    ids, g = np.asarray(ids), np.asarray(g, np.float64)
+    keep = ids < v
+    dense = np.zeros((v, d), np.float64)
+    np.add.at(dense, ids[keep], g[keep])
+    sq = dense**2 if acc.shape[-1] == d else (dense**2).sum(-1, keepdims=True)
+    touched = np.zeros(v, bool)
+    touched[ids[keep]] = True
+    accn = np.asarray(acc, np.float64).copy()
+    accn[touched] = decay * accn[touched] + sq[touched]
+    want = np.asarray(table, np.float64) - lr * dense / np.sqrt(np.where(accn > 0, accn, 1.0))
+    return want, accn, touched
+
+
+def _check_against_oracle(table, acc, ids, g, lr=0.13, decay=1.0, **kw):
+    kt, ka = _kernel(table, acc, ids, g, lr, decay=decay, **kw)
+    want, accn, touched = _oracle(table, acc, ids, g, lr, decay)
+    kt, ka = np.asarray(kt), np.asarray(ka)
+    # float32 sums of repeated ids that cancel are good to about 1e-5.
+    np.testing.assert_allclose(kt[touched], want[touched], rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(ka[touched], accn[touched], rtol=1e-4)
+    np.testing.assert_array_equal(kt[~touched], np.asarray(table)[~touched])
+    np.testing.assert_array_equal(ka[~touched], np.asarray(acc)[~touched])
+    assert np.isfinite(kt).all() and np.isfinite(ka).all()
+    return kt, ka, touched
+
+
+def _case(name):
+    """(table, accumulator, ids, gradients, block_lanes) of a named case."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    v, d, bl = 1000, 9, 256
+    if name == "v_not_a_multiple_of_the_block":  # 1000 = 3 x 256 + 232
+        ids = rng.integers(0, v, 300)
+    elif name == "a_block_full_and_a_block_empty":  # block 0 whole, block 2 none
+        v = 512
+        bl = 128
+        ids = np.concatenate([np.arange(128), rng.integers(384, 512, 20)])
+    elif name == "more_updates_in_a_block_than_a_chunk":  # 700 > 256 in one block
+        v, bl = 4096, 1024
+        ids = np.concatenate([rng.permutation(1024)[:700] + 1024, rng.integers(0, v, 50)])
+    elif name == "drop_ids_and_repeats":  # caller's sentinels and ids past V, and runs
+        ids = np.concatenate([np.full(40, 7), np.full(9, v), np.full(3, v + 5), rng.integers(0, v, 200), [v - 1] * 4])
+    else:
+        raise AssertionError(name)
+    ids = rng.permutation(ids).astype(np.int32)
+    g = rng.standard_normal((ids.size, d)).astype(np.float32)
+    table = rng.standard_normal((v, d)).astype(np.float32)
+    acc = rng.uniform(0.05, 2.0, (v, d)).astype(np.float32)
+    return jnp.asarray(table), jnp.asarray(acc), jnp.asarray(ids), jnp.asarray(g), bl
+
+
+@pytest.mark.parametrize(
+    "name",
+    [
+        "v_not_a_multiple_of_the_block",
+        "a_block_full_and_a_block_empty",
+        "more_updates_in_a_block_than_a_chunk",
+        "drop_ids_and_repeats",
+    ],
+)
+def test_sweep_block_and_chunk_edges(name):
+    table, acc, ids, g, bl = _case(name)
+    _kt, _ka, touched = _check_against_oracle(table, acc, ids, g, block_lanes=bl)
+    if name == "a_block_full_and_a_block_empty":
+        assert touched[:128].all() and not touched[128:384].any()
+    if name == "drop_ids_and_repeats":
+        assert touched[7] and touched[-1]
+
+
+@pytest.mark.parametrize("acc_kind", ["row", "element"])
+@pytest.mark.parametrize("d", [9, 17, 89])
+def test_sweep_matches_dense_oracle_over_widths(d, acc_kind):
+    rng = np.random.default_rng(d)
+    v = 700
+    ids = jnp.asarray(rng.integers(0, v, 400), jnp.int32)
+    g = jnp.asarray(rng.standard_normal((400, d)), jnp.float32)
+    table = jnp.asarray(rng.standard_normal((v, d)), jnp.float32)
+    acc = jnp.full((v, d if acc_kind == "element" else 1), 0.1, jnp.float32)
+    _check_against_oracle(table, acc, ids, g, lr=0.5, block_lanes=256)
+
+
+@pytest.mark.parametrize("acc_kind", ["row", "element"])
+def test_an_untouched_row_with_a_zero_accumulator_stays_as_it_is(acc_kind):
+    """``0/√0`` is never computed into a row the batch did not touch: the
+    update is selected by the one-hot's hit, not multiplied by it."""
+    ids, g, table, accum_row, accum_elem = _operands(8)
+    acc = jnp.zeros_like(accum_row if acc_kind == "row" else accum_elem)
+    kt, ka = _kernel(table, acc, ids, g, 0.13)
+    kt, ka = np.asarray(kt), np.asarray(ka)
+    untouched = np.setdiff1d(np.arange(V), np.asarray(ids))
+    assert untouched.size and np.isfinite(kt).all() and np.isfinite(ka).all()
+    np.testing.assert_array_equal(kt[untouched], np.asarray(table)[untouched])
+    assert not ka[untouched].any() and (ka[np.asarray(ids)] > 0).all()
+
+
+@pytest.mark.parametrize("acc_kind", ["row", "element"])
+def test_decay_reaches_touched_rows_only(acc_kind):
+    ids, g, table, accum_row, accum_elem = _operands(9)
+    acc = accum_row if acc_kind == "row" else accum_elem
+    _kt, ka, touched = _check_against_oracle(table, acc, ids, g, decay=0.9)
+    # A touched row whose gradient is zero still decays (the classic lazy
+    # decay's contract); an untouched one does not.
+    kt0, ka0 = _kernel(table, acc, ids, jnp.zeros_like(g), 0.13, decay=0.9)
+    np.testing.assert_allclose(np.asarray(ka0)[touched], 0.9 * np.asarray(acc)[touched], rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(ka0)[~touched], np.asarray(acc)[~touched])
+    assert jnp.all(kt0 == table)
+
+
+def test_split3_sums_back_to_the_float32_value():
+    from fast_tffm_tpu.ops.pallas_tail import _split3
+
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((16, 256)).astype(np.float32) * np.float32(10.0) ** rng.integers(-20, 20, (16, 256)).astype(np.float32)
+    x[0, :4] = [0.0, -0.0, 1.0, -1.5]
+    parts = np.asarray(jax.jit(_split3)(jnp.asarray(x)).astype(jnp.float32))
+    hi, mid, lo = parts[:16], parts[16:32], parts[32:]
+    np.testing.assert_array_equal((hi + mid) + lo, x)
+
+
+def test_the_blocks_gradient_is_the_summed_gradient():
+    """The one-hot contraction returns the float32 summed gradient bit for
+    bit, not its bfloat16 rounding: read back through the element
+    accumulator, whose new value under γ = 0 is ``0·acc + g·g``, one
+    rounding of g² with or without an FMA."""
+    from fast_tffm_tpu.optim import dedup_rows
+
+    ids, g, table, _accum_row, accum_elem = _operands(10)
+    _t, ka = _kernel(table, accum_elem, ids, g, 0.13, decay=0.0)
+    uids, gsum = jax.jit(lambda i, r: dedup_rows(i, r, V))(ids, g)
+    n = int(jnp.sum(uids < V))
+    want = np.asarray(gsum[:n]) ** 2  # 0·acc + g·g, one rounding either way
+    np.testing.assert_array_equal(np.asarray(ka)[np.asarray(uids[:n])], want)
+
+
+@pytest.mark.parametrize(
+    "shapes, backend, form",
+    [
+        ((2**26, 65536 * 39, 9, 9), "tpu", "sweep"),  # fm8_criteo.train_fmb: 21 ms against 570
+        ((2**20, 32768 * 39, 157, 157), "tpu", "rows"),  # ffm4_criteo: rows past one tile
+        ((2**26, 1024 * 39, 9, 9), "tpu", "rows"),  # a small batch on the same table: 21 against 9
+        ((2**26, 65536 * 39, 9, 9), "cpu", "rows"),  # no kernel interpreted inside a train step
+    ],
+    ids=["fm8_on_tpu", "d157", "b1024", "cpu"],
+)
+def test_auto_chooses_the_form_from_shapes_and_backend(shapes, backend, form):
+    from fast_tffm_tpu.optim import rows_tail_form
+
+    assert rows_tail_form(*shapes, backend=backend) == form
+    if backend == "cpu":  # what this suite's train steps get when nobody says
+        assert rows_tail_form(*shapes) == "rows"
 
 
 def test_fused_tail_bit_identical_to_rows_classic():
@@ -209,7 +372,8 @@ def test_fused_k_cap_edge(k_cap):
 
 def test_remainder_tail_small_blocks():
     # block_rows=8 over 40 occurrences: multiple grid blocks plus a
-    # partially-valid remainder block (predicated DMA rows).
+    # partially-valid remainder block (predicated DMA rows).  The fused
+    # half is the known red test (ROADMAP D2).
     ids, g, table, accum_row, _ = _operands(7)
     fused = pack_fused(table, accum_row, 0.1)
     rt, rs = _classic(table, accum_row, ids, g, 0.13)
@@ -218,8 +382,10 @@ def test_remainder_tail_small_blocks():
     )(fused)
     tu, au = unpack_fused(f2, V, D)
     assert jnp.all(tu == rt) and jnp.all(au == rs.accum)
-    t2, a2 = _kernel(table, accum_row, ids, g, 0.13, block_rows=8)
-    assert jnp.all(t2 == rt) and jnp.all(a2 == rs.accum)
+    # The rows sweep's remainder: a block of 128 lanes over 64 rows.
+    t2, a2 = _kernel(table, accum_row, ids, g, 0.13, block_lanes=128)
+    _assert_few_ulp(t2, rt)
+    _assert_accum_few_ulp(a2, rs.accum)
 
 
 # -- trainer-level wiring -------------------------------------------------
@@ -343,6 +509,8 @@ def test_drivers_pallas_tail_bit_identical(tmp_path):
     _s, xla_logs = _run(_cfg(tmp_path, "xla", tail="xla"))
     _s, pal_logs = _run(_cfg(tmp_path, "pallas", tail="pallas"))
     assert _losses(xla_logs) == _losses(pal_logs)
+    assert any(l.startswith("sparse tail: xla rows (") for l in xla_logs)
+    assert any(l.startswith("sparse tail: pallas rows sweep (block 256 lanes, 1 blocks") for l in pal_logs)
     _s, cache_logs = _run(
         _cfg(tmp_path, "cache", tail="pallas", device_cache=True,
              binary_cache=True)
