@@ -15,8 +15,8 @@ free when the next one starts; this parent never imports jax:
   serve         python fast_tffm.py serve --port 0 — SERVE_READY, 64 rows as
                 binary FMD1 frames through fast_tffm_tpu.serving.client,
                 scores equal predict's, SIGTERM -> exit 0
-  kernels       the three Pallas entry points against the XLA paths they
-                replace (compiled and matched, or refused and not selected)
+  kernels       the two Pallas entry points against the XLA paths they
+                replace (compiled and matched)
   dist_*        on a host with four chips: dist_train / dist_predict on
                 BASELINE #2, table shards, both lookups, loss parity
 
@@ -536,11 +536,6 @@ class Smoke:
             raise SmokeFailure("kernels", f"anova_inter disagrees with the XLA path: {a}")
         if on_chip and not a["compiled"]:
             raise SmokeFailure("kernels", "anova_inter ran interpreted on the chip")
-        if res["auto_tail"] != "xla":
-            raise SmokeFailure(
-                "kernels", f"tail = auto resolves to {res['auto_tail']!r} for the "
-                "packed layouts, whose kernel never compiled on the chip"
-            )
         rows = res["rows_tail"]
         if rows.get("refused"):
             raise SmokeFailure("kernels", f"the rows sweep did not run: {rows['refused']}")
@@ -550,25 +545,14 @@ class Smoke:
             raise SmokeFailure("kernels", f"the rows sweep disagrees with the XLA row operations: {rows}")
         self.echo(
             f"chip_smoke: kernels rows_tail: {'compiled' if rows['compiled'] else 'interpreted'}"
-            f"+matched (max_abs_diff={rows['max_abs_diff']:.2g}); what the rows layout's "
-            "auto takes where optim.rows_tail_form says so"
-        )
-        refused = res["fused_tail"].get("refused")
-        if on_chip and not refused:
-            raise SmokeFailure(
-                "kernels", "fused_tail now compiles on the chip: auto could select "
-                "it again — redo the ROADMAP D2 decision"
-            )
-        self.echo(
-            "chip_smoke: kernels fused_tail: "
-            + (f"refused by the compiler ({refused[:160]}); not selected by auto"
-               if refused else "interpreted (not a TPU)")
+            f"+matched (max_abs_diff={rows['max_abs_diff']:.2g}); what the rows layout "
+            "takes where optim.rows_tail_form says so"
         )
         self.echo(
             f"chip_smoke: kernels ok platform={res['platform']} "
             f"anova_inter={'compiled' if a['compiled'] else 'interpreted'}+matched "
             f"(value_rel={a['value_rel']:.2g} grad_rel={a['grad_rel']:.2g} "
-            f"rtol={KERNEL_RTOL}) auto_tail={res['auto_tail']} "
+            f"rtol={KERNEL_RTOL}) "
             f"wall={time.monotonic() - t0:.1f}s"
         )
         return res
@@ -671,26 +655,18 @@ def run(out_dir: str, expect_platform: str, sizes: Sizes = FULL, echo=print) -> 
 
 
 def kernels_child(b: int, n: int, k: int) -> None:
-    """The three Pallas entry points on whatever backend this child gets."""
+    """The two Pallas entry points on whatever backend this child gets."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from fast_tffm_tpu.ops.fm import fm_score
-    from fast_tffm_tpu.ops.packed_table import pack_fused
-    from fast_tffm_tpu.ops.pallas_common import resolve_tail
-    from fast_tffm_tpu.ops.pallas_tail import (
-        fused_tail_adagrad_update,
-        rows_tail_adagrad_update,
-    )
+    from fast_tffm_tpu.ops.pallas_tail import rows_tail_adagrad_update
     from fast_tffm_tpu.telemetry import enable_compilation_cache
 
     enable_compilation_cache()
     rng = np.random.default_rng(0)
-    out = {
-        "platform": jax.devices()[0].platform,
-        "auto_tail": resolve_tail("auto"),
-    }
+    out = {"platform": jax.devices()[0].platform}
 
     # anova_inter forward + backward at BASELINE #5's width against the
     # XLA scan path fm_score(use_pallas=False).
@@ -714,12 +690,9 @@ def kernels_child(b: int, n: int, k: int) -> None:
         "grad_rel": float(jnp.max(jnp.abs(ker_g - ref_g)) / jnp.max(jnp.abs(ref_g))),
     }
 
-    # The two tail kernels at BASELINE #1's row width (D = 9 lanes; few
-    # rows).  The rows sweep (PR 30) compiles on a TPU and must match the
-    # XLA row operations; the fused kernel's per-row DMA does not (the
-    # compiler's objection is to the DMA's shape, which no row count
-    # changes), so an explicit request must raise the compiler's message.
-    # On the CPU test mesh both interpret.
+    # The rows sweep (PR 30) at BASELINE #1's row width (D = 9 lanes; few
+    # rows): it compiles on a TPU and must match the XLA row operations.  On
+    # the CPU test mesh it interprets.
     v, m = 4096, 512
     ids = jnp.asarray(rng.integers(0, v, (m,)), jnp.int32)
 
@@ -749,12 +722,6 @@ def kernels_child(b: int, n: int, k: int) -> None:
         out["rows_tail"]["max_abs_diff"] = float(
             jnp.maximum(jnp.max(jnp.abs(got_t - want_t)), jnp.max(jnp.abs(got_a - want_s.accum)))
         )
-    fused = pack_fused(
-        jnp.zeros((v, 8), jnp.float32), jnp.full((v, 1), 0.1, jnp.float32), 0.1
-    )
-    out["fused_tail"] = attempt(
-        jax.jit(lambda f: fused_tail_adagrad_update(f, ids, g9[:, :8], 0.05)), fused
-    )
     print("SMOKE_KERNELS " + json.dumps(out), flush=True)
 
 

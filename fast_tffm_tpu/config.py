@@ -82,16 +82,6 @@ class Config:
     #   sweep, measured 3.5× the sorted pipeline; compact = sort-free
     #   touched-row compaction, O(M) buffers — the giant-vocab path; sorted =
     #   the bit-parity reference pipeline; auto picks dense/compact by size)
-    tail: str = "auto"  # sparse Adagrad tail: xla (the gather/scatter program
-    #   chain) | pallas (ops/pallas_tail.py: on the rows layout one in-place
-    #   sweep over table and accumulator, on the fused layout the per-row
-    #   DMA kernel, which does not compile on the chip and raises there) |
-    #   auto (rows layout: the sweep on a TPU where optim.rows_tail_form
-    #   reckons it cheaper than the batch's row operations — sub-tile rows,
-    #   a batch that touches most of the table — else xla; packed layouts:
-    #   xla).  pallas with table_layout=packed requires
-    #   adagrad_accumulator=fused (the kernel's merged layout); incompatible
-    #   with dedup_gather_rows (no contract there; auto still chooses)
     thread_num: int = 0  # host-side parse workers; 0 = all cores (reference: queue threads)
     binary_cache: bool = False  # parse text once into <file>.fmb, stream that
     binary_cache_wait: float = 600.0  # multi-host: non-lead wait for lead's build (s)
@@ -687,30 +677,6 @@ class Config:
                 "requires packed_update = auto, dense or compact (the "
                 "sorted whole-tile-row RMW needs the element accumulator)"
             )
-        if self.tail not in ("auto", "xla", "pallas"):
-            raise ValueError(
-                f"unknown tail {self.tail!r} (auto | xla | pallas)"
-            )
-        if (
-            self.tail == "pallas"
-            and self.table_layout == "packed"
-            and self.adagrad_accumulator != "fused"
-        ):
-            # The packed Pallas tail addresses rows through the merged
-            # D+1-lane slots; the split packed accumulator layouts keep
-            # their XLA update strategies (packed_update).
-            raise ValueError(
-                "tail = pallas with table_layout = packed requires "
-                "adagrad_accumulator = fused (the kernel updates the "
-                "merged fused layout's D+1-lane slots in one pass)"
-            )
-        if self.tail == "pallas" and self.dedup_gather_rows > 0:
-            # Both features dedup the batch's ids; stacking them would
-            # dedup twice and measure neither cleanly.
-            raise ValueError(
-                "tail = pallas is incompatible with dedup_gather_rows > 0 "
-                "(the kernel dedups internally — pick one)"
-            )
         return self
 
 
@@ -837,7 +803,6 @@ def load_config(path: str) -> Config:
         t, "adagrad_accumulator", str, cfg.adagrad_accumulator
     ).lower()
     cfg.packed_update = get(t, "packed_update", str, cfg.packed_update).lower()
-    cfg.tail = get(t, "tail", str, cfg.tail).lower()
     cfg.packed_compact_cap = get(
         t, "packed_compact_cap", int, cfg.packed_compact_cap
     )

@@ -60,7 +60,6 @@ __all__ = [
     "packed_accum_gather_any",
     "fused_accum_gather",
     "scatter_logical_rows",
-    "lane_spread",
     "packed_dense_grad",
     "packed_dense_adagrad_update",
     "packed_compact_adagrad_update",
@@ -573,7 +572,7 @@ PACKED_UPDATE_FNS = {
 
 # --- fused row-accumulator layout (round 5) -------------------------------
 #
-# WHY (PROBE_UPDATE_OPS_r05): random wide gathers/scatters on this chip are
+# WHY (the update-ops probe of round 5, old installation): random wide gathers/scatters on this chip are
 # DESCRIPTOR-bound — a [K, 256] gather costs the same as [K, 128] (10.5 vs
 # 10.0 ms at K=639k) — so the sparse tail's cost is the NUMBER of random
 # row ops, not their bytes.  The separate-accumulator RMW needs 4 of them

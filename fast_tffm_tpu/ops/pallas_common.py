@@ -1,4 +1,4 @@
-"""Shared Pallas kernel plumbing: interpret-mode and tail resolution.
+"""Shared Pallas kernel plumbing: interpret-mode resolution.
 
 Every Pallas kernel in this package takes an ``interpret`` flag so the
 CPU tier-1 suite can run it in the Pallas interpreter.  The detection
@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["default_interpret", "resolve_interpret", "resolve_tail"]
+__all__ = ["default_interpret", "resolve_interpret"]
 
 
 def default_interpret() -> bool:
@@ -36,23 +36,3 @@ def resolve_interpret(interpret: bool | None) -> bool:
     suite sanctions.
     """
     return default_interpret() if interpret is None else bool(interpret)
-
-
-def resolve_tail(tail: str) -> str:
-    """``[Train] tail`` → the tail of the PACKED layouts (and whether a
-    driver was asked for a kernel at all).
-
-    ``auto`` is the XLA tail here on every backend: the fused layout's
-    Pallas kernel has never compiled on the chip (TPU v5 lite, jax 0.9.0 /
-    libtpu 0.0.34 — Mosaic refuses the sub-tile row DMA; ops/pallas_tail.py
-    quotes the message), and ``auto`` only ever selects a program that has.
-    An explicit ``pallas`` is honored anywhere and never drops back: on a
-    TPU it raises the compiler's error, on the CPU test mesh it interprets
-    (what the tier-1 parity tests run).
-
-    The ROWS layout does not ask here: its kernel (the rows sweep) compiles,
-    and under ``auto`` ``optim.sparse_adagrad_update`` chooses between it and
-    the XLA row operations from the shapes (``optim.rows_tail_form``: the
-    sweep on a TPU where it costs less, the rows anywhere else).
-    """
-    return "xla" if tail == "auto" else tail

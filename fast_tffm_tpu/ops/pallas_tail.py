@@ -1,73 +1,57 @@
-"""Pallas sparse tails: the Adagrad update of the touched rows as one kernel.
+"""The Pallas rows sweep: the Adagrad update of the touched rows as one
+in-place kernel pass.
 
 The XLA sparse tail is a CHAIN of programs — dedup (sort, segment sum), an
 accumulator gather, two table-shaped scatters — each of which walks its own
-descriptor stream over the same touched rows.  This module holds two
-kernels that take the tail's place after the SAME dedup (optim.dedup_rows —
-the sort/segment-sum pipeline the rows-layout classic update uses, so the
-summed gradients are bit-identical to it):
+descriptor stream over the same touched rows.  The kernel here takes the
+tail's place after the SAME dedup (optim.dedup_rows — the sort/segment-sum
+pipeline the rows-layout classic update uses, so the summed gradients are
+bit-identical to it).
 
-  * ``rows_tail_adagrad_update`` / ``sweep_adagrad_update`` — the **rows
-    sweep** (PR 30) for a plain ``[V, D]`` table with a separate ``[V, D]``
-    (element) or ``[V, 1]`` (row) accumulator: the resident rows layout AND
-    the tiered paramstore's compact ``[C, D]`` device table.  It never
-    addresses a row.  A ``[V, D]`` float32 buffer with ``D < 128`` is held
-    lane-major on the TPU (``{0,1:T(8,128)}``: the row index along the
-    lanes), which IS the row-major layout of its transpose, so the kernel
-    takes ``table.T`` / ``accum.T`` (bitcasts in the compiled step), walks
-    them block by block IN PLACE (``input_output_aliases``) and writes whole
-    tile columns.  The batch's dense delta never exists in HBM: a work list
-    computed from the sorted unique ids (scalar prefetch) pairs every block
-    with the 256-id chunks that fall in it, and the kernel builds the
-    block's gradient in VMEM as a one-hot of the ids against the row index,
-    contracted with the gradients on the MXU — exact in float32, because
-    every output has one non-zero term and the gradient goes in as three
-    bfloat16 parts that sum back to it bit for bit; a row of ones in the
-    gradients returns the hit mask.  Adagrad is then the classic
-    expressions on the block, SELECTED by the hit mask: an untouched row
-    comes out bit for bit whatever its accumulator holds (0 included), and a
-    lazily decayed accumulator decays only where touched.  Blocks no id
-    falls in are not visited.  A non-finite gradient spreads NaN over the
-    touched rows of its chunk's tiles (0·inf in the contraction); the step's
-    loss is non-finite then and the trainer's ``on_nan`` policy has it.
-  * ``fused_tail_adagrad_update`` — the resident fused layout
-    (``ops.packed_table.pack_fused``, ``[VPf, 128]``, P = 128//(D+1)
-    logical rows per tile row; accumulator in lane ``s·(D+1)+D``): per
-    deduped row, DMA **only the touched lanes** HBM→VMEM (the row's own
-    ``D+1``-lane slot), apply the update in VMEM, DMA the result back,
-    double-buffered two row-blocks deep (``_schedule``); the output aliases
-    the table operand, so untouched rows are never read or written.
+``rows_tail_adagrad_update`` / ``sweep_adagrad_update`` — the **rows sweep**
+(PR 30) — serve a plain ``[V, D]`` table with a separate ``[V, D]``
+(element) or ``[V, 1]`` (row) accumulator: the resident rows layout AND the
+tiered paramstore's compact ``[C, D]`` device table.  The sweep never
+addresses a row.  A ``[V, D]`` float32 buffer with ``D < 128`` is held
+lane-major on the TPU (``{0,1:T(8,128)}``: the row index along the lanes),
+which IS the row-major layout of its transpose, so the kernel takes
+``table.T`` / ``accum.T`` (bitcasts in the compiled step), walks them block
+by block IN PLACE (``input_output_aliases``) and writes whole tile columns.
+The batch's dense delta never exists in HBM: a work list computed from the
+sorted unique ids (scalar prefetch) pairs every block with the 256-id
+chunks that fall in it, and the kernel builds the block's gradient in VMEM
+as a one-hot of the ids against the row index, contracted with the
+gradients on the MXU — exact in float32, because every output has one
+non-zero term and the gradient goes in as three bfloat16 parts that sum
+back to it bit for bit; a row of ones in the gradients returns the hit
+mask.  Adagrad is then the classic expressions on the block, SELECTED by
+the hit mask: an untouched row comes out bit for bit whatever its
+accumulator holds (0 included), and a lazily decayed accumulator decays
+only where touched.  Blocks no id falls in are not visited.  A non-finite
+gradient spreads NaN over the touched rows of its chunk's tiles (0·inf in
+the contraction); the step's loss is non-finite then and the trainer's
+``on_nan`` policy has it.
 
 Decay-γ (``[Online] adagrad_decay``) threads through exactly like
 ``trainer.make_decayed_body``: γ=1.0 is a TRACE-TIME branch back to the
 classic expression (``accum += g²``); γ<1 decays lazily, touched rows only.
 
-Both run under ``interpret=`` for CPU tier-1 (ops.pallas_common resolves
-the flag, same pattern as ops/pallas_anova.py).
+It runs under ``interpret=`` for CPU tier-1 (ops.pallas_common resolves the
+flag, same pattern as ops/pallas_anova.py).
 
-STATUS ON THE CHIP (TPU v5 lite, jax 0.9.0, libtpu 0.0.34).  The rows sweep
+STATUS ON THE CHIP (TPU v5 lite, jax 0.9.0, libtpu 0.0.34).  The sweep
 compiles and runs (PR 30; tests/test_pallas_tail_chip_compile.py compiles
 it for a described v5e at the train cell's shapes): at ``fm8_criteo``'s
 shapes (2^26 rows of 9, 2,555,904 ids a step, 2.2M distinct) the kernel
 takes 38 ms inside the step (45 alone, with its work list) where the XLA
 row operations took 570, and table and accumulator come out as theirs
-(PERF.md §6 has the bitwise reading).  ``tail = auto`` takes it on a TPU where ``optim.rows_tail_form`` says
-the sweep costs less than the batch's row operations.  The fused kernel
-does NOT compile (PR 22): Mosaic refuses the per-row DMA it is built on::
-
-    INTERNAL: Mosaic failed to compile TPU kernel: Slice shape along
-    dimension 1 must be aligned to tiling (128), but is 9.
-      "tpu.memref_slice"(...) : (memref<74904x128xf32,
-      #tpu.tiled<(1,128),[1,1]>, #tpu.memory_space<hbm>>, i32, i32)
-      -> memref<1x9xf32, #tpu.tiled<(1,128),[1,1]>, ...<hbm>>
-
-An HBM row is stored 128 lanes wide and a DMA window must cover whole
-tiles, so a 9-lane window does not exist (the per-row DMA kernel that stood
-behind ``rows_tail_adagrad_update`` until PR 30 died of the same message
-and is gone).  So for the packed layouts ``tail = auto`` resolves to the
-XLA tail (ops.pallas_common.resolve_tail), ``tail = pallas`` on the fused
-layout raises the message above on a TPU, and ROADMAP D2 decides whether
-the fused kernel stays.  It still runs interpreted on the CPU test mesh.
+(PERF.md §6 has the bitwise reading).  ``optim.sparse_adagrad_update`` takes
+it on a TPU where ``optim.rows_tail_form`` says the sweep costs less than
+the batch's row operations.  There is no kernel that moves a touched row by
+a DMA of its own (two stood here, for the rows and the fused layouts, until
+PRs 30 and 31): Mosaic refuses it, "Slice shape along dimension 1 must be
+aligned to tiling (128), but is 9" — an HBM row is stored 128 lanes wide and
+a DMA window covers whole tiles.
 """
 
 from __future__ import annotations
@@ -84,15 +68,12 @@ from fast_tffm_tpu.optim import dedup_rows
 from fast_tffm_tpu.ops.pallas_common import resolve_interpret
 
 __all__ = [
-    "fused_tail_adagrad_update",
     "rows_tail_adagrad_update",
     "sweep_adagrad_update",
     "sweep_block_lanes",
     "sweep_fits",
-    "DEFAULT_BLOCK_ROWS",
 ]
 
-DEFAULT_BLOCK_ROWS = 256  # rows per grid step; 2 buffers × 256 × ≤128 lanes
 # The rows sweep (readings: PERF.md §6, PR 30, at fm8_criteo's shapes).
 # A block of the table is this many bytes in VMEM (its rows padded to whole
 # sublanes); eight such buffers are in flight (table and accumulator, in
@@ -127,206 +108,6 @@ def sweep_fits(v: int, d: int, m: int) -> bool:
     65,536 items, about 15M ids a batch on 2^26 rows of 9."""
     items = -(-v // sweep_block_lanes(v, d)) + -(-m // _CHUNK)
     return 12 * items <= 768 << 10
-
-
-def _nblocks(k: int, blk: int) -> int:
-    return max(1, -(-k // blk))
-
-
-def _pad_ids(uids: jax.Array, total: int, sentinel: int) -> jax.Array:
-    k = uids.shape[0]
-    if total == k:
-        return uids
-    return jnp.pad(uids, (0, total - k), constant_values=sentinel)
-
-
-def _schedule(i, nblocks, start_in, wait_in, start_out, wait_out, compute):
-    """The shared double-buffer schedule for one grid step ``i``.
-
-    Slot ``i % 2`` holds block ``i``; while it computes, block ``i+1``
-    gathers into the other slot, whose previous occupant's (block
-    ``i−1``'s) scatter DMAs are drained first.  All four DMA phases are
-    per-row-predicated identically, so semaphore starts and waits always
-    pair up."""
-    slot = lax.rem(i, 2)
-    other = lax.rem(i + 1, 2)
-
-    @pl.when(i == 0)
-    def _():
-        start_in(i, slot)
-
-    @pl.when(i >= 1)
-    def _():
-        wait_out(i - 1, other)
-
-    @pl.when(i + 1 < nblocks)
-    def _():
-        start_in(i + 1, other)
-
-    wait_in(i, slot)
-    compute(slot)
-    start_out(i, slot)
-
-    @pl.when(i == nblocks - 1)
-    def _():
-        wait_out(i, slot)
-
-
-# --------------------------------------------------------------------------
-# fused [VPf, 128] layout (ops.packed_table.pack_fused)
-# --------------------------------------------------------------------------
-
-
-def _fused_kernel(
-    uids_ref, nrows_ref, g_ref, fused_ref, out_ref, buf, in_sem, out_sem,
-    *, lr: float, decay: float, p: int, d: int, blk: int, nblocks: int,
-    vmax: int,
-):
-    i = pl.program_id(0)
-    nrows = nrows_ref[0]
-    d1 = d + 1
-
-    def slot_slice(row):
-        """Touched-lane address of deduped logical row ``row``: the
-        (tile row, first lane) of its D+1-lane slot."""
-        lid = jnp.minimum(uids_ref[row], vmax - 1)  # clamp pad sentinels
-        return lid // p, (lid % p) * d1
-
-    def _run(block, slot, *, outward, wait):
-        base = block * blk
-
-        def body(j, _):
-            @pl.when(base + j < nrows)
-            def _():
-                phys, lane0 = slot_slice(base + j)
-                vref = buf.at[slot, j]
-                href = (out_ref if outward else fused_ref).at[
-                    phys, pl.ds(lane0, d1)
-                ]
-                src, dst = (vref, href) if outward else (href, vref)
-                cp = pltpu.make_async_copy(
-                    src, dst, (out_sem if outward else in_sem).at[slot]
-                )
-                cp.wait() if wait else cp.start()
-            return 0
-
-        @pl.when(base < nrows)
-        def _():
-            lax.fori_loop(0, blk, body, 0)
-
-    def compute(slot):
-        cur = buf[slot]  # [blk, d+1]: d params + the row accumulator
-        g = g_ref[...]  # [blk, d] deduped summed gradients
-        w, acc0 = cur[:, :d], cur[:, d]
-        gsq = jnp.sum(g * g, axis=-1)
-        if decay == 1.0:  # trace-time: the exact classic program
-            acc2 = acc0 + gsq
-        else:  # lazy decay — every deduped row here WAS touched
-            acc2 = decay * acc0 + gsq
-        new_w = w - lr * g / jnp.sqrt(acc2)[:, None]
-        buf[slot] = jnp.concatenate([new_w, acc2[:, None]], axis=-1)
-
-    _schedule(
-        i, nblocks,
-        start_in=lambda b, s: _run(b, s, outward=False, wait=False),
-        wait_in=lambda b, s: _run(b, s, outward=False, wait=True),
-        start_out=lambda b, s: _run(b, s, outward=True, wait=False),
-        wait_out=lambda b, s: _run(b, s, outward=True, wait=True),
-        compute=compute,
-    )
-
-
-def _fused_rmw(fused, uids, nrows, gsum, *, lr, decay, p, d, interpret, blk):
-    """One-pass RMW over ``K = uids.shape[0]`` deduped logical rows."""
-    k = uids.shape[0]
-    nblocks = _nblocks(k, blk)
-    vmax = fused.shape[0] * p  # any lid ≥ vmax is a pad sentinel
-    uids = _pad_ids(uids.astype(jnp.int32), nblocks * blk, vmax)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((blk, d), lambda i, *_: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((2, blk, d + 1), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    kernel = functools.partial(
-        _fused_kernel, lr=float(lr), decay=float(decay), p=p, d=d, blk=blk,
-        nblocks=nblocks, vmax=vmax,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(fused.shape, fused.dtype),
-        input_output_aliases={3: 0},  # fused table updates in place
-        interpret=interpret,
-    )(uids, nrows, gsum, fused)
-
-
-def fused_tail_adagrad_update(
-    fused: jax.Array,
-    ids: jax.Array,
-    row_grads: jax.Array,
-    lr: float,
-    *,
-    decay: float = 1.0,
-    k_cap: int = 0,
-    interpret: bool | None = None,
-    block_rows: int = DEFAULT_BLOCK_ROWS,
-) -> jax.Array:
-    """Adagrad over the fused ``[VPf, 128]`` layout in one kernel pass.
-
-    Semantically ``ops.packed_table.apply_fused_update`` (row-granularity
-    accumulator): dedup to unique logical rows, ``acc ← γ·acc + ‖g‖²``,
-    ``w ← w − lr·g/√acc``.  The dedup is ``optim.dedup_rows`` — the SAME
-    sort/segment pipeline the rows-layout classic update uses, so at
-    γ=1.0 the result is ``sparse_adagrad_update``'s with a row accumulator
-    on the logical arrays, bit for bit where both run the same
-    expressions and within a few ULP inside the ``k_cap`` fallback
-    (test-pinned; the classic tail scatter-ADDS ``-lr·g/√acc`` since
-    PR 27); against the scatter-add-built XLA fused tails it is allclose
-    (summation order).
-
-    ``k_cap`` mirrors ``packed_compact_cap``: cap the kernel's deduped
-    row span, with an exact full-span ``lax.cond`` fallback when a batch
-    touches more rows — never silent truncation.
-    """
-    interpret = resolve_interpret(interpret)
-    d = row_grads.shape[-1]
-    p = 128 // (d + 1)
-    v = fused.shape[0] * p
-    flat = ids.reshape(-1)
-    uids, gsum = dedup_rows(flat, row_grads.reshape(-1, d), v)
-    m = uids.shape[0]
-    nrows = jnp.sum(uids < v).astype(jnp.int32)[None]
-    blk = max(8, min(block_rows, m))
-    run = functools.partial(
-        _fused_rmw, lr=lr, decay=decay, p=p, d=d, interpret=interpret,
-        blk=blk,
-    )
-    if k_cap and k_cap < m:
-        # Exact-capacity fallback, same shape as the XLA compact tail's:
-        # overflowing batches pay the full span, never lose updates.
-        return lax.cond(
-            nrows[0] <= k_cap,
-            lambda f: run(f, uids[:k_cap], nrows, gsum[:k_cap]),
-            lambda f: run(f, uids, nrows, gsum),
-            fused,
-        )
-    return run(fused, uids, nrows, gsum)
-
-
-# --------------------------------------------------------------------------
-# rows [V, D] (+ separate [V, A] accumulator) layout — resident rows path
-# and the tiered paramstore's compact [C, D] device table: one in-place
-# sweep over table and accumulator in their own lane-major layout
-# --------------------------------------------------------------------------
 
 
 def _sweep_plan(uids, v: int, tb: int, tile: int):

@@ -298,7 +298,8 @@ def sparse_adagrad_update(
     the trailing drop ids are distinct (a trace-time test on shapes).
     ``form`` ``"sweep"``: the same update as one in-place kernel pass over
     table and accumulator (ops.pallas_tail.sweep_adagrad_update).  ``None``
-    (every caller but an explicit ``[Train] tail``): ``rows_tail_form``.
+    (every driver; a test or ``chip_smoke.py`` names a form to run it on any
+    backend): ``rows_tail_form``.
 
     ``decay`` γ < 1 decays the accumulator LAZILY — only the rows a step
     touches pay ``accum = γ·accum + g²`` (an untouched row's history is

@@ -1,7 +1,6 @@
 from fast_tffm_tpu.parallel.mesh import (  # noqa: F401
     DATA_AXIS,
     ROW_AXIS,
-    batch_sharding,
     check_batch_divides,
     make_mesh,
     pad_vocab,

@@ -24,7 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 __all__ = [
     "make_mesh",
     "table_sharding",
-    "batch_sharding",
     "replicated",
     "pad_vocab",
     "axis_size",
@@ -59,13 +58,6 @@ def make_mesh(
 def table_sharding(mesh: Mesh) -> NamedSharding:
     """[V, D] tables: rows split over ROW_AXIS, replicated over DATA_AXIS."""
     return NamedSharding(mesh, P(ROW_AXIS, None))
-
-
-def batch_sharding(mesh: Mesh) -> NamedSharding:
-    """Batch-major arrays: leading dim split over EVERY chip (both axes) —
-    matches the train/predict steps' batch specs (compute is fully
-    data-parallel; only the table is row-sharded)."""
-    return NamedSharding(mesh, P((DATA_AXIS, ROW_AXIS)))
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

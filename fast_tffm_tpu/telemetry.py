@@ -36,7 +36,7 @@ schema and carrying no run identity.  ``RunMonitor`` unifies them:
     fires classified "compiling").  One event per stall episode; a
     recovered-then-stalled run fires again.
 
-``arm_hang_exit`` is the hard os._exit timer the bench/probe tools arm
+``arm_hang_exit`` is the hard os._exit timer the batch tools (tools/) arm
 BEFORE ``import jax``: a batch tool must not hang.  That contract is why
 this module — and the package ``__init__`` — must import without jax;
 everything jax-touching here is lazy.
@@ -199,7 +199,7 @@ def new_run_id() -> str:
 
 
 def artifact_stamp(run_id: str = "") -> dict:
-    """The join keys every committed BENCH_*/PROBE_* JSON must carry so a
+    """The join keys every committed JSON artifact of a tool must carry so a
     bench artifact is joinable to the telemetry JSONL stream(s) it was
     measured from: the envelope ``run_id`` (pass the run's; a fresh one
     is drawn for tools that never started a monitored run) and the
@@ -208,7 +208,7 @@ def artifact_stamp(run_id: str = "") -> dict:
 
 
 def write_json_artifact(path, obj, *, indent: int = 1, sort_keys: bool = True) -> None:
-    """Atomically publish a committed BENCH_*/PROBE_*-style JSON artifact:
+    """Atomically publish a tool's JSON artifact:
     full payload to a sibling tmp, then ``os.replace`` onto ``path`` — the
     same complete-or-previous contract every checkpoint publish honors
     (DESIGN crash-consistency invariant 1; gated by the atomic-publish
@@ -935,7 +935,7 @@ def arm_hang_exit(seconds: float = DEFAULT_HANG_EXIT_SECS, what: str = "bench"):
     if not cancelled within ``seconds``.
 
     A batch tool must not hang: a hung benchmark is worse than a missing
-    one, because it stalls the whole harness.  The bench/probe scripts arm
+    one, because it stalls the whole harness.  The scripts under tools/ arm
     this BEFORE importing jax/fast_tffm_tpu (backend initialization is
     itself a place a process can block) and cancel it once their last
     result line is printed — which is why this module (and the package

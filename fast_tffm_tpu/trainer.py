@@ -39,7 +39,6 @@ __all__ = [
     "make_train_step",
     "make_decayed_body",
     "make_dedup_body",
-    "make_pallas_tail_body",
     "make_accum_restart",
     "make_scanned_train_step",
     "make_predict_step",
@@ -102,7 +101,7 @@ def batch_loss(model, table_rows, dense, batch: Batch):
 
 def train_step_body(
     model, learning_rate: float, state: TrainState, batch: Batch,
-    decay: float = 1.0, tail_form: str | None = None,
+    decay: float = 1.0, gather=gather_rows,
 ):
     """The (unjitted) single-device step, by the scope its ops carry:
     ``fm.gather`` (the batch's rows) → ``fm.interaction`` (fused scorer and
@@ -110,16 +109,18 @@ def train_step_body(
     permutation gather, segment sum on 128-lane rows, unique ids by a second
     sort) → ``fm.tail`` (one gather and one scatter-set of the accumulator,
     one scatter-add into the table, all declared sorted and unique; or the
-    in-place sweep; optim.sparse_adagrad_update, whose ``form`` is
-    ``tail_form``: None leaves the choice to ``optim.rows_tail_form``).
+    in-place sweep: optim.sparse_adagrad_update, which chooses between the
+    two from the shapes, ``optim.rows_tail_form``).
     Shared verbatim by ``make_train_step`` and the device-cache step
     (data/device_cache.py) so the two paths are the SAME math on the same
     values — the bit-identity their parity test pins.
 
     ``decay`` is the online-learning ``[Online] adagrad_decay`` γ (lazy
     touched-row accumulator decay — optim.sparse_adagrad_update); γ=1.0
-    branches back to the exact classic program at trace time."""
-    rows = gather_rows(state.table, batch.ids)  # [B, N, D]
+    branches back to the exact classic program at trace time.  ``gather``
+    reads the batch's rows ``[B, N, D]`` from the table
+    (``make_dedup_body`` passes another)."""
+    rows = gather(state.table, batch.ids)  # [B, N, D]
 
     grad_fn = jax.value_and_grad(
         partial(batch_loss, model), argnums=(0, 1), has_aux=True
@@ -128,7 +129,7 @@ def train_step_body(
 
     table, table_opt = sparse_adagrad_update(
         state.table, state.table_opt, batch.ids, g_rows, learning_rate,
-        decay=decay, form=tail_form,
+        decay=decay,
     )
     dense, dense_opt = state.dense, state.dense_opt
     if jax.tree.leaves(state.dense):
@@ -164,83 +165,44 @@ def make_train_step(model, learning_rate: float, decay: float = 1.0, body=None):
     return step
 
 
-def make_decayed_body(decay: float, tail_form: str | None = None):
-    """``train_step_body`` with ``[Online] adagrad_decay`` γ and the tail's
-    form (an explicit ``[Train] tail``; None: ``optim.rows_tail_form``) baked
-    in — the ``body`` shape the scanned and device-cache step factories take."""
+def make_decayed_body(decay: float):
+    """``train_step_body`` with ``[Online] adagrad_decay`` γ baked in — the
+    ``body`` shape the scanned and device-cache step factories take."""
 
     def body(model, learning_rate, state, batch):
-        return train_step_body(
-            model, learning_rate, state, batch, decay, tail_form
-        )
+        return train_step_body(model, learning_rate, state, batch, decay)
 
     return body
 
 
-def make_pallas_tail_body(decay: float = 1.0):
-    """``train_step_body`` with the sparse Adagrad tail as the Pallas rows
-    sweep whatever the shapes (``[Train] tail = pallas``;
-    ops.pallas_tail.sweep_adagrad_update): same gather → fused scorer → loss
-    → dedup front, then ONE in-place kernel pass over table and accumulator
-    instead of the XLA row gather and the two row scatters.
-
-    Same ``(model, lr, state, batch)`` body contract as the scanned /
-    device-cache / tiered factories, so it plugs into
-    ``make_train_step(body=...)``, ``make_scanned_train_step(body=...)``,
-    and the tiered paramstore's ``wrap_step`` unchanged — the tiered
-    compact ``[C, D]`` staging table is the operand shape the kernel takes.
-    On the CPU test mesh the kernel interprets itself (ops.pallas_common)."""
-    return make_decayed_body(decay, "sweep")
-
-
-def make_dedup_body(cap: int, decay: float = 1.0, tail_form: str | None = None):
-    """Device-side dedup-before-gather (ROADMAP item 2(a)): the forward
-    gather reads each of the batch's ≤ ``cap`` UNIQUE rows from the
-    [V, D] table exactly once; per-slot re-reads index a compact
-    ``[cap, D]`` buffer instead of HBM.  At the measured Zipf(1.1) dedup
-    ratio (PROBE_IDSTATS_r09: 0.291) that is ~71% of forward-gather
-    bytes gone.  Gathered VALUES are identical to the direct gather, so
-    the loss/grad pipeline — and the unchanged sparse Adagrad update —
-    produce bit-identical results (test-pinned).
+def make_dedup_body(cap: int, decay: float = 1.0):
+    """Device-side dedup-before-gather (ROADMAP D12): ``train_step_body``
+    whose forward gather reads each of the batch's ≤ ``cap`` UNIQUE rows
+    from the [V, D] table exactly once; per-slot re-reads index a compact
+    ``[cap, D]`` buffer instead of HBM.  Gathered VALUES are identical to
+    the direct gather, so the loss/grad pipeline — and the unchanged sparse
+    Adagrad update — produce bit-identical results (test-pinned).
 
     ``cap`` must bound the batch's unique-id count; the input stream
     VERIFIES that per batch before shipping (training._stream's dedup
     guard), so a too-small cap is a loud error, never silent truncation
     (``jnp.unique(size=...)`` would otherwise drop the largest ids).
-    Same ``body`` contract as the scanned/device-cache factories;
-    ``tail_form`` as in ``make_decayed_body``."""
+    Same ``body`` contract as the scanned/device-cache factories."""
 
-    def body(model, learning_rate, state: TrainState, batch: Batch):
-        import jax.numpy as jnp
-
-        v, d = state.table.shape
-        flat = batch.ids.reshape(-1)
+    def gather(table, ids):
+        v, d = table.shape
+        flat = ids.reshape(-1)
         # Sorted unique ids padded with the out-of-range sentinel ``v``
         # (the gather clamps it to a row whose value is never used).
         with jax.named_scope("fm.gather"):
             uids = jnp.unique(flat, size=cap, fill_value=v)
-            compact = state.table[jnp.minimum(uids, v - 1)]
+            compact = table[jnp.minimum(uids, v - 1)]
             inv = jnp.searchsorted(uids, flat)
-            rows = compact[inv].reshape(*batch.ids.shape, d)
+            return compact[inv].reshape(*ids.shape, d)
 
-        grad_fn = jax.value_and_grad(
-            partial(batch_loss, model), argnums=(0, 1), has_aux=True
-        )
-        (_, data_loss), (g_rows, g_dense) = grad_fn(rows, state.dense, batch)
-
-        table, table_opt = sparse_adagrad_update(
-            state.table, state.table_opt, batch.ids, g_rows, learning_rate,
-            decay=decay, form=tail_form,
-        )
-        dense, dense_opt = state.dense, state.dense_opt
-        if jax.tree.leaves(state.dense):
-            dense, dense_opt = dense_adagrad_update(
-                state.dense, state.dense_opt, g_dense, learning_rate,
-                decay=decay,
-            )
-        return (
-            TrainState(table, table_opt, dense, dense_opt, state.step + 1),
-            data_loss,
+    def body(model, learning_rate, state: TrainState, batch: Batch):
+        return train_step_body(
+            model, learning_rate, state, batch, decay, gather
         )
 
     return body
@@ -378,7 +340,7 @@ def init_packed_state(
 
 def packed_train_step_body(
     model, learning_rate: float, state: TrainState, batch: Batch,
-    update: str = "auto", compact_cap: int = 0, tail: str = "xla",
+    update: str = "auto", compact_cap: int = 0,
 ):
     """train_step_body on a lane-packed table: identical math, tile-row
     physical movement (the narrow-scatter cliff fix — DESIGN §6).
@@ -389,15 +351,11 @@ def packed_train_step_body(
     a dense Adagrad sweep (measured 3.5× the sorted path at vocab 2^24);
     ``compact`` — sort-free touched-row compaction, O(M) buffers (the
     giant-vocab path); ``sorted`` — sort/segment-sum/RMW (bit-parity
-    reference); ``auto`` — dense under DENSE_G_MAX_BYTES, else compact.
-
-    ``tail = "pallas"`` (fused layout only — config.validate enforces it)
-    replaces the whole XLA update chain with the one-pass Pallas kernel
-    (ops.pallas_tail.fused_tail_adagrad_update); ``update`` is then moot
-    and ``compact_cap`` becomes the kernel's deduped-row cap."""
+    reference); ``auto`` — dense under DENSE_G_MAX_BYTES, else compact."""
     from fast_tffm_tpu.ops.packed_table import (
         FUSED_UPDATE_FNS,
         PACKED_UPDATE_FNS,
+        apply_fused_update,
         fused_gather,
         packed_gather,
         resolve_fused_update,
@@ -417,25 +375,15 @@ def packed_train_step_body(
     )
     (_, data_loss), (g_rows, g_dense) = grad_fn(rows, state.dense, batch)
 
-    # Every packed tail (dense / compact / sorted / fused / Pallas) runs
-    # under the rows layout's name for the same stage.
+    # Every packed tail (dense / compact / sorted / fused) runs under the
+    # rows layout's name for the same stage.
     with jax.named_scope("fm.tail"):
         if fused:
-            if tail == "pallas":
-                from fast_tffm_tpu.ops.pallas_tail import fused_tail_adagrad_update
-
-                table = fused_tail_adagrad_update(
-                    state.table, batch.ids, g_rows, learning_rate,
-                    k_cap=compact_cap,
-                )
-            else:
-                from fast_tffm_tpu.ops.packed_table import apply_fused_update
-
-                mode = resolve_fused_update(update, state.table.shape[0])
-                table = apply_fused_update(
-                    state.table, batch.ids, g_rows, learning_rate, mode,
-                    compact_cap,
-                )
+            mode = resolve_fused_update(update, state.table.shape[0])
+            table = apply_fused_update(
+                state.table, batch.ids, g_rows, learning_rate, mode,
+                compact_cap,
+            )
             accum = acc
         else:
             mode = resolve_packed_update(update, state.table.shape[0], acc.shape[-1])
@@ -456,18 +404,15 @@ def packed_train_step_body(
 
 def make_packed_train_step(
     model, learning_rate: float, update: str = "auto", compact_cap: int = 0,
-    tail: str = "xla",
 ):
     """``compact_cap`` (fused compact tail only): cap the compacted-row
     buffer below the exact worst case, with an exact-capacity lax.cond
-    fallback when a batch touches more rows (config: packed_compact_cap).
-    ``tail``: resolved ``[Train] tail`` — ``pallas`` routes the fused
-    layout through the one-pass Pallas kernel."""
+    fallback when a batch touches more rows (config: packed_compact_cap)."""
 
     @partial(jax.jit, donate_argnums=(0,))
     def step(state: TrainState, batch: Batch):
         return packed_train_step_body(
-            model, learning_rate, state, batch, update, compact_cap, tail
+            model, learning_rate, state, batch, update, compact_cap
         )
 
     return step

@@ -1332,32 +1332,15 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
         state = init_state(
             model, jax.random.key(0), cfg.init_accumulator_value, cfg.adagrad_accumulator
         )
-    # [Train] tail: resolve auto ONCE, up front, so every step factory
-    # below (packed, rows, scanned, device cache) sees the same resolved
-    # choice.  For the packed / fused layouts auto = the XLA tail (their
-    # kernel does not compile on the chip — ops.pallas_common.resolve_tail);
-    # for the rows layout auto leaves the form to the shapes
-    # (optim.rows_tail_form: the Pallas sweep or the XLA row operations).
-    # An EXPLICIT pallas builds the kernel step and lets the compiler's
-    # error raise — it never drops back to xla — and is a config error
-    # where the kernel has no contract (split packed accumulators,
-    # dedup_gather_rows); an explicit xla keeps the row operations.
-    from fast_tffm_tpu.ops.pallas_common import resolve_tail
-
-    tail = resolve_tail(cfg.tail)
     tail_profile = {}  # what the rows layout's tail says of itself, once
     if packed:
         predict_step = make_packed_predict_step(model, fused=fused)
-        packed_tail = tail if fused else "xla"
-        if packed_tail == "pallas":
-            log("sparse tail: pallas (fused one-pass gather→Adagrad→scatter)")
         step_body = lambda mdl, lr, st, b: packed_train_step_body(
-            mdl, lr, st, b, cfg.packed_update, cfg.packed_compact_cap,
-            packed_tail,
+            mdl, lr, st, b, cfg.packed_update, cfg.packed_compact_cap
         )
         step_fn = make_packed_train_step(
             model, cfg.learning_rate, cfg.packed_update,
-            compact_cap=cfg.packed_compact_cap, tail=packed_tail,
+            compact_cap=cfg.packed_compact_cap,
         )
     else:
         predict_step = make_predict_step(model)
@@ -1373,23 +1356,23 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
         )
         from fast_tffm_tpu.trainer import make_decayed_body, make_dedup_body
 
-        m_ids = cfg.batch_size * cfg.max_nnz
-        num_rows, row_dim = state.table.shape
-        tail_form = {"xla": "rows", "pallas": "sweep"}.get(cfg.tail) or (
-            rows_tail_form(
-                num_rows, m_ids, row_dim, state.table_opt.accum.shape[-1]
-            )
-        )
         if cfg.dedup_gather_rows > 0:
-            # Device-side dedup-before-gather (ROADMAP item 2(a)): the
+            # Device-side dedup-before-gather (ROADMAP D12): the
             # forward gather touches each unique row once; the stream's
             # host-side guard (_dedup_cap_guard) pins the cap.  Values —
             # and therefore losses — are bit-identical (test-pinned).
-            step_body = make_dedup_body(cfg.dedup_gather_rows, decay, tail_form)
-        elif decay != 1.0 or cfg.tail != "auto":
-            step_body = make_decayed_body(decay, tail_form)
+            step_body = make_dedup_body(cfg.dedup_gather_rows, decay)
+        elif decay != 1.0:
+            step_body = make_decayed_body(decay)
         else:
-            step_body = None  # train_step_body: the same rule at trace time
+            step_body = None  # train_step_body
+        # The form the step's tail takes is optim.sparse_adagrad_update's
+        # choice at trace time; asked here only to say it once.
+        m_ids = cfg.batch_size * cfg.max_nnz
+        num_rows, row_dim = state.table.shape
+        tail_form = rows_tail_form(
+            num_rows, m_ids, row_dim, state.table_opt.accum.shape[-1]
+        )
         tail_profile = dict(
             segment_sum_lanes=segment_sum_lanes(m_ids, row_dim),
             tail_form=tail_form,
@@ -1534,12 +1517,8 @@ def _tiered_train(cfg: Config, *, resume: bool, log=print, step_hook=None):
     decay = float(cfg.online_adagrad_decay)
     # The tiered inner step already runs over the compact [C, D] staging
     # table with remapped slot ids — exactly the rows-layout operands, so
-    # the SAME bodies serve both tiers: an explicit tail fixes the form,
-    # auto leaves it to the shapes (optim.rows_tail_form).
-    tail_form = {"xla": "rows", "pallas": "sweep"}.get(cfg.tail)
-    if tail_form == "sweep":
-        log("sparse tail: pallas (rows sweep over the compact tier)")
-    body = make_decayed_body(decay, tail_form) if decay != 1.0 or tail_form else None
+    # the SAME bodies serve both tiers.
+    body = make_decayed_body(decay) if decay != 1.0 else None
     if cfg.steps_per_call > 1:
         inner = make_scanned_train_step(model, cfg.learning_rate, body=body)
     else:
@@ -1752,16 +1731,6 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
 
     if not cfg.train_files:
         raise ValueError("no train_files configured")
-    if cfg.tail == "pallas":
-        # Loud, not silent: a run that pins the Pallas tail but launches
-        # the sharded driver would measure the XLA tail and call it
-        # pallas.  (``auto`` resolves to xla here — the sharded step's
-        # collective tail is not the kernel's contract yet.)
-        raise ValueError(
-            "tail = pallas is not supported by dist_train yet (the "
-            "sharded step keeps the XLA sparse tail); use tail = auto "
-            "or xla for distributed runs"
-        )
     if cfg.weight_files and len(cfg.weight_files) != len(cfg.train_files):
         # Checked here, not in Config.validate: a shared config must still
         # LOAD on predict-only machines where train-file globs match

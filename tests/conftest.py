@@ -24,6 +24,7 @@ os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
 )
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 # Multi-device tests gate themselves on len(jax.devices()) (test_parallel's
 # skipif), so no device-count assert here — an ambient XLA_FLAGS with a
@@ -112,3 +113,15 @@ def pytest_terminal_summary(terminalreporter, exitstatus, config):
             "at risk; mark long tests slow or trim them",
             yellow=True,
         )
+
+
+@pytest.fixture
+def sweep_form(monkeypatch):
+    """``optim.rows_tail_form`` says the sweep whatever the shapes and the
+    backend: every step TRACED while this holds takes the kernel (here
+    interpreted), as a TPU's does where the rule says so."""
+    asked = []
+    monkeypatch.setattr(
+        "fast_tffm_tpu.optim.rows_tail_form", lambda *a, **k: asked.append(a) or "sweep"
+    )
+    return asked  # the shapes of every step traced so

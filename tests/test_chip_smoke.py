@@ -67,7 +67,7 @@ def test_toy_rehearsal_runs_every_phase_with_a_jax_free_parent(tmp_path):
     (train,) = [l for l in out.splitlines() if l.startswith("chip_smoke: train ok")]
     for field in ("device_kind=", "devices=", "wall=", "compiles=", "cache_hits=", "parser="):
         assert field in train, train
-    assert "auto_tail=xla" in out
+    assert "chip_smoke: kernels rows_tail: interpreted+matched" in out
 
 
 def test_main_refuses_the_cpu_and_prints_no_result():

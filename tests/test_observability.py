@@ -121,15 +121,18 @@ def test_streamed_profile_and_datastats(dataset):
         (dict(model="fm", factor_num=4), 5, 128, "rows", None),  # under a tile: padded to one
         (dict(model="ffm", factor_num=4, num_fields=39), 157, 256, "rows", None),  # libffm's Criteo row: two tiles
         (dict(model="fm", factor_num=127), 128, 128, "rows", None),  # tile-wide already
-        (dict(model="fm", factor_num=4, tail="pallas"), 5, 128, "sweep", 256),  # asked for: 200 rows, two tiles
+        (dict(model="fm", factor_num=4), 5, 128, "sweep", 256),  # the rule says so (patched): 200 rows, two tiles
         (dict(model="fm", factor_num=4, table_layout="packed"), 5, None, None, None),  # no rows-layout tail
     ],
     ids=["fm_k4", "ffm_39x4", "fm_k127", "fm_k4_sweep", "packed"],
 )
-def test_the_steps_profile_record_says_the_row_width_and_the_tails_lanes(dataset, kw, row_dim, lanes, form, block):
+def test_the_steps_profile_record_says_the_row_width_and_the_tails_lanes(dataset, request, kw, row_dim, lanes, form, block):
     """What only the start-up log line said (``describe_rows_tail``): the row
     width, the lanes ``dedup_rows`` sums segments at and the form the tail
-    took (off a TPU the rows unless asked), trace-time choices all."""
+    took (off a TPU the rows, unless ``rows_tail_form`` is made to say the
+    sweep), trace-time choices all."""
+    if form == "sweep":
+        request.getfixturevalue("sweep_form")
     cfg = _cfg(dataset, tag="lanes", epoch_num=1, **kw)
     logs = []
     train(cfg, log=lambda *a: logs.append(" ".join(map(str, a))))

@@ -1,7 +1,7 @@
 """Pallas ANOVA kernel vs. brute-force oracle and the lax.scan path.
 
 Runs the kernels in the Pallas interpreter on the CPU mesh; real-TPU
-compilation of the same kernels is exercised by bench.py / the driver.
+compilation of the same kernels is exercised by chip_smoke.py on the chip.
 """
 
 import jax

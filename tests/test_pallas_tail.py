@@ -1,7 +1,6 @@
-"""Pallas sparse tails (the fused kernel of ISSUE 18, the rows sweep of
-ISSUE 30) vs their XLA oracles.
+"""The Pallas rows sweep (ISSUE 30) vs its XLA oracles.
 
-Runs the kernels in the Pallas interpreter on the CPU mesh (resolve
+Runs the kernel in the Pallas interpreter on the CPU mesh (resolve
 auto-detects the backend, so no per-test plumbing); the sweep's compile
 for the chip at the cell's shapes and its chip readings are PERF.md's.
 
@@ -17,13 +16,14 @@ Parity contract (acceptance criteria):
     interpreted kernel body is a different fusion), and the row
     accumulator's Σg² runs over sublanes in the kernel (``_assert_few_ulp``;
     on the chip the accumulator read bit-equal, PERF.md §6, PR 30);
-  * fused layout vs the scatter-add-built XLA fused tails — allclose
-    (summation order), and BITWISE vs the rows-classic program on the
-    unpacked logical arrays (the structural oracle);
-  * k_cap overflow takes the exact lax.cond fallback, remainder blocks
-    and K-step scans are exact, and the tiered / device-cache / streamed
-    drivers log identical losses end to end.
+  * remainder blocks and K-step scans are exact, and the tiered /
+    device-cache / streamed drivers log identical losses end to end when
+    ``optim.rows_tail_form`` says the sweep (patched: on the CPU it says
+    the rows).
 """
+
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -32,19 +32,11 @@ import pytest
 
 from fast_tffm_tpu.config import Config
 from fast_tffm_tpu.models import Batch, FMModel
-from fast_tffm_tpu.ops.packed_table import (
-    apply_fused_update,
-    pack_fused,
-    unpack_fused,
-)
-from fast_tffm_tpu.ops.pallas_tail import (
-    fused_tail_adagrad_update,
-    rows_tail_adagrad_update,
-)
+from fast_tffm_tpu.ops.pallas_tail import rows_tail_adagrad_update
 from fast_tffm_tpu.optim import AdagradState, sparse_adagrad_update
 from fast_tffm_tpu import trainer as tr
 
-V, D = 64, 7  # D+1 = 8 divides the 128-lane tile: p = 16 rows per tile row
+V, D = 64, 7
 
 
 def _operands(seed=0, m=40, v=V, d=D):
@@ -295,6 +287,19 @@ def test_the_blocks_gradient_is_the_summed_gradient():
     np.testing.assert_array_equal(np.asarray(ka)[np.asarray(uids[:n])], want)
 
 
+def _cell_shapes(config, shards=1):
+    """``rows_tail_form``'s arguments at a benchmark configuration's shapes
+    (``benchmark/configs/<config>.json``): rows (a chip's share of them),
+    ids a step, row width, accumulator columns."""
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs", config + ".json")
+    with open(path) as f:
+        c = json.load(f)
+    train = c["ini"]["Train"]
+    d = c.get("row_dim", 1 + c["factor_num"])
+    cols = 1 if train.get("adagrad_accumulator") == "row" else d
+    return c["vocabulary_size"] // shards, train["batch_size"] * train["max_nnz"], d, cols
+
+
 @pytest.mark.parametrize(
     "shapes, backend, form",
     [
@@ -302,87 +307,32 @@ def test_the_blocks_gradient_is_the_summed_gradient():
         ((2**20, 32768 * 39, 157, 157), "tpu", "rows"),  # ffm4_criteo: rows past one tile
         ((2**26, 1024 * 39, 9, 9), "tpu", "rows"),  # a small batch on the same table: 21 against 9
         ((2**26, 65536 * 39, 9, 9), "cpu", "rows"),  # no kernel interpreted inside a train step
+        # The benchmark's own configurations, read from their files: each
+        # side of the choice has a cell (PERF.md §4).
+        (("fm8_criteo",), "tpu", "sweep"),
+        (("ffm4_criteo",), "tpu", "rows"),
+        (("fm8_criteo_rowacc",), "tpu", "sweep"),  # the row accumulator: fewer bytes to sweep
+        (("fm16_criteo_row4", 4), "tpu", "sweep"),  # a chip's 2^25 rows of 17 under the global batch
     ],
-    ids=["fm8_on_tpu", "d157", "b1024", "cpu"],
+    ids=[
+        "fm8_on_tpu", "d157", "b1024", "cpu",
+        "cell_fm8_criteo", "cell_ffm4_criteo", "cell_fm8_criteo_rowacc", "cell_fm16_criteo_row4",
+    ],
 )
 def test_auto_chooses_the_form_from_shapes_and_backend(shapes, backend, form):
     from fast_tffm_tpu.optim import rows_tail_form
 
+    if isinstance(shapes[0], str):
+        shapes = _cell_shapes(*shapes)
     assert rows_tail_form(*shapes, backend=backend) == form
     if backend == "cpu":  # what this suite's train steps get when nobody says
         assert rows_tail_form(*shapes) == "rows"
 
 
-def test_fused_tail_bit_identical_to_rows_classic():
-    ids, g, table, accum_row, _ = _operands(4)
-    fused = pack_fused(table, accum_row, 0.1)
-    rt, rs = _classic(table, accum_row, ids, g, 0.13)
-    f2 = jax.jit(
-        lambda f: fused_tail_adagrad_update(f, ids, g, 0.13)
-    )(fused)
-    tu, au = unpack_fused(f2, V, D)
-    assert jnp.all(tu == rt) and jnp.all(au == rs.accum)
-    # Untouched logical rows (and pad slots) preserved bitwise in the
-    # fused array itself.
-    f3 = jnp.asarray(f2)
-    touched_phys = np.unique(np.asarray(ids) // (128 // (D + 1)))
-    mask = np.ones(fused.shape[0], bool)
-    mask[touched_phys] = False
-    np.testing.assert_array_equal(
-        np.asarray(f3)[mask], np.asarray(fused)[mask]
-    )
-
-
-@pytest.mark.parametrize("mode", ["dense", "compact"])
-def test_fused_tail_allclose_to_xla_fused(mode):
-    ids, g, table, accum_row, _ = _operands(5)
-    fused = pack_fused(table, accum_row, 0.1)
-    ref = jax.jit(
-        lambda f: apply_fused_update(f, ids, g, 0.13, mode, 0)
-    )(fused)
-    got = jax.jit(
-        lambda f: fused_tail_adagrad_update(f, ids, g, 0.13)
-    )(fused)
-    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)
-
-
-@pytest.mark.parametrize("k_cap", [4, 1000])
-def test_fused_k_cap_edge(k_cap):
-    # k_cap=4 < unique-row count forces the exact lax.cond full-span
-    # fallback; k_cap=1000 > M is a no-op cap.  Both stay exact.
-    ids, g, table, accum_row, _ = _operands(6)
-    fused = pack_fused(table, accum_row, 0.1)
-    rt, rs = _classic(table, accum_row, ids, g, 0.13)
-    f2 = jax.jit(
-        lambda f: fused_tail_adagrad_update(f, ids, g, 0.13, k_cap=k_cap)
-    )(fused)
-    tu, au = unpack_fused(f2, V, D)
-    if k_cap >= ids.shape[0]:
-        assert jnp.all(tu == rt) and jnp.all(au == rs.accum)
-    else:
-        # Inside the lax.cond fallback the row accumulator's Σg² over the
-        # classic dedup's sums (since PR 27 a slice of 128-lane rows) is
-        # associated differently from the classic program's own: 1-2 ULP
-        # of the accumulator, and the table follows (module docstring).
-        np.testing.assert_allclose(
-            au, rs.accum, rtol=4 * float(np.finfo(np.float32).eps)
-        )
-        _assert_few_ulp(tu, rt)
-
-
 def test_remainder_tail_small_blocks():
-    # block_rows=8 over 40 occurrences: multiple grid blocks plus a
-    # partially-valid remainder block (predicated DMA rows).  The fused
-    # half is the known red test (ROADMAP D2).
-    ids, g, table, accum_row, _ = _operands(7)
-    fused = pack_fused(table, accum_row, 0.1)
-    rt, rs = _classic(table, accum_row, ids, g, 0.13)
-    f2 = jax.jit(
-        lambda f: fused_tail_adagrad_update(f, ids, g, 0.13, block_rows=8)
-    )(fused)
-    tu, au = unpack_fused(f2, V, D)
-    assert jnp.all(tu == rt) and jnp.all(au == rs.accum)
     # The rows sweep's remainder: a block of 128 lanes over 64 rows.
+    ids, g, table, accum_row, _ = _operands(7)
+    rt, rs = _classic(table, accum_row, ids, g, 0.13)
     t2, a2 = _kernel(table, accum_row, ids, g, 0.13, block_lanes=128)
     _assert_few_ulp(t2, rt)
     _assert_accum_few_ulp(a2, rs.accum)
@@ -409,40 +359,35 @@ def _batches(n=3, B=16, N=6, v=100, seed=1):
     return out
 
 
-def test_train_step_pallas_body_bit_identical():
+def test_train_step_pallas_body_bit_identical(request):
     model = FMModel(vocabulary_size=100, factor_num=4, order=2)
-    s0 = tr.init_state(model, jax.random.key(0), 0.1, "element")
-    s1 = tr.init_state(model, jax.random.key(0), 0.1, "element")
-    step_x = tr.make_train_step(model, 0.05)
-    step_p = tr.make_train_step(model, 0.05, body=tr.make_pallas_tail_body())
-    for i, b in enumerate(_batches()):
-        s0, l0 = step_x(s0, b)
-        s1, l1 = step_p(s1, b)
-        # The first loss sees the same table; later ones a table a few ULP
-        # apart (module docstring), and the gap compounds over the steps.
-        np.testing.assert_allclose(l1, l0, rtol=0 if i == 0 else 1e-6)
+    batches = _batches()
+
+    def run():
+        state, losses = tr.init_state(model, jax.random.key(0), 0.1, "element"), []
+        step = tr.make_train_step(model, 0.05)
+        for b in batches:
+            state, loss = step(state, b)
+            losses.append(loss)
+        return state, losses
+
+    s0, l0 = run()  # the rows: what the rule says on the CPU
+    asked = request.getfixturevalue("sweep_form")  # from here on
+    s1, l1 = run()
+    assert asked == [(100, 16 * 6, 5, 5)]
+    # The first loss sees the same table; later ones a table a few ULP
+    # apart (module docstring), and the gap compounds over the steps.
+    assert l1[0] == l0[0]
+    np.testing.assert_allclose(l1, l0, rtol=1e-6)
     _assert_few_ulp(s1.table, s0.table, ulps=16)
     np.testing.assert_allclose(s1.table_opt.accum, s0.table_opt.accum, rtol=1e-6)
 
 
-def test_packed_fused_step_tail_pallas():
-    model = FMModel(vocabulary_size=100, factor_num=4, order=2)
-    s0 = tr.init_packed_state(model, jax.random.key(0), 0.1, "fused")
-    s1 = tr.init_packed_state(model, jax.random.key(0), 0.1, "fused")
-    step_x = tr.make_packed_train_step(model, 0.05, "auto")
-    step_p = tr.make_packed_train_step(model, 0.05, tail="pallas")
-    for b in _batches():
-        s0, l0 = step_x(s0, b)
-        s1, l1 = step_p(s1, b)
-        np.testing.assert_allclose(l1, l0, rtol=1e-6)
-    np.testing.assert_allclose(s1.table, s0.table, rtol=1e-5, atol=1e-6)
-
-
-def test_scanned_pallas_body_matches_sequential():
+def test_scanned_pallas_body_matches_sequential(sweep_form):
     model = FMModel(vocabulary_size=100, factor_num=4, order=2)
     batches = _batches()
     s1 = tr.init_state(model, jax.random.key(0), 0.1, "element")
-    step_p = tr.make_train_step(model, 0.05, body=tr.make_pallas_tail_body())
+    step_p = tr.make_train_step(model, 0.05)
     for b in batches:
         s1, _ = step_p(s1, b)
     stack = lambda f: jnp.stack([getattr(b, f) for b in batches])
@@ -451,10 +396,9 @@ def test_scanned_pallas_body_matches_sequential():
         fields=stack("fields"), weights=stack("weights"),
     )
     s4 = tr.init_state(model, jax.random.key(0), 0.1, "element")
-    scan_p = tr.make_scanned_train_step(
-        model, 0.05, body=tr.make_pallas_tail_body()
-    )
+    scan_p = tr.make_scanned_train_step(model, 0.05)
     s4, _losses = scan_p(s4, sb)
+    assert sweep_form == [(100, 16 * 6, 5, 5)] * 2  # both steps traced as the sweep
     assert jnp.all(s4.table == s1.table)
     assert jnp.all(s4.table_opt.accum == s1.table_opt.accum)
 
@@ -502,48 +446,24 @@ def _run(cfg):
     return state, logs
 
 
-def test_drivers_pallas_tail_bit_identical(tmp_path):
-    """Streamed, device-cached, and tiered drivers under tail=pallas all
-    log the XLA tail's loss sequence bit for bit (rows layout, γ=1)."""
+@pytest.mark.parametrize(
+    "driver, kw",
+    [
+        ("streamed", {}),
+        ("device_cache", dict(device_cache=True, binary_cache=True)),
+        ("tiered", dict(paramstore=True, paramstore_hot_rows=48)),
+    ],
+)
+def test_drivers_pallas_tail_bit_identical(tmp_path, request, driver, kw):
+    """Each driver (streamed, device-cached, tiered), when ``rows_tail_form``
+    says the sweep, logs the loss sequence the streamed driver logs with the
+    XLA rows, bit for bit (rows layout, γ=1): the only tier-1 run of the
+    sweep THROUGH the drivers."""
     _write_dataset(str(tmp_path / "train.libsvm"))
-    _s, xla_logs = _run(_cfg(tmp_path, "xla", tail="xla"))
-    _s, pal_logs = _run(_cfg(tmp_path, "pallas", tail="pallas"))
-    assert _losses(xla_logs) == _losses(pal_logs)
+    _s, xla_logs = _run(_cfg(tmp_path, "xla"))
     assert any(l.startswith("sparse tail: xla rows (") for l in xla_logs)
-    assert any(l.startswith("sparse tail: pallas rows sweep (block 256 lanes, 1 blocks") for l in pal_logs)
-    _s, cache_logs = _run(
-        _cfg(tmp_path, "cache", tail="pallas", device_cache=True,
-             binary_cache=True)
-    )
-    assert _losses(xla_logs) == _losses(cache_logs)
-    _s, tier_logs = _run(
-        _cfg(tmp_path, "tier", tail="pallas", paramstore=True,
-             paramstore_hot_rows=48)
-    )
-    assert _losses(xla_logs) == _losses(tier_logs)
-
-
-# -- config surface -------------------------------------------------------
-
-
-def test_config_tail_validation(tmp_path):
-    _write_dataset(str(tmp_path / "train.libsvm"))
-    with pytest.raises(ValueError, match="unknown tail"):
-        _cfg(tmp_path, "bad", tail="fast")
-    with pytest.raises(ValueError, match="adagrad_accumulator = fused"):
-        _cfg(tmp_path, "bad", tail="pallas", table_layout="packed")
-    with pytest.raises(ValueError, match="dedup_gather_rows"):
-        _cfg(tmp_path, "bad", tail="pallas", dedup_gather_rows=64)
-    # auto + packed element layout is fine: auto falls back to xla there.
-    _cfg(tmp_path, "ok", tail="auto", table_layout="packed")
-    _cfg(tmp_path, "ok2", tail="pallas", table_layout="packed",
-         adagrad_accumulator="fused")
-
-
-def test_dist_train_rejects_explicit_pallas(tmp_path):
-    from fast_tffm_tpu.training import dist_train
-
-    _write_dataset(str(tmp_path / "train.libsvm"))
-    cfg = _cfg(tmp_path, "dist", tail="pallas")
-    with pytest.raises(ValueError, match="dist_train"):
-        dist_train(cfg)
+    asked = request.getfixturevalue("sweep_form")  # from here on
+    _s, pal_logs = _run(_cfg(tmp_path, driver, **kw))
+    assert asked and _losses(xla_logs) == _losses(pal_logs)
+    if driver != "tiered":  # the tiered driver says nothing of its compact tier's tail
+        assert any(l.startswith("sparse tail: pallas rows sweep (block 256 lanes, 1 blocks") for l in pal_logs)

@@ -1,8 +1,8 @@
 """Soak-harness tests (ISSUE 11): the ~30 s miniature soak runs inside
 tier-1 — trainer tail-following a live writer, continuous delta publish,
 a loaded replica fleet applying the chain, one trainer kill + one stream
-stall, every sentinel enforced.  The full multi-minute soak (the
-committed PROBE_SOAK artifact) is slow-marked."""
+stall, every sentinel enforced.  The full multi-minute soak is
+slow-marked."""
 
 import json
 import os

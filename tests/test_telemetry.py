@@ -521,112 +521,6 @@ def test_report_compare_gates_throughput_regression(tmp_path):
     assert s2["throughput_median"] == 700.0  # the later (slow) run only
 
 
-def test_write_bench_report(tmp_path):
-    report = _load_report_module()
-    (tmp_path / "BENCH_r05.json").write_text(
-        json.dumps({"value": 1000.0, "scale_value": 50.0, "metric": "x"})
-    )
-    out = report.write_bench_report(
-        {"value": 1200.0, "scale_value": 40.0, "new_key": 1.0, "metric": "x"},
-        str(tmp_path),
-    )
-    assert out and out.endswith("REPORT_r06.md")
-    text = open(out).read()
-    assert "+20.0%" in text and "-20.0%" in text and "new_key" in text
-    # no prior round -> no report
-    assert report.write_bench_report({"value": 1.0}, str(tmp_path / "empty")) is None
-
-
-def test_report_bench_tail_section_and_gate(tmp_path):
-    """--bench renders the Sparse-tail A/B section; --strict with
-    --bench-base gates per-mode tail throughput, bytes/example, and a
-    measured mode going dark.  Both artifact shapes load: the raw
-    bench.py result and the CI wrapper that keeps only a stdout tail."""
-    report = _load_report_module()
-
-    def art(path, pallas_value, pallas_bpe, wrap=False, skipped=False):
-        modes = {
-            "xla": {
-                "value": 170000.0,
-                "measured_bytes_per_example": 320.0,
-                "modeled_bytes_per_example": 319.0,
-            }
-        }
-        if skipped:
-            modes["pallas"] = {
-                "skipped": "no TPU backend (kernel would interpret)",
-                "modeled_bytes_per_example": 101.0,
-            }
-        else:
-            modes["pallas"] = {
-                "value": pallas_value,
-                "measured_bytes_per_example": pallas_bpe,
-                "modeled_bytes_per_example": 101.0,
-            }
-        result = {
-            "value": 1.0,
-            "scale_vocab_rows": 201326592,
-            "tail_ab": {"batch": 16384, "modes": modes},
-        }
-        payload = (
-            {
-                "cmd": "python bench.py",
-                "rc": 0,
-                "parsed": None,
-                "tail": "warmup noise\n" + json.dumps(result) + "\n",
-            }
-            if wrap
-            else result
-        )
-        path.write_text(json.dumps(payload))
-        return str(path)
-
-    base = art(tmp_path / "BENCH_r17.json", 500000.0, 100.0)
-    good = art(tmp_path / "BENCH_r18.json", 480000.0, 102.0, wrap=True)
-    slow = art(tmp_path / "BENCH_r18s.json", 300000.0, 100.0)
-    dark = art(tmp_path / "BENCH_r18d.json", 0.0, 0.0, skipped=True)
-
-    run_b = report.load_bench_train(good)  # wrapper unwraps from stdout tail
-    base_b = report.load_bench_train(base)
-    assert run_b["tail_ab"]["batch"] == 16384
-    text = report.render_bench_tail(run_b, base_b)
-    assert "Sparse-tail A/B" in text and "| pallas |" in text
-    assert report.compare_bench_tail(run_b, base_b, 0.15) == []
-    regs = report.compare_bench_tail(report.load_bench_train(slow), base_b, 0.15)
-    assert any("throughput regressed" in r for r in regs)
-    regs = report.compare_bench_tail(report.load_bench_train(dark), base_b, 0.15)
-    assert any("went dark" in r for r in regs)
-    # bytes/example creep past the threshold gates even at equal ex/s
-    fat = art(tmp_path / "BENCH_r18f.json", 500000.0, 130.0)
-    regs = report.compare_bench_tail(report.load_bench_train(fat), base_b, 0.15)
-    assert any("bytes/example regressed" in r for r in regs)
-
-    mon = RunMonitor(str(tmp_path / "run.jsonl"), run_id=new_run_id())
-    for i in range(1, 4):
-        mon.emit(
-            "train", step=i * 4, epoch=0, loss=0.7,
-            examples_per_sec=1000.0, examples_per_sec_per_chip=1000.0,
-        )
-    mon.close()
-    tool = os.path.join(REPO, "tools", "report.py")
-
-    def run(*args):
-        return subprocess.run(
-            [sys.executable, tool, str(tmp_path / "run.jsonl"), *args],
-            capture_output=True,
-            text=True,
-        )
-
-    r = run("--bench", good)
-    assert r.returncode == 0, r.stderr
-    assert "Sparse-tail A/B" in r.stdout
-    assert run("--bench", good, "--bench-base", base, "--strict").returncode == 0
-    r = run("--bench", slow, "--bench-base", base, "--strict")
-    assert r.returncode == 1 and "SPARSE-TAIL BENCH REGRESSED" in r.stdout
-    # half a flag pair is a usage error, not a silent pass
-    assert run("--bench-base", base).returncode == 2
-
-
 # -- throughput meter (satellite) ----------------------------------------
 
 def test_throughput_sliding_window():

@@ -11,7 +11,7 @@ all orderings around this idiom; this checker pins it statically:
   * **direct-write** — ``open(p, "w"/"wb")`` straight onto a published
     name.  "Published" is judged three ways: the expression is a known
     published-artifact spelling (``out_path`` / ``args.out`` /
-    ``model_file`` — the committed BENCH_*/PROBE_* writers and the
+    ``model_file`` — the tools' JSON artifact writers and the
     checkpoint path); its constant fragments end in ``.npz``/``.json``;
     or the same module ``os.replace``s onto that exact attribute chain
     somewhere (``self._path``).  Exempt: append modes (JSONL logs are

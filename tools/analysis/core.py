@@ -244,7 +244,7 @@ def discover(root: str) -> list[str]:
                     rels.append(
                         os.path.relpath(os.path.join(dirpath, fn), root)
                     )
-    for fn in ("bench.py", "bench_all.py", "chip_smoke.py", "fast_tffm.py"):
+    for fn in ("chip_smoke.py", "fast_tffm.py"):
         if os.path.isfile(os.path.join(root, fn)):
             rels.append(fn)
     return rels

@@ -2,12 +2,12 @@
 """Serving load generator: drive an engine or a socket front end, emit
 BENCH_SERVE JSON.
 
-The serving analog of bench.py's train BENCH files: one JSON object with
+One JSON object with
 client-observed latency percentiles (p50/p95/p99, overall AND per client
 class), achieved QPS, typed-shed counts (overloaded / deadline /
 unavailable), the engine's own queue/compute/occupancy metrics, and the
 compile counts that pin "zero steady-state recompiles" — so future PRs
-can track a serving trajectory the way BENCH_r*.json tracks training.
+can track a serving trajectory.
 
 Two transports:
 

@@ -17,7 +17,7 @@ planted-model synthetic data whose generating process matches the family:
 Each row reports the best validation AUC from the driver's JSONL metrics
 next to the ORACLE ceiling (the planted model scoring the same held-out
 rows — the best ANY learner can do on Bernoulli(sigmoid(score)) labels).
-Writes QUALITY_ZOO_r05.json; bench_all.py folds the rows into BENCH_ALL.
+Writes QUALITY_ZOO_r05.json.
 
 Usage: python tools/quality_zoo.py [--rows 1200000] [--epochs 6] [--quick]
 """

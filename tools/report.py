@@ -19,18 +19,13 @@ stalls, or anomalies.  Exit 2 = unusable input.
 
 Stdlib-only on purpose: the report must render on a machine that can't
 even import jax (e.g. triaging a stall dump from a wedged TPU host).
-
-bench.py also imports ``write_bench_report`` to drop a REPORT_rNN.md
-next to each BENCH_rNN.json (delta table vs the previous round).
 """
 
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
-import re
 import statistics
 import sys
 
@@ -1274,126 +1269,6 @@ def compare_bench_serve(run_b: dict, base_b: dict, threshold: float) -> list[str
     return regressions
 
 
-# -- training bench (bench.py artifacts) ----------------------------------
-
-
-def load_bench_train(path: str) -> dict:
-    """A ``python bench.py`` result (BENCH_rNN.json): either the raw
-    result dict bench prints, or the CI wrapper ``{cmd, rc, parsed,
-    tail}`` that captures it (``parsed`` when the JSON line survived,
-    else re-parsed from the stdout ``tail``).  Raises ValueError when no
-    bench result can be recovered."""
-    with open(path) as f:
-        data = json.load(f)
-    if isinstance(data, dict) and "cmd" in data and "tail" in data:
-        parsed = data.get("parsed")
-        if not isinstance(parsed, dict):
-            # Wrapper kept only a stdout tail; the result is the last
-            # line that parses as a JSON object (bench prints it last).
-            parsed = None
-            for line in reversed((data.get("tail") or "").splitlines()):
-                line = line.strip()
-                if line.startswith("{") and line.endswith("}"):
-                    try:
-                        parsed = json.loads(line)
-                    except json.JSONDecodeError:
-                        continue
-                    break
-        if not isinstance(parsed, dict):
-            raise ValueError(
-                f"{path}: bench wrapper holds no parseable result line "
-                "(stdout tail truncated mid-JSON?)"
-            )
-        data = parsed
-    if not isinstance(data, dict) or "value" not in data:
-        raise ValueError(f"{path}: not a bench.py result artifact")
-    return data
-
-
-def render_bench_tail(b: dict, base: dict | None = None) -> str:
-    """The "Sparse-tail A/B" section: XLA vs Pallas one-pass tail at the
-    scale rung — examples/sec and HBM bytes/example, measured (XLA cost
-    model of the compiled step) against modeled (the hand roofline), per
-    mode.  With ``base``, prior-round numbers ride alongside."""
-    L = ["## Sparse-tail A/B (XLA vs Pallas)", ""]
-    ab = b.get("tail_ab")
-    if not isinstance(ab, dict):
-        L.append(
-            "_no `tail_ab` key in this bench artifact (pre-tail-A/B round)_"
-        )
-        L.append("")
-        return "\n".join(L)
-    batch = ab.get("batch")
-    if batch:
-        L.append(f"Batch {_fmt(batch)}, scale rung vocab "
-                 f"{_fmt(b.get('scale_vocab_rows'))} rows.")
-        L.append("")
-    base_modes = ((base or {}).get("tail_ab") or {}).get("modes") or {}
-    L += [
-        "| tail | ex/s | bytes/ex (measured) | bytes/ex (modeled) | note |",
-        "|---|---:|---:|---:|---|",
-    ]
-    for mode, e in sorted((ab.get("modes") or {}).items()):
-        note = e.get("skipped") or e.get("error") or ""
-        if e.get("b65536_value"):
-            note = f"B=65536: {_fmt(e['b65536_value'])} ex/s"
-        elif e.get("b65536_error"):
-            note = f"B=65536 failed: {str(e['b65536_error'])[:60]}"
-        bm = base_modes.get(mode) or {}
-        if bm.get("value") is not None:
-            note = (note + "; " if note else "") + f"base {_fmt(bm['value'])} ex/s"
-        L.append(
-            f"| {mode} | {_fmt(e.get('value'))} | "
-            f"{_fmt(e.get('measured_bytes_per_example'))} | "
-            f"{_fmt(e.get('modeled_bytes_per_example'))} | {note} |"
-        )
-    L.append("")
-    return "\n".join(L)
-
-
-def compare_bench_tail(run_b: dict, base_b: dict, threshold: float) -> list[str]:
-    """Strict-gate regressions between two bench artifacts' tail A/B:
-    per-mode tail throughput down past the threshold, measured
-    bytes/example up past it, and a mode the base measured going dark
-    (skipped or errored) in the run."""
-    regressions = []
-    run_modes = (run_b.get("tail_ab") or {}).get("modes") or {}
-    base_modes = (base_b.get("tail_ab") or {}).get("modes") or {}
-    for mode, bm in sorted(base_modes.items()):
-        bv = bm.get("value")
-        if not isinstance(bv, (int, float)) or bv <= 0:
-            continue
-        rm = run_modes.get(mode) or {}
-        rv = rm.get("value")
-        if not isinstance(rv, (int, float)):
-            why = rm.get("skipped") or rm.get("error") or "mode absent from run"
-            regressions.append(
-                f"{mode} tail went dark (base {bv} ex/s): {why}"
-            )
-            continue
-        if rv < bv * (1 - threshold):
-            regressions.append(
-                f"{mode} tail throughput regressed "
-                f"{(bv - rv) / bv * 100:.1f}% (> {threshold * 100:.0f}%): "
-                f"{bv} -> {rv} ex/s"
-            )
-        rb, bb = rm.get("measured_bytes_per_example"), bm.get(
-            "measured_bytes_per_example"
-        )
-        if (
-            isinstance(rb, (int, float))
-            and isinstance(bb, (int, float))
-            and bb > 0
-            and rb > bb * (1 + threshold)
-        ):
-            regressions.append(
-                f"{mode} tail measured bytes/example regressed "
-                f"{(rb - bb) / bb * 100:.1f}% (> {threshold * 100:.0f}%): "
-                f"{bb} -> {rb}"
-            )
-    return regressions
-
-
 # -- static analysis ------------------------------------------------------
 
 
@@ -1532,61 +1407,6 @@ def compare_analysis(run_a: dict, base_a: dict) -> list[str]:
     return regressions
 
 
-# -- bench wiring ---------------------------------------------------------
-
-
-def write_bench_report(result: dict, root: str, prefix: str = "BENCH_r") -> str | None:
-    """Delta table for one bench result vs the previous committed round:
-    finds the highest-numbered ``BENCH_rNN.json`` under ``root``, compares
-    every shared numeric key, and writes ``REPORT_rMM.md`` (MM = NN + 1,
-    the round this result will be committed as) next to it.  Returns the
-    report path, or None when there is no previous round to compare."""
-    rounds = []
-    for p in glob.glob(os.path.join(root, prefix + "*.json")):
-        m = re.search(r"_r(\d+)\.json$", p)
-        if m:
-            rounds.append((int(m.group(1)), p))
-    if not rounds:
-        return None
-    prev_n, prev_path = max(rounds)
-    try:
-        with open(prev_path) as f:
-            prev = json.load(f)
-    except (OSError, json.JSONDecodeError):
-        return None
-    L = [
-        f"# Bench report — round r{prev_n + 1:02d} vs r{prev_n:02d}",
-        "",
-        f"Baseline file: `{os.path.basename(prev_path)}`.  Positive delta =",
-        "this run is higher; whether that is good depends on the key",
-        "(examples/sec up = good, *_error present = bad).",
-        "",
-        "| key | " + f"r{prev_n:02d} | new | delta |",
-        "|---|---:|---:|---:|",
-    ]
-    keys = [
-        k
-        for k in result
-        if isinstance(result.get(k), (int, float))
-        and isinstance(prev.get(k), (int, float))
-    ]
-    for k in sorted(keys):
-        a, b = result[k], prev[k]
-        delta = f"{(a - b) / abs(b) * 100:+.1f}%" if b else f"{a - b:+g}"
-        L.append(f"| {k} | {_fmt(b)} | {_fmt(a)} | {delta} |")
-    only_new = sorted(set(result) - set(prev))
-    only_old = sorted(set(prev) - set(result))
-    if only_new:
-        L += ["", "New keys: " + ", ".join(f"`{k}`" for k in only_new)]
-    if only_old:
-        L += ["", "Dropped keys: " + ", ".join(f"`{k}`" for k in only_old)]
-    L.append("")
-    out = os.path.join(root, f"REPORT_r{prev_n + 1:02d}.md")
-    with open(out, "w") as f:
-        f.write("\n".join(L))
-    return out
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="report",
@@ -1646,20 +1466,6 @@ def main(argv=None) -> int:
         metavar="JSON",
         help="baseline round's serving bench artifact for the QPS/p99 gate",
     )
-    ap.add_argument(
-        "--bench",
-        metavar="JSON",
-        help="training bench artifact (python bench.py output or the CI "
-        "wrapper, BENCH_rNN.json): render a Sparse-tail A/B section "
-        "(XLA vs Pallas tail, ex/s + bytes/example measured vs "
-        "modeled); with --strict and --bench-base, gate on per-mode "
-        "tail-throughput and bytes/example regressions past --threshold",
-    )
-    ap.add_argument(
-        "--bench-base",
-        metavar="JSON",
-        help="baseline round's training bench artifact for the tail gate",
-    )
     args = ap.parse_args(argv)
 
     def _load_many(paths):
@@ -1692,13 +1498,6 @@ def main(argv=None) -> int:
             file=sys.stderr,
         )
         return 2
-    if args.bench_base and not args.bench:
-        print(
-            "report: --bench-base requires --bench (the run's own bench "
-            "artifact) — tail gate would be silently skipped",
-            file=sys.stderr,
-        )
-        return 2
     bench_run = bench_base = None
     if args.bench_serve:
         try:
@@ -1709,16 +1508,6 @@ def main(argv=None) -> int:
             print(f"report: {e}", file=sys.stderr)
             return 2
         text = text + "\n" + render_bench_serve(bench_run, bench_base)
-    train_run = train_base = None
-    if args.bench:
-        try:
-            train_run = load_bench_train(args.bench)
-            if args.bench_base:
-                train_base = load_bench_train(args.bench_base)
-        except (OSError, ValueError, json.JSONDecodeError) as e:
-            print(f"report: {e}", file=sys.stderr)
-            return 2
-        text = text + "\n" + render_bench_tail(train_run, train_base)
     run_analysis = base_analysis = None
     if args.analysis:
         try:
@@ -1774,24 +1563,6 @@ def main(argv=None) -> int:
             if extra:
                 text += (
                     "\n**SERVING BENCH REGRESSED:**\n"
-                    + "\n".join(f"- {r}" for r in extra)
-                    + "\n"
-                )
-                rc = 1
-    # Same contract for the training-bench tail gate: --strict alone,
-    # no --compare needed (only the BENCH_r artifacts persist in CI).
-    if args.strict and train_run is not None:
-        if train_base is None:
-            print(
-                "report: note: --bench given without --bench-base — "
-                "sparse-tail gate skipped",
-                file=sys.stderr,
-            )
-        else:
-            extra = compare_bench_tail(train_run, train_base, args.threshold)
-            if extra:
-                text += (
-                    "\n**SPARSE-TAIL BENCH REGRESSED:**\n"
                     + "\n".join(f"- {r}" for r in extra)
                     + "\n"
                 )
