@@ -60,10 +60,12 @@ SERVE_ATOL = 2e-6
 # TPU v5 lite at B=16384 N=11 k=8: value identical, gradient 2.7e-7 of its
 # scale.  Relative to the largest magnitude, not elementwise.
 KERNEL_RTOL = 1e-5
-# The rows sweep against the XLA row operations on the same deduped
-# gradients: table and accumulator came out bit-equal at fm8_criteo's size
-# on a TPU v5 lite (PERF.md §6, PR 30); on the CPU the interpreted kernel
-# is another fusion, a few ULP off.  Steps here are about 1e-3, accumulators 0.1.
+# The rows sweep against the XLA row operations on the same batch: where no
+# id repeats table and accumulator came out bit-equal at fm8_criteo's size on
+# a TPU v5 lite (PERF.md §6, PR 30 and 32); where ids repeat the sweep adds a
+# row's occurrences in another order than the rows' segment sum, a few ULP
+# of the sum; on the CPU the interpreted kernel is another fusion besides, a
+# few ULP off.  Steps here are about 1e-3, accumulators 0.1 (one ULP 7.5e-9).
 ROWS_SWEEP_ATOL = 1e-7
 # Sharded first-step loss against the one-chip step on the same batch:
 # the bound __graft_entry__.py asserts on the CPU mesh.
@@ -541,12 +543,13 @@ class Smoke:
             raise SmokeFailure("kernels", f"the rows sweep did not run: {rows['refused']}")
         if on_chip and not rows["compiled"]:
             raise SmokeFailure("kernels", "the rows sweep ran interpreted on the chip")
-        if not rows["max_abs_diff"] <= ROWS_SWEEP_ATOL:
+        if not max(rows["max_abs_diff"], rows["max_abs_diff_repeats"]) <= ROWS_SWEEP_ATOL:
             raise SmokeFailure("kernels", f"the rows sweep disagrees with the XLA row operations: {rows}")
         self.echo(
             f"chip_smoke: kernels rows_tail: {'compiled' if rows['compiled'] else 'interpreted'}"
-            f"+matched (max_abs_diff={rows['max_abs_diff']:.2g}); what the rows layout "
-            "takes where optim.rows_tail_form says so"
+            f"+matched (max_abs_diff={rows['max_abs_diff']:.2g} on a batch without repeats, "
+            f"{rows['max_abs_diff_repeats']:.2g} with {rows['repeats']} repeated ids of 512, "
+            f"atol={ROWS_SWEEP_ATOL}); what the rows layout takes where optim.rows_tail_form says so"
         )
         self.echo(
             f"chip_smoke: kernels ok platform={res['platform']} "
@@ -692,9 +695,16 @@ def kernels_child(b: int, n: int, k: int) -> None:
 
     # The rows sweep (PR 30) at BASELINE #1's row width (D = 9 lanes; few
     # rows): it compiles on a TPU and must match the XLA row operations.  On
-    # the CPU test mesh it interprets.
+    # the CPU test mesh it interprets.  Two batches: one in which no id
+    # repeats (the two forms then add nothing in different orders: bit-equal
+    # on the chip) and one with the benchmark generator's heavy tail (PR 32:
+    # the sweep sums a row's occurrences in its own contraction, the rows by
+    # a segment sum, so the float32 sums may differ in the last digits).
     v, m = 4096, 512
-    ids = jnp.asarray(rng.integers(0, v, (m,)), jnp.int32)
+    batches = {
+        "max_abs_diff": rng.permutation(v)[:m],
+        "max_abs_diff_repeats": (v * rng.random(m) ** 2.5).astype(np.int64),
+    }
 
     def attempt(program, *args):
         try:
@@ -709,19 +719,23 @@ def kernels_child(b: int, n: int, k: int) -> None:
     from fast_tffm_tpu.optim import AdagradState, sparse_adagrad_update
 
     tail = {  # the same update in its two forms
-        "sweep": jax.jit(lambda t, a: rows_tail_adagrad_update(t, a, ids, g9, 0.05)),
+        "sweep": jax.jit(lambda t, a, ids: rows_tail_adagrad_update(t, a, ids, g9, 0.05)),
         "rows": jax.jit(
-            lambda t, a: sparse_adagrad_update(t, AdagradState(a), ids, g9, 0.05, form="rows")
+            lambda t, a, ids: sparse_adagrad_update(t, AdagradState(a), ids, g9, 0.05, form="rows")
         ),
     }
-    out["rows_tail"] = attempt(tail["sweep"], t9, a9)
+    first = jnp.asarray(batches["max_abs_diff"], jnp.int32)
+    out["rows_tail"] = attempt(tail["sweep"], t9, a9, first)
     if not out["rows_tail"]["refused"]:
-        want_t, want_s = tail["rows"](t9, a9)
-        got_t, got_a = tail["sweep"](t9, a9)
-        out["rows_tail"]["compiled"] = "tpu_custom_call" in tail["sweep"].lower(t9, a9).as_text()
-        out["rows_tail"]["max_abs_diff"] = float(
-            jnp.maximum(jnp.max(jnp.abs(got_t - want_t)), jnp.max(jnp.abs(got_a - want_s.accum)))
-        )
+        out["rows_tail"]["compiled"] = "tpu_custom_call" in tail["sweep"].lower(t9, a9, first).as_text()
+        for key, ids in batches.items():
+            ids = jnp.asarray(ids, jnp.int32)
+            want_t, want_s = tail["rows"](t9, a9, ids)
+            got_t, got_a = tail["sweep"](t9, a9, ids)
+            out["rows_tail"][key] = float(
+                jnp.maximum(jnp.max(jnp.abs(got_t - want_t)), jnp.max(jnp.abs(got_a - want_s.accum)))
+            )
+        out["rows_tail"]["repeats"] = m - int(np.unique(batches["max_abs_diff_repeats"]).size)
     print("SMOKE_KERNELS " + json.dumps(out), flush=True)
 
 

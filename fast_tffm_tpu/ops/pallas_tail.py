@@ -1,12 +1,14 @@
 """The Pallas rows sweep: the Adagrad update of the touched rows as one
 in-place kernel pass.
 
-The XLA sparse tail is a CHAIN of programs — dedup (sort, segment sum), an
-accumulator gather, two table-shaped scatters — each of which walks its own
-descriptor stream over the same touched rows.  The kernel here takes the
-tail's place after the SAME dedup (optim.dedup_rows — the sort/segment-sum
-pipeline the rows-layout classic update uses, so the summed gradients are
-bit-identical to it).
+The XLA sparse tail is a CHAIN of programs — dedup (sort, permutation,
+segment sum, a second sort), an accumulator gather, two table-shaped
+scatters — each of which walks its own descriptor stream over the same
+touched rows.  The kernel here takes the place of everything after the
+FIRST sort (optim.sort_ids, which both forms share): it is handed the
+batch's occurrences in id order (optim.occurrences_by_id), duplicates and
+all, and sums a row's occurrences itself (PR 32; until then it ran after
+optim.dedup_rows' segment sum and saw every row once).
 
 ``rows_tail_adagrad_update`` / ``sweep_adagrad_update`` — the **rows sweep**
 (PR 30) — serve a plain ``[V, D]`` table with a separate ``[V, D]``
@@ -18,19 +20,26 @@ which IS the row-major layout of its transpose, so the kernel takes
 ``table.T`` / ``accum.T`` (bitcasts in the compiled step), walks them block
 by block IN PLACE (``input_output_aliases``) and writes whole tile columns.
 The batch's dense delta never exists in HBM: a work list computed from the
-sorted unique ids (scalar prefetch) pairs every block with the 256-id
-chunks that fall in it, and the kernel builds the block's gradient in VMEM
-as a one-hot of the ids against the row index, contracted with the
-gradients on the MXU — exact in float32, because every output has one
-non-zero term and the gradient goes in as three bfloat16 parts that sum
-back to it bit for bit; a row of ones in the gradients returns the hit
-mask.  Adagrad is then the classic expressions on the block, SELECTED by
-the hit mask: an untouched row comes out bit for bit whatever its
-accumulator holds (0 included), and a lazily decayed accumulator decays
-only where touched.  Blocks no id falls in are not visited.  A non-finite
-gradient spreads NaN over the touched rows of its chunk's tiles (0·inf in
-the contraction); the step's loss is non-finite then and the trainer's
-``on_nan`` policy has it.
+sorted ids (scalar prefetch) pairs every block with the 256-id chunks that
+fall in it, and the kernel builds the block's gradient in VMEM as a one-hot
+of the ids against the row index, contracted with the gradients on the MXU.
+A contraction over a chunk sums every id that matches the same row, and the
+scratch ``gacc`` carries a row across the chunks of its block, so an output
+is the float32 sum (the MXU's accumulator) over the row's occurrences of
+each of three bfloat16 parts of the gradient, then ``(Σhi + Σmid) + Σlo``.
+No bfloat16 rounding of any gradient enters: each part is exact in bfloat16
+(the three sum back to the float32 value bit for bit) and its product with
+1.0 is exact.  That is ``segment_sum``'s mathematics at its precision in
+another order of addition: bit-equal to it on a row that occurs once, within
+float32 summation error on a row that occurs more often (PERF.md §6, PR 32,
+has the chip's reading).  A row of ones in the gradients returns the
+occurrence count, whose ``> 0.5`` is the hit mask.  Adagrad is then the
+classic expressions on the block, SELECTED by the hit mask: an untouched row
+comes out bit for bit whatever its accumulator holds (0 included), and a
+lazily decayed accumulator decays only where touched.  Blocks no id falls
+in are not visited.  A non-finite gradient spreads NaN over the touched
+rows of its chunk's tiles (0·inf in the contraction); the step's loss is
+non-finite then and the trainer's ``on_nan`` policy has it.
 
 Decay-γ (``[Online] adagrad_decay``) threads through exactly like
 ``trainer.make_decayed_body``: γ=1.0 is a TRACE-TIME branch back to the
@@ -42,10 +51,10 @@ flag, same pattern as ops/pallas_anova.py).
 STATUS ON THE CHIP (TPU v5 lite, jax 0.9.0, libtpu 0.0.34).  The sweep
 compiles and runs (PR 30; tests/test_pallas_tail_chip_compile.py compiles
 it for a described v5e at the train cell's shapes): at ``fm8_criteo``'s
-shapes (2^26 rows of 9, 2,555,904 ids a step, 2.2M distinct) the kernel
+shapes (2^26 rows of 9, 2,555,904 ids a step, 2.3M distinct) the kernel
 takes 38 ms inside the step (45 alone, with its work list) where the XLA
 row operations took 570, and table and accumulator come out as theirs
-(PERF.md §6 has the bitwise reading).  ``optim.sparse_adagrad_update`` takes
+(PERF.md §6 has the bitwise reading on a batch without repeats).  ``optim.sparse_adagrad_update`` takes
 it on a TPU where ``optim.rows_tail_form`` says the sweep costs less than
 the batch's row operations.  There is no kernel that moves a touched row by
 a DMA of its own (two stood here, for the rows and the fused layouts, until
@@ -64,7 +73,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from fast_tffm_tpu.optim import dedup_rows
+from fast_tffm_tpu.optim import occurrences_by_id
 from fast_tffm_tpu.ops.pallas_common import resolve_interpret
 
 __all__ = [
@@ -110,13 +119,13 @@ def sweep_fits(v: int, d: int, m: int) -> bool:
     return 12 * items <= 768 << 10
 
 
-def _sweep_plan(uids, v: int, tb: int, tile: int):
-    """The sweep's work list, from the sorted ``uids`` alone (XLA, a few
-    arrays of ``nb + nchunks`` ints).
+def _sweep_plan(sid, v: int, tb: int, tile: int):
+    """The sweep's work list, from the ascending ids ``sid`` alone, repeated
+    or not (XLA, a few arrays of ``nb + nchunks`` ints).
 
     One item is one (block of ``tb`` table rows, chunk of ``_CHUNK``
-    updates) pair that overlap: block ``b`` owns ``uids[off[b]:off[b+1]]``
-    (``off = searchsorted(uids, b·tb)``), which lies in the chunks
+    updates) pair that overlap: block ``b`` owns ``sid[off[b]:off[b+1]]``
+    (``off = searchsorted(sid, b·tb)``), which lies in the chunks
     ``off[b] // _CHUNK .. (off[b+1]-1) // _CHUNK``.  Blocks no update falls
     in get no item — they are never read or written.  The list has a static
     length (every block once plus every chunk boundary once); slots past the
@@ -131,11 +140,11 @@ def _sweep_plan(uids, v: int, tb: int, tile: int):
     last-of-block << 21, the tiles (``tile`` rows) of the block that the
     chunk's ids in it span (none: first 1, last 0).
     """
-    m_pad = uids.shape[0]
+    m_pad = sid.shape[0]
     nb, nchunks = -(-v // tb), m_pad // _CHUNK
     w = nb + nchunks
     bounds = jnp.minimum(jnp.arange(nb + 1, dtype=jnp.int32) * tb, v)
-    off = jnp.searchsorted(uids, bounds, method="scan_unrolled").astype(jnp.int32)
+    off = jnp.searchsorted(sid, bounds, method="scan_unrolled").astype(jnp.int32)
     lo, hi = off[:-1], off[1:]
     c0 = lo // _CHUNK
     n_items = jnp.where(hi > lo, (hi - 1) // _CHUNK - c0 + 1, 0)
@@ -155,8 +164,8 @@ def _sweep_plan(uids, v: int, tb: int, tile: int):
     s = jnp.maximum(lo[blk], ch * _CHUNK)
     e = jnp.minimum(hi[blk], (ch + 1) * _CHUNK) - 1
     some = real & (e >= s)  # a real item lacks ids only if the batch drops all
-    t0 = jnp.where(some, (uids[jnp.minimum(s, m_pad - 1)] - blk * tb) // tile, 1)
-    t1 = jnp.where(some, (uids[jnp.clip(e, 0, m_pad - 1)] - blk * tb) // tile, 0)
+    t0 = jnp.where(some, (sid[jnp.minimum(s, m_pad - 1)] - blk * tb) // tile, 1)
+    t1 = jnp.where(some, (sid[jnp.clip(e, 0, m_pad - 1)] - blk * tb) // tile, 0)
     meta = (
         t0 | (t1 << 10) | (first.astype(jnp.int32) << 20)
         | (last.astype(jnp.int32) << 21)
@@ -205,7 +214,7 @@ def _sweep_kernel(
         r = lax.dot_general(
             g3, hot.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [3·dp, tile]: one non-zero term an output, so exact
+        )  # [3·dp, tile]: a row's occurrences in the chunk, summed in float32
         gacc[t] += (r[:dp] + r[dp:2 * dp]) + r[2 * dp:]
         return carry
 
@@ -216,7 +225,7 @@ def _sweep_kernel(
         for t in range(tb // tile):
             cols = slice(t * tile, (t + 1) * tile)
             g = gacc[t, :d, :]
-            hit = gacc[t, d:d + 1, :] > 0.5  # the row of ones
+            hit = gacc[t, d:d + 1, :] > 0.5  # the row of ones: occurrences
             w, acc = t_ref[:, cols], a_ref[:, cols]
             asq = g * g
             if acc.shape[0] == 1 and d != 1:  # row-granularity accumulator
@@ -233,17 +242,19 @@ def _sweep_kernel(
 def sweep_adagrad_update(
     table: jax.Array,
     accum: jax.Array,
-    uids: jax.Array,
-    gsum: jax.Array,
+    sid: jax.Array,
+    gt: jax.Array,
     lr: float,
     *,
     decay: float = 1.0,
     interpret: bool | None = None,
     block_lanes: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """The sweep on ``optim.dedup_rows``' output: ``uids`` ascending and
-    unique below ``V`` (anything from ``V`` up is dropped), ``gsum`` their
-    summed gradients.
+    """The sweep on ``optim.occurrences_by_id``'s output: ``sid [M]`` the
+    batch's ids ASCENDING, repeated as often as they occur (anything from
+    ``V`` up is dropped), ``gt [D, M]`` the occurrences' gradients in that
+    order, column by column along the lanes.  Unique ids with summed
+    gradients (``optim.dedup_rows``) are a case of it.
 
     A ``[V, D]`` float32 array with ``D < 128`` is held lane-major on the
     TPU (the row index along the lanes), which is the row-major layout of
@@ -251,14 +262,14 @@ def sweep_adagrad_update(
     blocks of ``block_lanes`` rows, aliased to its outputs, builds each
     block's dense gradient in VMEM from the slice of the ids that falls in
     it (a one-hot against the row index, contracted with the gradients on
-    the MXU, exact in float32) and writes ``w − lr·g/√acc'`` and ``acc'``
-    where the one-hot hit, the old values elsewhere.  Blocks without an
-    update are not visited.
+    the MXU: a row's occurrences are summed there, in float32) and writes
+    ``w − lr·g/√acc'`` and ``acc'`` where the one-hot hit, the old values
+    elsewhere.  Blocks without an update are not visited.
     """
     interpret = resolve_interpret(interpret)
     v, d = table.shape
     a = accum.shape[-1]
-    m = uids.shape[0]
+    m = sid.shape[0]
     tb = sweep_block_lanes(v, d, block_lanes)
     tile = _TILE
     while tb % tile:  # a table shorter than a block: the tile that divides it
@@ -267,12 +278,12 @@ def sweep_adagrad_update(
         raise ValueError(f"block_lanes {tb} is more than 1024 tiles of {tile} rows")
     dp = -(-(d + 1) // 16) * 16  # whole bfloat16 tiles, room for the ones
     m_pad = -(-m // _CHUNK) * _CHUNK
-    uids = jnp.pad(
-        uids.astype(jnp.int32), (0, m_pad - m),
+    sid = jnp.pad(
+        sid.astype(jnp.int32), (0, m_pad - m),
         constant_values=jnp.iinfo(jnp.int32).max,
     )
-    gt = jnp.pad(gsum.T, ((0, dp - d), (0, m_pad - m))).at[d].set(1.0)
-    blk, ch, meta = _sweep_plan(uids, v, tb, tile)
+    gt = jnp.pad(gt, ((0, dp - d), (0, m_pad - m))).at[d].set(1.0)
+    blk, ch, meta = _sweep_plan(sid, v, tb, tile)
     by_block = lambda i, blk, ch, meta: (0, blk[i])
     by_chunk = lambda i, blk, ch, meta: (0, ch[i])
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -303,7 +314,7 @@ def sweep_adagrad_update(
         ),
         input_output_aliases={5: 0, 6: 1},  # table and accum in place
         interpret=interpret,
-    )(blk, ch, meta, uids[None, :], _split3(gt), table.T, accum.T)
+    )(blk, ch, meta, sid[None, :], _split3(gt), table.T, accum.T)
     return table_t.T, accum_t.T
 
 
@@ -318,12 +329,15 @@ def rows_tail_adagrad_update(
     interpret: bool | None = None,
     block_lanes: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """``optim.sparse_adagrad_update`` as one in-place sweep: same dedup
-    (``optim.dedup_rows``), same accumulator expressions, same lazy-decay
-    semantics, through ``sweep_adagrad_update``."""
+    """``optim.sparse_adagrad_update`` in its ``sweep`` form (and what it
+    calls there): the batch's occurrences brought to id order
+    (``optim.occurrences_by_id``: one sort, one permutation, no sums), then
+    ``sweep_adagrad_update``; same accumulator expressions and lazy-decay
+    semantics as the rows."""
     d = table.shape[-1]
-    uids, gsum = dedup_rows(ids.reshape(-1), row_grads.reshape(-1, d), table.shape[0])
-    return sweep_adagrad_update(
-        table, accum, uids, gsum, lr, decay=decay, interpret=interpret,
-        block_lanes=block_lanes,
-    )
+    sid, gt = occurrences_by_id(ids.reshape(-1), row_grads.reshape(-1, d), table.shape[0])
+    with jax.named_scope("fm.tail"):
+        return sweep_adagrad_update(
+            table, accum, sid, gt, lr, decay=decay, interpret=interpret,
+            block_lanes=block_lanes,
+        )

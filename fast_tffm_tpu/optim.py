@@ -19,6 +19,11 @@ g (accum += g² must see the *summed* gradient, and duplicate scatter
 targets would race).  Where the batch touches so many sub-tile rows that
 streaming the whole table costs less (``rows_tail_form``), the same update
 runs as one in-place kernel sweep instead (ops/pallas_tail.py, PR 30).
+The form is chosen BEFORE the dedup (PR 32), because the two want different
+inputs of one algorithm: row operations want unique ids with one summed
+gradient each (``dedup_rows``); the sweep's contraction sums a row's
+occurrences itself and wants them in id order, no more
+(``occurrences_by_id``).  They share the sort (``sort_ids``).
 
 Row descriptors (PR 27; PERF.md §6 has the chip's readings).  On the TPU a
 row narrower than a 128-lane tile is laid out with the ROW INDEX along the
@@ -67,6 +72,10 @@ __all__ = [
     "segment_sum_lanes",
     "rows_tail_form",
     "describe_rows_tail",
+    "rows_tail_profile",
+    "sort_ids",
+    "occurrences_by_id",
+    "occurrences_permutation",
 ]
 
 
@@ -161,17 +170,36 @@ def distinct_sentinels(num_rows: int, m: int, dtype=jnp.int32) -> bool:
 
 
 # What the rows layout's tail costs in its two forms on a TPU v5e, read on
-# 2^26 rows of 9 floats under batches of 80K to 2.6M ids (PERF.md §6, PR 30;
-# PR 27 read the row primitives alone: scatter-set 100 ns, scatter-add 100,
-# gather 23 a row).  Row by row the tail costs 208 ns an id more than the
-# sweep does (19 / 37 / 75 / 150 / 303 ms against 33 / 36 / 41 / 49 / 68 at
-# 80K / 160K / 319K / 639K / 1.28M ids, dedup included on both sides).  The
-# sweep pays for the table instead: both arrays read and written once in
-# their padded lane-major layout, 17.2 GB in 31 ms with few ids (550 GB/s;
-# an elementwise XLA pass over the same bytes takes 27, the HBM's 819 GB/s
-# would take 21).
-_ROWS_OVER_SWEEP_NS = 208
-_SWEEP_BYTES_PER_S = 550e9
+# 2^26 rows of 9 floats under batches of 80K to 1.28M ids (PERF.md §6, PR 32,
+# each side with the front it now has: the rows' with its segment sum, the
+# sweep's without; PR 27 read the row primitives alone: scatter-set 100 ns,
+# scatter-add 100, gather 23 a row).  Row by row the tail costs 224 ns an id
+# more than the sweep does (19 / 37 / 75 / 149 / 302 ms against 32 / 33 / 34
+# / 38 / 46 at 80K / 160K / 319K / 639K / 1.28M ids; PR 30 read 208 with the
+# segment sum on both sides).  The sweep pays for the table instead: both
+# arrays read and written once in their padded lane-major layout, 17.2 GB in
+# 30.7 ms with few ids (560 GB/s; an elementwise XLA pass over the same
+# bytes takes 27, the HBM's 819 GB/s would take 21).
+_ROWS_OVER_SWEEP_NS = 224
+_SWEEP_BYTES_PER_S = 560e9
+# Columns the sweep's permutation carries through the sort of the ids as
+# operands (PERF.md §6, PR 32, 2,555,904 ids on a TPU v5e, the whole path
+# from the row gradients to the kernel's operand).  Nine columns: the stable
+# sort of key and columns 25.2 ms (22.2 unstable), against 64.1 for the
+# 2-operand sort and a gather of the 36-byte rows by its order (23 ns a row:
+# nine single-lane accesses) and 39.9 for a gather of rows padded to a whole
+# tile (2.6 GB of temporaries).  Seventeen: 44.7 against 87.0.  But the sort
+# COMPILES in 19 s with 2 operands, 115 s with 10 (72 unstable) and 284 s
+# with 18, once for every batch shape, so it carries up to one 16-row group
+# of the kernel's operand and wider rows keep the gather.
+_SORT_OPERAND_COLUMNS = 16
+
+
+def occurrences_permutation(d: int) -> str:
+    """How ``occurrences_by_id`` brings gradients ``d`` wide to id order:
+    ``"sort operands"`` or ``"row gather"`` (what ``dedup_rows`` does at
+    every width: its segment sum wants rows)."""
+    return "sort operands" if d <= _SORT_OPERAND_COLUMNS else "row gather"
 
 
 def rows_tail_form(
@@ -191,9 +219,9 @@ def rows_tail_form(
       * only where the sweep's bytes (both arrays, read and written, rows
         padded to whole sublanes of 8) take less time at the rate the sweep
         reaches than the batch's ``m`` ids pay for going row by row.
-        ``fm8_criteo`` (2^26 rows of 9, 65,536 x 39 ids): 31 ms against 530,
-        the sweep; the same table under 1,024 x 39 ids: 31 against 8, the
-        rows; the two cross at 150K ids, as read;
+        ``fm8_criteo`` (2^26 rows of 9, 65,536 x 39 ids): 31 ms against 570,
+        the sweep; the same table under 1,024 x 39 ids: 31 against 9, the
+        rows; the two cross at 137K ids, as read (136K);
       * only where the sweep's work list fits the scalar memory
         (ops.pallas_tail.sweep_fits: past about 15M ids a batch it does not).
     """
@@ -208,22 +236,58 @@ def rows_tail_form(
     return "sweep" if sweep_s < m * _ROWS_OVER_SWEEP_NS * 1e-9 else "rows"
 
 
-def describe_rows_tail(num_rows: int, m: int, d: int, form: str = "rows") -> str:
-    """The form ``sparse_adagrad_update`` takes at these shapes, for the
-    trainer's start-up log (it is a trace-time choice, so it is said once)."""
-    lanes = segment_sum_lanes(m, d)
+def rows_tail_profile(num_rows: int, m: int, d: int, form: str = "rows") -> dict:
+    """The tail's trace-time choices at these shapes, as the step's
+    ``kind=profile`` record carries them: ``tail_form``; how a row's
+    duplicates are summed (``tail_duplicates``: ``segment_sum`` on rows
+    ``segment_sum_lanes`` wide ahead of the row operations, or ``kernel``:
+    the sweep's own contraction, and then no segment sum runs and its lanes
+    are null); how the gradients reach id order (``tail_permutation``); the
+    sweep's ``tail_block_lanes``."""
     if form == "sweep":
         from fast_tffm_tpu.ops.pallas_tail import sweep_block_lanes
 
-        block = sweep_block_lanes(num_rows, d)
+        return dict(
+            tail_form="sweep", tail_duplicates="kernel", segment_sum_lanes=None,
+            tail_permutation=occurrences_permutation(d),
+            tail_block_lanes=sweep_block_lanes(num_rows, d),
+        )
+    return dict(
+        tail_form="rows", tail_duplicates="segment_sum",
+        segment_sum_lanes=segment_sum_lanes(m, d), tail_permutation="row gather",
+        tail_block_lanes=None,
+    )
+
+
+def describe_rows_tail(num_rows: int, m: int, d: int, form: str = "rows") -> str:
+    """The form ``sparse_adagrad_update`` takes at these shapes, for the
+    trainer's start-up log (it is a trace-time choice, so it is said once)."""
+    p = rows_tail_profile(num_rows, m, d, form)
+    if form == "sweep":
+        block = p["tail_block_lanes"]
         return (
             f"pallas rows sweep (block {block} lanes, {-(-num_rows // block)} "
-            f"blocks; segment sum on {lanes}-lane rows, row width {d})"
+            f"blocks; duplicates summed in the kernel, occurrences brought to "
+            f"id order as {p['tail_permutation']}, row width {d})"
         )
     hints = "sorted+unique" if distinct_sentinels(num_rows, m) else "sorted"
     return (
-        f"xla rows (segment sum on {lanes}-lane rows (row width {d}), "
+        f"xla rows (segment sum on {p['segment_sum_lanes']}-lane rows (row width {d}), "
         f"table updated by one scatter-add, row ops declared {hints})"
+    )
+
+
+def sort_ids(ids: jax.Array, num_rows: int):
+    """The first half of both forms of the tail: ONE stable sort of the
+    batch's ids, clamped to ``num_rows`` (every drop id becomes the first one).
+
+    Returns ``(sid [M], order [M])``: the ids ascending, repeats and all, and
+    each one's position in ``ids`` (``ids[argsort(ids)]`` would be an 18 ms
+    gather of 2.56M ints more).  Stable, so occurrences of one id keep the
+    batch's order and every sum over them is the same from run to run."""
+    return lax.sort_key_val(
+        jnp.minimum(ids, num_rows), jnp.arange(ids.shape[0], dtype=jnp.int32),
+        is_stable=True,
     )
 
 
@@ -247,19 +311,18 @@ def dedup_rows(ids: jax.Array, row_grads: jax.Array, num_rows: int):
       which sorts before every trailing slot's id, so the whole of ``uids``
       is sorted and (where distinct) unique.
 
-    No row operation here is a partial-lane scatter: the segment sum runs on
-    rows padded to whole 128-lane tiles (``segment_sum_lanes``; same op, same
-    addends in the same order as the ``D``-wide form, so the sums are the
-    same floats), and ``uids`` comes from a second sort of the ids, not from
-    a scatter-set by segment (5 ms against 13, PERF.md §6, PR 27).
+    What row operations need (the XLA rows tail, the shards that exchange
+    unique sums); the sweep sums duplicates itself and takes
+    ``occurrences_by_id``.  No row operation here is a partial-lane scatter:
+    the segment sum runs on rows padded to whole 128-lane tiles
+    (``segment_sum_lanes``; same op, same addends in the same order as the
+    ``D``-wide form, so the sums are the same floats), and ``uids`` comes
+    from a second sort of the ids, not from a scatter-set by segment (5 ms
+    against 13, PERF.md §6, PR 27).
     """
     m, d = row_grads.shape
     with jax.named_scope("fm.dedup"):
-        # One stable sort hands back the sorted ids beside the order
-        # (``ids[argsort(ids)]`` is an 18 ms gather of 2.56M ints more).
-        sid, order = lax.sort_key_val(
-            jnp.minimum(ids, num_rows), jnp.arange(m, dtype=jnp.int32), is_stable=True
-        )
+        sid, order = sort_ids(ids, num_rows)
         sg = row_grads[order]
         is_new = jnp.concatenate([jnp.ones((1,), bool), sid[1:] != sid[:-1]])
         seg = jnp.cumsum(is_new) - 1  # [M] segment index per occurrence
@@ -276,6 +339,31 @@ def dedup_rows(ids: jax.Array, row_grads: jax.Array, num_rows: int):
             drop = drop + jnp.arange(m, dtype=sid.dtype)
         uids = jnp.sort(jnp.where(is_new, sid, drop))
     return uids, gsum
+
+
+def occurrences_by_id(ids: jax.Array, row_grads: jax.Array, num_rows: int):
+    """The batch's occurrences in id order, duplicates NOT summed: what the
+    sweep takes (ops.pallas_tail.sweep_adagrad_update sums a row's
+    occurrences in its own contraction, so the segment sum, its two
+    128-lane temporaries and the second sort of ``dedup_rows`` have nothing
+    to do here).
+
+    Returns ``(sid [M], gt [D, M])``: ``sort_ids``' ids (ascending, repeats
+    and all, drop ids clamped to ``num_rows``) and the gradients in that
+    order, COLUMN BY COLUMN along the lanes — the layout the kernel reads
+    and the one a ``[M, D]`` float32 buffer with ``D`` under a tile has on
+    the TPU anyway.  So nothing here needs an ``[M, D]`` row, and the
+    permutation has two forms (``occurrences_permutation``): the ``D``
+    columns ride the ONE stable sort of the ids as its operands, or they are
+    gathered row by row in the order that sort returns."""
+    with jax.named_scope("fm.dedup"):
+        if occurrences_permutation(row_grads.shape[1]) == "sort operands":
+            sid, *cols = lax.sort(
+                (jnp.minimum(ids, num_rows), *row_grads.T), num_keys=1, is_stable=True
+            )
+            return sid, jnp.stack(cols)
+        sid, order = sort_ids(ids, num_rows)
+        return sid, row_grads[order].T
 
 
 def sparse_adagrad_update(
@@ -297,9 +385,11 @@ def sparse_adagrad_update(
     ``dedup_rows`` guarantees about ``uids`` — ascending, and unique where
     the trailing drop ids are distinct (a trace-time test on shapes).
     ``form`` ``"sweep"``: the same update as one in-place kernel pass over
-    table and accumulator (ops.pallas_tail.sweep_adagrad_update).  ``None``
-    (every driver; a test or ``chip_smoke.py`` names a form to run it on any
-    backend): ``rows_tail_form``.
+    table and accumulator (ops.pallas_tail.rows_tail_adagrad_update), which
+    sums a row's occurrences itself: no ``dedup_rows`` runs, the batch's
+    occurrences go in in id order (``occurrences_by_id``).  ``None`` (every
+    driver; a test or ``chip_smoke.py`` names a form to run it on any
+    backend): ``rows_tail_form``, asked before anything is sorted.
 
     ``decay`` γ < 1 decays the accumulator LAZILY — only the rows a step
     touches pay ``accum = γ·accum + g²`` (an untouched row's history is
@@ -311,19 +401,18 @@ def sparse_adagrad_update(
     paths)."""
     D = table.shape[-1]
     flat = ids.reshape(-1)
-    uids, gsum = dedup_rows(flat, row_grads.reshape(-1, D), table.shape[0])
     if form is None:
         form = rows_tail_form(
             table.shape[0], flat.shape[0], D, state.accum.shape[-1]
         )
     if form == "sweep":
-        from fast_tffm_tpu.ops.pallas_tail import sweep_adagrad_update
+        from fast_tffm_tpu.ops.pallas_tail import rows_tail_adagrad_update
 
-        with jax.named_scope("fm.tail"):
-            table, accum = sweep_adagrad_update(
-                table, state.accum, uids, gsum, lr, decay=decay
-            )
+        table, accum = rows_tail_adagrad_update(
+            table, state.accum, flat, row_grads, lr, decay=decay
+        )
         return table, AdagradState(accum)
+    uids, gsum = dedup_rows(flat, row_grads.reshape(-1, D), table.shape[0])
     known = dict(
         indices_are_sorted=True,
         unique_indices=distinct_sentinels(table.shape[0], flat.shape[0], uids.dtype),

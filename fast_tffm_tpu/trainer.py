@@ -3,11 +3,13 @@
 The TPU-native analog of the reference's session step loop
 (`renyi533/fast_tffm` :: local trainer: sess.run(train_op) over the graph
 parser → gather → scorer → loss → Adagrad scatter-add).  Here one jitted
-function fuses gather → fused scorer (custom VJP) → loss → dedup (sort,
-segment sum on tile-wide rows) → sparse Adagrad tail (row by row:
+function fuses gather → fused scorer (custom VJP) → loss → dedup (one sort
+of the ids, the gradients brought to that order; ahead of row operations
+also a segment sum on tile-wide rows) → sparse Adagrad tail (row by row:
 accumulator gather and scatter-set, table scatter-add; or, where the batch
 touches most of a table of sub-tile rows on a TPU, one in-place kernel
-sweep: optim.rows_tail_form); XLA compiles the whole step into a single
+sweep that sums duplicates itself: optim.rows_tail_form, asked before the
+dedup); XLA compiles the whole step into a single
 program whose ops carry the stage's name (``fm.gather``,
 ``fm.interaction``, ``fm.loss``, ``fm.dedup``, ``fm.tail``).
 
@@ -105,12 +107,16 @@ def train_step_body(
 ):
     """The (unjitted) single-device step, by the scope its ops carry:
     ``fm.gather`` (the batch's rows) → ``fm.interaction`` (fused scorer and
-    its backward) → ``fm.loss`` → ``fm.dedup`` (one sort for ids and order,
-    permutation gather, segment sum on 128-lane rows, unique ids by a second
-    sort) → ``fm.tail`` (one gather and one scatter-set of the accumulator,
-    one scatter-add into the table, all declared sorted and unique; or the
-    in-place sweep: optim.sparse_adagrad_update, which chooses between the
-    two from the shapes, ``optim.rows_tail_form``).
+    its backward) → ``fm.loss`` → ``fm.dedup`` → ``fm.tail``, in one of two
+    forms that optim.sparse_adagrad_update chooses between from the shapes
+    BEFORE it dedups (``optim.rows_tail_form``).  ``rows``: ``fm.dedup`` is
+    one sort for ids and order, the permutation gather, a segment sum on
+    128-lane rows and the unique ids by a second sort; ``fm.tail`` one
+    gather and one scatter-set of the accumulator and one scatter-add into
+    the table, all declared sorted and unique.  ``sweep``: ``fm.dedup`` is
+    the sort and the permutation gather alone (the occurrences in id order,
+    duplicates not summed); ``fm.tail`` the in-place kernel pass, whose
+    contraction sums a row's occurrences.
     Shared verbatim by ``make_train_step`` and the device-cache step
     (data/device_cache.py) so the two paths are the SAME math on the same
     values — the bit-identity their parity test pins.

@@ -617,10 +617,12 @@ def _run_training(
     optional custom touched-row bitmap marker — the device-cache drivers
     mark from their resident id arrays) parameterize the async/delta
     checkpoint subsystem (checkpoint_async.AsyncCheckpointer).
-    ``tail_profile`` (the rows layout's trace-time choices: the row width
-    its tail sums segments at, ``segment_sum_lanes``; ``tail_form``
-    ``sweep`` | ``rows``; the sweep's ``tail_block_lanes``; empty on every
-    other layout) rides the step's ``kind=profile`` record beside ``row_dim``.
+    ``tail_profile`` (the rows layout's trace-time choices,
+    ``optim.rows_tail_profile``: ``tail_form`` ``sweep`` | ``rows``; how
+    duplicates are summed, ``tail_duplicates`` ``kernel`` | ``segment_sum``,
+    the latter on rows ``segment_sum_lanes`` wide; ``tail_permutation``; the
+    sweep's ``tail_block_lanes``; empty on every other layout) rides the
+    step's ``kind=profile`` record beside ``row_dim``.
 
     ``datastats_ids`` (optional ``batch -> device ids``) lets the sampled
     id-statistics collector read a device-cache batch's ids straight off
@@ -778,6 +780,7 @@ def _run_training(
             row_dim=max(1, row_dim),
             **{
                 "segment_sum_lanes": None, "tail_form": None,
+                "tail_duplicates": None, "tail_permutation": None,
                 "tail_block_lanes": None, **(tail_profile or {}),
             },
         )
@@ -1352,7 +1355,7 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
         from fast_tffm_tpu.optim import (
             describe_rows_tail,
             rows_tail_form,
-            segment_sum_lanes,
+            rows_tail_profile,
         )
         from fast_tffm_tpu.trainer import make_decayed_body, make_dedup_body
 
@@ -1373,14 +1376,7 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
         tail_form = rows_tail_form(
             num_rows, m_ids, row_dim, state.table_opt.accum.shape[-1]
         )
-        tail_profile = dict(
-            segment_sum_lanes=segment_sum_lanes(m_ids, row_dim),
-            tail_form=tail_form,
-        )
-        if tail_form == "sweep":
-            from fast_tffm_tpu.ops.pallas_tail import sweep_block_lanes
-
-            tail_profile["tail_block_lanes"] = sweep_block_lanes(num_rows, row_dim)
+        tail_profile = rows_tail_profile(num_rows, m_ids, row_dim, tail_form)
         log("sparse tail: " + describe_rows_tail(num_rows, m_ids, row_dim, tail_form))
         step_fn = make_train_step(
             model, cfg.learning_rate, decay=decay, body=step_body

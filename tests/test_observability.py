@@ -121,16 +121,19 @@ def test_streamed_profile_and_datastats(dataset):
         (dict(model="fm", factor_num=4), 5, 128, "rows", None),  # under a tile: padded to one
         (dict(model="ffm", factor_num=4, num_fields=39), 157, 256, "rows", None),  # libffm's Criteo row: two tiles
         (dict(model="fm", factor_num=127), 128, 128, "rows", None),  # tile-wide already
-        (dict(model="fm", factor_num=4), 5, 128, "sweep", 256),  # the rule says so (patched): 200 rows, two tiles
+        (dict(model="fm", factor_num=4), 5, None, "sweep", 256),  # the rule says so (patched): 200 rows, two tiles; no segment sum
         (dict(model="fm", factor_num=4, table_layout="packed"), 5, None, None, None),  # no rows-layout tail
     ],
     ids=["fm_k4", "ffm_39x4", "fm_k127", "fm_k4_sweep", "packed"],
 )
 def test_the_steps_profile_record_says_the_row_width_and_the_tails_lanes(dataset, request, kw, row_dim, lanes, form, block):
     """What only the start-up log line said (``describe_rows_tail``): the row
-    width, the lanes ``dedup_rows`` sums segments at and the form the tail
-    took (off a TPU the rows, unless ``rows_tail_form`` is made to say the
-    sweep), trace-time choices all."""
+    width, the form the tail took (off a TPU the rows, unless
+    ``rows_tail_form`` is made to say the sweep) and how it sums a row's
+    duplicates: a segment sum on rows so many lanes wide ahead of the row
+    operations, or the sweep's own contraction, and then no segment sum
+    runs and its lanes are null.  Trace-time choices all
+    (``optim.rows_tail_profile``)."""
     if form == "sweep":
         request.getfixturevalue("sweep_form")
     cfg = _cfg(dataset, tag="lanes", epoch_num=1, **kw)
@@ -139,11 +142,19 @@ def test_the_steps_profile_record_says_the_row_width_and_the_tails_lanes(dataset
     (prof,) = [r for r in _read(cfg.metrics_path) if r["kind"] == "profile" and r["program"] == "train_step"]
     assert (prof["row_dim"], prof["segment_sum_lanes"]) == (row_dim, lanes)
     assert (prof["tail_form"], prof["tail_block_lanes"]) == (form, block)
+    duplicates = {"sweep": "kernel", "rows": "segment_sum", None: None}[form]
+    permutation = {"sweep": "sort operands", "rows": "row gather", None: None}[form]  # 5 columns ride the sort
+    assert (prof["tail_duplicates"], prof["tail_permutation"]) == (duplicates, permutation)
     said = [l for l in logs if l.startswith("sparse tail: ")]
     if form == "sweep":
-        assert said == [f"sparse tail: pallas rows sweep (block 256 lanes, 1 blocks; segment sum on 128-lane rows, row width {row_dim})"]
+        assert said == [
+            "sparse tail: pallas rows sweep (block 256 lanes, 1 blocks; duplicates summed in the kernel, "
+            f"occurrences brought to id order as sort operands, row width {row_dim})"
+        ]
     elif form == "rows":
         assert len(said) == 1 and said[0].startswith(f"sparse tail: xla rows (segment sum on {lanes}-lane rows")
+    else:
+        assert not said
 
 
 def test_a_backend_without_cost_analysis_still_records_what_was_dispatched(dataset, monkeypatch):

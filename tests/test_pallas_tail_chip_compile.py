@@ -3,12 +3,16 @@ shapes (no chip: the TPU's compiler is installed here and compiles for a
 topology that is described, not attached).  What interpret mode cannot
 show: that Mosaic takes the kernel (it refused the per-row DMA kernels this
 one replaced), that it fits VMEM at every row width, and that XLA hands it
-table and accumulator in place — the four transposes are bitcasts.
+table and accumulator in place — the four transposes are bitcasts.  And
+of the whole step at the two train cells' shapes: which ops stand under
+``fm.dedup`` and ``fm.tail`` in each form (ISSUE 32: the sweep's step sums
+no segments and sorts once; the rows' step is what it was).
 
 All of it in this one file and behind a fixture: one process may hold the
 TPU's library, so only the worker that runs this file loads it.
 """
 
+import collections
 import re
 
 import jax
@@ -33,10 +37,10 @@ def one_chip():
 def _compiled_text(one_chip, v, d, a, m):
     sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     sweep = jax.jit(
-        lambda t, acc, u, g: sweep_adagrad_update(t, acc, u, g, 0.05, interpret=False),
+        lambda t, acc, u, gt: sweep_adagrad_update(t, acc, u, gt, 0.05, interpret=False),
         donate_argnums=(0, 1),
     )
-    args = (sd((v, d), jnp.float32), sd((v, a), jnp.float32), sd((m,), jnp.int32), sd((m, d), jnp.float32))
+    args = (sd((v, d), jnp.float32), sd((v, a), jnp.float32), sd((m,), jnp.int32), sd((d, m), jnp.float32))
     return sweep.lower(*args).compile().as_text()
 
 
@@ -64,3 +68,70 @@ def test_the_sweep_compiles_for_the_chip_in_place(one_chip, v, d, a, m):
     assert ops <= {"parameter", "bitcast", "custom-call", "get-tuple-element", "tuple"}, ops
     assert "bitcast" in ops and "custom-call" in ops
     assert f"f32[{v},{d}]{{0,1:T(8,128)}}" in text  # the lane-major layout the view rests on
+
+
+def _step_ops(one_chip, monkeypatch, model, b, n):
+    """(compiled text, {scope: Counter of HLO opcodes}, forms asked) of the
+    train step as ``make_train_step`` builds it, compiled for the described
+    chip; ``rows_tail_form`` is told the backend is a TPU (it asks
+    ``jax.default_backend()``, which is the CPU here) and the kernel is
+    compiled, not interpreted."""
+    from fast_tffm_tpu import optim
+    from fast_tffm_tpu.models.base import Batch
+    from fast_tffm_tpu.ops import pallas_tail
+    from fast_tffm_tpu.trainer import init_state, make_train_step
+
+    rule, asked = optim.rows_tail_form, []
+    monkeypatch.setattr(optim, "rows_tail_form", lambda *a, backend=None: asked.append(rule(*a, backend="tpu")) or asked[-1])
+    monkeypatch.setattr(pallas_tail, "resolve_interpret", lambda interpret: False)
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    state = jax.eval_shape(lambda: init_state(model, jax.random.key(0), 0.1, "element"))
+    state = jax.tree.map(lambda x: sd(x.shape, x.dtype), state)
+    batch = Batch(
+        labels=sd((b,), jnp.float32), ids=sd((b, n), jnp.int32), vals=sd((b, n), jnp.float32),
+        fields=sd((b, n if model.uses_fields else 0), jnp.int32), weights=sd((b,), jnp.float32),
+    )
+    text = make_train_step(model, 0.05).lower(state, batch).compile().as_text()
+    ops = collections.defaultdict(collections.Counter)
+    for line in text.splitlines():
+        op = re.match(r"\s*(ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)  # a long tuple type holds /*index=5*/
+        scope = re.search(r'op_name="jit\(step\)/(fm\.(?:dedup|tail))/', line)
+        if op and scope:
+            ops[scope.group(1)][op.group(3)] += 1
+    return text, ops, asked
+
+
+def test_the_sweeps_step_sums_no_segments_and_sorts_once(one_chip, monkeypatch):
+    """``fm8_criteo.train_fmb``'s step (2^26 rows of 9, 65,536 x 39 ids): the
+    form is the sweep, and between the backward pass and the kernel stands
+    ONE sort, which carries the nine gradient columns as operands: no
+    segment sum (a ``scatter`` under ``fm.dedup``), none of its two
+    ``f32[2555904,128]`` temporaries, no second sort, and no gather of
+    ``f32[2555904,9]`` rows by the sort's order."""
+    from fast_tffm_tpu.models import FMModel
+
+    text, ops, asked = _step_ops(one_chip, monkeypatch, FMModel(vocabulary_size=2**26, factor_num=8, order=2), 65536, 39)
+    assert asked == ["sweep"] and "tpu_custom_call" in text
+    assert ops["fm.dedup"]["sort"] == 1 and ops["fm.dedup"]["scatter"] == 0
+    assert "2555904,128]" not in text and "scatter-add" not in text
+    assert ops["fm.dedup"]["gather"] == 0  # the permutation rides the sort
+    assert ops["fm.tail"]["sort"] == 0 and ops["fm.tail"]["scatter"] == 0
+
+
+def test_the_rows_step_keeps_its_dedup_and_its_row_operations(one_chip, monkeypatch):
+    """``ffm4_criteo.train_fmb_fields``' step (2^20 rows of 157, 32,768 x 39
+    ids): rows past one tile keep the rows form, whose ``fm.dedup`` is two
+    sorts, the permutation gather and the segment sum on 256-lane rows, and
+    whose ``fm.tail`` is one gather and two scatters, as before ISSUE 32
+    (on the chip the whole compiled step is the parent's as text: PERF.md
+    §6)."""
+    from fast_tffm_tpu.models import FFMModel
+
+    model = FFMModel(vocabulary_size=2**20, num_fields=39, factor_num=4)
+    text, ops, asked = _step_ops(one_chip, monkeypatch, model, 32768, 39)
+    assert asked == ["rows"] and "tpu_custom_call" not in text
+    dedup = {k: ops["fm.dedup"][k] for k in ("sort", "gather", "scatter")}
+    assert dedup == {"sort": 2, "gather": 1, "scatter": 1}
+    assert "f32[1277952,256]" in text  # the segment sum's wide rows
+    tail = {k: ops["fm.tail"][k] for k in ("sort", "gather", "scatter")}
+    assert tail == {"sort": 0, "gather": 1, "scatter": 2}
