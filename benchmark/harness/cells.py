@@ -68,15 +68,19 @@ def load_cell(workload: str, bench_dir: str = BENCH_DIR) -> dict:
     raise SystemExit(f"unknown workload {workload!r}: no configs/<config>.json + traffic/<mix>.json")
 
 
-def load_metrics(kind: str, bench_dir: str = BENCH_DIR) -> list[dict]:
-    """Every metric file whose ``kinds`` holds this cell's traffic kind."""
+def load_metrics(kind: str, bench_dir: str = BENCH_DIR, workload: str | None = None) -> list[dict]:
+    """Every metric file whose ``kinds`` holds this cell's traffic kind.  A
+    file may name its cells (``"workloads": [...]``, where only some cells of
+    the kind have what its reader reads): it is left out of a ``workload`` it
+    does not name."""
     out = []
     mdir = os.path.join(bench_dir, "metrics")
     for fn in sorted(os.listdir(mdir)):
         if fn.endswith(".json"):
             m = _load(os.path.join(mdir, fn))
             m.setdefault("name", fn[: -len(".json")])
-            if kind in m["kinds"]:
+            named = m.get("workloads")
+            if kind in m["kinds"] and (workload is None or named is None or workload in named):
                 out.append(m)
     return out
 

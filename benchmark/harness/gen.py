@@ -13,7 +13,7 @@ import numpy as np
 _HEADER = struct.Struct("<4sIqqqBBB5xqqq")
 _ALIGN = 64
 _FLAG_FIELDS_ALL_ZERO = 2
-_SCATTER = 1999  # prime; rank * _SCATTER stays inside uint32 for any field span
+_SCATTER = 1999  # prime, and coprime with every field span (asserted): the scatter is a bijection on a field's range
 _CHUNKS = 8  # row chunks, each with its own stream of the seed, drawn on threads
 
 
@@ -22,7 +22,11 @@ def _chunk(seed, c, n_rows, lo, span, alpha_half):
     u = rng.random((n_rows, lo.size), dtype=np.float32)
     p = u * u * np.sqrt(u) if alpha_half else u * u
     ranks = np.minimum((p * span.astype(np.float32)).astype(np.uint32), span - 1)
-    ids = (lo + (ranks * np.uint32(_SCATTER)) % span).astype(np.int32)
+    if int(span.max()) * _SCATTER < 2**32:
+        ids = (lo + (ranks * np.uint32(_SCATTER)) % span).astype(np.int32)
+    else:  # the product passes uint32 (a span over 2,148,557: 2^27 rows / 39 fields): taken in uint64
+        scattered = (ranks.astype(np.uint64) * np.uint64(_SCATTER)) % span.astype(np.uint64)
+        ids = (lo + scattered.astype(np.uint32)).astype(np.int32)
     vals = np.abs(rng.standard_normal((n_rows, lo.size), dtype=np.float32) * 0.35 + 0.5) + 0.05
     vals = np.round(vals, 4)
     # Labels from a cheap hidden per-id bias, so that clicks depend on ids.
@@ -42,7 +46,8 @@ def rows_from_seed(seed: int, n_rows: int, fields: int, vocab: int, alpha: float
     bounds = np.linspace(0, vocab, fields + 1).astype(np.int64)
     lo = bounds[:-1].astype(np.uint32)[None, :]
     span = (bounds[1:] - bounds[:-1]).astype(np.uint32)[None, :]
-    assert all(gcd(int(s), _SCATTER) == 1 for s in span[0]) and int(span.max()) * _SCATTER < 2**32
+    # Ids are int32 in FMB and on the wire; a span that shares a factor with _SCATTER would fold hot ids together.
+    assert 0 < vocab <= 2**31 - 1 and all(gcd(int(s), _SCATTER) == 1 for s in span[0])
     assert alpha in (2.0, 2.5)
     cuts = np.linspace(0, n_rows, _CHUNKS + 1).astype(int)
     with ThreadPoolExecutor(_CHUNKS) as pool:

@@ -352,7 +352,7 @@ def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cell
             least, _ = peaks.least_seconds(0.0, model.score_bytes(d("go", "close", "rows"), cfg.max_nnz), device["kind"])
             values["score_mfu"] = 100.0 * least / red["busy_s"]
         ctx = {"records": readers.read_jsonl(cfg.metrics_path + ".r0"), "steps": "warmup_flag", "values": values, "trace": red, "trace_dir": trace_dir, "model": model}
-        result["metrics"] = readers.read_all(cells.load_metrics(cell["kind"], cell["bench_dir"]), ctx)
+        result["metrics"] = readers.read_all(cells.load_metrics(cell["kind"], cell["bench_dir"], cell["name"]), ctx)
     else:
         result["metrics"] = {
             "serve_p50_ms": common.metric(pop["serve_p50_ms"], "ms"),

@@ -175,7 +175,7 @@ def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cell
             "step_bytes": float(work_bytes[0]),
             "step_flops": model.step_flops(batch, nnz, int(work_bytes[1])),
         }
-        result["metrics"] = readers.read_all(cells.load_metrics(cell["kind"], cell["bench_dir"]), ctx)
+        result["metrics"] = readers.read_all(cells.load_metrics(cell["kind"], cell["bench_dir"], cell["name"]), ctx)
     else:
         result["metrics"] = {
             "train_examples_per_s_per_chip": common.metric(rate, "examples/s/chip"),

@@ -78,10 +78,15 @@ def test_benchmark_json_agrees_with_the_files():
     files = {m["name"]: m for k in set(kinds.values()) for m in cells.load_metrics(k)}
     listed = {m["name"]: m for m in b["per_layer"]}
     assert set(listed) == set(files)
+    reported = {w: {m["name"] for m in cells.load_metrics(k, workload=w)} for w, k in kinds.items()}
     for name, m in listed.items():
         f = files[name]
         assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) == (
             f["unit"], f["better"], f["source"], f["layer"], f["moves"])
         assert m["moves"] in e2e and readers.reader(f["reader"])
-        # Every metric lists its cells, so that a PR that adds a cell only appends.
-        assert sorted(m["workloads"]) == sorted(w for w, k in kinds.items() if k in f["kinds"])
+        # Every metric lists its cells, so that a PR that adds a cell only appends: those its file
+        # names where it names any (each of a kind it reads), else every cell of its kinds.
+        of_kind = sorted(w for w, k in kinds.items() if k in f["kinds"])
+        assert set(f.get("workloads", of_kind)) <= set(of_kind)
+        assert sorted(m["workloads"]) == sorted(f.get("workloads", of_kind))
+        assert [w for w in kinds if name in reported[w]] == [w for w in kinds if w in m["workloads"]]
