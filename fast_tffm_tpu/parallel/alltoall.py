@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from fast_tffm_tpu.parallel.exchange import exchange_scope
 from fast_tffm_tpu.parallel.mesh import DATA_AXIS, ROW_AXIS, axis_size
 
 __all__ = ["routed_gather", "routed_update", "routing_overflow", "capacity_for"]
@@ -57,8 +58,10 @@ def routing_overflow(ids: jnp.ndarray, shard_rows: int, capacity: int):
     """
     R = axis_size(ROW_AXIS)
     counts = jnp.bincount(ids.reshape(-1) // shard_rows, length=R)
-    local = jnp.any(counts > capacity)
-    return lax.psum(local.astype(jnp.int32), (DATA_AXIS, ROW_AXIS)) > 0
+    local = jnp.any(counts > capacity).astype(jnp.int32)
+    with exchange_scope("fm.gather"):
+        chips_over = lax.psum(local, (DATA_AXIS, ROW_AXIS))
+    return chips_over > 0
 
 
 def capacity_for(ids_per_chip: int, row_parallel: int, capacity_factor: float) -> int:
@@ -141,7 +144,8 @@ def routed_gather(
     send_ids = send_ids.at[sorted_owner, send_pos].set(sorted_ids, mode="drop")
 
     # Exchange requests; serve locally; exchange answers.
-    recv_ids = lax.all_to_all(send_ids, ROW_AXIS, 0, 0, tiled=True)  # [R, C]
+    with exchange_scope():
+        recv_ids = lax.all_to_all(send_ids, ROW_AXIS, 0, 0, tiled=True)  # [R, C]
     local = recv_ids - base
     ok = (local >= 0) & (local < shard_rows)  # sentinels fail
     safe = jnp.where(ok, local, 0)
@@ -152,7 +156,8 @@ def routed_gather(
     else:
         served = table_shard[safe]
     served = served * ok[..., None].astype(served.dtype)
-    recv_rows = lax.all_to_all(served, ROW_AXIS, 0, 0, tiled=True)  # [R, C, D]
+    with exchange_scope():
+        recv_rows = lax.all_to_all(served, ROW_AXIS, 0, 0, tiled=True)  # [R, C, D]
 
     # recv_rows[s, c] answers MY request in send slot [s, c]; invert the
     # bucket placement, then the sort.
@@ -240,12 +245,15 @@ def routed_update(
     send_ids = send_ids.at[sorted_owner, send_pos].set(sorted_ids, mode="drop")
     send_g = send_g.at[sorted_owner, send_pos].set(sorted_g, mode="drop")
 
-    recv_ids = lax.all_to_all(send_ids, ROW_AXIS, 0, 0, tiled=True)  # [R, C]
-    recv_g = lax.all_to_all(send_g, ROW_AXIS, 0, 0, tiled=True)  # [R, C, D]
+    with exchange_scope():
+        recv_ids = lax.all_to_all(send_ids, ROW_AXIS, 0, 0, tiled=True)  # [R, C]
+        recv_g = lax.all_to_all(send_g, ROW_AXIS, 0, 0, tiled=True)  # [R, C, D]
     # Data-axis union: every replica of this row shard must apply the SAME
     # update, so gather all data-peers' received contributions.
-    all_ids = lax.all_gather(recv_ids.reshape(-1), DATA_AXIS, tiled=True)
-    all_g = lax.all_gather(recv_g.reshape(-1, D), DATA_AXIS, tiled=True)
+    recv_ids, recv_g = recv_ids.reshape(-1), recv_g.reshape(-1, D)
+    with exchange_scope():
+        all_ids = lax.all_gather(recv_ids, DATA_AXIS, tiled=True)
+        all_g = lax.all_gather(recv_g, DATA_AXIS, tiled=True)
     guids, ggsum = dedup_rows(all_ids, all_g, num_rows_global)
 
     if fused:
@@ -277,5 +285,7 @@ def routed_update(
         table_shard, accum_shard = apply_shard_adagrad(
             table_shard, accum_shard, guids, ggsum, lr, base, decay=decay
         )
-    overflow = lax.psum(overflow.astype(jnp.int32), (DATA_AXIS, ROW_AXIS)) > 0
-    return table_shard, accum_shard, overflow
+    overflow = overflow.astype(jnp.int32)
+    with exchange_scope():
+        chips_over = lax.psum(overflow, (DATA_AXIS, ROW_AXIS))
+    return table_shard, accum_shard, chips_over > 0

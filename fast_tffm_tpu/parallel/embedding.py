@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from fast_tffm_tpu.optim import AdagradState, dedup_rows
+from fast_tffm_tpu.parallel.exchange import exchange_scope
 from fast_tffm_tpu.parallel.mesh import DATA_AXIS, ROW_AXIS, axis_size
 
 __all__ = [
@@ -114,12 +115,14 @@ def sharded_gather(table_shard: jax.Array, ids: jax.Array) -> jax.Array:
     # Ids are int32 and tiny next to D-wide rows; gather all ROW peers' ids,
     # serve the rows we own, and reduce-scatter each peer its answers (each
     # row is owned by exactly one shard, so the sum IS the row).
-    all_ids = lax.all_gather(ids, ROW_AXIS, tiled=True)  # [R*B_local, N]
+    with exchange_scope():
+        all_ids = lax.all_gather(ids, ROW_AXIS, tiled=True)  # [R*B_local, N]
     local = all_ids - base
     owned = (local >= 0) & (local < shard_rows)
     local = jnp.where(owned, local, 0)
     rows = table_shard[local] * owned[..., None].astype(table_shard.dtype)
-    return lax.psum_scatter(rows, ROW_AXIS, scatter_dimension=0, tiled=True)
+    with exchange_scope():
+        return lax.psum_scatter(rows, ROW_AXIS, scatter_dimension=0, tiled=True)
 
 
 def sharded_sparse_adagrad_update(
@@ -151,8 +154,9 @@ def sharded_sparse_adagrad_update(
             table_shard, accum_shard, guids, ggsum, lr, 0, decay=decay
         )
     uids, gsum = dedup_rows(ids.reshape(-1), row_grads.reshape(-1, D), num_rows_global)
-    all_uids = lax.all_gather(uids, (DATA_AXIS, ROW_AXIS), tiled=True)  # [P*M]
-    all_gsum = lax.all_gather(gsum, (DATA_AXIS, ROW_AXIS), tiled=True)  # [P*M, D]
+    with exchange_scope("fm.tail"):
+        all_uids = lax.all_gather(uids, (DATA_AXIS, ROW_AXIS), tiled=True)  # [P*M]
+        all_gsum = lax.all_gather(gsum, (DATA_AXIS, ROW_AXIS), tiled=True)  # [P*M, D]
     # Drop ids (>= num_rows_global, one per trailing slot of each peer's
     # dedup) collapse into one segment inside dedup_rows and are dropped
     # again below.
@@ -188,11 +192,13 @@ def packed_sharded_gather(
         in_range = (ids >= 0) & (ids < shard_logical_rows)
         rows = packed_gather(packed_shard, jnp.where(in_range, ids, 0), d)
         return rows * in_range[..., None].astype(rows.dtype)
-    all_ids = lax.all_gather(ids, ROW_AXIS, tiled=True)  # [R*B_local, N]
+    with exchange_scope("fm.gather"):
+        all_ids = lax.all_gather(ids, ROW_AXIS, tiled=True)  # [R*B_local, N]
     local, owned = owned_local_ids(all_ids, shard_logical_rows, 0)
     rows = packed_gather(packed_shard, local, d)
     rows = rows * owned[..., None].astype(rows.dtype)
-    return lax.psum_scatter(rows, ROW_AXIS, scatter_dimension=0, tiled=True)
+    with exchange_scope("fm.gather"):
+        return lax.psum_scatter(rows, ROW_AXIS, scatter_dimension=0, tiled=True)
 
 
 def packed_sharded_update(
@@ -226,8 +232,9 @@ def packed_sharded_update(
             packed_shard, accum_shard, ids, row_grads, lr
         )
     uids, gsum = dedup_rows(ids.reshape(-1), row_grads.reshape(-1, D), num_rows_global)
-    all_uids = lax.all_gather(uids, (DATA_AXIS, ROW_AXIS), tiled=True)
-    all_gsum = lax.all_gather(gsum, (DATA_AXIS, ROW_AXIS), tiled=True)
+    with exchange_scope():  # the caller's fm.tail
+        all_uids = lax.all_gather(uids, (DATA_AXIS, ROW_AXIS), tiled=True)
+        all_gsum = lax.all_gather(gsum, (DATA_AXIS, ROW_AXIS), tiled=True)
 
     # Past-the-end sentinel: phys = vp -> dropped by the packed scatter.
     local, _ = owned_local_ids(all_uids, shard_logical_rows, packed_shard.shape[0] * p)
@@ -274,8 +281,9 @@ def packed_sharded_dense_update(
         # 1×1 mesh: no combine, no owned mapping (batch ids are already
         # in-range logical ids) — this IS the single-device packed step.
         return update_fn(packed_shard, accum_shard, flat_ids, flat_g, lr)
-    all_ids = lax.all_gather(flat_ids, (DATA_AXIS, ROW_AXIS), tiled=True)
-    all_g = lax.all_gather(flat_g, (DATA_AXIS, ROW_AXIS), tiled=True)
+    with exchange_scope():  # the caller's fm.tail
+        all_ids = lax.all_gather(flat_ids, (DATA_AXIS, ROW_AXIS), tiled=True)
+        all_g = lax.all_gather(flat_g, (DATA_AXIS, ROW_AXIS), tiled=True)
     if one_shard:
         # One row shard, several data peers: the combine is needed but
         # every gathered id is owned — skip the identity owned mapping.
@@ -307,11 +315,13 @@ def fused_sharded_gather(
         in_range = (ids >= 0) & (ids < shard_logical_rows)
         rows = fused_gather(fused_shard, jnp.where(in_range, ids, 0), d)
         return rows * in_range[..., None].astype(rows.dtype)
-    all_ids = lax.all_gather(ids, ROW_AXIS, tiled=True)
+    with exchange_scope("fm.gather"):
+        all_ids = lax.all_gather(ids, ROW_AXIS, tiled=True)
     local, owned = owned_local_ids(all_ids, shard_logical_rows, 0)
     rows = fused_gather(fused_shard, local, d)
     rows = rows * owned[..., None].astype(rows.dtype)
-    return lax.psum_scatter(rows, ROW_AXIS, scatter_dimension=0, tiled=True)
+    with exchange_scope("fm.gather"):
+        return lax.psum_scatter(rows, ROW_AXIS, scatter_dimension=0, tiled=True)
 
 
 def fused_sharded_update(
@@ -344,8 +354,9 @@ def fused_sharded_update(
     one_shard = axis_size(ROW_AXIS) == 1
     if one_shard and axis_size(DATA_AXIS) == 1:
         return apply(fused_shard, flat_ids, flat_g)
-    all_ids = lax.all_gather(flat_ids, (DATA_AXIS, ROW_AXIS), tiled=True)
-    all_g = lax.all_gather(flat_g, (DATA_AXIS, ROW_AXIS), tiled=True)
+    with exchange_scope():  # the caller's fm.tail
+        all_ids = lax.all_gather(flat_ids, (DATA_AXIS, ROW_AXIS), tiled=True)
+        all_g = lax.all_gather(flat_g, (DATA_AXIS, ROW_AXIS), tiled=True)
     if one_shard:
         return apply(fused_shard, all_ids, all_g)
     local, _ = owned_local_ids(all_ids, shard_logical_rows, fused_shard.shape[0] * p)

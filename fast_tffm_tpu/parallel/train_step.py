@@ -31,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from fast_tffm_tpu.models.base import Batch
 from fast_tffm_tpu.optim import AdagradState, dense_adagrad_update
 from fast_tffm_tpu.parallel.embedding import sharded_gather, sharded_sparse_adagrad_update
+from fast_tffm_tpu.parallel.exchange import exchange_scope
 from fast_tffm_tpu.parallel.mesh import (
     DATA_AXIS,
     ROW_AXIS,
@@ -38,7 +39,7 @@ from fast_tffm_tpu.parallel.mesh import (
     replicated,
     table_sharding,
 )
-from fast_tffm_tpu.trainer import TrainState, init_state
+from fast_tffm_tpu.trainer import TrainState, init_dense_state, init_table_state
 
 __all__ = [
     "init_sharded_state",
@@ -271,41 +272,62 @@ def _pad_model_vocab(model, mesh: Mesh, pack: int = 1):
     return dataclasses.replace(model, vocabulary_size=padded)
 
 
+def _sharded_table_init(model, mesh: Mesh, init_accumulator_value: float, accumulator: str):
+    """The jitted ``table key -> (table, AdagradState)`` of
+    ``init_sharded_state``: ``trainer.init_table_state`` with both arrays born
+    row-sharded (``model`` already padded to equal shards).  Its own function
+    so that a test or a rehearsal can compile it and read what a device is
+    asked to hold."""
+    ts = table_sharding(mesh)
+    return jax.jit(
+        lambda key: init_table_state(model, key, init_accumulator_value, accumulator),
+        out_shardings=(ts, AdagradState(ts)),
+    )
+
+
 def init_sharded_state(
     model, mesh: Mesh, key, init_accumulator_value: float = 0.1,
     accumulator: str = "element", table_layout: str = "rows",
 ):
-    """init_state placed with row-sharded table and replicated dense params.
+    """init_state with row-sharded table and replicated dense params, each
+    shard DRAWN ON THE DEVICE THAT HOLDS IT: table and accumulator come out
+    of one jitted construction whose ``out_shardings`` are theirs, so that no
+    device ever holds a whole [V, D] array (at 2^27 x 17 the table is 8.5
+    GiB and the accumulator as much again: no one chip's).  The values are
+    those of ``trainer.init_state`` on the padded model element for element
+    (the partitionable threefry draws a shard of the one-device draw; the
+    small dense leaves are drawn as there, outside the jit, and replicated).
 
     ``table_layout='packed'`` stores the shards lane-packed
-    ([VP_shard, 128] each — ops/packed_table.py); the shard-aligned vocab
+    ([VP_shard, 128] each — ops/packed_table.py), packed per shard on its
+    own device (``pack_sharded_on_device``); the shard-aligned vocab
     padding makes the global packed array exactly the concatenation of the
     per-shard packings.  ``accumulator='row'`` with the packed layout
     packs the [V, 1] accumulator as [VP_shard, P] scalar slots;
     ``accumulator='fused'`` stores the row accumulator inside the table's
     own tile rows ([VPf_shard, 128], stride D+1 — the 2-random-op RMW)."""
-    if table_layout == "packed":
-        from fast_tffm_tpu.trainer import pack_state
-
-        fused = accumulator == "fused"
-        model, _, _ = packed_shard_meta(model, mesh, fused=fused)
-        state = pack_state(
-            init_state(model, key, init_accumulator_value, accumulator),
-            init_accumulator_value,
-            fused=fused,
-        )
+    packed = table_layout == "packed"
+    fused = accumulator == "fused"
+    if packed:
+        padded, _, _ = packed_shard_meta(model, mesh, fused=fused)
     else:
-        model = _pad_model_vocab(model, mesh)
-        state = init_state(model, key, init_accumulator_value, accumulator)
-    ts = table_sharding(mesh)
-    rep = replicated(mesh)
-    return TrainState(
-        table=jax.device_put(state.table, ts),
-        table_opt=AdagradState(jax.device_put(state.table_opt.accum, ts)),
-        dense=jax.tree.map(lambda x: jax.device_put(x, rep), state.dense),
-        dense_opt=jax.tree.map(lambda x: jax.device_put(x, rep), state.dense_opt),
-        step=jax.device_put(state.step, rep),
+        padded = _pad_model_vocab(model, mesh)
+    k_table, k_dense = jax.random.split(key)  # init_state's two keys
+    table, table_opt = _sharded_table_init(
+        padded, mesh, init_accumulator_value, accumulator
+    )(k_table)
+    dense, dense_opt = jax.device_put(
+        init_dense_state(padded, k_dense, init_accumulator_value), replicated(mesh)
     )
+    state = TrainState(
+        table=table, table_opt=table_opt, dense=dense, dense_opt=dense_opt,
+        step=jax.device_put(jnp.zeros((), jnp.int32), replicated(mesh)),
+    )
+    if packed:
+        state = pack_sharded_on_device(
+            state, model, mesh, init_accumulator_value, fused=fused
+        )
+    return state
 
 
 def packed_shard_meta(model, mesh: Mesh, fused: bool = False):
@@ -625,7 +647,10 @@ def make_sharded_train_step(
                     - scores * batch.labels
                     + jnp.log1p(jnp.exp(-jnp.abs(scores)))
                 )
-                denom = jnp.maximum(lax.psum(jnp.sum(batch.weights), _BOTH), 1.0)
+                weight = jnp.sum(batch.weights)
+                with exchange_scope():
+                    weight = lax.psum(weight, _BOTH)
+                denom = jnp.maximum(weight, 1.0)
                 data_loss = jnp.sum(per * batch.weights) / denom
                 reg = model.regularization(rows, dense, batch)
                 return data_loss + reg, data_loss
@@ -743,13 +768,15 @@ def make_sharded_train_step(
             table, accum, g_dense, data_loss_local = allgather_branch()
             overflowed = jnp.asarray(False)
         if jax.tree.leaves(dense):
-            g_dense = lax.psum(g_dense, _BOTH)
+            with exchange_scope("fm.tail"):
+                g_dense = lax.psum(g_dense, _BOTH)
             dense, dense_acc = dense_adagrad_update(
                 dense, AdagradState(dense_acc), g_dense, learning_rate,
                 decay=decay,
             )
             dense_acc = dense_acc.accum
-        data_loss = lax.psum(data_loss_local, _BOTH)
+        with exchange_scope("fm.loss"):
+            data_loss = lax.psum(data_loss_local, _BOTH)
         return table, accum, dense, dense_acc, data_loss, overflowed.astype(jnp.int32)
 
     dense_spec = jax.tree.map(lambda _: P(), model.init_dense(jax.random.key(0)))
@@ -857,7 +884,8 @@ def make_sharded_predict_step(
         # Replicate the (tiny, [B]) score vector so the result is fetchable
         # on every process of a multi-host mesh — a P(('data','row'))-sharded
         # output would span non-addressable devices there.
-        return lax.all_gather(scores, _BOTH, tiled=True)
+        with exchange_scope():
+            return lax.all_gather(scores, _BOTH, tiled=True)
 
     dense_spec = jax.tree.map(lambda _: P(), model.init_dense(jax.random.key(0)))
     mapped = shard_map(

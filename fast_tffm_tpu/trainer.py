@@ -60,6 +60,20 @@ class TrainState(NamedTuple):
     step: jax.Array  # i64 scalar
 
 
+def init_table_state(model, key: jax.Array, init_accumulator_value: float, accumulator: str):
+    """(table, its Adagrad state) of ``init_state``, from ITS table key: the
+    half the sharded init draws shard by shard under one jit
+    (parallel/train_step.init_sharded_state)."""
+    table = model.init_table(key)
+    return table, init_table_adagrad(table, init_accumulator_value, accumulator)
+
+
+def init_dense_state(model, key: jax.Array, init_accumulator_value: float):
+    """(dense params, their Adagrad state) of ``init_state``, from its dense key."""
+    dense = model.init_dense(key)
+    return dense, init_adagrad(dense, init_accumulator_value)
+
+
 def init_state(
     model,
     key: jax.Array,
@@ -71,13 +85,13 @@ def init_state(
     measured speed-neutral — see optim.py).  The dense (MLP) path is
     always element-wise."""
     k1, k2 = jax.random.split(key)
-    table = model.init_table(k1)
-    dense = model.init_dense(k2)
+    table, table_opt = init_table_state(model, k1, init_accumulator_value, accumulator)
+    dense, dense_opt = init_dense_state(model, k2, init_accumulator_value)
     return TrainState(
         table=table,
-        table_opt=init_table_adagrad(table, init_accumulator_value, accumulator),
+        table_opt=table_opt,
         dense=dense,
-        dense_opt=init_adagrad(dense, init_accumulator_value),
+        dense_opt=dense_opt,
         step=jnp.zeros((), jnp.int32),
     )
 

@@ -577,6 +577,7 @@ def _run_training(
     step_hook=None,
     row_dim=0,
     tail_profile=None,
+    exchange_profile=None,
     mark_touched=None,
     start_cursor=None,
     rollback=None,
@@ -623,6 +624,10 @@ def _run_training(
     the latter on rows ``segment_sum_lanes`` wide; ``tail_permutation``; the
     sweep's ``tail_block_lanes``; empty on every other layout) rides the
     step's ``kind=profile`` record beside ``row_dim``.
+    ``exchange_profile`` (dist_train's: ``mesh`` {data, row}, ``shard_rows``,
+    ``lookup``, ``exchange_bytes_per_step`` = the payload bytes a chip sends
+    and receives in the step's collectives, parallel/exchange.py) rides that
+    record too, and its byte count every ``kind=train`` record.
 
     ``datastats_ids`` (optional ``batch -> device ids``) lets the sampled
     id-statistics collector read a device-cache batch's ids straight off
@@ -740,6 +745,10 @@ def _run_training(
             ids_fn=datastats_ids,
         )
     accum_cols = max(1, row_dim) if cfg.adagrad_accumulator == "element" else 1
+    exchange_counter = (
+        {"exchange_bytes_per_step": exchange_profile["exchange_bytes_per_step"]}
+        if exchange_profile else {}
+    )
 
     def _stage_step_profile(b, parsed):
         """First-dispatch capture: abstract shapes (before donation) plus
@@ -783,6 +792,7 @@ def _run_training(
                 "tail_duplicates": None, "tail_permutation": None,
                 "tail_block_lanes": None, **(tail_profile or {}),
             },
+            **(exchange_profile or {}),
         )
 
     # Pod liveness: this host's heartbeat (armed at bring-up) starts
@@ -1084,6 +1094,7 @@ def _run_training(
                         examples_per_sec_per_chip=round(rate / n_chips, 1),
                         **stage_ms,
                         **extra,
+                        **exchange_counter,
                     )
                     if input_stats is not None:
                         rec = input_stats.drain()
@@ -1700,6 +1711,39 @@ def _device_cached_input(cfg: Config, model, max_nnz: int, log, body=None):
     )
 
 
+def _exchange_profile(cfg, model, mesh, step_fn, state, max_nnz, steps_per_call=1) -> dict:
+    """What the sharded step says of its exchange, once: the mesh, the rows a
+    shard holds, the lookup, and the payload bytes a chip sends and receives
+    in one step's collectives (``parallel.exchange.exchange_bytes`` of the
+    step traced at the run's shapes; a fused call's K steps divided out)."""
+    from fast_tffm_tpu.parallel.exchange import exchange_bytes
+    from fast_tffm_tpu.parallel.mesh import ROW_AXIS
+    from fast_tffm_tpu.parallel.train_step import packed_shard_meta
+
+    lead = (steps_per_call,) if steps_per_call > 1 else ()
+    b = cfg.batch_size
+    spec = lambda dtype, *shape: jax.ShapeDtypeStruct(lead + shape, dtype)
+    batch = Batch(
+        labels=spec(np.float32, b), ids=spec(np.int32, b, max_nnz),
+        vals=spec(np.float32, b, max_nnz),
+        fields=spec(np.int32, b, max_nnz if model.uses_fields else 0),
+        weights=spec(np.float32, b),
+    )
+    abstract_state = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
+    if cfg.table_layout == "packed":  # the table's leading dim is physical there
+        _, shard_rows, _ = packed_shard_meta(
+            model, mesh, fused=cfg.adagrad_accumulator == "fused"
+        )
+    else:
+        shard_rows = state.table.shape[0] // mesh.shape[ROW_AXIS]
+    return dict(
+        mesh={k: int(v) for k, v in mesh.shape.items()},
+        shard_rows=int(shard_rows),
+        lookup=cfg.lookup,
+        exchange_bytes_per_step=exchange_bytes(step_fn, abstract_state, batch) // max(1, steps_per_call),
+    )
+
+
 def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_hook=None):
     """Mesh-distributed training — the reference's `dist_train` mode.
 
@@ -1882,6 +1926,9 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
                 ],
             },
         )
+    # With device_cache the scan lives in the cached wrapper below
+    # (it slices resident batches); the raw SPMD step stays per-batch.
+    step_k = 1 if cfg.device_cache else cfg.steps_per_call
     step_fn = make_sharded_train_step(
         model, cfg.learning_rate, mesh,
         lookup=cfg.lookup, capacity_factor=cfg.lookup_capacity_factor,
@@ -1889,15 +1936,22 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
         packed_update=cfg.packed_update,
         accumulator=cfg.adagrad_accumulator,
         compact_cap=cfg.packed_compact_cap,
-        # With device_cache the scan lives in the cached wrapper below
-        # (it slices resident batches); the raw SPMD step stays per-batch.
-        steps_per_call=(1 if cfg.device_cache else cfg.steps_per_call),
+        steps_per_call=step_k,
         adagrad_decay=cfg.online_adagrad_decay,
     )
     predict_step = make_sharded_predict_step(
         model, mesh, lookup=cfg.lookup, capacity_factor=cfg.lookup_capacity_factor,
         overflow_mode=cfg.lookup_overflow, table_layout=cfg.table_layout,
         accumulator=cfg.adagrad_accumulator,
+    )
+    exchange_profile = _exchange_profile(
+        cfg, model, mesh, step_fn, state, max_nnz, steps_per_call=step_k
+    )
+    log(
+        "exchange: {lookup} lookup, {shard_rows} rows a shard, "
+        "{exchange_bytes_per_step} bytes a chip sends and receives a step".format(
+            **exchange_profile
+        )
     )
     dist_saveable = None
     if cfg.table_layout == "packed":
@@ -2237,6 +2291,7 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
         saveable=dist_saveable,
         step_hook=step_hook,
         row_dim=model.row_dim,
+        exchange_profile=exchange_profile,
         mark_touched=mark_touched,
         runtime=runtime,
         mesh=mesh,

@@ -36,3 +36,88 @@ def test_the_named_harness_model_has_the_programs_row(config, cells, tmp_path):
     assert cell["model"].row_dim == program.row_dim
     assert cell["model"].reads_fields == bool(getattr(program, "uses_fields", False))
 
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_the_harness_draws_an_input_for_every_shipped_configuration(config, cells):
+    """``gen.rows_from_seed`` at the configuration's own vocabulary (2^27 rows
+    at ``fm16_criteo_row4``, where the draw's product passes 32 bits): 64 rows
+    are drawn, every id inside its field's range and so inside the table."""
+    import json
+
+    import numpy as np
+    from harness import gen
+
+    cfg = json.load(open(os.path.join(BENCH, "configs", config + ".json")))
+    fields, vocab = int(cfg["fields"]), int(cfg["vocabulary_size"])
+    assert vocab == int(cfg["ini"]["General"]["vocabulary_size"]) and fields == int(cfg["ini"]["Train"]["max_nnz"])
+    labels, ids, vals = gen.rows_from_seed(3000003599, 64, fields, vocab)
+    assert ids.shape == vals.shape == (64, fields) and labels.shape == (64,) and ids.dtype == np.int32
+    bounds = np.linspace(0, vocab, fields + 1).astype(np.int64)
+    assert (ids >= bounds[:-1]).all() and (ids < bounds[1:]).all()
+    assert np.isfinite(vals).all() and set(np.unique(labels)) <= {0.0, 1.0}
+
+
+class _NoExchange:
+    """``lax`` as parallel/embedding sees it, its gather over both mesh axes
+    handing each chip its own part and nothing of its peers': the update's
+    exchange of unique ids and summed gradients left out."""
+
+    def __getattr__(self, name):
+        from jax import lax
+
+        return getattr(lax, name)
+
+    def all_gather(self, x, axis_name, **kw):
+        import math
+
+        import jax.numpy as jnp
+        from jax import lax
+
+        if not isinstance(axis_name, tuple):  # the lookup's exchange of ids stays
+            return lax.all_gather(x, axis_name, **kw)
+        peers = math.prod(lax.axis_size(a) for a in axis_name) - 1
+        return jnp.concatenate([x] + [jnp.zeros_like(x)] * peers)
+
+
+@pytest.mark.parametrize("exchange", ["sound", "left_out"])
+def test_three_steps_of_dist_train_follow_the_plain_reference(exchange, cells, tmp_path, monkeypatch):
+    """Steps 1-3 of ``training.dist_train`` on a {data: 1, row: 4} mesh of
+    virtual devices against ``harness/models/fm2`` + ``reference.train_steps``
+    on rows drawn from a seed, at a toy size of ``fm16_criteo_row4``: the three
+    gaps are under the mix's limits; with the gradients' exchange between the
+    shards left out they are over them."""
+    import json
+    import shutil
+    import time
+
+    import jax
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four virtual devices")
+    from harness import train
+
+    bench = tmp_path / "bench"
+    shutil.copytree(os.path.join(BENCH, "metrics"), bench / "metrics")
+    for d in ("configs", "traffic"):
+        (bench / d).mkdir()
+    cfg = json.load(open(os.path.join(BENCH, "configs", "fm16_criteo_row4.json")))
+    cfg["ini"]["General"]["vocabulary_size"] = 1 << 14
+    cfg["ini"]["Train"].update(batch_size=512, thread_num=2)
+    json.dump(cfg, open(bench / "configs" / "fm16_criteo_row4.json", "w"))
+    mix = json.load(open(os.path.join(BENCH, "traffic", "dist_train_fmb.json")))
+    mix["file_batches"] = 8
+    json.dump(mix, open(bench / "traffic" / "dist_train_fmb.json", "w"))
+    if exchange == "left_out":
+        from fast_tffm_tpu.parallel import embedding
+
+        monkeypatch.setattr(embedding, "lax", _NoExchange())
+    cell = cells.load_cell("fm16_criteo_row4.dist_train_fmb", str(bench))
+    r = train.run(cell, 3000003511, 0.2, False, time.time(), require_chip=False, workroot=str(tmp_path))
+    assert r["device"]["count"] == 4 and set(r["compared"]) == {"loss_gap", "grad1_norm_gap", "delta3_norm_gap"}
+    over = {k: c["value"] > c["limit"] for k, c in r["compared"].items()}
+    if exchange == "sound":
+        assert r["correct"] is True and r["failed"] == 0 and not any(over.values())
+    else:
+        assert r["correct"] is False and over["grad1_norm_gap"] and over["delta3_norm_gap"]
+        assert r["compared"]["grad1_norm_gap"]["value"] > 10 * r["compared"]["grad1_norm_gap"]["limit"]

@@ -511,3 +511,192 @@ def test_impossible_overflow_still_counts_zero():
     )
     state, loss, overflowed = step(state, b)
     assert int(overflowed) == 0 and np.isfinite(float(loss))
+
+
+# --- PR 35: the state drawn shard by shard, the exchange named and counted --
+
+V_PAD = 1001  # needs padding on every mesh below, under both layouts
+
+
+@pytest.mark.parametrize("layout", ["rows", "packed"])
+@pytest.mark.parametrize("accumulator", ["element", "row"])
+@pytest.mark.parametrize("mesh_shape", [(1, 4), (2, 2), (4, 1)], ids=lambda s: f"data{s[0]}xrow{s[1]}")
+def test_init_sharded_state_is_the_one_device_draw(mesh_shape, accumulator, layout):
+    """Table, accumulator and dense leaves of init_sharded_state are
+    trainer.init_state's on the padded model BIT FOR BIT (the packed layout:
+    its pack_state), padded rows included, with the table row-sharded."""
+    from fast_tffm_tpu.parallel.train_step import _pad_model_vocab, packed_shard_meta
+    from fast_tffm_tpu.trainer import pack_state
+
+    model = DeepFMModel(vocabulary_size=V_PAD, num_fields=3, factor_num=4, hidden_dims=(8,))
+    mesh = make_mesh(*mesh_shape)
+    got = init_sharded_state(model, mesh, jax.random.key(7), 0.25, accumulator, table_layout=layout)
+    if layout == "packed":
+        padded, _, _ = packed_shard_meta(model, mesh)
+        want = pack_state(init_state(padded, jax.random.key(7), 0.25, accumulator), 0.25)
+    else:
+        padded = _pad_model_vocab(model, mesh)
+        want = init_state(padded, jax.random.key(7), 0.25, accumulator)
+    assert padded.vocabulary_size >= V_PAD and (mesh_shape[1] == 1 or padded.vocabulary_size > V_PAD)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype, path
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), err_msg=jax.tree_util.keystr(path))
+    assert got.table.sharding.spec == jax.sharding.PartitionSpec("row", None)
+    assert {s.data.shape[0] for s in got.table.addressable_shards} == {got.table.shape[0] // mesh_shape[1]}
+    assert got.dense["w0"].sharding.is_fully_replicated
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 4), (2, 2)], ids=lambda s: f"data{s[0]}xrow{s[1]}")
+def test_init_sharded_state_asks_no_device_for_a_whole_table(mesh_shape):
+    """Compiled, the construction behind init_sharded_state hands each device
+    its shard of table and accumulator and nothing table-shaped besides: its
+    outputs are the shard's bytes, and arguments + outputs + temporaries are
+    the row-th part of what the one-device draw asks (5% of margin), at
+    V = 2^16 rows of 17."""
+    from fast_tffm_tpu.parallel.train_step import _sharded_table_init
+    from fast_tffm_tpu.trainer import init_table_state
+
+    vocab, rows = 1 << 16, mesh_shape[1]
+    model = FMModel(vocabulary_size=vocab, factor_num=16)
+    key = jax.random.split(jax.random.key(0))[0]
+
+    def asked(jitted):
+        m = jitted.lower(key).compile().memory_analysis()
+        return m.output_size_in_bytes, m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes
+
+    one_out, one_all = asked(jax.jit(lambda k: init_table_state(model, k, 0.1, "element")))
+    out, everything = asked(_sharded_table_init(model, make_mesh(*mesh_shape), 0.1, "element"))
+    whole = vocab * 17 * 4
+    assert one_out >= 2 * whole  # the premise: one device, both arrays whole
+    assert 2 * whole // rows <= out <= 2 * whole // rows + 64
+    assert everything <= 1.05 * one_all / rows
+
+
+_COLLECTIVES = {"all_gather", "reduce_scatter", "all_to_all", "psum", "psum_invariant", "ppermute"}
+_HLO_COLLECTIVE = re.compile(
+    r"= \S+ (?:all-gather|all-reduce|all-to-all|reduce-scatter|collective-permute)(?:-start)?\("
+)
+
+
+def _leaf_eqns(jaxpr):
+    """Every equation that holds no jaxpr of its own, at any depth."""
+    from fast_tffm_tpu.parallel.exchange import _sub_jaxprs
+
+    for eqn in jaxpr.eqns:
+        inner = list(_sub_jaxprs(eqn))
+        if not inner:
+            yield eqn
+        for j in inner:
+            yield from _leaf_eqns(j)
+
+
+@pytest.mark.parametrize(
+    "lookup,overflow_mode,layout,accumulator",
+    [
+        ("allgather", "abort", "rows", "element"),
+        ("alltoall", "abort", "rows", "element"),
+        ("alltoall", "fallback", "rows", "element"),
+        ("allgather", "abort", "packed", "element"),
+        ("alltoall", "fallback", "packed", "row"),
+        ("allgather", "abort", "packed", "fused"),
+    ],
+)
+def test_every_collective_of_the_sharded_step_is_named_fm_exchange(lookup, overflow_mode, layout, accumulator):
+    """As traced: an equation runs under ``fm.exchange`` exactly when it is a
+    collective (the scope names the exchange and nothing of the local work),
+    nested in fm.gather, fm.tail or fm.loss.  As compiled: every collective
+    instruction carries the scope in its op_name."""
+    model = DeepFMModel(vocabulary_size=V, num_fields=6, factor_num=4, hidden_dims=(8,))
+    mesh = make_mesh(2, 4)
+    state = init_sharded_state(model, mesh, jax.random.key(0), 0.1, accumulator, table_layout=layout)
+    b = _batches(np.random.default_rng(0), n=1, B=256)[0]
+    step = make_sharded_train_step(
+        model, 0.1, mesh, lookup=lookup, overflow_mode=overflow_mode, capacity_factor=0.5,
+        table_layout=layout, accumulator=accumulator,
+    )
+    eqns = list(_leaf_eqns(jax.make_jaxpr(step)(state, b).jaxpr))
+    named = [e for e in eqns if "fm.exchange" in str(e.source_info.name_stack)]
+    collectives = [e for e in eqns if e.primitive.name in _COLLECTIVES]
+    assert collectives and {id(e) for e in named} == {id(e) for e in collectives}
+    for e in collectives:
+        assert re.search(r"fm\.(gather|tail|loss)\)*/fm\.exchange$", str(e.source_info.name_stack)), e.source_info.name_stack
+    if lookup == "alltoall":
+        assert any(e.primitive.name == "all_to_all" for e in collectives)
+    hlo = step.lower(state, b).compile().as_text()
+    lines = [ln for ln in hlo.splitlines() if _HLO_COLLECTIVE.search(ln)]
+    assert lines and all("fm.exchange" in ln for ln in lines)
+
+
+@pytest.mark.parametrize(
+    "mesh_shape,lookup,want",
+    [
+        # ids i32[2,3] all-gathered over row=4 (24 B x 2 x 3), rows f32[8,3,5]
+        # reduce-scattered (480 B x 2 x 3/4), the update's unique ids i32[6] and
+        # sums f32[6,5] all-gathered over 4 chips (24 and 120 B x 2 x 3), two
+        # scalar all-reduces (the batch's weight, the loss: 4 B x 4 x 3/4).
+        ((1, 4), "allgather", 144 + 720 + 144 + 720 + 12 + 12),
+        # row=1: the lookup crosses no chip; the update's all-gathers do.
+        ((4, 1), "allgather", 144 + 720 + 12 + 12),
+        # row=2: ids 24 B x 2 x 1, rows f32[4,3,5] 240 B x 2 x 1/2; update over 4 chips.
+        ((2, 2), "allgather", 48 + 240 + 144 + 720 + 12 + 12),
+        # routed, capacity 8 a destination (capacity_for's floor): ids i32[4,8]
+        # and rows f32[4,8,5] all-to-all (128 and 640 B x 2 x 3/4) for the lookup
+        # and as much again for the update, the overflow flag's all-reduce (12)
+        # beside the two scalars.
+        ((1, 4), "alltoall", 2 * (192 + 960) + 12 + 12 + 12),
+    ],
+)
+def test_exchange_bytes_are_the_collectives_operands_by_hand(mesh_shape, lookup, want):
+    from fast_tffm_tpu.parallel.exchange import exchange_bytes
+
+    model = FMModel(vocabulary_size=V, factor_num=4)
+    mesh = make_mesh(*mesh_shape)
+    state = init_sharded_state(model, mesh, jax.random.key(0))
+    B, N = 8, 3
+    sds = jax.ShapeDtypeStruct
+    batch = Batch(
+        labels=sds((B,), jnp.float32), ids=sds((B, N), jnp.int32), vals=sds((B, N), jnp.float32),
+        fields=sds((B, 0), jnp.int32), weights=sds((B,), jnp.float32),
+    )
+    step = make_sharded_train_step(model, 0.1, mesh, lookup=lookup)
+    assert exchange_bytes(step, state, batch) == want
+    # A fused call of K steps moves K times as much; the fallback's cond
+    # counts the routed branch, not both.
+    if lookup == "allgather":
+        k_step = make_sharded_train_step(model, 0.1, mesh, steps_per_call=3)
+        k_batch = jax.tree.map(lambda x: sds((3,) + x.shape, x.dtype), batch)
+        assert exchange_bytes(k_step, state, k_batch) == 3 * want
+
+
+@pytest.mark.parametrize("steps_per_call", [1, 2])
+def test_dist_train_records_what_its_exchange_moves(tmp_path, steps_per_call):
+    """dist_train says once at start-up, on its kind=profile record and on
+    every kind=train record how many bytes a chip sends and receives in one
+    step's collectives, worked out here by hand for a 1x4 mesh: 16 rows a
+    chip x 8 ids, rows of 5 float32."""
+    import json
+
+    from fast_tffm_tpu.config import Config
+    from fast_tffm_tpu.training import dist_train
+
+    f = tmp_path / "d.libsvm"
+    f.write_text("".join(f"{i % 2} " + " ".join(f"{(i * 7 + j * 13) % 96}:1.0" for j in range(8)) + "\n" for i in range(256)))
+    cfg = Config(
+        model="fm", factor_num=4, vocabulary_size=96, model_file=str(tmp_path / "m.ckpt"),
+        train_files=(str(f),), epoch_num=1, batch_size=64, learning_rate=0.1, log_every=2,
+        data_parallel=1, row_parallel=4, steps_per_call=steps_per_call,
+        metrics_path=str(tmp_path / "metrics.jsonl"),
+    ).validate()
+    said = []
+    dist_train(cfg, log=said.append, mesh=make_mesh(1, 4))
+    ids, rows = 16 * 8 * 4, 16 * 8 * 5 * 4  # a chip's ids and its rows, in bytes
+    by_hand = ids * 6 + 4 * rows * 3 // 2 + ids * 6 + rows * 6 + 12 + 12
+    records = [json.loads(line) for line in (tmp_path / "metrics.jsonl").read_text().splitlines()]
+    trains = [r for r in records if r["kind"] == "train"]
+    assert trains and all(r["exchange_bytes_per_step"] == by_hand for r in trains)
+    profile = next(r for r in records if r["kind"] == "profile" and r["program"] == "train_step")
+    assert (profile["mesh"], profile["shard_rows"], profile["lookup"], profile["exchange_bytes_per_step"]) == (
+        {"data": 1, "row": 4}, 24, "allgather", by_hand)
+    assert "tail_form" in profile and "row_dim" in profile  # beside the tail's fields
+    assert any(str(s).startswith("exchange: allgather lookup, 24 rows a shard") and f"{by_hand} bytes" in str(s) for s in said)
