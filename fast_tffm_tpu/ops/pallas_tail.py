@@ -50,11 +50,11 @@ flag, same pattern as ops/pallas_anova.py).
 
 STATUS ON THE CHIP (TPU v5 lite, jax 0.9.0, libtpu 0.0.34).  The sweep
 compiles and runs (PR 30; tests/test_pallas_tail_chip_compile.py compiles
-it for a described v5e at the train cell's shapes): at ``fm8_criteo``'s
-shapes (2^26 rows of 9, 2,555,904 ids a step, 2.3M distinct) the kernel
-takes 38 ms inside the step (45 alone, with its work list) where the XLA
-row operations took 570, and table and accumulator come out as theirs
-(PERF.md §6 has the bitwise reading on a batch without repeats).  ``optim.sparse_adagrad_update`` takes
+it for a described v5e at the train cells' shapes): at ``fm8_criteo``'s
+(2^26 rows of 9, 2,555,904 ids a step, 2.3M distinct) the kernel takes 38
+ms inside the step (45 alone, with its work list) where the XLA row
+operations took 570 (PERF.md §6 has the bitwise reading on a batch without
+repeats); at D = 17 (PR 36: ``fm16_criteo_row4``'s row SHARD, 2^25 rows of 17 under all four chips' 2,555,904 slots, a quarter of them the shard's) 25.2 ms on each of four chips where the shard's gather-and-set took 770.  ``optim.sparse_adagrad_update`` takes
 it on a TPU where ``optim.rows_tail_form`` says the sweep costs less than
 the batch's row operations.  There is no kernel that moves a touched row by
 a DMA of its own (two stood here, for the rows and the fused layouts, until

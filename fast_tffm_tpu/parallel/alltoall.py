@@ -168,7 +168,6 @@ def routed_gather(
     return out.reshape(B, N, -1)
 
 
-@jax.named_scope("fm.tail")
 def routed_update(
     table_shard: jnp.ndarray,
     accum_shard: jnp.ndarray,
@@ -198,9 +197,13 @@ def routed_update(
     Per chip: dedup local occurrences, route each (id, summed grad) to its
     home shard over ROW (all_to_all, capacity C per destination), then
     all_gather the received buffers over DATA only — every replica of a
-    row shard sees the identical union of contributions, dedups it once
-    more, and applies Adagrad exactly once per row.  ICI bytes
-    ~ data·(R·C)·D ≈ data·slack·M·D instead of data·row·M·D.
+    row shard sees the identical union of contributions and applies Adagrad
+    exactly once per row: the rows layout through
+    ``embedding.apply_shard_adagrad``, whose tail sums a row's contributions
+    itself; the packed layouts after one more dedup of the union.  ICI bytes
+    ~ data·(R·C)·D ≈ data·slack·M·D instead of data·row·M·D.  Scopes: the
+    dedups stand under ``fm.dedup`` alone, the routing, its collectives
+    (``fm.tail/fm.exchange``) and the apply under ``fm.tail``.
 
     Returns (table, accum, overflow) — ``overflow`` is a GLOBAL flag
     (psum over both axes): any chip that had to drop contributions raises
@@ -229,63 +232,68 @@ def routed_update(
         raise ValueError("fused routed_update requires shard_logical_rows")
     D = row_grads.shape[-1]
     shard_rows = shard_logical_rows if packed else table_shard.shape[0]
-    base = lax.axis_index(ROW_AXIS) * shard_rows
     R = axis_size(ROW_AXIS)
     uids, gsum = dedup_rows(ids.reshape(-1), row_grads.reshape(-1, D), num_rows_global)
-    # Sentinel uids (>= num_rows_global) route to owner R: excluded from
-    # counts (bincount length R) and dropped by the out-of-range scatter.
-    owner = jnp.where(uids >= num_rows_global, R, uids // shard_rows)
-    order, sorted_owner, send_pos, _in_cap, overflow = _bucketize(owner, R, capacity)
-    sorted_ids = uids[order]
-    sorted_g = gsum[order]
+    with jax.named_scope("fm.tail"):
+        # Sentinel uids (>= num_rows_global) route to owner R: excluded from
+        # counts (bincount length R) and dropped by the out-of-range scatter.
+        owner = jnp.where(uids >= num_rows_global, R, uids // shard_rows)
+        order, sorted_owner, send_pos, _in_cap, overflow = _bucketize(owner, R, capacity)
+        sorted_ids = uids[order]
+        sorted_g = gsum[order]
 
-    sentinel = jnp.asarray(num_rows_global, uids.dtype)
-    send_ids = jnp.full((R, capacity), sentinel, dtype=uids.dtype)
-    send_g = jnp.zeros((R, capacity, D), gsum.dtype)
-    send_ids = send_ids.at[sorted_owner, send_pos].set(sorted_ids, mode="drop")
-    send_g = send_g.at[sorted_owner, send_pos].set(sorted_g, mode="drop")
+        sentinel = jnp.asarray(num_rows_global, uids.dtype)
+        send_ids = jnp.full((R, capacity), sentinel, dtype=uids.dtype)
+        send_g = jnp.zeros((R, capacity, D), gsum.dtype)
+        send_ids = send_ids.at[sorted_owner, send_pos].set(sorted_ids, mode="drop")
+        send_g = send_g.at[sorted_owner, send_pos].set(sorted_g, mode="drop")
 
-    with exchange_scope():
-        recv_ids = lax.all_to_all(send_ids, ROW_AXIS, 0, 0, tiled=True)  # [R, C]
-        recv_g = lax.all_to_all(send_g, ROW_AXIS, 0, 0, tiled=True)  # [R, C, D]
-    # Data-axis union: every replica of this row shard must apply the SAME
-    # update, so gather all data-peers' received contributions.
-    recv_ids, recv_g = recv_ids.reshape(-1), recv_g.reshape(-1, D)
-    with exchange_scope():
-        all_ids = lax.all_gather(recv_ids, DATA_AXIS, tiled=True)
-        all_g = lax.all_gather(recv_g, DATA_AXIS, tiled=True)
-    guids, ggsum = dedup_rows(all_ids, all_g, num_rows_global)
+        with exchange_scope():
+            recv_ids = lax.all_to_all(send_ids, ROW_AXIS, 0, 0, tiled=True)  # [R, C]
+            recv_g = lax.all_to_all(send_g, ROW_AXIS, 0, 0, tiled=True)  # [R, C, D]
+        # Data-axis union: every replica of this row shard must apply the SAME
+        # update, so gather all data-peers' received contributions.
+        recv_ids, recv_g = recv_ids.reshape(-1), recv_g.reshape(-1, D)
+        with exchange_scope():
+            all_ids = lax.all_gather(recv_ids, DATA_AXIS, tiled=True)
+            all_g = lax.all_gather(recv_g, DATA_AXIS, tiled=True)
+        overflow = overflow.astype(jnp.int32)
+        with exchange_scope():
+            chips_over = lax.psum(overflow, (DATA_AXIS, ROW_AXIS))
 
-    if fused:
-        from fast_tffm_tpu.ops.packed_table import (
-            apply_fused_update,
-            fused_rows_per_tile,
-        )
-        from fast_tffm_tpu.parallel.embedding import owned_local_ids
-
-        p = fused_rows_per_tile(D)
-        local, _ = owned_local_ids(guids, shard_rows, table_shard.shape[0] * p)
-        table_shard = apply_fused_update(
-            table_shard, local, ggsum, lr, packed_mode, compact_cap
-        )
-    elif packed:
-        from fast_tffm_tpu.ops.packed_table import PACKED_UPDATE_FNS, rows_per_tile
-        from fast_tffm_tpu.parallel.embedding import owned_local_ids
-
-        p = rows_per_tile(D)
-        # Unowned and sentinel ids map past the last physical row → drop.
-        local, _ = owned_local_ids(guids, shard_rows, table_shard.shape[0] * p)
-        update_fn = PACKED_UPDATE_FNS[packed_mode]
-        table_shard, accum_shard = update_fn(
-            table_shard, accum_shard, local, ggsum, lr
-        )
-    else:
+    if not packed:
+        # The rows layout: the shard's tail sums what several chips sent for
+        # one row itself, under the scopes it names (fm.dedup, fm.tail).
         from fast_tffm_tpu.parallel.embedding import apply_shard_adagrad
 
         table_shard, accum_shard = apply_shard_adagrad(
-            table_shard, accum_shard, guids, ggsum, lr, base, decay=decay
+            table_shard, accum_shard, all_ids, all_g, lr, decay=decay
         )
-    overflow = overflow.astype(jnp.int32)
-    with exchange_scope():
-        chips_over = lax.psum(overflow, (DATA_AXIS, ROW_AXIS))
+        return table_shard, accum_shard, chips_over > 0
+
+    from fast_tffm_tpu.parallel.embedding import owned_local_ids
+
+    guids, ggsum = dedup_rows(all_ids, all_g, num_rows_global)
+    with jax.named_scope("fm.tail"):
+        if fused:
+            from fast_tffm_tpu.ops.packed_table import (
+                apply_fused_update,
+                fused_rows_per_tile,
+            )
+
+            p = fused_rows_per_tile(D)
+            local, _ = owned_local_ids(guids, shard_rows, table_shard.shape[0] * p)
+            table_shard = apply_fused_update(
+                table_shard, local, ggsum, lr, packed_mode, compact_cap
+            )
+        else:
+            from fast_tffm_tpu.ops.packed_table import PACKED_UPDATE_FNS, rows_per_tile
+
+            p = rows_per_tile(D)
+            # Unowned and sentinel ids map past the last physical row → drop.
+            local, _ = owned_local_ids(guids, shard_rows, table_shard.shape[0] * p)
+            update_fn = PACKED_UPDATE_FNS[packed_mode]
+            table_shard, accum_shard = update_fn(
+                table_shard, accum_shard, local, ggsum, lr
+            )
     return table_shard, accum_shard, chips_over > 0

@@ -18,8 +18,11 @@ inside `shard_map`:
   update:  per-occurrence row gradients are deduped locally (sort +
            segment-sum, static shapes), all_gathered over BOTH axes
            (replacing Hogwild's racy async scatter with a deterministic
-           synchronous combine), re-deduped, and each shard applies sparse
-           Adagrad to the rows it owns — no second collective.
+           synchronous combine), and each shard applies the single-device
+           step's sparse Adagrad tail (optim.sparse_adagrad_update, in
+           the form optim.rows_tail_form chooses at the shard's shapes)
+           to the ids it owns; a row several chips touched is summed
+           there, once — no second dedup, no second collective.
 
 These functions run INSIDE a shard_map body (parallel/train_step.py).
 """
@@ -30,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from fast_tffm_tpu.optim import AdagradState, dedup_rows
+from fast_tffm_tpu.optim import AdagradState, dedup_rows, sparse_adagrad_update
 from fast_tffm_tpu.parallel.exchange import exchange_scope
 from fast_tffm_tpu.parallel.mesh import DATA_AXIS, ROW_AXIS, axis_size
 
@@ -59,32 +62,32 @@ def owned_local_ids(global_ids, shard_logical_rows: int, sentinel: int):
     return jnp.where(owned, local, sentinel), owned
 
 
-@jax.named_scope("fm.tail")
-def apply_shard_adagrad(table_shard, accum_shard, guids, ggsum, lr, base, decay=1.0):
-    """Adagrad on this shard's rows from globally-combined unique grads.
+def apply_shard_adagrad(table_shard, accum_shard, ids, grads, lr, decay=1.0):
+    """Adagrad on the rows of this shard among ``ids [M]`` (GLOBAL row ids,
+    repeated or not) with ``grads [M, D]``: ``optim.sparse_adagrad_update``,
+    the single-device step's tail, on the ids the shard owns.
 
-    The one place the sharded Adagrad math lives — the all-gather update
-    below and the all-to-all routed update (parallel/alltoall.py) must
-    stay numerically identical, and both end here.  ``guids`` out of this
-    shard's range (other shards' rows, dedup sentinels) drop.
-
-    ``decay`` γ < 1 is the lazy touched-row accumulator decay
-    (``[Online] adagrad_decay`` — optim.sparse_adagrad_update's sharded
-    twin); γ=1.0 is a trace-time branch to the exact classic program."""
-    from fast_tffm_tpu.optim import accum_sq
-
+    The one place the sharded rows layout's update ends: the all-gather
+    update below and the all-to-all routed update (parallel/alltoall.py)
+    both hand it the union of every chip's contributions, so every replica
+    of a row shard applies the same update.  Ids outside the shard's range
+    on EITHER side (other shards' rows, dedup sentinels from
+    ``num_rows_global`` up) become the drop id ``shard_rows``; no negative
+    id reaches a sort.  There is no Adagrad expression here: the form (the
+    in-place Pallas rows sweep, or the XLA rows: one accumulator gather,
+    one scatter-set, one scatter-add), the summing of a row's occurrences
+    (several chips may have touched it: Adagrad sees the fully summed
+    gradient exactly once), the element or row accumulator and the lazy
+    ``decay`` are that function's, chosen by ``optim.rows_tail_form`` from
+    the SHARD's shapes, and its scopes stand as it names them: the sort and
+    the permutation under ``fm.dedup``, the update under ``fm.tail``."""
     shard_rows = table_shard.shape[0]
-    local = guids - base
-    owned = (local >= 0) & (local < shard_rows)
-    local = jnp.where(owned, local, shard_rows)  # out of range → mode='drop'
-    acc_prev = accum_shard[jnp.minimum(local, shard_rows - 1)]
-    if decay != 1.0:
-        acc_prev = decay * acc_prev
-    acc_rows = acc_prev + accum_sq(accum_shard, ggsum)
-    upd_rows = table_shard[jnp.minimum(local, shard_rows - 1)] - lr * ggsum / jnp.sqrt(acc_rows)
-    accum_shard = accum_shard.at[local].set(acc_rows, mode="drop")
-    table_shard = table_shard.at[local].set(upd_rows, mode="drop")
-    return table_shard, accum_shard
+    with jax.named_scope("fm.dedup"):
+        local, _ = owned_local_ids(ids, shard_rows, sentinel=shard_rows)
+    table_shard, opt = sparse_adagrad_update(
+        table_shard, AdagradState(accum_shard), local, grads, lr, decay=decay
+    )
+    return table_shard, opt.accum
 
 
 @jax.named_scope("fm.gather")
@@ -136,35 +139,31 @@ def sharded_sparse_adagrad_update(
 ):
     """Sparse Adagrad on the local row shard from global per-occurrence grads.
 
-    Dedup happens twice: locally (cheap, shrinks the all_gather payload's
-    effective content) and again after gathering every chip's
-    contributions, because the same row id can be touched by several
-    micro-batches and Adagrad must see the fully summed gradient exactly
-    once (the determinism the reference's Hogwild explicitly gave up —
-    SURVEY.md §4.2).
+    Each chip dedups its own occurrences (cheap; the routed lookup sizes its
+    capacity by the same function, and the payload's content shrinks) and
+    all-gathers unique ids and summed gradients over both axes.  The same
+    row id can still be touched by several micro-batches, and Adagrad must
+    see the fully summed gradient exactly once (the determinism the
+    reference's Hogwild explicitly gave up — SURVEY.md §4.2): the shard's
+    tail sums a row's occurrences itself (``apply_shard_adagrad``), so no
+    second, global dedup stands between the exchange and it.
     """
     D = table_shard.shape[-1]
     if axis_size(ROW_AXIS) == 1 and axis_size(DATA_AXIS) == 1:
-        # 1×1 mesh: no peers to combine with — one dedup, straight to the
-        # shard apply (exactly the single-device step's structure).
-        guids, ggsum = dedup_rows(
-            ids.reshape(-1), row_grads.reshape(-1, D), num_rows_global
-        )
+        # 1×1 mesh: no peers to combine with — the single-device step's
+        # tail on the batch's occurrences as they are.
         return apply_shard_adagrad(
-            table_shard, accum_shard, guids, ggsum, lr, 0, decay=decay
+            table_shard, accum_shard, ids.reshape(-1), row_grads.reshape(-1, D),
+            lr, decay=decay,
         )
     uids, gsum = dedup_rows(ids.reshape(-1), row_grads.reshape(-1, D), num_rows_global)
     with exchange_scope("fm.tail"):
         all_uids = lax.all_gather(uids, (DATA_AXIS, ROW_AXIS), tiled=True)  # [P*M]
         all_gsum = lax.all_gather(gsum, (DATA_AXIS, ROW_AXIS), tiled=True)  # [P*M, D]
     # Drop ids (>= num_rows_global, one per trailing slot of each peer's
-    # dedup) collapse into one segment inside dedup_rows and are dropped
-    # again below.
-    guids, ggsum = dedup_rows(all_uids, all_gsum, num_rows_global)
-
-    base = lax.axis_index(ROW_AXIS) * table_shard.shape[0]
+    # dedup, zero gradients) lie above every shard's range and drop there.
     return apply_shard_adagrad(
-        table_shard, accum_shard, guids, ggsum, lr, base, decay=decay
+        table_shard, accum_shard, all_uids, all_gsum, lr, decay=decay
     )
 
 

@@ -48,6 +48,7 @@ __all__ = [
     "unpack_sharded_to_logical",
     "unpack_sharded_on_device",
     "make_sharded_train_step",
+    "shard_tail_ids",
     "make_sharded_predict_step",
     "make_global_batch",
     "make_global_superbatch",
@@ -557,6 +558,22 @@ def _make_gather(
             )
         ), cap, cap < m
     return (lambda table, ids: routed_gather(table, ids, cap)), cap, cap < m
+
+
+def shard_tail_ids(mesh: Mesh, ids_per_chip: int, lookup: str, capacity_factor: float) -> int:
+    """How many (id, gradient) slots the rows layout's shard tail
+    (``embedding.apply_shard_adagrad``) is handed a step, where a chip's
+    micro-batch holds ``ids_per_chip`` ids: every chip's under the
+    all-gather update; under the routed one the ``capacity`` slots each row
+    peer sends here, from every data peer (``_make_gather``'s sizing).  It is
+    the ``m`` ``optim.rows_tail_form`` sees at the shard, for whoever says
+    the tail's form aloud (training.dist_train)."""
+    slots = ids_per_chip
+    if lookup == "alltoall":
+        from fast_tffm_tpu.parallel.alltoall import capacity_for
+
+        slots = capacity_for(ids_per_chip, mesh.shape[ROW_AXIS], capacity_factor)
+    return mesh.shape[DATA_AXIS] * mesh.shape[ROW_AXIS] * slots
 
 
 def make_sharded_train_step(

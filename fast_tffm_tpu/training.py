@@ -1953,6 +1953,28 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
             **exchange_profile
         )
     )
+    tail_profile = {}  # what the rows layout's shard tail says of itself, once
+    if cfg.table_layout == "rows":
+        # The form is optim.sparse_adagrad_update's choice at trace time, from
+        # the SHARD's shapes (embedding.apply_shard_adagrad); asked here only
+        # to say it once, as train does.
+        from fast_tffm_tpu.optim import (
+            describe_rows_tail,
+            rows_tail_form,
+            rows_tail_profile,
+        )
+        from fast_tffm_tpu.parallel.train_step import shard_tail_ids
+
+        shard_rows = exchange_profile["shard_rows"]
+        m_ids = shard_tail_ids(
+            mesh, cfg.batch_size // mesh.size * max_nnz, cfg.lookup,
+            cfg.lookup_capacity_factor,
+        )
+        tail_form = rows_tail_form(
+            shard_rows, m_ids, model.row_dim, state.table_opt.accum.shape[-1]
+        )
+        tail_profile = rows_tail_profile(shard_rows, m_ids, model.row_dim, tail_form)
+        log("sparse tail: " + describe_rows_tail(shard_rows, m_ids, model.row_dim, tail_form))
     dist_saveable = None
     if cfg.table_layout == "packed":
         # Checkpoints hold LOGICAL [V, D] arrays.  Multi-process: unpack
@@ -2291,6 +2313,7 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
         saveable=dist_saveable,
         step_hook=step_hook,
         row_dim=model.row_dim,
+        tail_profile=tail_profile,
         exchange_profile=exchange_profile,
         mark_touched=mark_touched,
         runtime=runtime,

@@ -678,15 +678,14 @@ def test_sharded_1x1_mesh_bitwise_matches_local(update):
     for b in batches:
         lr_s, _ = lr_step(lr_s, b)
         sr_s, _ = sr_step(sr_s, b)
-    # Since PR 27 the two rows tails are not one expression any more: the
-    # single-device one hands ``-(lr·g/√acc)`` to ONE scatter-add, the
-    # shard's (``apply_shard_adagrad``) still gathers the row and sets
-    # ``w − lr·g/√acc``, which the CPU contracts into an FMA.  A few float32
-    # ULP at the operands' magnitude (|w| ≲ 0.05 here, |x| < lr = 0.05),
-    # compounded over three steps; the packed pairs above stay bitwise.
-    np.testing.assert_allclose(
-        np.asarray(sr_s.table), np.asarray(lr_s.table),
-        rtol=0, atol=16 * float(np.finfo(np.float32).eps) * 0.05,
+    # The two rows tails are ONE expression: the shard's
+    # (``apply_shard_adagrad``) calls ``optim.sparse_adagrad_update`` on the
+    # ids it owns, and on a 1 x 1 mesh it is handed the batch's occurrences
+    # as the single-device step is.  So bitwise, the accumulator too, as the
+    # packed pairs above (no FMA contracted in one program and not the other).
+    np.testing.assert_array_equal(np.asarray(sr_s.table), np.asarray(lr_s.table))
+    np.testing.assert_array_equal(
+        np.asarray(sr_s.table_opt.accum), np.asarray(lr_s.table_opt.accum)
     )
 
 

@@ -6,7 +6,9 @@ one replaced), that it fits VMEM at every row width, and that XLA hands it
 table and accumulator in place — the four transposes are bitcasts.  And
 of the whole step at the two train cells' shapes: which ops stand under
 ``fm.dedup`` and ``fm.tail`` in each form (ISSUE 32: the sweep's step sums
-no segments and sorts once; the rows' step is what it was).
+no segments and sorts once; the rows' step is what it was), and of the
+SHARDED step at the four-chip cell's shapes (ISSUE 36: the shard's tail is
+the same sweep, under the same scopes).
 
 All of it in this one file and behind a fixture: one process may hold the
 TPU's library, so only the worker that runs this file loads it.
@@ -24,14 +26,18 @@ from fast_tffm_tpu.ops.pallas_tail import sweep_adagrad_update
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def four_chips():
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    return SingleDeviceSharding(four_chips[0])
 
 
 def _compiled_text(one_chip, v, d, a, m):
@@ -50,8 +56,9 @@ def _compiled_text(one_chip, v, d, a, m):
         (2**26, 9, 9, 65536 * 39),  # fm8_criteo.train_fmb
         (2**26, 9, 1, 65536 * 39),  # fm8_criteo_rowacc's state under the same batch
         (2**20, 89, 89, 32768 * 22),  # an Avazu-shaped FFM row: 12 sublane groups a block
+        (2**25, 17, 17, 65536 * 39),  # fm16_criteo_row4's shard under all four chips' ids
     ],
-    ids=["fm8_element", "fm8_row", "ffm_d89"],
+    ids=["fm8_element", "fm8_row", "ffm_d89", "fm16_shard"],
 )
 def test_the_sweep_compiles_for_the_chip_in_place(one_chip, v, d, a, m):
     text = _compiled_text(one_chip, v, d, a, m)
@@ -135,3 +142,68 @@ def test_the_rows_step_keeps_its_dedup_and_its_row_operations(one_chip, monkeypa
     assert "f32[1277952,256]" in text  # the segment sum's wide rows
     tail = {k: ops["fm.tail"][k] for k in ("sort", "gather", "scatter")}
     assert tail == {"sort": 0, "gather": 1, "scatter": 2}
+
+
+def test_the_sharded_step_takes_the_sweep_on_the_ids_the_shard_owns(four_chips, monkeypatch):
+    """``fm16_criteo_row4.dist_train_fmb``'s step (2^27 rows of 17 over
+    ``{data: 1, row: 4}``, 65,536 x 39 ids, allgather lookup) compiled for the
+    described ``v5e:2x2``: the shard's tail is the Pallas sweep under
+    ``fm.tail``, asked ONCE, at the shard's shapes and all four chips' ids;
+    nothing scatters into a ``f32[33554432,17]`` shard; the global dedup is
+    gone (one segment sum, the local one, and its ``[638976,128]`` rows; none
+    on ``[2555904,128]``); and no instruction stands under both ``fm.tail``
+    and ``fm.dedup`` (``harness/scopes.py`` would count it twice)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from fast_tffm_tpu import optim
+    from fast_tffm_tpu.models import FMModel
+    from fast_tffm_tpu.models.base import Batch
+    from fast_tffm_tpu.ops import pallas_tail
+    from fast_tffm_tpu.parallel import make_sharded_train_step
+    from fast_tffm_tpu.parallel.train_step import _batch_specs, shard_tail_ids
+    from fast_tffm_tpu.trainer import init_state
+
+    mesh = Mesh(np.array(four_chips).reshape(1, 4), ("data", "row"))
+    rule, asked = optim.rows_tail_form, []
+    monkeypatch.setattr(optim, "rows_tail_form", lambda *a, backend=None: asked.append((a, rule(*a, backend="tpu"))) or asked[-1][1])
+    monkeypatch.setattr(pallas_tail, "resolve_interpret", lambda interpret: False)
+    model, b, n = FMModel(vocabulary_size=2**27, factor_num=16, order=2), 65536, 39
+    ns = lambda spec: NamedSharding(mesh, spec)
+    sd = lambda x, spec: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=ns(spec))
+    state = jax.eval_shape(lambda: init_state(model, jax.random.key(0), 0.1, "element"))
+    state = state._replace(
+        table=sd(state.table, P("row", None)), table_opt=type(state.table_opt)(sd(state.table_opt.accum, P("row", None))),
+        step=sd(state.step, P()),
+    )
+    batch = Batch(
+        labels=jax.ShapeDtypeStruct((b,), jnp.float32), ids=jax.ShapeDtypeStruct((b, n), jnp.int32),
+        vals=jax.ShapeDtypeStruct((b, n), jnp.float32), fields=jax.ShapeDtypeStruct((b, 0), jnp.int32),
+        weights=jax.ShapeDtypeStruct((b,), jnp.float32),
+    )
+    batch = jax.tree.map(sd, batch, _batch_specs())
+    text = make_sharded_train_step(model, 0.05, mesh).lower(state, batch).compile().as_text()
+    assert asked == [((2**25, shard_tail_ids(mesh, b // 4 * n, "allgather", 2.0), 17, 17), "sweep")]
+    assert asked[0][0][1] == 2555904
+    shard = re.compile(r"f32\[(33554432,17|17,33554432)\]")
+    on_shard, both, tail_calls, segment_sums = set(), [], 0, []
+    for line in text.splitlines():
+        op = re.match(r"\s*(ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if not op:
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        path = name.group(1).split("/") if name else []
+        scopes = {c for c in path if c.startswith(("fm.tail", "fm.dedup"))}
+        if len(scopes) > 1:
+            both.append(line.strip()[:160])
+        if op.group(3) == "custom-call" and "tpu_custom_call" in line:
+            assert "fm.tail" in path and "fm.dedup" not in path, path
+            tail_calls += 1
+        if shard.search(op.group(2)):  # the instruction's own result is shard-shaped
+            on_shard.add(op.group(3))
+        if op.group(3) == "scatter":
+            segment_sums.append(op.group(2))
+    assert tail_calls == 1
+    assert on_shard <= {"parameter", "bitcast", "custom-call", "get-tuple-element", "tuple"}, on_shard
+    assert not both, both
+    assert "2555904,128]" not in text and len(segment_sums) == 1 and "638976,128]" in segment_sums[0], segment_sums

@@ -700,3 +700,79 @@ def test_dist_train_records_what_its_exchange_moves(tmp_path, steps_per_call):
         {"data": 1, "row": 4}, 24, "allgather", by_hand)
     assert "tail_form" in profile and "row_dim" in profile  # beside the tail's fields
     assert any(str(s).startswith("exchange: allgather lookup, 24 rows a shard") and f"{by_hand} bytes" in str(s) for s in said)
+
+
+# --- PR 36: the shard's tail is the single-device step's, and says which form it took --
+
+
+@pytest.mark.parametrize("lookup", ["allgather", "alltoall"])
+@pytest.mark.parametrize("form", ["rows", "sweep"])
+def test_dist_train_says_the_form_its_shard_tail_took(tmp_path, monkeypatch, form, lookup):
+    """dist_train's start-up line and its kind=profile record carry the tail's
+    trace-time choices at the SHARD's shapes and the gathered ids, and they are
+    the truth: ``optim.rows_tail_form`` is asked at those very shapes by the
+    step as it is traced, and the traced step holds the Pallas call exactly
+    when the record says ``sweep`` (patched in, as a TPU's rule would say; on
+    the CPU mesh the rule itself says ``rows``)."""
+    import json
+
+    from fast_tffm_tpu import optim
+    from fast_tffm_tpu.config import Config
+    import fast_tffm_tpu.parallel as par
+    from fast_tffm_tpu.parallel.alltoall import capacity_for
+    from fast_tffm_tpu.training import dist_train
+
+    rule, asked = optim.rows_tail_form, []
+    monkeypatch.setattr(
+        optim, "rows_tail_form",
+        lambda *a, **k: asked.append(a) or ("sweep" if form == "sweep" else rule(*a, **k)),
+    )
+    steps, make = [], par.make_sharded_train_step
+    monkeypatch.setattr(par, "make_sharded_train_step", lambda *a, **k: steps.append(make(*a, **k)) or steps[-1])
+    f = tmp_path / "d.libsvm"
+    f.write_text("".join(f"{i % 2} " + " ".join(f"{(i * 7 + j * 13) % 96}:1.0" for j in range(8)) + "\n" for i in range(128)))
+    cfg = Config(
+        model="fm", factor_num=16, vocabulary_size=96, model_file=str(tmp_path / "m.ckpt"),
+        train_files=(str(f),), epoch_num=1, batch_size=64, learning_rate=0.1, log_every=2,
+        data_parallel=1, row_parallel=4, lookup=lookup, metrics_path=str(tmp_path / "metrics.jsonl"),
+    ).validate()
+    said = []
+    state = dist_train(cfg, log=said.append, mesh=make_mesh(1, 4))
+    assert np.isfinite(np.asarray(state.table)).all()
+    # A shard of 24 rows of 17; four chips' 16 x 8 ids each, or under the routed
+    # update the capacity each of four row peers sends here.
+    slots = 4 * (16 * 8 if lookup == "allgather" else capacity_for(16 * 8, 4, cfg.lookup_capacity_factor))
+    # dist_train's question and the traced step's are the same one (under
+    # ``lookup_overflow = fallback`` the cond's other branch asks the all-gather's).
+    assert asked.count((24, slots, 17, 17)) >= 2
+    assert set(asked) <= {(24, slots, 17, 17), (24, 4 * 16 * 8, 17, 17)}
+    records = [json.loads(line) for line in (tmp_path / "metrics.jsonl").read_text().splitlines()]
+    profile = next(r for r in records if r["kind"] == "profile" and r["program"] == "train_step")
+    want = optim.rows_tail_profile(24, slots, 17, form)
+    assert want["tail_form"] == form and profile["row_dim"] == 17
+    assert {k: profile[k] for k in want} == want
+    if form == "sweep":
+        assert (profile["tail_duplicates"], profile["tail_permutation"], profile["segment_sum_lanes"]) == ("kernel", "row gather", None)
+        line = "sparse tail: pallas rows sweep (block 128 lanes, 1 blocks; duplicates summed in the kernel"
+    else:
+        assert (profile["tail_duplicates"], profile["tail_block_lanes"]) == ("segment_sum", None)
+        line = "sparse tail: xla rows (segment sum on 128-lane rows (row width 17)"
+    assert sum(str(s).startswith("sparse tail: ") for s in said) == 1  # said once
+    assert any(str(s).startswith(line) for s in said), said
+    # The step as dist_train built it, traced again at the run's shapes.
+    sds = jax.ShapeDtypeStruct
+    batch = Batch(
+        labels=sds((64,), jnp.float32), ids=sds((64, 8), jnp.int32), vals=sds((64, 8), jnp.float32),
+        fields=sds((64, 0), jnp.int32), weights=sds((64,), jnp.float32),
+    )
+    abstract = jax.tree.map(lambda x: sds(x.shape, x.dtype), state)
+    from fast_tffm_tpu.parallel.exchange import _sub_jaxprs
+
+    def primitives(jaxpr):  # of every equation at any depth, those that hold a jaxpr too
+        for e in jaxpr.eqns:
+            yield e.primitive.name
+            for inner in _sub_jaxprs(e):
+                yield from primitives(inner)
+
+    prims = set(primitives(jax.make_jaxpr(steps[0])(abstract, batch).jaxpr))
+    assert ("pallas_call" in prims) == (form == "sweep")
