@@ -34,7 +34,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["fm_score", "anova_kernel", "fm_score_order2_raw", "fm_score_anova_raw"]
+__all__ = [
+    "fm_score",
+    "anova_kernel",
+    "fm_score_order2_raw",
+    "fm_score_anova_raw",
+    "interaction_form",
+    "interaction_profile",
+    "describe_interaction",
+]
 
 
 # ---------------------------------------------------------------------------
@@ -190,6 +198,58 @@ def fm_score_anova_raw(rows: jax.Array, vals: jax.Array, order: int) -> jax.Arra
 # ---------------------------------------------------------------------------
 
 
+def interaction_form(
+    order: int, use_pallas: bool | None = None, backend: str | None = None
+) -> str:
+    """The form ``fm_score`` takes, a trace-time function of the order and the
+    backend: ``order2`` (the closed form, any backend), ``pallas_anova`` (the
+    ANOVA dynamic program in ops/pallas_anova.py's kernel: on a TPU, or
+    wherever ``use_pallas=True`` asks for it) or ``scan`` (the same program
+    as a ``lax.scan``, anywhere else)."""
+    if order == 2:
+        return "order2"
+    if use_pallas is None:
+        use_pallas = (backend or jax.default_backend()) == "tpu"
+    return "pallas_anova" if use_pallas else "scan"
+
+
+def interaction_profile(
+    order: int, batch_rows: int, factor_num: int, *, backward: bool = True,
+    form: str | None = None,
+) -> dict:
+    """The interaction's trace-time choices as a step's ``kind=profile``
+    record carries them: ``order``, ``interaction_form`` and, under the
+    kernel, ``anova_programs_per_step`` = the grid programs of one step on
+    ``batch_rows`` rows (forward, and as many again where the step runs the
+    backward kernel); null for the other forms."""
+    form = form or interaction_form(order)
+    programs = None
+    if form == "pallas_anova":
+        from fast_tffm_tpu.ops.pallas_anova import grid_programs
+
+        programs = (2 if backward else 1) * grid_programs(batch_rows, factor_num)
+    return dict(order=order, interaction_form=form, anova_programs_per_step=programs)
+
+
+def describe_interaction(
+    order: int, batch_rows: int, factor_num: int, *, backward: bool = True,
+    form: str | None = None,
+) -> str:
+    """``interaction_profile`` as one start-up line (a trace-time choice, so
+    it is said once)."""
+    p = interaction_profile(order, batch_rows, factor_num, backward=backward, form=form)
+    if p["interaction_form"] == "order2":
+        return "order 2, closed form"
+    if p["interaction_form"] == "scan":
+        return f"order {order}, ANOVA dynamic program as a lax.scan over the row's features"
+    passes = "forward and backward" if backward else "forward"
+    return (
+        f"order {order}, pallas ANOVA kernel ({p['anova_programs_per_step']} grid "
+        f"programs a step, {passes}; {batch_rows} rows in tiles of 128 x "
+        f"{factor_num} factors)"
+    )
+
+
 def fm_score(
     rows: jax.Array, vals: jax.Array, order: int = 2, *, use_pallas: bool | None = None
 ) -> jax.Array:
@@ -202,10 +262,13 @@ def fm_score(
       order: interaction order ≥ 2.  order=2 uses the fused (Σv)²−Σv² path;
              order≥3 the ANOVA dynamic program.  Both carry hand-written VJPs.
       use_pallas: route the order≥3 interaction DP through the Pallas TPU
-             kernel (ops/pallas_anova.py).  None = auto: on a TPU backend
-             only — the kernel compiled and matched this path there (PR 22,
-             TPU v5 lite, B=16384 N=11 k=8 order 3).  True is honored
-             anywhere and never drops back: a compiler refusal raises.
+             kernel (ops/pallas_anova.py).  None = auto (``interaction_form``):
+             on a TPU backend only — the kernel compiled and matched this
+             path there at B=16384 N=11 k=8 (PR 22) and, since PR 37, runs
+             in a measured cell at B=65536 N=11 k=30, order 3
+             (``fm3_k30_kdd12.train_fmb_order3``: 512 x 30 grid programs
+             forward and as many backward).  True is honored anywhere and
+             never drops back: a compiler refusal raises.
 
     Returns:
       [batch] raw (pre-sigmoid) scores.
@@ -213,13 +276,15 @@ def fm_score(
     if order < 2:
         raise ValueError(f"FM order must be >= 2, got {order}")
     # Every caller's interaction (train step, scorer, any layout, the
-    # sharded step) carries this name in the compiled program.
+    # sharded step) carries this name in the compiled program; the ANOVA
+    # dynamic program, in either form, ``fm.anova`` inside it (forward
+    # ``jvp(fm.interaction)/fm.anova``, backward
+    # ``transpose(jvp(fm.interaction))/fm.anova``).
     with jax.named_scope("fm.interaction"):
-        if order == 2:
+        form = interaction_form(order, use_pallas)
+        if form == "order2":
             return _fm_score_order2(rows, vals)
-        if use_pallas is None:
-            use_pallas = jax.default_backend() == "tpu"
-        if use_pallas:
+        if form == "pallas_anova":
             from fast_tffm_tpu.ops.pallas_anova import anova_inter
             from fast_tffm_tpu.ops.pallas_common import default_interpret
 
@@ -229,5 +294,8 @@ def fm_score(
             # Pallas interpreter (ops.pallas_common).
             linear = jnp.sum(rows[..., 0] * vals, axis=-1)
             z = rows[..., 1:] * vals[..., None]
-            return linear + anova_inter(z, order, default_interpret())
-        return _fm_score_anova(rows, vals, order)
+            with jax.named_scope("fm.anova"):  # the kernels and their layout transposes
+                inter = anova_inter(z, order, default_interpret())
+            return linear + inter
+        with jax.named_scope("fm.anova"):
+            return _fm_score_anova(rows, vals, order)

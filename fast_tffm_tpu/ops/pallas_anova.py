@@ -37,7 +37,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["anova_inter", "anova_inter_reference"]
+__all__ = ["anova_inter", "anova_inter_reference", "grid_programs"]
 
 _LANES = 128
 
@@ -45,6 +45,12 @@ _LANES = 128
 def _rows_for(order: int) -> int:
     """Sublane count for the DP state: degrees 0..order, padded to 8k."""
     return max(8, ((order + 1 + 7) // 8) * 8)
+
+
+def grid_programs(batch_rows: int, factor_num: int) -> int:
+    """Grid programs of ONE pass (the forward kernel; the backward kernel runs
+    as many): a tile of 128 examples times a factor dimension each."""
+    return -(-batch_rows // _LANES) * factor_num
 
 
 def _row_iota(rows: int) -> jax.Array:

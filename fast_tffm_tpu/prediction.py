@@ -18,7 +18,12 @@ from fast_tffm_tpu.checkpoint import restore_checkpoint
 from fast_tffm_tpu.config import Config, build_model
 from fast_tffm_tpu.models.base import Batch
 from fast_tffm_tpu.telemetry import RunMonitor, log_device
-from fast_tffm_tpu.training import _batch_converter, _stream, scan_max_nnz
+from fast_tffm_tpu.training import (
+    _batch_converter,
+    _say_interaction,
+    _stream,
+    scan_max_nnz,
+)
 from fast_tffm_tpu.trainer import init_state, make_predict_step
 
 __all__ = [
@@ -110,10 +115,16 @@ def make_score_fn(cfg: Config, state, max_nnz: int, model=None) -> ScoreFn:
 
 
 def _run_predict(
-    cfg: Config, state, predict_step, max_nnz, log=print, mesh=None, with_fields=True
+    cfg: Config, state, predict_step, max_nnz, log=print, mesh=None, with_fields=True,
+    *, model,
 ) -> str:
     if not cfg.predict_files:
         raise ValueError("no predict_files configured")
+    # The interaction's form on a chip's share of a batch, said once and
+    # carried by the predict program's kind=profile record (forward only).
+    interaction = _say_interaction(
+        log, model, cfg.batch_size // (mesh.size if mesh is not None else 1), backward=False,
+    )
     # Multi-host: the sharded predict step is ONE SPMD program over the
     # global mesh; replicated scores come back on every process and process
     # 0 writes them.  When the batch size divides evenly, the INPUT is also
@@ -205,6 +216,7 @@ def _run_predict(
                 ledger.stage(
                     "predict_step", predict_step, (state, b),
                     examples=int(getattr(b.labels, "shape", (0,))[0] or 0) or None,
+                    **interaction,
                 )
             scores = np.asarray(predict_step(state, b))
             batches += 1
@@ -264,7 +276,8 @@ def predict(cfg: Config, log=print) -> str:
     model, state = load_scoring_state(cfg, log)
     score = make_score_fn(cfg, state, scan_max_nnz(cfg), model=model)
     return _run_predict(
-        cfg, state, score.fn, score.max_nnz, log, with_fields=score.uses_fields
+        cfg, state, score.fn, score.max_nnz, log, with_fields=score.uses_fields,
+        model=model,
     )
 
 
@@ -333,4 +346,5 @@ def dist_predict(cfg: Config, log=print, mesh=None) -> str:
         log,
         mesh=mesh,
         with_fields=model.uses_fields,
+        model=model,
     )

@@ -561,6 +561,19 @@ def _window_clocks(clocks: dict, steps: int, sync_s: float) -> dict:
     }
 
 
+def _say_interaction(log, model, batch_rows: int, backward: bool = True) -> dict:
+    """The form the model's interaction takes on ``batch_rows`` rows (a chip's
+    share of a step), said once at start-up and returned as the step's
+    ``kind=profile`` fields.  ``fm_score``'s choice at trace time
+    (``ops.fm.interaction_form``); every model but the FM of order 3 and up
+    scores by a closed form of order 2."""
+    from fast_tffm_tpu.ops.fm import describe_interaction, interaction_profile
+
+    shape = (getattr(model, "order", 2), batch_rows, model.factor_num)
+    log("interaction: " + describe_interaction(*shape, backward=backward))
+    return interaction_profile(*shape, backward=backward)
+
+
 def _run_training(
     cfg: Config,
     state,
@@ -578,6 +591,7 @@ def _run_training(
     row_dim=0,
     tail_profile=None,
     exchange_profile=None,
+    interaction_profile=None,
     mark_touched=None,
     start_cursor=None,
     rollback=None,
@@ -628,6 +642,10 @@ def _run_training(
     ``lookup``, ``exchange_bytes_per_step`` = the payload bytes a chip sends
     and receives in the step's collectives, parallel/exchange.py) rides that
     record too, and its byte count every ``kind=train`` record.
+    ``interaction_profile`` (``ops.fm.interaction_profile``: ``order``,
+    ``interaction_form`` ``order2`` | ``pallas_anova`` | ``scan``, and the
+    kernel's ``anova_programs_per_step``, null for the other forms) rides it
+    as well.
 
     ``datastats_ids`` (optional ``batch -> device ids``) lets the sampled
     id-statistics collector read a device-cache batch's ids straight off
@@ -793,6 +811,7 @@ def _run_training(
                 "tail_block_lanes": None, **(tail_profile or {}),
             },
             **(exchange_profile or {}),
+            **(interaction_profile or {}),
         )
 
     # Pod liveness: this host's heartbeat (armed at bring-up) starts
@@ -1403,6 +1422,7 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
     run_kwargs = dict(
         to_batch=to_batch, saveable=saveable, step_hook=step_hook,
         row_dim=model.row_dim, tail_profile=tail_profile,
+        interaction_profile=_say_interaction(log, model, cfg.batch_size),
     )
     if cfg.online_accum_restart_steps > 0:
         from fast_tffm_tpu.trainer import make_accum_restart
@@ -2315,6 +2335,7 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
         row_dim=model.row_dim,
         tail_profile=tail_profile,
         exchange_profile=exchange_profile,
+        interaction_profile=_say_interaction(log, model, cfg.batch_size // mesh.size),
         mark_touched=mark_touched,
         runtime=runtime,
         mesh=mesh,

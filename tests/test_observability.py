@@ -157,6 +157,48 @@ def test_the_steps_profile_record_says_the_row_width_and_the_tails_lanes(dataset
         assert not said
 
 
+@pytest.mark.parametrize(
+    "kw, form, programs",
+    [
+        (dict(model="fm", factor_num=4, order=2), "order2", None),
+        (dict(model="ffm", factor_num=4, num_fields=39), "order2", None),  # a closed form of order 2 as well
+        (dict(model="fm", factor_num=30, order=3), "scan", None),  # off a TPU the lax.scan
+        (dict(model="fm", factor_num=30, order=3), "pallas_anova", 2 * 30),  # made to say the kernel: one tile of 128 rows x 30 factors
+    ],
+    ids=["fm_order2", "ffm", "fm_order3_scan", "fm_order3_kernel"],
+)
+def test_the_profile_records_say_the_interactions_order_form_and_grid(dataset, monkeypatch, kw, form, programs):
+    """``order``, ``interaction_form`` and ``anova_programs_per_step`` (the
+    kernel's grid programs, forward and backward; null for the other forms)
+    ride the step's ``kind=profile`` record beside the tail's fields, and the
+    predict program's (forward only); the start-up line ``interaction: ...``
+    says the same.  Trace-time choices (``ops.fm.interaction_form``)."""
+    from fast_tffm_tpu.ops import fm
+    from fast_tffm_tpu.prediction import predict
+
+    if form == "pallas_anova":
+        monkeypatch.setattr(fm, "interaction_form", lambda order, use_pallas=None, backend=None: "pallas_anova")
+    extra = dict(predict_files=(str(dataset / "train.libsvm"),), score_path=str(dataset / "scores.txt"))
+    cfg = _cfg(dataset, tag="inter", epoch_num=1, **extra, **kw)
+    logs = []
+    train(cfg, log=lambda *a: logs.append(" ".join(map(str, a))))
+    (prof,) = [r for r in _read(cfg.metrics_path) if r["kind"] == "profile" and r["program"] == "train_step"]
+    order = kw.get("order", 2)
+    assert (prof["order"], prof["interaction_form"], prof["anova_programs_per_step"]) == (order, form, programs)
+    assert "tail_form" in prof and "row_dim" in prof  # beside the tail's fields, which keep their names
+    (said,) = [l for l in logs if l.startswith("interaction: ")]
+    assert said.startswith(f"interaction: order {order}, ")
+    assert (f"{programs} grid programs a step, forward and backward" in said) == (programs is not None)
+
+    pcfg = _cfg(dataset, tag="inter_p", model_file=cfg.model_file, metrics_path=str(dataset / "m_inter_p.jsonl"), **extra, **kw)
+    logs.clear()
+    predict(pcfg, log=lambda *a: logs.append(" ".join(map(str, a))))
+    (prof,) = [r for r in _read(pcfg.metrics_path) if r["kind"] == "profile" and r["program"] == "predict_step"]
+    assert (prof["order"], prof["interaction_form"]) == (order, form)
+    assert prof["anova_programs_per_step"] == (programs // 2 if programs else None)  # no backward pass
+    assert len([l for l in logs if l.startswith("interaction: ")]) == 1
+
+
 def test_a_backend_without_cost_analysis_still_records_what_was_dispatched(dataset, monkeypatch):
     """The TPU's PJRT client analyses no lowering (``Lowered.cost_analysis``
     is None there): the record is written with the measured fields null."""

@@ -230,7 +230,7 @@ def test_sweep_block_and_chunk_edges(name):
 
 
 @pytest.mark.parametrize("acc_kind", ["row", "element"])
-@pytest.mark.parametrize("d", [9, 17, 89])
+@pytest.mark.parametrize("d", [9, 17, 31, 89])
 def test_sweep_matches_dense_oracle_over_widths(d, acc_kind):
     rng = np.random.default_rng(d)
     v = 700
@@ -372,7 +372,7 @@ def test_sweep_sums_the_occurrences_of_a_row(name, acc_kind, decay):
             _assert_accum_few_ulp(ka[look], decay * np.asarray(acc)[look])
 
 
-@pytest.mark.parametrize("d, form", [(9, "sort operands"), (16, "sort operands"), (17, "row gather"), (89, "row gather")])
+@pytest.mark.parametrize("d, form", [(9, "sort operands"), (16, "sort operands"), (17, "row gather"), (31, "row gather"), (89, "row gather")])
 def test_occurrences_reach_id_order_the_same_way_in_both_forms(d, form):
     """``occurrences_by_id``: the ids ascending with drop ids clamped to V,
     the gradients column by column in that order, ties in the batch's order
@@ -420,10 +420,12 @@ def _cell_shapes(config, shards=1):
         (("ffm4_criteo",), "tpu", "rows"),
         (("fm8_criteo_rowacc",), "tpu", "sweep"),  # the row accumulator: fewer bytes to sweep
         (("fm16_criteo_row4", 4), "tpu", "sweep"),  # a chip's 2^25 rows of 17 under the global batch
+        (("fm3_k30_kdd12",), "tpu", "sweep"),  # 2^25 rows of 31 (32 sublanes, fm8's bytes) under 65,536 x 11 ids
     ],
     ids=[
         "fm8_on_tpu", "d157", "b1024", "under_the_crossing", "over_the_crossing", "cpu",
         "cell_fm8_criteo", "cell_ffm4_criteo", "cell_fm8_criteo_rowacc", "cell_fm16_criteo_row4",
+        "cell_fm3_k30_kdd12",
     ],
 )
 def test_auto_chooses_the_form_from_shapes_and_backend(shapes, backend, form):

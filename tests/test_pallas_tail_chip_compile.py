@@ -207,3 +207,25 @@ def test_the_sharded_step_takes_the_sweep_on_the_ids_the_shard_owns(four_chips, 
     assert on_shard <= {"parameter", "bitcast", "custom-call", "get-tuple-element", "tuple"}, on_shard
     assert not both, both
     assert "2555904,128]" not in text and len(segment_sums) == 1 and "638976,128]" in segment_sums[0], segment_sums
+
+
+def test_the_anova_kernel_compiles_for_the_chip_at_the_order_3_cells_shape(one_chip):
+    """``fm3_k30_kdd12.train_fmb_order3``'s interaction (ISSUE 37: 65,536 rows
+    x 11 ids x k = 30, order 3), forward and backward, compiled for the
+    described chip in seconds: Mosaic takes both kernels at a grid of 512 x 30
+    programs on blocks of [1, 11, 128], and the layout transposes around them
+    are not copies: XLA lays ``z[B, N, k]`` out batch-minor, so ``[k, N, B]``
+    is a bitcast of it, in and out.  (Here for the one library load this
+    file's fixture makes; the whole step at these shapes takes the compiler
+    half a minute and is rehearsed by the builder, PERF.md §6.)"""
+    import time
+
+    from fast_tffm_tpu.ops.pallas_anova import anova_inter, grid_programs
+
+    b, n, k = 65536, 11, 30
+    z = jax.ShapeDtypeStruct((b, n, k), jnp.float32, sharding=one_chip)
+    t = time.time()
+    text = jax.jit(jax.value_and_grad(lambda z: jnp.sum(anova_inter(z, 3, False)))).lower(z).compile().as_text()
+    assert time.time() - t < 30
+    assert text.count("tpu_custom_call") == 2 and grid_programs(b, k) == 15360
+    assert f"f32[{k},{n},{b}]" in text and not re.search(rf"f32\[{k},{n},{b}\]\S* (copy|transpose)\(", text)
