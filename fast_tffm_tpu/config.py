@@ -251,7 +251,13 @@ class Config:
     data_parallel: int = 0  # 0 = all devices / row_parallel
     row_parallel: int = 0  # 0 = vocabulary_block_num
     lookup: str = "allgather"  # embedding lookup collective (| alltoall)
-    lookup_capacity_factor: float = 2.0  # alltoall per-destination slack
+    # How far over its uniform share (a chip's ids / row_parallel) a row
+    # shard's traffic may run before the step takes the slower exact path,
+    # under BOTH exchanges: the alltoall's per-destination slots (past them
+    # lookup_overflow decides), and the slots a shard's Adagrad tail keeps of
+    # the allgather update's list (past them it takes the whole list, counted
+    # as shard_tail_full_steps; nothing is dropped).
+    lookup_capacity_factor: float = 2.0
     lookup_overflow: str = "fallback"  # fallback (retry step via allgather) | abort
     coordinator_address: str = ""  # multi-host: host:port of process 0
     num_processes: int = 0  # multi-host: total process count

@@ -466,7 +466,6 @@ def load_sharded_device_dataset(
 
 def make_cached_sharded_train_step(
     sharded_step, data: DeviceDataset, steps_per_call: int = 1,
-    overflow_flagged: bool | None = None,
 ):
     """Wrap a ``make_sharded_train_step`` step so each call slices batch
     ``i`` out of the mesh-sharded resident arrays on-device (sequential
@@ -480,14 +479,11 @@ def make_cached_sharded_train_step(
     ``step(state, idxs [K]) -> (state, losses [K])`` runs K consecutive
     resident batches through ONE dispatch, the SPMD body scanning on
     device (epoch_index_chunks supplies the pre-placed index vectors,
-    remainder included).  An overflow-flagged sharded step (the alltoall
-    ``fallback`` 3-tuple) scans transparently: per-step losses stay [K]
-    and the per-step overflow flags SUM into one replicated int32 (the
-    driver only ever counts them, so K-granularity is not lost — the
-    count is exact).  ``overflow_flagged`` tells the scan whether the
-    wrapped step returns that 3-tuple; callers that built the step from
-    config (dist_train) pass it explicitly, and the default reads the
-    marker make_sharded_train_step sets on its return value.
+    remainder included).  A sharded step that returns counters after its
+    loss (the alltoall ``fallback``'s overflow flag, ``count_full_tails``)
+    scans transparently: per-step losses stay [K] and each counter's
+    per-step values SUM into one replicated int32 (the driver only ever
+    counts them, so K-granularity is not lost — the count is exact).
     """
     from fast_tffm_tpu.models.base import Batch as _Batch
 
@@ -505,32 +501,17 @@ def make_cached_sharded_train_step(
 
         return step
 
-    # The scan must mirror the wrapped step's signature exactly.
-    flagged = (
-        bool(getattr(sharded_step, "overflow_flagged", False))
-        if overflow_flagged is None
-        else bool(overflow_flagged)
-    )
-
     @partial(jax.jit, donate_argnums=(0,))
     def _scan_step(state, arrs, idxs):
         def one(st, i):
             sl = lambda a: lax.dynamic_slice_in_dim(a, i, 1, axis=0)[0]
-            out = sharded_step(st, _Batch(*map(sl, arrs)))
-            if flagged:
-                st, loss, ovf = out
-            else:
-                st, loss = out
-                ovf = jnp.zeros((), jnp.int32)
-            return st, (loss, ovf)
+            st, loss, *counts = sharded_step(st, _Batch(*map(sl, arrs)))
+            return st, (loss, counts)
 
-        state, (losses, ovfs) = lax.scan(one, state, idxs)
-        return state, losses, jnp.sum(ovfs)
+        state, (losses, counts) = lax.scan(one, state, idxs)
+        return (state, losses, *(jnp.sum(c) for c in counts))
 
     def step_k(state, idxs):
-        state, losses, ovf_sum = _scan_step(state, arrays, idxs)
-        if flagged:
-            return state, losses, ovf_sum
-        return state, losses
+        return _scan_step(state, arrays, idxs)
 
     return step_k

@@ -329,8 +329,8 @@ def rows_tail_adagrad_update(
     interpret: bool | None = None,
     block_lanes: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """``optim.sparse_adagrad_update`` in its ``sweep`` form (and what it
-    calls there): the batch's occurrences brought to id order
+    """``optim.sparse_adagrad_update``'s ``sweep`` form on a whole batch (it
+    makes these two calls itself): the occurrences brought to id order
     (``optim.occurrences_by_id``: one sort, one permutation, no sums), then
     ``sweep_adagrad_update``; same accumulator expressions and lazy-decay
     semantics as the rows."""

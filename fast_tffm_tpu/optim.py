@@ -277,21 +277,33 @@ def describe_rows_tail(num_rows: int, m: int, d: int, form: str = "rows") -> str
     )
 
 
-def sort_ids(ids: jax.Array, num_rows: int):
+def sort_ids(ids: jax.Array, num_rows: int, keep: int | None = None):
     """The first half of both forms of the tail: ONE stable sort of the
     batch's ids, clamped to ``num_rows`` (every drop id becomes the first one).
 
     Returns ``(sid [M], order [M])``: the ids ascending, repeats and all, and
     each one's position in ``ids`` (``ids[argsort(ids)]`` would be an 18 ms
     gather of 2.56M ints more).  Stable, so occurrences of one id keep the
-    batch's order and every sum over them is the same from run to run."""
-    return lax.sort_key_val(
+    batch's order and every sum over them is the same from run to run.
+
+    ``keep``: only the first ``keep`` entries of the order are returned, so
+    that nothing after the sort moves a row past them.  The drop ids sort
+    last: a caller who knows that at most ``keep`` of its ids are under
+    ``num_rows`` (a row shard among all the chips' slots,
+    parallel.embedding.apply_shard_adagrad) loses nothing but drop ids.  The
+    ONE place the rows' forms honour it; ``None`` is the program without it."""
+    sid, order = lax.sort_key_val(
         jnp.minimum(ids, num_rows), jnp.arange(ids.shape[0], dtype=jnp.int32),
         is_stable=True,
     )
+    if keep is not None:
+        sid, order = sid[:keep], order[:keep]
+    return sid, order
 
 
-def dedup_rows(ids: jax.Array, row_grads: jax.Array, num_rows: int):
+def dedup_rows(
+    ids: jax.Array, row_grads: jax.Array, num_rows: int, keep: int | None = None
+):
     """Sum per-occurrence row gradients over duplicate ids.
 
     Args:
@@ -300,6 +312,7 @@ def dedup_rows(ids: jax.Array, row_grads: jax.Array, num_rows: int):
                  updates dedup all-gathered ``uids`` a second time).
       row_grads: [M, D] gradient per occurrence.
       num_rows:  table row count V (the first drop id).
+      keep:      ``sort_ids``' bound: every ``M`` below is ``keep`` then.
 
     Returns:
       (uids [M], gsum [M, D]): unique ids, ASCENDING, with their summed
@@ -320,9 +333,10 @@ def dedup_rows(ids: jax.Array, row_grads: jax.Array, num_rows: int):
     from a second sort of the ids, not from a scatter-set by segment (5 ms
     against 13, PERF.md §6, PR 27).
     """
-    m, d = row_grads.shape
+    d = row_grads.shape[1]
     with jax.named_scope("fm.dedup"):
-        sid, order = sort_ids(ids, num_rows)
+        sid, order = sort_ids(ids, num_rows, keep)
+        m = sid.shape[0]
         sg = row_grads[order]
         is_new = jnp.concatenate([jnp.ones((1,), bool), sid[1:] != sid[:-1]])
         seg = jnp.cumsum(is_new) - 1  # [M] segment index per occurrence
@@ -341,7 +355,9 @@ def dedup_rows(ids: jax.Array, row_grads: jax.Array, num_rows: int):
     return uids, gsum
 
 
-def occurrences_by_id(ids: jax.Array, row_grads: jax.Array, num_rows: int):
+def occurrences_by_id(
+    ids: jax.Array, row_grads: jax.Array, num_rows: int, keep: int | None = None
+):
     """The batch's occurrences in id order, duplicates NOT summed: what the
     sweep takes (ops.pallas_tail.sweep_adagrad_update sums a row's
     occurrences in its own contraction, so the segment sum, its two
@@ -355,14 +371,17 @@ def occurrences_by_id(ids: jax.Array, row_grads: jax.Array, num_rows: int):
     the TPU anyway.  So nothing here needs an ``[M, D]`` row, and the
     permutation has two forms (``occurrences_permutation``): the ``D``
     columns ride the ONE stable sort of the ids as its operands, or they are
-    gathered row by row in the order that sort returns."""
+    gathered row by row in the order that sort returns.  ``keep``
+    (``sort_ids``): ``M`` is ``keep``, the gather stops there (the sorted
+    columns are cut there)."""
     with jax.named_scope("fm.dedup"):
         if occurrences_permutation(row_grads.shape[1]) == "sort operands":
             sid, *cols = lax.sort(
                 (jnp.minimum(ids, num_rows), *row_grads.T), num_keys=1, is_stable=True
             )
-            return sid, jnp.stack(cols)
-        sid, order = sort_ids(ids, num_rows)
+            gt = jnp.stack(cols)
+            return (sid, gt) if keep is None else (sid[:keep], gt[:, :keep])
+        sid, order = sort_ids(ids, num_rows, keep)
         return sid, row_grads[order].T
 
 
@@ -374,6 +393,7 @@ def sparse_adagrad_update(
     lr: float,
     decay: float = 1.0,
     form: str | None = None,
+    keep: int | None = None,
 ):
     """Sparse Adagrad step on a ``[V, D]`` table.
 
@@ -385,11 +405,17 @@ def sparse_adagrad_update(
     ``dedup_rows`` guarantees about ``uids`` — ascending, and unique where
     the trailing drop ids are distinct (a trace-time test on shapes).
     ``form`` ``"sweep"``: the same update as one in-place kernel pass over
-    table and accumulator (ops.pallas_tail.rows_tail_adagrad_update), which
+    table and accumulator (ops.pallas_tail.sweep_adagrad_update), which
     sums a row's occurrences itself: no ``dedup_rows`` runs, the batch's
     occurrences go in in id order (``occurrences_by_id``).  ``None`` (every
     driver; a test or ``chip_smoke.py`` names a form to run it on any
     backend): ``rows_tail_form``, asked before anything is sorted.
+
+    ``keep`` (a row shard's tail; ``None`` everywhere else, and then the
+    program is the one without it): the caller's word that at most ``keep``
+    of the ids are rows of this table, the rest drop ids from ``V`` up.  Only
+    the first ``keep`` entries of the sort's order are permuted, summed and
+    handed on (``sort_ids``), and ``rows_tail_form`` is asked at that many.
 
     ``decay`` γ < 1 decays the accumulator LAZILY — only the rows a step
     touches pay ``accum = γ·accum + g²`` (an untouched row's history is
@@ -400,22 +426,23 @@ def sparse_adagrad_update(
     XLA program, bit-identical results (test-pinned on all three train
     paths)."""
     D = table.shape[-1]
-    flat = ids.reshape(-1)
+    flat, row_grads = ids.reshape(-1), row_grads.reshape(-1, D)
+    m = flat.shape[0] if keep is None else keep
     if form is None:
-        form = rows_tail_form(
-            table.shape[0], flat.shape[0], D, state.accum.shape[-1]
-        )
+        form = rows_tail_form(table.shape[0], m, D, state.accum.shape[-1])
     if form == "sweep":
-        from fast_tffm_tpu.ops.pallas_tail import rows_tail_adagrad_update
+        from fast_tffm_tpu.ops.pallas_tail import sweep_adagrad_update
 
-        table, accum = rows_tail_adagrad_update(
-            table, state.accum, flat, row_grads, lr, decay=decay
-        )
+        sid, gt = occurrences_by_id(flat, row_grads, table.shape[0], keep)
+        with jax.named_scope("fm.tail"):
+            table, accum = sweep_adagrad_update(
+                table, state.accum, sid, gt, lr, decay=decay
+            )
         return table, AdagradState(accum)
-    uids, gsum = dedup_rows(flat, row_grads.reshape(-1, D), table.shape[0])
+    uids, gsum = dedup_rows(flat, row_grads, table.shape[0], keep)
     known = dict(
         indices_are_sorted=True,
-        unique_indices=distinct_sentinels(table.shape[0], flat.shape[0], uids.dtype),
+        unique_indices=distinct_sentinels(table.shape[0], m, uids.dtype),
     )
     with jax.named_scope("fm.tail"):
         acc_prev = state.accum.at[uids].get(mode="clip", **known)

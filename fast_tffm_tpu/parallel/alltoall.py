@@ -266,7 +266,9 @@ def routed_update(
         # one row itself, under the scopes it names (fm.dedup, fm.tail).
         from fast_tffm_tpu.parallel.embedding import apply_shard_adagrad
 
-        table_shard, accum_shard = apply_shard_adagrad(
+        # ``R x C`` slots from every data peer: what the all-gather update's
+        # tail is bounded to (train_step.shard_tail_ids), by construction.
+        table_shard, accum_shard, _ = apply_shard_adagrad(
             table_shard, accum_shard, all_ids, all_g, lr, decay=decay
         )
         return table_shard, accum_shard, chips_over > 0

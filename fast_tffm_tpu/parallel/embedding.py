@@ -22,12 +22,20 @@ inside `shard_map`:
            step's sparse Adagrad tail (optim.sparse_adagrad_update, in
            the form optim.rows_tail_form chooses at the shard's shapes)
            to the ids it owns; a row several chips touched is summed
-           there, once — no second dedup, no second collective.
+           there, once — no second dedup, no second collective.  A shard
+           owns about one in ROW of the slots it is handed, and the sort
+           puts them first: the tail keeps a static bound of them
+           (``lookup_capacity_factor`` over the uniform share, the routed
+           update's slot count: it bounds the tail under BOTH exchanges)
+           and takes the whole list, counted, where a shard owns more
+           (``apply_shard_adagrad``).
 
 These functions run INSIDE a shard_map body (parallel/train_step.py).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -62,7 +70,9 @@ def owned_local_ids(global_ids, shard_logical_rows: int, sentinel: int):
     return jnp.where(owned, local, sentinel), owned
 
 
-def apply_shard_adagrad(table_shard, accum_shard, ids, grads, lr, decay=1.0):
+def apply_shard_adagrad(
+    table_shard, accum_shard, ids, grads, lr, decay=1.0, bound: int | None = None
+):
     """Adagrad on the rows of this shard among ``ids [M]`` (GLOBAL row ids,
     repeated or not) with ``grads [M, D]``: ``optim.sparse_adagrad_update``,
     the single-device step's tail, on the ids the shard owns.
@@ -80,14 +90,49 @@ def apply_shard_adagrad(table_shard, accum_shard, ids, grads, lr, decay=1.0):
     gradient exactly once), the element or row accumulator and the lazy
     ``decay`` are that function's, chosen by ``optim.rows_tail_form`` from
     the SHARD's shapes, and its scopes stand as it names them: the sort and
-    the permutation under ``fm.dedup``, the update under ``fm.tail``."""
+    the permutation under ``fm.dedup``, the update under ``fm.tail``.
+
+    ``bound`` (static; ``train_step.shard_tail_ids``): how many of the ``M``
+    slots a shard's own share may come to before the step takes the slower
+    exact path.  Under the all-gather update ``M`` is every chip's slots and
+    a shard owns about one in ``row`` of them; the sort puts those first and
+    everything after them is the drop id, which the tail ignores.  So where
+    ``bound < M`` the shard counts what it owns and, under ``lax.cond``, hands
+    the tail the first ``bound`` entries of the sort's order
+    (``sparse_adagrad_update(keep=bound)``: the permutation gather and the
+    kernel's operands stop there) or, with more than ``bound`` owned, the
+    whole list as before.  Nothing is ever dropped: the kept prefix holds
+    every owned slot in the same order, so the state is bit for bit the
+    unbounded tail's; a skewed shard is slower, never wrong.  The branches
+    hold no collective, so each shard decides for itself.  Where ``bound`` is
+    ``None`` or not under ``M`` (the routed update, whose slots ARE the bound;
+    one row shard; a 1 x 1 mesh) there is no ``cond`` and no count: a
+    trace-time branch.  The ``cond`` stands under no scope of its own (a
+    branch's operations would then be under both ``fm.dedup`` and
+    ``fm.tail`` and be counted twice).
+
+    Returns ``(table_shard, accum_shard, took_whole_list)``, the last an
+    int32 scalar: 1 where this shard's owned slots passed ``bound``, 0
+    otherwise, ``None`` where no ``cond`` was traced."""
     shard_rows = table_shard.shape[0]
     with jax.named_scope("fm.dedup"):
-        local, _ = owned_local_ids(ids, shard_rows, sentinel=shard_rows)
-    table_shard, opt = sparse_adagrad_update(
-        table_shard, AdagradState(accum_shard), local, grads, lr, decay=decay
+        local, owned = owned_local_ids(ids, shard_rows, sentinel=shard_rows)
+
+    def tail(table, accum, local, grads, keep=None):
+        table, opt = sparse_adagrad_update(
+            table, AdagradState(accum), local, grads, lr, decay=decay, keep=keep
+        )
+        return table, opt.accum
+
+    operands = (table_shard, accum_shard, local, grads)
+    if bound is None or bound >= local.shape[0]:
+        return (*tail(*operands), None)
+    with jax.named_scope("fm.dedup"):
+        whole = jnp.sum(owned, dtype=jnp.int32) > bound
+    table_shard, accum_shard = lax.cond(
+        whole, tail, functools.partial(tail, keep=bound), *operands
     )
-    return table_shard, opt.accum
+    return table_shard, accum_shard, whole.astype(jnp.int32)
 
 
 @jax.named_scope("fm.gather")
@@ -136,6 +181,7 @@ def sharded_sparse_adagrad_update(
     lr: float,
     num_rows_global: int,
     decay: float = 1.0,
+    bound: int | None = None,
 ):
     """Sparse Adagrad on the local row shard from global per-occurrence grads.
 
@@ -145,8 +191,9 @@ def sharded_sparse_adagrad_update(
     row id can still be touched by several micro-batches, and Adagrad must
     see the fully summed gradient exactly once (the determinism the
     reference's Hogwild explicitly gave up — SURVEY.md §4.2): the shard's
-    tail sums a row's occurrences itself (``apply_shard_adagrad``), so no
-    second, global dedup stands between the exchange and it.
+    tail sums a row's occurrences itself (``apply_shard_adagrad``, whose
+    ``bound`` and third result these are), so no second, global dedup stands
+    between the exchange and it.
     """
     D = table_shard.shape[-1]
     if axis_size(ROW_AXIS) == 1 and axis_size(DATA_AXIS) == 1:
@@ -154,7 +201,7 @@ def sharded_sparse_adagrad_update(
         # tail on the batch's occurrences as they are.
         return apply_shard_adagrad(
             table_shard, accum_shard, ids.reshape(-1), row_grads.reshape(-1, D),
-            lr, decay=decay,
+            lr, decay=decay, bound=bound,
         )
     uids, gsum = dedup_rows(ids.reshape(-1), row_grads.reshape(-1, D), num_rows_global)
     with exchange_scope("fm.tail"):
@@ -163,7 +210,7 @@ def sharded_sparse_adagrad_update(
     # Drop ids (>= num_rows_global, one per trailing slot of each peer's
     # dedup, zero gradients) lie above every shard's range and drop there.
     return apply_shard_adagrad(
-        table_shard, accum_shard, all_uids, all_gsum, lr, decay=decay
+        table_shard, accum_shard, all_uids, all_gsum, lr, decay=decay, bound=bound
     )
 
 

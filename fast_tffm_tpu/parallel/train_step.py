@@ -560,19 +560,24 @@ def _make_gather(
     return (lambda table, ids: routed_gather(table, ids, cap)), cap, cap < m
 
 
-def shard_tail_ids(mesh: Mesh, ids_per_chip: int, lookup: str, capacity_factor: float) -> int:
+def shard_tail_ids(mesh: Mesh, ids_per_chip: int, capacity_factor: float) -> int:
     """How many (id, gradient) slots the rows layout's shard tail
-    (``embedding.apply_shard_adagrad``) is handed a step, where a chip's
-    micro-batch holds ``ids_per_chip`` ids: every chip's under the
-    all-gather update; under the routed one the ``capacity`` slots each row
-    peer sends here, from every data peer (``_make_gather``'s sizing).  It is
-    the ``m`` ``optim.rows_tail_form`` sees at the shard, for whoever says
-    the tail's form aloud (training.dist_train)."""
-    slots = ids_per_chip
-    if lookup == "alltoall":
-        from fast_tffm_tpu.parallel.alltoall import capacity_for
+    (``embedding.apply_shard_adagrad``) takes a step, where a chip's
+    micro-batch holds ``ids_per_chip`` ids: from every chip the ``capacity``
+    slots one row peer may send another (``alltoall.capacity_for``: the
+    uniform share times ``lookup_capacity_factor``, and the binomial tail's
+    room).  Under the routed update those ARE the slots a shard receives
+    (``_make_gather``'s sizing); under the all-gather update, which hands
+    every shard every chip's ``ids_per_chip``, they are the static bound the
+    tail keeps of the sorted list, with the whole list as the counted
+    fallback.  The same number under both lookups, and never more than the
+    slots handed in (``capacity_for`` caps at ``ids_per_chip``: one row
+    shard, or a factor of ``row`` and up, bound nothing).  It is the ``m``
+    ``optim.rows_tail_form`` sees at the shard, for whoever says the tail's
+    form aloud (training.dist_train)."""
+    from fast_tffm_tpu.parallel.alltoall import capacity_for
 
-        slots = capacity_for(ids_per_chip, mesh.shape[ROW_AXIS], capacity_factor)
+    slots = capacity_for(ids_per_chip, mesh.shape[ROW_AXIS], capacity_factor)
     return mesh.shape[DATA_AXIS] * mesh.shape[ROW_AXIS] * slots
 
 
@@ -582,6 +587,7 @@ def make_sharded_train_step(
     table_layout: str = "rows", packed_update: str = "auto",
     accumulator: str = "element", compact_cap: int = 0,
     steps_per_call: int = 1, adagrad_decay: float = 1.0,
+    count_full_tails: bool = False,
 ):
     """Returns jitted SPMD ``step(state, batch) -> (state, global mean loss)``.
 
@@ -594,7 +600,8 @@ def make_sharded_train_step(
     from the input shape (the epoch-tail remainder superbatch compiles its
     own executable).  Under ``fallback`` the return is
     ``(state, losses [K], overflow_steps)`` with the per-step flags SUMMED
-    into one replicated int32 (drivers only count them).  Per-step losses
+    into one replicated int32 (drivers only count them; every counter the
+    step returns is summed so).  Per-step losses
     and the final state are bit-identical to K sequential K=1 steps
     (test-pinned).
 
@@ -613,6 +620,17 @@ def make_sharded_train_step(
     step's result is exactly the allgather step's, and training continues
     deterministically; the step then returns ``(state, loss, overflowed)``
     with a replicated int32 flag so the driver can count skew events.
+
+    The rows layout's shard tail (``embedding.apply_shard_adagrad``) keeps
+    the first ``shard_tail_ids`` slots of its sorted list under BOTH lookups:
+    ``capacity_factor`` is how far over its uniform share a shard's traffic
+    may run before the step takes the slower exact path, whichever exchange
+    fed it (the routed lookup reruns through the all-gather collectives, the
+    all-gather update's tail takes the whole list; neither drops anything).
+    ``count_full_tails`` appends to the step's return a replicated int32:
+    the row shards that took the whole list this step (0 where the shapes
+    make that impossible; summed over the K steps of a fused call), for the
+    driver to count (``shard_tail_full_steps``).
 
     Note the defaults differ by layer on purpose: the CONFIG default
     (``lookup_overflow = fallback``, what the train/predict drivers pass)
@@ -673,6 +691,15 @@ def make_sharded_train_step(
                 return data_loss + reg, data_loss
 
         grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)
+        no_flag = jnp.zeros((), jnp.int32)
+        # The rows layout's shard tail keeps this many of the all-gather
+        # update's slots; whether that is fewer than it is handed is a
+        # trace-time fact, as ``can_overflow`` is.
+        tail_bound, may_fill = None, False
+        if not packed and (lookup == "allgather" or (fallback and can_overflow)):
+            ids_per_chip = batch.ids.shape[0] * batch.ids.shape[1]
+            tail_bound = shard_tail_ids(mesh, ids_per_chip, capacity_factor)
+            may_fill = tail_bound < mesh.size * ids_per_chip
 
         def allgather_branch():
             if fused:
@@ -692,7 +719,7 @@ def make_sharded_train_step(
                         table, batch.ids, g_rows, learning_rate,
                         shard_logical_rows, mode=fmode, k_cap=compact_cap,
                     )
-                return t2, accum, g_dense, dl
+                return t2, accum, g_dense, dl, no_flag
             if packed:
                 from fast_tffm_tpu.ops.packed_table import resolve_packed_update
                 from fast_tffm_tpu.parallel.embedding import (
@@ -719,14 +746,14 @@ def make_sharded_train_step(
                             table, accum, batch.ids, g_rows, learning_rate,
                             num_rows_global, shard_logical_rows,
                         )
-                return t2, a2, g_dense, dl
+                return t2, a2, g_dense, dl, no_flag
             rows = sharded_gather(table, batch.ids)
             (_, dl), (g_rows, g_dense) = grad_fn(rows, dense)
-            t2, a2 = sharded_sparse_adagrad_update(
+            t2, a2, whole = sharded_sparse_adagrad_update(
                 table, accum, batch.ids, g_rows, learning_rate,
-                num_rows_global, decay=decay,
+                num_rows_global, decay=decay, bound=tail_bound,
             )
-            return t2, a2, g_dense, dl
+            return t2, a2, g_dense, dl, whole if may_fill else no_flag
 
         if lookup == "alltoall":
             from fast_tffm_tpu.parallel.alltoall import routed_update, routing_overflow
@@ -765,7 +792,7 @@ def make_sharded_train_step(
                     # NaN the loss so the training loop aborts before
                     # checkpointing.
                     dl = jnp.where(overflow, jnp.nan, dl)
-                return t2, a2, g_dense, dl
+                return t2, a2, g_dense, dl, no_flag
 
             # When overflow is statically impossible, emit the routed branch
             # alone — no bincount, no dual compile (HLO-pinned by
@@ -775,14 +802,14 @@ def make_sharded_train_step(
                 # for packed shards the table's leading dim is PHYSICAL, so
                 # the closure's logical count is the correct one either way.
                 overflowed = routing_overflow(batch.ids, shard_logical_rows, cap)
-                table, accum, g_dense, data_loss_local = lax.cond(
+                table, accum, g_dense, data_loss_local, whole = lax.cond(
                     overflowed, allgather_branch, routed_branch
                 )
             else:
-                table, accum, g_dense, data_loss_local = routed_branch()
+                table, accum, g_dense, data_loss_local, whole = routed_branch()
                 overflowed = jnp.asarray(False)
         else:
-            table, accum, g_dense, data_loss_local = allgather_branch()
+            table, accum, g_dense, data_loss_local, whole = allgather_branch()
             overflowed = jnp.asarray(False)
         if jax.tree.leaves(dense):
             with exchange_scope("fm.tail"):
@@ -793,8 +820,14 @@ def make_sharded_train_step(
             )
             dense_acc = dense_acc.accum
         with exchange_scope("fm.loss"):
-            data_loss = lax.psum(data_loss_local, _BOTH)
-        return table, accum, dense, dense_acc, data_loss, overflowed.astype(jnp.int32)
+            if count_full_tails and may_fill:
+                # The flag rides the loss's all-reduce; a row shard's data
+                # replicas are handed the same slots and decide alike.
+                data_loss, whole = lax.psum((data_loss_local, whole), _BOTH)
+                whole = whole // mesh.shape[DATA_AXIS]
+            else:
+                data_loss, whole = lax.psum(data_loss_local, _BOTH), no_flag
+        return table, accum, dense, dense_acc, data_loss, overflowed.astype(jnp.int32), whole
 
     dense_spec = jax.tree.map(lambda _: P(), model.init_dense(jax.random.key(0)))
     mapped = shard_map(
@@ -808,48 +841,39 @@ def make_sharded_train_step(
             _batch_specs(),
         ),
         out_specs=(
-            P(ROW_AXIS, None), P(ROW_AXIS, None), dense_spec, dense_spec, P(), P(),
+            P(ROW_AXIS, None), P(ROW_AXIS, None), dense_spec, dense_spec, P(), P(), P(),
         ),
         check_vma=False,
     )
 
     def _apply(state: TrainState, batch: Batch):
-        table, accum, dense, dense_acc, loss, overflowed = mapped(
+        table, accum, dense, dense_acc, loss, overflowed, whole = mapped(
             state.table, state.table_opt.accum, state.dense, state.dense_opt.accum, batch
         )
         new = TrainState(
             table, AdagradState(accum), dense, AdagradState(dense_acc), state.step + 1
         )
-        return new, loss, overflowed
+        # The counters the caller asked for, in the order the return names them.
+        return new, loss, (overflowed,) * fallback + (whole,) * count_full_tails
 
     if steps_per_call <= 1:
 
         @partial(jax.jit, donate_argnums=(0,))
         def step(state: TrainState, batch: Batch):
-            new, loss, overflowed = _apply(state, batch)
-            if fallback:
-                return new, loss, overflowed
-            return new, loss
+            new, loss, counts = _apply(state, batch)
+            return (new, loss, *counts)
 
     else:
 
         @partial(jax.jit, donate_argnums=(0,))
         def step(state: TrainState, superbatch: Batch):
             def one(st, b):
-                new, loss, overflowed = _apply(st, b)
-                return new, (loss, overflowed)
+                new, loss, counts = _apply(st, b)
+                return new, (loss, counts)
 
-            state, (losses, ovfs) = lax.scan(one, state, superbatch)
-            if fallback:
-                return state, losses, jnp.sum(ovfs)
-            return state, losses
+            state, (losses, counts) = lax.scan(one, state, superbatch)
+            return (state, losses, *(jnp.sum(c) for c in counts))
 
-    # The cached-dataset wrapper (make_cached_sharded_train_step) must
-    # mirror the flagged signature without re-deriving the config.
-    try:
-        step.overflow_flagged = fallback
-    except AttributeError:  # jit wrapper without settable attributes
-        pass
     return step
 
 

@@ -624,7 +624,8 @@ def _run_training(
     per-step host round-trip.
     ``extra_metrics()`` (optional) is drained at every log point and its
     dict merged into the stdout line and the JSONL record (dist_train uses
-    it to report alltoall overflow-fallback step counts).  ``saveable``
+    it to report alltoall overflow-fallback step counts and the row shards
+    whose tail took the whole exchanged list, ``shard_tail_full_steps``).  ``saveable``
     (optional) converts the live state to its checkpoint form before
     every save — the packed table layout uses it to store LOGICAL [V, D]
     arrays, keeping packed and rows checkpoints interchangeable.
@@ -636,8 +637,10 @@ def _run_training(
     ``optim.rows_tail_profile``: ``tail_form`` ``sweep`` | ``rows``; how
     duplicates are summed, ``tail_duplicates`` ``kernel`` | ``segment_sum``,
     the latter on rows ``segment_sum_lanes`` wide; ``tail_permutation``; the
-    sweep's ``tail_block_lanes``; empty on every other layout) rides the
-    step's ``kind=profile`` record beside ``row_dim``.
+    sweep's ``tail_block_lanes``; dist_train's ``tail_slots``, the exchanged
+    slots a row shard's tail takes, ``parallel.train_step.shard_tail_ids``;
+    empty on every other layout) rides the step's ``kind=profile`` record
+    beside ``row_dim``.
     ``exchange_profile`` (dist_train's: ``mesh`` {data, row}, ``shard_rows``,
     ``lookup``, ``exchange_bytes_per_step`` = the payload bytes a chip sends
     and receives in the step's collectives, parallel/exchange.py) rides that
@@ -808,7 +811,8 @@ def _run_training(
             **{
                 "segment_sum_lanes": None, "tail_form": None,
                 "tail_duplicates": None, "tail_permutation": None,
-                "tail_block_lanes": None, **(tail_profile or {}),
+                "tail_block_lanes": None, "tail_slots": None,
+                **(tail_profile or {}),
             },
             **(exchange_profile or {}),
             **(interaction_profile or {}),
@@ -1958,6 +1962,7 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
         compact_cap=cfg.packed_compact_cap,
         steps_per_call=step_k,
         adagrad_decay=cfg.online_adagrad_decay,
+        count_full_tails=cfg.table_layout == "rows",
     )
     predict_step = make_sharded_predict_step(
         model, mesh, lookup=cfg.lookup, capacity_factor=cfg.lookup_capacity_factor,
@@ -1986,15 +1991,23 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
         from fast_tffm_tpu.parallel.train_step import shard_tail_ids
 
         shard_rows = exchange_profile["shard_rows"]
-        m_ids = shard_tail_ids(
-            mesh, cfg.batch_size // mesh.size * max_nnz, cfg.lookup,
-            cfg.lookup_capacity_factor,
-        )
+        ids_per_chip = cfg.batch_size // mesh.size * max_nnz
+        # The slots the tail takes (its bound under both lookups) of those
+        # the update's exchange hands every shard.
+        m_ids = shard_tail_ids(mesh, ids_per_chip, cfg.lookup_capacity_factor)
+        handed = m_ids if cfg.lookup == "alltoall" else mesh.size * ids_per_chip
         tail_form = rows_tail_form(
             shard_rows, m_ids, model.row_dim, state.table_opt.accum.shape[-1]
         )
-        tail_profile = rows_tail_profile(shard_rows, m_ids, model.row_dim, tail_form)
-        log("sparse tail: " + describe_rows_tail(shard_rows, m_ids, model.row_dim, tail_form))
+        tail_profile = dict(
+            rows_tail_profile(shard_rows, m_ids, model.row_dim, tail_form),
+            tail_slots=m_ids,
+        )
+        log(
+            "sparse tail: "
+            + describe_rows_tail(shard_rows, m_ids, model.row_dim, tail_form)
+            + f"; the shard's first {m_ids} of {handed} exchanged slots"
+        )
     dist_saveable = None
     if cfg.table_layout == "packed":
         # Checkpoints hold LOGICAL [V, D] arrays.  Multi-process: unpack
@@ -2077,10 +2090,7 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
             f"{cached_data.batches} batches/epoch)"
         )
         step_fn = make_cached_sharded_train_step(
-            step_fn, cached_data, steps_per_call=cfg.steps_per_call,
-            overflow_flagged=(
-                cfg.lookup == "alltoall" and cfg.lookup_overflow == "fallback"
-            ),
+            step_fn, cached_data, steps_per_call=cfg.steps_per_call
         )
 
     mark_touched = None
@@ -2092,19 +2102,25 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
 
         mark_touched, _ = make_cached_touched_marker(cached_data)
 
+    # The counters the step returns after its loss, in its order: the routed
+    # lookup's fallback steps, and (rows layout) the row shards whose tail
+    # took the whole exchanged list and not its bounded prefix.
+    counted = ["lookup_overflow_steps"] * (
+        cfg.lookup == "alltoall" and cfg.lookup_overflow == "fallback"
+    ) + ["shard_tail_full_steps"] * (cfg.table_layout == "rows")
     extra_metrics = None
-    if cfg.lookup == "alltoall" and cfg.lookup_overflow == "fallback":
-        # The fallback step returns a replicated overflow flag; fold it into
-        # ONE running device scalar (no host sync, no per-step buffer — a
-        # pending list would pin a live device scalar per step between log
-        # points) and fetch/reset it only at log points.
+    if counted:
+        # Fold each into ONE running device scalar (no host sync, no per-step
+        # buffer — a pending list would pin a live device scalar per step
+        # between log points) and fetch/reset them only at log points.
         raw_step = step_fn
-        overflow_sum = [None]
+        running = [None]
 
         def step_fn(state, b):
-            state, loss, overflowed = raw_step(state, b)
-            overflow_sum[0] = (
-                overflowed if overflow_sum[0] is None else overflow_sum[0] + overflowed
+            state, loss, *counts = raw_step(state, b)
+            running[0] = (
+                counts if running[0] is None
+                else [a + c for a, c in zip(running[0], counts)]
             )
             return state, loss
 
@@ -2113,9 +2129,8 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
             step_fn.lower = raw_step.lower
 
         def extra_metrics():
-            n = int(overflow_sum[0]) if overflow_sum[0] is not None else 0
-            overflow_sum[0] = None
-            return {"lookup_overflow_steps": n}
+            sums, running[0] = running[0] or [0] * len(counted), None
+            return {name: int(n) for name, n in zip(counted, sums)}
 
     train_stream = examples_per_step = evaluate = None
     to_batch = _batch_converter(model.uses_fields)

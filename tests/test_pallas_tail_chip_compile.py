@@ -148,11 +148,15 @@ def test_the_sharded_step_takes_the_sweep_on_the_ids_the_shard_owns(four_chips, 
     """``fm16_criteo_row4.dist_train_fmb``'s step (2^27 rows of 17 over
     ``{data: 1, row: 4}``, 65,536 x 39 ids, allgather lookup) compiled for the
     described ``v5e:2x2``: the shard's tail is the Pallas sweep under
-    ``fm.tail``, asked ONCE, at the shard's shapes and all four chips' ids;
-    nothing scatters into a ``f32[33554432,17]`` shard; the global dedup is
-    gone (one segment sum, the local one, and its ``[638976,128]`` rows; none
-    on ``[2555904,128]``); and no instruction stands under both ``fm.tail``
-    and ``fm.dedup`` (``harness/scopes.py`` would count it twice)."""
+    ``fm.tail``, asked at the shard's shapes and (ISSUE 38) at the 1,284,384
+    slots the tail keeps of all four chips' 2,555,904, and at those in the
+    whole list's branch; each branch of the ONE conditional holds one kernel,
+    in place: nothing but the kernels, and no copy, is on a
+    ``f32[33554432,17]`` shard; the bounded branch's permutation gather is
+    ``f32[1284384,17]`` under ``fm.dedup``; the global dedup is gone (one
+    segment sum, the local one, and its ``[638976,128]`` rows; none on
+    ``[2555904,128]``); and no instruction stands under both ``fm.tail`` and
+    ``fm.dedup`` (``harness/scopes.py`` would count it twice)."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -183,11 +187,16 @@ def test_the_sharded_step_takes_the_sweep_on_the_ids_the_shard_owns(four_chips, 
     )
     batch = jax.tree.map(sd, batch, _batch_specs())
     text = make_sharded_train_step(model, 0.05, mesh).lower(state, batch).compile().as_text()
-    assert asked == [((2**25, shard_tail_ids(mesh, b // 4 * n, "allgather", 2.0), 17, 17), "sweep")]
-    assert asked[0][0][1] == 2555904
+    bound = shard_tail_ids(mesh, b // 4 * n, 2.0)
+    assert bound == 1284384
+    assert sorted(asked) == [((2**25, bound, 17, 17), "sweep"), ((2**25, 2555904, 17, 17), "sweep")]
     shard = re.compile(r"f32\[(33554432,17|17,33554432)\]")
-    on_shard, both, tail_calls, segment_sums = set(), [], 0, []
+    on_shard, both, tail_calls, segment_sums, bounded_gathers = set(), [], collections.Counter(), [], 0
+    computation = None
     for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            computation = head.group(2)
         op = re.match(r"\s*(ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
         if not op:
             continue
@@ -197,14 +206,16 @@ def test_the_sharded_step_takes_the_sweep_on_the_ids_the_shard_owns(four_chips, 
         if len(scopes) > 1:
             both.append(line.strip()[:160])
         if op.group(3) == "custom-call" and "tpu_custom_call" in line:
-            assert "fm.tail" in path and "fm.dedup" not in path, path
-            tail_calls += 1
+            assert "fm.tail" in path and "fm.dedup" not in path and "cond" in path, path
+            tail_calls[computation] += 1
         if shard.search(op.group(2)):  # the instruction's own result is shard-shaped
             on_shard.add(op.group(3))
         if op.group(3) == "scatter":
             segment_sums.append(op.group(2))
-    assert tail_calls == 1
-    assert on_shard <= {"parameter", "bitcast", "custom-call", "get-tuple-element", "tuple"}, on_shard
+        bounded_gathers += op.group(2).startswith(f"f32[{bound},17]") and "fm.dedup" in path and path[-1] == "gather"
+    assert text.count(" conditional(") == 1 and sorted(tail_calls.values()) == [1, 1]  # one kernel a branch
+    assert on_shard <= {"parameter", "bitcast", "custom-call", "get-tuple-element", "tuple", "conditional"}, on_shard
+    assert bounded_gathers >= 1  # the fusion and the gather inside it
     assert not both, both
     assert "2555904,128]" not in text and len(segment_sums) == 1 and "638976,128]" in segment_sums[0], segment_sums
 

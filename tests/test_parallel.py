@@ -691,10 +691,13 @@ def test_dist_train_records_what_its_exchange_moves(tmp_path, steps_per_call):
     said = []
     dist_train(cfg, log=said.append, mesh=make_mesh(1, 4))
     ids, rows = 16 * 8 * 4, 16 * 8 * 5 * 4  # a chip's ids and its rows, in bytes
-    by_hand = ids * 6 + 4 * rows * 3 // 2 + ids * 6 + rows * 6 + 12 + 12
+    # ... the two scalars of the loss, and (ISSUE 38) the int32 flag beside them:
+    # "this shard's tail took the whole list" (384 of the 512 slots bound it here).
+    by_hand = ids * 6 + 4 * rows * 3 // 2 + ids * 6 + rows * 6 + 12 + 12 + 12
     records = [json.loads(line) for line in (tmp_path / "metrics.jsonl").read_text().splitlines()]
     trains = [r for r in records if r["kind"] == "train"]
     assert trains and all(r["exchange_bytes_per_step"] == by_hand for r in trains)
+    assert all(r["shard_tail_full_steps"] == 0 for r in trains)  # 96 distinct ids: nobody owns over 384
     profile = next(r for r in records if r["kind"] == "profile" and r["program"] == "train_step")
     assert (profile["mesh"], profile["shard_rows"], profile["lookup"], profile["exchange_bytes_per_step"]) == (
         {"data": 1, "row": 4}, 24, "allgather", by_hand)
@@ -739,18 +742,25 @@ def test_dist_train_says_the_form_its_shard_tail_took(tmp_path, monkeypatch, for
     said = []
     state = dist_train(cfg, log=said.append, mesh=make_mesh(1, 4))
     assert np.isfinite(np.asarray(state.table)).all()
-    # A shard of 24 rows of 17; four chips' 16 x 8 ids each, or under the routed
-    # update the capacity each of four row peers sends here.
-    slots = 4 * (16 * 8 if lookup == "allgather" else capacity_for(16 * 8, 4, cfg.lookup_capacity_factor))
-    # dist_train's question and the traced step's are the same one (under
-    # ``lookup_overflow = fallback`` the cond's other branch asks the all-gather's).
+    # A shard of 24 rows of 17; from each of four chips the capacity one row peer
+    # sends another: the routed update's slots, and (ISSUE 38) what the tail keeps
+    # of the all-gather update's 4 x 16 x 8.
+    slots = 4 * capacity_for(16 * 8, 4, cfg.lookup_capacity_factor)
+    handed = slots if lookup == "alltoall" else 4 * 16 * 8
+    assert slots == 384
+    # dist_train's question and the traced step's are the same one (the tail's
+    # whole-list branch, and under ``lookup_overflow = fallback`` the cond's other
+    # branch, ask at the all-gather's slots).
     assert asked.count((24, slots, 17, 17)) >= 2
     assert set(asked) <= {(24, slots, 17, 17), (24, 4 * 16 * 8, 17, 17)}
     records = [json.loads(line) for line in (tmp_path / "metrics.jsonl").read_text().splitlines()]
     profile = next(r for r in records if r["kind"] == "profile" and r["program"] == "train_step")
     want = optim.rows_tail_profile(24, slots, 17, form)
     assert want["tail_form"] == form and profile["row_dim"] == 17
-    assert {k: profile[k] for k in want} == want
+    assert {k: profile[k] for k in want} == want and profile["tail_slots"] == slots
+    trains = [r for r in records if r["kind"] == "train"]
+    assert trains and all(r["shard_tail_full_steps"] == 0 for r in trains)
+    assert sum(f"; the shard's first {slots} of {handed} exchanged slots" in str(s) for s in said) == 1
     if form == "sweep":
         assert (profile["tail_duplicates"], profile["tail_permutation"], profile["segment_sum_lanes"]) == ("kernel", "row gather", None)
         line = "sparse tail: pallas rows sweep (block 128 lanes, 1 blocks; duplicates summed in the kernel"

@@ -21,6 +21,13 @@ accumulator, decayed or not:
     segment sum, and the CPU contracts the update into an FMA in one program
     and not the other), untouched rows and the vocabulary's padding bit for
     bit.
+
+ISSUE 38: under the all-gather update a shard's tail keeps the first
+``train_step.shard_tail_ids`` entries of its sorted list (the capacity the
+routed update would have handed it) and takes the whole list only where it
+owns more than that, counted (``count_full_tails``).  The tests at the end
+run steps large enough for the bound to lie under the slot count (those above
+are too small: ``capacity_for`` caps at a chip's ids there).
 """
 
 import jax
@@ -99,7 +106,7 @@ def test_the_shard_tail_steps_the_rows_it_owns_once_and_drops_the_rest(request, 
     mesh = make_mesh(1, ROWS)
     shard = P("row", None)
     tail = jax.jit(shard_map(
-        lambda t, a, i, g: apply_shard_adagrad(t, a, i, g, lr, decay=decay),
+        lambda t, a, i, g: apply_shard_adagrad(t, a, i, g, lr, decay=decay)[:2],
         mesh=mesh, in_specs=(shard, shard, P(), P()), out_specs=(shard, shard), check_vma=False,
     ))
     got_t, got_a = (np.asarray(x) for x in tail(table, accum, ids, grads))
@@ -180,3 +187,202 @@ def test_the_sharded_step_is_the_single_device_step(request, mesh_shape, lookup,
     # |w| <= 0.01 at the start and a step moves it by less than lr = 0.1.
     np.testing.assert_allclose(t[touched], np.asarray(ref.table)[touched], rtol=0, atol=32 * _EPS * 0.1)
     np.testing.assert_allclose(a[touched], np.asarray(ref.table_opt.accum)[touched], rtol=1e-5, atol=0)
+
+
+# --- ISSUE 38: the tail takes its own share of the exchanged slots ---------
+
+V_BIG = 4 * 4096  # 4,096 rows a shard over four, 8,192 over two
+B_BIG, N_BIG = 128, 8  # 256 ids a chip on four chips
+# Mesh, factor: the bound is 4 x capacity_for(256, 4, 2.0) = 672 of 1,024 slots;
+# a row axis of 2 bounds nothing at factor 2, so it runs at 1: 4 x 184 = 736.
+_BOUNDED = pytest.mark.parametrize(
+    "mesh_shape, factor, bound", [((1, 4), 2.0, 672), ((2, 2), 1.0, 736)], ids=["data1xrow4", "data2xrow2"]
+)
+_UNBOUNDED = 4.0  # capacity_for caps at a chip's ids: the parent's program
+
+
+def _big_batches(seed, n=2, high=V_BIG):
+    rng = np.random.default_rng(seed)
+    return [Batch(
+        labels=jnp.asarray(rng.integers(0, 2, size=(B_BIG,)).astype(np.float32)),
+        ids=jnp.asarray(rng.integers(0, high, size=(B_BIG, N_BIG)).astype(np.int32)),
+        vals=jnp.asarray(rng.normal(size=(B_BIG, N_BIG)).astype(np.float32)),
+        fields=jnp.zeros((B_BIG, 0), jnp.int32), weights=jnp.ones((B_BIG,), jnp.float32),
+    ) for _ in range(n)]
+
+
+def _run(model, mesh, batches, **kw):
+    state = init_sharded_state(model, mesh, jax.random.key(11), 0.1, "element")
+    step = make_sharded_train_step(model, 0.1, mesh, count_full_tails=True, **kw)
+    full = []
+    for b in batches:
+        state, _, whole = step(state, b)
+        full.append(int(whole))
+    return np.asarray(state.table), np.asarray(state.table_opt.accum), full
+
+
+def _primitives(jaxpr, out=None):
+    """(primitive name, equation) of every equation, sub-jaxprs included."""
+    from fast_tffm_tpu.parallel.exchange import _sub_jaxprs
+
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        out.append((eqn.primitive.name, eqn))
+        for sub in _sub_jaxprs(eqn):
+            _primitives(sub, out)
+    return out
+
+
+@_FORMS
+@_BOUNDED
+def test_the_bounded_tail_is_bitwise_the_unbounded_tail(request, mesh_shape, factor, bound, form):
+    """(a) Uniform ids: every shard owns about a quarter (a half) of the 1,024
+    slots, under its bound; what the bound cuts off is drop ids alone, so table
+    and accumulator are the whole list's bit for bit, and no shard counts."""
+    from fast_tffm_tpu.parallel.train_step import shard_tail_ids
+
+    model = FMModel(vocabulary_size=V_BIG, factor_num=8, order=2, factor_lambda=1e-4, bias_lambda=1e-4)
+    mesh, batches = make_mesh(*mesh_shape), _big_batches(3)
+    assert shard_tail_ids(mesh, B_BIG * N_BIG // 4, factor) == bound < B_BIG * N_BIG
+    assert shard_tail_ids(mesh, B_BIG * N_BIG // 4, _UNBOUNDED) == B_BIG * N_BIG
+    asked = _take_form(request, form)
+    want_t, want_a, none = _run(model, mesh, batches, capacity_factor=_UNBOUNDED)
+    if asked is not None:
+        assert {a[1] for a in asked} == {B_BIG * N_BIG}
+        del asked[:]
+    got_t, got_a, full = _run(model, mesh, batches, capacity_factor=factor)
+    if asked is not None:  # the bounded branch and the whole list's, each asked at its own count
+        assert {a[1] for a in asked} == {bound, B_BIG * N_BIG}
+    assert full == none == [0, 0]
+    assert np.any(got_t != np.asarray(init_sharded_state(model, mesh, jax.random.key(11), 0.1, "element").table))
+    np.testing.assert_array_equal(got_t, want_t)
+    np.testing.assert_array_equal(got_a, want_a)
+
+
+@_FORMS
+@_BOUNDED
+def test_a_shard_that_owns_more_than_its_bound_takes_the_whole_list_and_is_counted(request, mesh_shape, factor, bound, form):
+    """(b) Every id in shard 0's range: it owns all four chips' slots (about
+    990 distinct of 1,024, over the bound), takes the whole list and is the
+    one shard counted, a step; the others own nothing and take the bounded
+    branch.  The state is still the single-device step's."""
+    model = FMModel(vocabulary_size=V_BIG, factor_num=8, order=2, factor_lambda=1e-4, bias_lambda=1e-4)
+    batches = _big_batches(5, high=4096)
+    ref = init_state(model, jax.random.key(11), 0.1, "element")
+    ref_step = make_train_step(model, 0.1)
+    for b in batches:  # the one device's rows form, traced before the rule is patched
+        ref, _ = ref_step(ref, b)
+    owned = [sum(len(np.unique(c)) for c in np.split(np.asarray(b.ids).ravel(), 4)) for b in batches]
+    assert min(owned) > bound
+    _take_form(request, form)
+    mesh = make_mesh(*mesh_shape)
+    got_t, got_a, full = _run(model, mesh, batches, capacity_factor=factor)
+    assert full == [1, 1]  # that shard alone, whatever the data axis replicates
+    touched = np.unique(np.concatenate([np.asarray(b.ids).ravel() for b in batches]))
+    rest = np.setdiff1d(np.arange(V_BIG), touched)
+    first = init_state(model, jax.random.key(11), 0.1, "element")
+    np.testing.assert_array_equal(got_t[rest], np.asarray(first.table)[rest])
+    np.testing.assert_allclose(got_t[touched], np.asarray(ref.table)[touched], rtol=0, atol=32 * _EPS * 0.1)
+    np.testing.assert_allclose(got_a[touched], np.asarray(ref.table_opt.accum)[touched], rtol=1e-5, atol=0)
+
+
+@pytest.mark.parametrize(
+    "mesh_shape, kw",
+    [((2, 2), dict(capacity_factor=2.0)), ((1, 4), dict(lookup="alltoall")), ((4, 1), {}), ((1, 4), dict(capacity_factor=_UNBOUNDED))],
+    ids=["row2-factor2", "alltoall", "one-row-shard", "factor4"],
+)
+def test_where_the_bound_is_not_under_the_slots_there_is_no_conditional_and_no_count(mesh_shape, kw):
+    """(c) A trace-time branch, as ``test_impossible_overflow_skips_cond`` pins
+    for the lookup: the traced step holds no ``cond``, counts nothing (no
+    reduction to an int32 scalar) and its psums carry the two scalars of the
+    loss alone, asked for the counter or not; the counter is a constant 0."""
+    model = FMModel(vocabulary_size=V_BIG, factor_num=8, order=2)
+    mesh, (b,) = make_mesh(*mesh_shape), _big_batches(7, n=1)
+    state = init_sharded_state(model, mesh, jax.random.key(0), 0.1, "element")
+    step = make_sharded_train_step(model, 0.1, mesh, count_full_tails=True, **kw)
+    eqns = _primitives(jax.make_jaxpr(step)(state, b).jaxpr)
+    names = [n for n, _ in eqns]
+    assert "cond" not in names and "shard_map" in names
+    counts = [e for n, e in eqns if n == "reduce_sum" and e.outvars[0].aval.shape == () and e.outvars[0].aval.dtype == jnp.int32]
+    assert not counts
+    scalars = lambda eqns: sorted(str(v.aval.dtype) for n, e in eqns if n.startswith("psum") for v in e.invars if v.aval.shape == ())
+    # The weights' sum and the loss (and the routed update's own overflow flag).
+    assert scalars(eqns) == ["float32", "float32"] + ["int32"] * ("lookup" in kw)
+    _, _, whole = step(state, b)
+    assert int(whole) == 0
+    # ... and the step that is bounded holds exactly those: one cond, and the flag beside the loss.
+    if "lookup" not in kw and mesh_shape == (1, 4):
+        eqns = _primitives(jax.make_jaxpr(make_sharded_train_step(model, 0.1, mesh, count_full_tails=True))(state, b).jaxpr)
+        assert [n for n, _ in eqns].count("cond") == 1
+        assert scalars(eqns) == ["float32", "float32", "int32"]
+
+
+@pytest.mark.parametrize("mesh_shape, factor", [((1, 4), 2.0), ((2, 2), 1.0), ((2, 2), 2.0), ((1, 4), 0.5)])
+def test_the_tail_is_asked_at_the_same_slots_under_both_lookups(monkeypatch, mesh_shape, factor):
+    """(d) ``shard_tail_ids`` does not ask which exchange fed the tail: data x
+    row x the routed capacity, never more than the slots handed in; and it is
+    the ``m`` ``rows_tail_form`` is asked at as either step is traced (the
+    all-gather's whole-list branch asks at every chip's slots besides)."""
+    from fast_tffm_tpu import optim
+    from fast_tffm_tpu.parallel.alltoall import capacity_for
+    from fast_tffm_tpu.parallel.train_step import shard_tail_ids
+
+    rule, asked = optim.rows_tail_form, []
+    monkeypatch.setattr(optim, "rows_tail_form", lambda *a, **k: asked.append(a[1]) or rule(*a, **k))
+    model = FMModel(vocabulary_size=V_BIG, factor_num=8, order=2)
+    mesh, (b,) = make_mesh(*mesh_shape), _big_batches(7, n=1)
+    per_chip, slots = B_BIG * N_BIG // 4, B_BIG * N_BIG
+    bound = shard_tail_ids(mesh, per_chip, factor)
+    assert bound == 4 * capacity_for(per_chip, mesh_shape[1], factor) <= slots
+    state = init_sharded_state(model, mesh, jax.random.key(0), 0.1, "element")
+    for lookup, want in (("allgather", {bound, slots}), ("alltoall", {bound})):
+        del asked[:]
+        jax.make_jaxpr(make_sharded_train_step(model, 0.1, mesh, lookup=lookup, capacity_factor=factor))(state, b)
+        assert set(asked) == want, (lookup, asked)
+
+
+@_BOUNDED
+def test_the_bounded_step_exchanges_the_unbounded_steps_bytes_and_one_flag(mesh_shape, factor, bound):
+    """(e) No collective moves a row more or less: the bounded step's
+    ``exchange_bytes`` are the whole list's plus the flag's int32 on the loss's
+    psum (4 (n - 1) / n of its 4 bytes), and nothing where nobody counts."""
+    from fast_tffm_tpu.parallel.exchange import exchange_bytes
+
+    model = FMModel(vocabulary_size=V_BIG, factor_num=8, order=2)
+    mesh, (b,) = make_mesh(*mesh_shape), _big_batches(7, n=1)
+    state = init_sharded_state(model, mesh, jax.random.key(0), 0.1, "element")
+    parent = exchange_bytes(make_sharded_train_step(model, 0.1, mesh, capacity_factor=_UNBOUNDED), state, b)
+    assert exchange_bytes(make_sharded_train_step(model, 0.1, mesh, capacity_factor=factor), state, b) == parent
+    counted = make_sharded_train_step(model, 0.1, mesh, capacity_factor=factor, count_full_tails=True)
+    assert exchange_bytes(counted, state, b) == parent + 4 * 3 * 4 // 4
+
+
+@pytest.mark.parametrize("d, what", [(9, "sort operands"), (17, "row gather"), (17, "dedup_rows")])
+def test_keep_cuts_the_sorted_list_and_nothing_else(d, what):
+    """``keep`` is honoured right after the ONE sort, in each of the tail's
+    three fronts: what comes back is the first ``keep`` entries of what comes
+    back without it (for ``dedup_rows``: while the kept prefix holds every id
+    under ``num_rows``), and ``None`` is the call without it."""
+    from fast_tffm_tpu import optim
+
+    rng = np.random.default_rng(d)
+    m, keep, rows = 96, 40, 50
+    ids = rng.integers(0, rows, size=m).astype(np.int32)
+    ids[rng.permutation(m)[: m - 30]] = rows + 5  # 30 ids of the table, 66 drop ids
+    g = rng.standard_normal((m, d)).astype(np.float32)
+    sid, order = optim.sort_ids(ids, rows)
+    np.testing.assert_array_equal(np.asarray(sid)[30:], rows)  # the drop ids sort last, clamped
+    for got, want in zip(optim.sort_ids(ids, rows, keep), (sid, order)):
+        np.testing.assert_array_equal(got, want[:keep])
+    if what == "dedup_rows":
+        (u, s), (uk, sk) = optim.dedup_rows(ids, g, rows), optim.dedup_rows(ids, g, rows, keep)
+        n = len(np.unique(ids[ids < rows]))
+        assert uk.shape == (keep,) and sk.shape == (keep, d)
+        np.testing.assert_array_equal(uk[:n], u[:n])
+        np.testing.assert_array_equal(sk[:n], s[:n])
+        assert np.all(np.asarray(uk[n:]) >= rows) and np.all(np.diff(np.asarray(uk)) > 0)
+        return
+    assert optim.occurrences_permutation(d) == what
+    (s0, g0), (sk, gk) = optim.occurrences_by_id(ids, g, rows), optim.occurrences_by_id(ids, g, rows, keep)
+    np.testing.assert_array_equal(sk, s0[:keep])
+    np.testing.assert_array_equal(gk, g0[:, :keep])
