@@ -216,7 +216,9 @@ class ServingEngine:
         # train/predict drivers (shared run_id per engine lifetime); the
         # compile sentinel turns any steady-state flush compile into a
         # kind=compile event — the bucket-ladder pin, now observable.
-        # No stall watchdog here: an idle engine is healthy, not stalled.
+        # No stall deadline here: an idle engine is healthy, not stalled;
+        # the monitor's thread runs as the host clock alone (freeze_ms,
+        # gc_ms on every kind=serving record, ``ServingMetrics.log_to``).
         self._monitor = RunMonitor(
             cfg.metrics_path,
             run_id=cfg.telemetry_run_id,
@@ -921,6 +923,13 @@ class ServingEngine:
             # it must NEVER kill the collector.
             pass
         self._last_flush_t = t_resolved
+        if self.metrics.interval_age(t_resolved) == 0.0:
+            # This flush opens the next record's interval: what the host
+            # lost while the engine sat idle (start-up, a quiet server) is
+            # no frame's, so the record's freeze_ms / gc_ms count from
+            # here, as its stage fields do.  The kind=freeze events and the
+            # summary keep all of it.
+            self._monitor.drain_host_clock()
         # One metrics group per request plus one per BLOCK: a frame's
         # rows share submit/resolve instants, so its group carries a row
         # count instead of n duplicate histogram insertions.

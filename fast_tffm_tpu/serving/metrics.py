@@ -427,6 +427,11 @@ class ServingMetrics:
         envelope) or, for bare callers, a utils.tracing.MetricsLogger
         (no-op logger ⇒ no-op here)."""
         snap = {**self.snapshot(), **self._close_interval()}
+        # The host clock's fields over the same interval (a RunMonitor's;
+        # a bare logger runs no clock and the record lacks them).
+        drain = getattr(sink, "drain_host_clock", None)
+        if drain is not None:
+            snap.update(drain())
         emit = getattr(sink, "emit", None)
         if emit is not None:
             emit("serving", **snap)

@@ -24,17 +24,31 @@ schema and carrying no run identity.  ``RunMonitor`` unifies them:
     bytes (``memory_stats`` where the runtime exposes it, live-array sum
     otherwise), plus peak-so-far; one final record is always emitted at
     close so every run documents its high-water mark.
-  * **liveness watchdog** — a heartbeat thread: when no dispatch
-    completes for ``stall_timeout_s``, it dumps every Python thread's
-    stack and the prefetch queue depth as a ``kind=stall`` event,
-    classified input-starved (empty queue: the producer is the
-    bottleneck) vs device-bound (data ready, the consumer/device is
-    wedged).  Armed by the first completed dispatch; suspended
-    (``suspended()``) through phases that legitimately dispatch nothing
-    (validation, checkpoint saves); defers while a stack shows an XLA
-    compile in progress (slow, not stuck — up to 10x the deadline, then
-    fires classified "compiling").  One event per stall episode; a
-    recovered-then-stalled run fires again.
+  * **liveness watchdog and host clock** — ONE daemon thread a monitor
+    (none where there is neither a sink nor a ``stall_timeout_s``), two
+    duties.  *Liveness*: when no dispatch completes for
+    ``stall_timeout_s``, it dumps every Python thread's stack and the
+    prefetch queue depth as a ``kind=stall`` event, classified
+    input-starved (empty queue: the producer is the bottleneck) vs
+    device-bound (data ready, the consumer/device is wedged).  Armed by
+    the first completed dispatch; suspended (``suspended()``) through
+    phases that legitimately dispatch nothing (validation, checkpoint
+    saves); defers while a stack shows an XLA compile in progress (slow,
+    not stuck — up to 10x the deadline, then fires classified
+    "compiling").  One event per stall episode; a recovered-then-stalled
+    run fires again.  *Host clock*: the thread only sleeps, 20 ms at a
+    time on the monotonic clock, so how late it wakes is how long NO
+    Python thread of the process ran — a freeze, which stretches whichever
+    span is open on every thread and which no stage clock can name.
+    Lateness past 50 ms is summed into ``freeze_ms`` / ``freezes`` /
+    ``freeze_max_ms`` of the next ``kind=train`` / ``kind=serving``
+    record (``drain_host_clock``) beside the exact pauses of Python's
+    cyclic collector (``gc_ms``, ``gc_collections``: a process-wide pair
+    of ``gc.callbacks`` entries, around every other library's, whose
+    totals monitors difference), and each writes a
+    ``kind=freeze`` event (at most 20 a run) with the evidence that
+    classifies it: ``gc`` | ``off-cpu`` | ``gil`` | ``clock-late``
+    (``_classify_freeze``).
 
 ``arm_hang_exit`` is the hard os._exit timer the batch tools (tools/) arm
 BEFORE ``import jax``: a batch tool must not hang.  That contract is why
@@ -45,6 +59,7 @@ everything jax-touching here is lazy.
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import os
 import sys
@@ -52,7 +67,7 @@ import threading
 import time
 import traceback
 
-from fast_tffm_tpu.utils.tracing import MetricsLogger
+from fast_tffm_tpu.utils.tracing import MetricsLogger, span
 
 __all__ = [
     "SCHEMA_VERSION",
@@ -65,6 +80,7 @@ __all__ = [
     "write_json_artifact",
     "thread_stacks",
     "classify_stall",
+    "gc_pause_totals",
     "first_nonfinite_leaf",
     "arm_hang_exit",
     "enable_compilation_cache",
@@ -107,12 +123,28 @@ SCHEMAS: dict[str, tuple[str, ...]] = {
         "host_rss_peak_bytes",
         "device_bytes",
         "device_peak_bytes",
+        "sample_ms",  # what the sample cost the thread that took it
     ),
     "stall": (
         "deadline_s",
         "since_last_step_s",
         "classification",
         "prefetch_queue_depth",
+        "stacks",
+    ),
+    # The host clock (RunMonitor._watch): one event a freeze — for late_ms
+    # (50 or more) the clock thread, which only sleeps, did not wake.
+    # freeze_ms is the part of it during which no heartbeat() arrived
+    # either (0 under classification clock-late); gc_ms and cpu_ms are the
+    # collector's pause and all threads' CPU time inside the tick.  NOT a
+    # stall: report.py counts those and --strict gates on the count.
+    "freeze": (
+        "late_ms",
+        "freeze_ms",
+        "classification",
+        "gc_ms",
+        "cpu_ms",
+        "beats",
         "stacks",
     ),
     "anomaly": ("event", "loss"),
@@ -185,6 +217,8 @@ SCHEMAS: dict[str, tuple[str, ...]] = {
         "total_compiles",
         "steady_compiles",
         "stalls",
+        "freezes",
+        "freeze_ms",
         "anomalies",
         "platform",
         "device_kind",
@@ -455,6 +489,7 @@ class _MemWatermarks:
         self._dev_peak = 0
 
     def sample(self) -> dict:
+        t0 = time.perf_counter()
         host = host_rss_bytes()
         dev = device_live_bytes()
         if host is not None:
@@ -469,7 +504,201 @@ class _MemWatermarks:
             "host_rss_peak_bytes": self._host_peak or None,
             "device_bytes": dev,
             "device_peak_bytes": self._dev_peak if dev is not None else None,
+            # The sample runs on whichever thread is due (on_dispatch: the
+            # train loop, the serve collector), so it says what it cost whom.
+            "sample_ms": round(1e3 * (time.perf_counter() - t0), 3),
+            "thread": threading.current_thread().name,
         }
+
+
+# -- host clock -----------------------------------------------------------
+
+# The clock thread sleeps this long at a time; well over the interpreter's
+# 5 ms switch interval, so a thread that merely has to be handed the
+# interpreter lock is never late by a tick.  A freeze reads up to a period
+# short (the clock was due that far into it).  The period is not what the
+# clock costs: on the chip the scorer's p50 read the same at 20 ms and at
+# 50 ms (PERF.md section 6, PR 39), so the finer one is kept.
+CLOCK_PERIOD_S = 0.02
+# Lateness of a wake past this is a freeze (ten switch intervals: on a
+# loaded test machine with more workers than cores the clock reads 5-25 ms
+# late, tests/test_host_clock.py), and each writes a kind=freeze event, at
+# most so many a run (the counters go on counting).
+FREEZE_S = 0.05
+FREEZE_RECORDS_MAX = 20
+_OS_SAMPLE_EVERY_S = 1.0
+
+# Process-wide gc.callbacks entries, installed once: like jax.monitoring's
+# listeners they are never taken out again, so monitors difference the
+# totals (pause seconds, collections, generation-2 seconds, when the
+# collection in progress began or None) instead of each adding entries.  One
+# tuple, replaced whole: a reader on another thread sees one collection's
+# state.  TWO entries, the first of the list and the last: the pause a
+# thread pays for a collection holds every other library's callbacks too,
+# and jax's own (``xla_client._xla_gc_callback``: the runtime's deferred
+# frees, under the interpreter lock, on both phases) is most of a long one.
+_gc_state = (0.0, 0, 0.0, None)
+_gc_span = None
+_gc_installed = False
+
+
+def _on_gc_start(phase: str, info: dict) -> None:
+    global _gc_state, _gc_span
+    if phase != "start":
+        return
+    total, n, gen2, _ = _gc_state
+    _gc_state = (total, n, gen2, time.perf_counter())
+    # A full collection is the one long enough to make an idle gap on the
+    # device: under a profiler session it lies on the host plane, on the
+    # thread that paid it.  Only where jax is already loaded: the router's
+    # and the supervisor's monitors stay jax-free.
+    if info.get("generation") == 2 and "jax" in sys.modules:
+        _gc_span = span("host.gc", generation=2)
+        _gc_span.__enter__()
+
+
+def _on_gc_stop(phase: str, info: dict) -> None:
+    global _gc_state, _gc_span
+    total, n, gen2, began = _gc_state
+    if phase != "stop" or began is None:
+        return
+    dt = time.perf_counter() - began
+    is2 = info.get("generation") == 2
+    _gc_state = (total + dt, n + 1, gen2 + dt if is2 else gen2, None)
+    if _gc_span is not None:
+        ann, _gc_span = _gc_span, None
+        ann.__exit__(None, None, None)
+
+
+def _ensure_gc_listener() -> None:
+    global _gc_installed
+    with _compile_lock:
+        if _gc_installed:
+            return
+        _gc_installed = True
+    gc.callbacks.insert(0, _on_gc_start)
+    gc.callbacks.append(_on_gc_stop)
+
+
+def gc_pause_totals() -> tuple[float, int, float]:
+    """Process-wide (seconds paused, collections, seconds in generation 2)
+    of Python's cyclic collector, the callbacks of ``gc.callbacks`` between
+    ours included, since the first monitor was created.  A
+    collection in progress on another thread counts up to now: its ``stop``
+    callback is Python code, and the interpreter lock can be handed to the
+    reader before it has run."""
+    total, n, gen2, began = _gc_state
+    if began is not None:
+        total += max(0.0, time.perf_counter() - began)
+    return total, n, gen2
+
+
+_cpu_stat_path = None  # the cgroup's cpu.stat once found; "" where there is none
+
+
+def _find_cpu_stat() -> str:
+    """This process's cgroup ``cpu.stat``: the v2 root and v1's ``cpu``
+    mount as a container sees them, then the paths /proc/self/cgroup names
+    (a host's view, a nested group, ``cpu,cpuacct``).  Looked for once."""
+    paths = ["/sys/fs/cgroup/cpu.stat", "/sys/fs/cgroup/cpu/cpu.stat"]
+    try:
+        with open("/proc/self/cgroup") as f:
+            for line in f:
+                _, ctrls, path = line.rstrip("\n").split(":", 2)
+                if not ctrls:  # v2: "0::/path"
+                    paths += [f"/sys/fs/cgroup{path}/cpu.stat", f"/sys/fs/cgroup/unified{path}/cpu.stat"]
+                elif "cpu" in ctrls.split(","):
+                    paths += [f"/sys/fs/cgroup/{ctrls}{path}/cpu.stat", f"/sys/fs/cgroup/cpu{path}/cpu.stat",
+                              f"/sys/fs/cgroup/{ctrls}/cpu.stat"]
+    except (OSError, ValueError):
+        pass
+    for path in paths:
+        try:
+            with open(path) as f:
+                if "nr_throttled" in f.read():
+                    return path
+        except OSError:
+            continue
+    return ""
+
+
+_OS_COUNTERS = (
+    "throttled_usec",
+    "nr_throttled",
+    "psi_cpu_usec",
+    "psi_memory_usec",
+    "psi_io_usec",
+    "steal_ticks",
+    "majflt",
+    "nivcsw",
+)
+
+
+def os_counters() -> dict:
+    """What the OS counts of a process kept off the CPU, each None where
+    its file is not there: the cgroup's CPU-quota throttling
+    (``_find_cpu_stat``: v2 ``throttled_usec``, v1's ``throttled_time`` in
+    ns), the
+    pressure-stall totals of /proc/pressure (``some``: microseconds in which
+    a task waited for the resource), the machine's stolen time (/proc/stat,
+    clock ticks the hypervisor ran something else), and this process's
+    major page faults and involuntary context switches.  Read by the clock
+    thread, never by a hot thread."""
+    global _cpu_stat_path
+    out = dict.fromkeys(_OS_COUNTERS)
+    if _cpu_stat_path is None:
+        _cpu_stat_path = _find_cpu_stat()
+    if _cpu_stat_path:
+        try:
+            with open(_cpu_stat_path) as f:
+                kv = dict(line.split()[:2] for line in f if line.strip())
+            if "throttled_usec" in kv:
+                out["throttled_usec"] = int(kv["throttled_usec"])
+            elif "throttled_time" in kv:
+                out["throttled_usec"] = int(kv["throttled_time"]) // 1000
+            if "nr_throttled" in kv:
+                out["nr_throttled"] = int(kv["nr_throttled"])
+        except (OSError, ValueError):
+            pass
+    for res in ("cpu", "memory", "io"):
+        try:
+            with open(f"/proc/pressure/{res}") as f:
+                out[f"psi_{res}_usec"] = int(f.readline().rsplit("total=", 1)[1])
+        except (OSError, ValueError, IndexError):
+            pass
+    try:
+        with open("/proc/stat") as f:
+            out["steal_ticks"] = int(f.readline().split()[8])
+    except (OSError, ValueError, IndexError):
+        pass
+    try:
+        import resource
+
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        out["majflt"], out["nivcsw"] = int(ru.ru_majflt), int(ru.ru_nivcsw)
+    # analysis: ok exception-hygiene resource probe degrades to None by documented contract ("None where the file is not there")
+    except Exception:
+        pass
+    return out
+
+
+def _classify_freeze(frozen_s: float, gc_s: float, cpu_s: float) -> str:
+    """What a late wake of the clock was.  ``frozen_s`` is the part of the
+    lateness before any ``heartbeat()`` arrived: under ``FREEZE_S`` of it
+    Python ran meanwhile and the lateness was the clock thread's own (the
+    scheduler's): **clock-late**.  Else **gc** where the collector's pause
+    covers at least half of it; else **off-cpu** where all threads' CPU
+    time over the late part is under a quarter of it (the process as a
+    whole did not run: throttled, stolen, paging — the OS deltas say
+    which); else **gil**: the process ran and Python did not, one thread
+    held the interpreter lock in a C call (the stacks say whose)."""
+    if frozen_s < FREEZE_S:
+        return "clock-late"
+    if gc_s >= 0.5 * frozen_s:
+        return "gc"
+    if cpu_s < 0.25 * frozen_s:
+        return "off-cpu"
+    return "gil"
 
 
 # -- stall forensics ------------------------------------------------------
@@ -568,14 +797,16 @@ def first_nonfinite_leaf(tree) -> str | None:
 
 class RunMonitor:
     """Owns the MetricsLogger and stamps the shared envelope on every
-    record; hosts the compile sentinel, the memory sampler, and the
-    liveness watchdog.  Thread-safe: drivers emit from their loop thread,
-    the watchdog from its own.
+    record; hosts the compile sentinel, the memory sampler, and the one
+    thread that is liveness watchdog and host clock.  Thread-safe: drivers
+    emit from their loop thread, the watchdog from its own.
 
     ``source`` names the driver (train / predict / serving) on compile
     events.  ``queue_depth_fn`` (settable later via
     ``set_queue_depth_fn``) lets the stall classifier read the live
-    prefetch-queue depth.  ``stall_timeout_s`` 0 disables the watchdog;
+    prefetch-queue depth.  ``stall_timeout_s`` 0 disables the liveness
+    check (the thread still runs as the host clock wherever records reach a
+    file; with neither there is no thread);
     ``mem_every_s`` 0 reduces kind=mem to the one guaranteed close()
     record.  ``device`` is ``log_device()``'s triple, stamped on the
     kind=summary record.
@@ -642,9 +873,24 @@ class RunMonitor:
         self._last_beat = None
         self._stall_fired = False
         self._suspended = 0
+        # The host clock's side of heartbeat(): when the clock's next wake
+        # is due, and the heartbeats that arrived after it was (Python ran
+        # while the clock did not: its lateness is not a freeze).
+        self._due = float("inf")
+        self._late_beats = 0
+        self._late_beat_t = None
+        # Freezes since the last drain_host_clock(): ms, count, longest;
+        # the collector's totals as that drain saw them; the run's totals.
+        self._fz = [0.0, 0, 0.0]
+        self._gc_seen = (0.0, 0, 0.0)
+        self.freezes = 0
+        self.freeze_ms = 0.0
+        self._freeze_records = 0
         self._stop = threading.Event()
         self._watchdog = None
-        if self._stall_timeout > 0:
+        if self._stall_timeout > 0 or self._logger.active:
+            _ensure_gc_listener()
+            self._gc_seen = gc_pause_totals()
             self._watchdog = threading.Thread(
                 target=self._watch, name="telemetry-watchdog", daemon=True
             )
@@ -699,8 +945,12 @@ class RunMonitor:
         """The liveness signal: call whenever a dispatch completes."""
         with self._lock:
             self._step = int(step)
-            self._last_beat = time.monotonic()
+            self._last_beat = now = time.monotonic()
             self._stall_fired = False
+            if now > self._due:
+                self._late_beats += 1
+                if self._late_beat_t is None:
+                    self._late_beat_t = now
 
     @contextlib.contextmanager
     def suspended(self):
@@ -801,74 +1051,192 @@ class RunMonitor:
 
     # -- watchdog ---------------------------------------------------------
 
+    def drain_host_clock(self) -> dict:
+        """The host clock's flat fields over the interval since this
+        monitor's previous drain, for the record that closes it (a log
+        window's kind=train, an interval's kind=serving): ``freeze_ms``
+        (lateness of the clock's wakes past ``FREEZE_S``, summed),
+        ``freezes``, ``freeze_max_ms``; ``gc_ms``, ``gc_collections``,
+        ``gc_gen2_ms`` (the collector's pauses, exact).  A zero is
+        "watched, none seen"; {} from a monitor that runs no clock."""
+        if self._watchdog is None:
+            return {}
+        gc_now = gc_pause_totals()
+        with self._lock:
+            (ms, n, longest), self._fz = self._fz, [0.0, 0, 0.0]
+            seen, self._gc_seen = self._gc_seen, gc_now
+        return {
+            "freeze_ms": round(ms, 3),
+            "freezes": n,
+            "freeze_max_ms": round(longest, 3),
+            "gc_ms": round(1e3 * (gc_now[0] - seen[0]), 3),
+            "gc_collections": gc_now[1] - seen[1],
+            "gc_gen2_ms": round(1e3 * (gc_now[2] - seen[2]), 3),
+        }
+
     def _watch(self) -> None:
+        """The monitor's one thread.  It sleeps ``CLOCK_PERIOD_S`` at a
+        time and does nothing else that can block, so ``now - due`` at a
+        wake is time in which no Python thread of the process ran
+        (``_on_late_wake``); once a second it samples the OS counters a
+        freeze record differences; every ``poll`` it checks liveness."""
         poll = max(0.02, min(self._stall_timeout / 4.0, 1.0))
-        while not self._stop.wait(poll):
+        now = time.monotonic()
+        next_stall = now + poll if self._stall_timeout > 0 else float("inf")
+        next_os = now
+        os_last = {}
+        # All threads' CPU time and the collector's pauses at the last wake,
+        # and the CPU time of the last tick that was on time.
+        cpu_last, gc_last, cpu_tick = time.process_time(), gc_pause_totals()[0], 0.0
+        due = now + CLOCK_PERIOD_S
+        with self._lock:
+            self._due = due
+        while not self._stop.wait(max(0.0, due - time.monotonic())):
+            now = time.monotonic()
+            late = now - due
+            cpu, gc_s = time.process_time(), gc_pause_totals()[0]
             with self._lock:
-                if self._last_beat is None or self._suspended:
-                    continue  # not armed yet / in a no-dispatch phase
-                since = time.monotonic() - self._last_beat
-                fired = self._stall_fired
-                step = self._step
-            if since < self._stall_timeout or fired:
-                continue
-            stacks = thread_stacks()
-            stacks.pop("telemetry-watchdog", None)  # our own frame is noise
-            compiling = compiling_now(stacks)
-            if compiling and since < 10.0 * self._stall_timeout:
-                # An XLA compile in progress (e.g. a new shape's warmup
-                # program) is slow, not wedged — don't fire, don't latch;
-                # re-check next poll.  Past 10x the deadline it IS worth
-                # an event, classified "compiling".
-                continue
-            with self._lock:
-                self._stall_fired = True
-                self.stalls += 1
-            depth = None
-            if self._queue_depth_fn is not None:
-                try:
-                    depth = self._queue_depth_fn()
-                # analysis: ok exception-hygiene driver-injected probe; the watchdog must survive any probe bug — depth=None still classifies
-                except Exception:
-                    depth = None
-            alive = None
-            if self._producer_alive_fn is not None:
-                try:
-                    alive = self._producer_alive_fn()
-                # analysis: ok exception-hygiene driver-injected probe; the watchdog must survive any probe bug — alive=None still classifies
-                except Exception:
-                    alive = None
-            s_idle = None
-            if self._stream_idle_fn is not None:
-                try:
-                    s_idle = self._stream_idle_fn()
-                # analysis: ok exception-hygiene driver-injected probe; the watchdog must survive any probe bug — s_idle=None still classifies
-                except Exception:
-                    s_idle = None
-            cls = (
-                "compiling"
-                if compiling
-                else classify_stall(depth, stacks, alive, s_idle)
-            )
-            try:
-                self.emit(
-                    "stall",
-                    step=step,
-                    deadline_s=self._stall_timeout,
-                    since_last_step_s=round(since, 3),
-                    classification=cls,
-                    prefetch_queue_depth=depth,
-                    producer_alive=alive,
-                    stacks=stacks,
+                beats, beat_t = self._late_beats, self._late_beat_t
+            frozen_wake = late >= FREEZE_S
+            stacks = None
+            if frozen_wake and self._freeze_records < FREEZE_RECORDS_MAX:
+                # First of what can hand the interpreter lock back: whoever
+                # held it is still in the frame that did.
+                stacks = thread_stacks()
+                stacks.pop("telemetry-watchdog", None)  # our own frame is noise
+            if frozen_wake or now >= next_os:
+                os_now = os_counters()
+                os_delta = {
+                    k: v - os_last[k] for k, v in os_now.items()
+                    if v is not None and os_last.get(k) is not None
+                }
+                os_last, next_os = os_now, now + _OS_SAMPLE_EVERY_S
+            if frozen_wake:
+                # Frozen until the first sign that Python ran: a heartbeat
+                # past the due time, or this wake.
+                frozen = late if beat_t is None else min(late, max(0.0, beat_t - due))
+                self._on_late_wake(
+                    late, frozen, beats, gc_s - gc_last, cpu - cpu_last, cpu_tick,
+                    os_delta, stacks,
                 )
-            except (OSError, ValueError):
-                pass  # a full metrics disk must not kill stall detection
-            log_quietly(
-                self._log,
-                f"telemetry watchdog: no step for {since:.1f}s "
-                f"(deadline {self._stall_timeout:.1f}s) at step {step} — "
-                f"{cls}; thread stacks -> kind=stall record",
+            else:
+                cpu_tick = cpu - cpu_last
+            cpu_last, gc_last = cpu, gc_s
+            if now >= next_stall:
+                next_stall = now + poll
+                self._check_stall()
+            # The next wake is due a period from HERE: what this wake's own
+            # work took (stacks, file reads, a record) is not lateness.
+            due = time.monotonic() + CLOCK_PERIOD_S
+            with self._lock:
+                self._late_beats, self._late_beat_t, self._due = 0, None, due
+
+    def _on_late_wake(
+        self, late: float, frozen: float, beats: int, gc_s: float,
+        cpu_s: float, cpu_tick_s: float, os_delta: dict, stacks: dict | None,
+    ) -> None:
+        """One wake of the clock ``late`` seconds past its due time.  The CPU
+        time of the late part is the tick's less what an on-time tick (the
+        ``CLOCK_PERIOD_S`` before the due time) used last."""
+        cls = _classify_freeze(frozen, gc_s, max(0.0, cpu_s - cpu_tick_s))
+        counted = 0.0 if cls == "clock-late" else 1e3 * frozen
+        if counted:
+            with self._lock:
+                self._fz[0] += counted
+                self._fz[1] += 1
+                self._fz[2] = max(self._fz[2], counted)
+                self.freezes += 1
+                self.freeze_ms += counted
+            if "jax" in sys.modules:
+                # A marker on the profiler's host plane: its start less
+                # late_ms is where the freeze began.
+                with span("host.freeze", late_ms=round(1e3 * late, 1)):
+                    pass
+        if stacks is None:
+            return  # the run has had its events
+        self._freeze_records += 1
+        try:
+            self.emit(
+                "freeze",
+                late_ms=round(1e3 * late, 3),
+                freeze_ms=round(counted, 3),
+                classification=cls,
+                gc_ms=round(1e3 * gc_s, 3),
+                cpu_ms=round(1e3 * cpu_s, 3),
+                cpu_prev_tick_ms=round(1e3 * cpu_tick_s, 3),
+                beats=beats,
+                os_delta=os_delta,
+                stacks=stacks,
             )
+        except (OSError, ValueError):
+            pass  # a full metrics disk must not kill the clock
+
+    def _check_stall(self) -> None:
+        with self._lock:
+            if self._last_beat is None or self._suspended:
+                return  # not armed yet / in a no-dispatch phase
+            since = time.monotonic() - self._last_beat
+            fired = self._stall_fired
+            step = self._step
+        if since < self._stall_timeout or fired:
+            return
+        stacks = thread_stacks()
+        stacks.pop("telemetry-watchdog", None)  # our own frame is noise
+        compiling = compiling_now(stacks)
+        if compiling and since < 10.0 * self._stall_timeout:
+            # An XLA compile in progress (e.g. a new shape's warmup
+            # program) is slow, not wedged — don't fire, don't latch;
+            # re-check next poll.  Past 10x the deadline it IS worth
+            # an event, classified "compiling".
+            return
+        with self._lock:
+            self._stall_fired = True
+            self.stalls += 1
+        depth = None
+        if self._queue_depth_fn is not None:
+            try:
+                depth = self._queue_depth_fn()
+            # analysis: ok exception-hygiene driver-injected probe; the watchdog must survive any probe bug — depth=None still classifies
+            except Exception:
+                depth = None
+        alive = None
+        if self._producer_alive_fn is not None:
+            try:
+                alive = self._producer_alive_fn()
+            # analysis: ok exception-hygiene driver-injected probe; the watchdog must survive any probe bug — alive=None still classifies
+            except Exception:
+                alive = None
+        s_idle = None
+        if self._stream_idle_fn is not None:
+            try:
+                s_idle = self._stream_idle_fn()
+            # analysis: ok exception-hygiene driver-injected probe; the watchdog must survive any probe bug — s_idle=None still classifies
+            except Exception:
+                s_idle = None
+        cls = (
+            "compiling"
+            if compiling
+            else classify_stall(depth, stacks, alive, s_idle)
+        )
+        try:
+            self.emit(
+                "stall",
+                step=step,
+                deadline_s=self._stall_timeout,
+                since_last_step_s=round(since, 3),
+                classification=cls,
+                prefetch_queue_depth=depth,
+                producer_alive=alive,
+                stacks=stacks,
+            )
+        except (OSError, ValueError):
+            pass  # a full metrics disk must not kill stall detection
+        log_quietly(
+            self._log,
+            f"telemetry watchdog: no step for {since:.1f}s "
+            f"(deadline {self._stall_timeout:.1f}s) at step {step} — "
+            f"{cls}; thread stacks -> kind=stall record",
+        )
 
     # -- shutdown ---------------------------------------------------------
 
@@ -911,6 +1279,8 @@ class RunMonitor:
             total_compiles=self.compiles_total,
             steady_compiles=self.compiles_steady,
             stalls=self.stalls,
+            freezes=self.freezes,
+            freeze_ms=round(self.freeze_ms, 3),
             anomalies=self.anomalies,
             **self.device,
             **summary_fields,

@@ -1092,6 +1092,10 @@ def _run_training(
                     stage_ms = _window_clocks(
                         clocks, pending_steps, time.perf_counter() - t_sync
                     )
+                    # What the host lost all at once in this window (freezes
+                    # of the interpreter, the collector's pauses): inside
+                    # the stage fields above, not beside them.
+                    stage_ms.update(monitor.drain_host_clock())
                     pending_steps = 0
                     _check_finite(
                         mean_loss, cfg, monitor=monitor,

@@ -55,7 +55,12 @@ def span(name: str, **ids):
     ``--trace 1``) the stage lies on the same xplane, on the same clock,
     as the device ops it waited for or fed.  ``ids`` (ints, short strings)
     ride the event as stats.  Off a profiler session this is a C++ flag
-    check: ~0.5 us, 0.8 with ids."""
+    check: ~0.5 us, 0.8 with ids.
+
+    Two names are not stages but time the host lost all at once, inside
+    whichever stage was open (telemetry's host clock): ``host.gc`` over a
+    full collection of Python's collector, on the thread that paid it, and
+    ``host.freeze``, a marker where the clock thread woke late."""
     import jax
 
     return jax.profiler.TraceAnnotation(name, **ids)

@@ -3,6 +3,7 @@ clock reading summed into a field of a record the program already writes,
 and one annotation of the same name on the profiler's host plane."""
 
 import contextlib
+import gc
 import glob
 import json
 import os
@@ -18,6 +19,7 @@ from fast_tffm_tpu.config import load_config
 from fast_tffm_tpu.models.base import Batch
 from fast_tffm_tpu.models.fm import FMModel
 from fast_tffm_tpu.serving import ServingEngine
+from fast_tffm_tpu.telemetry import CLOCK_PERIOD_S, FREEZE_S
 from fast_tffm_tpu.trainer import init_state, make_predict_step, make_train_step
 from fast_tffm_tpu.training import train
 from fast_tffm_tpu.utils.prefetch import prefetch
@@ -31,16 +33,31 @@ INTERVAL_FIELDS = (
     "collector_busy_share", "deadline_flush_share",
 )
 STAGES = ("assemble_ms", "dispatch_ms", "fetch_ms", "reply_ms")
+# The host clock's fields (telemetry.RunMonitor.drain_host_clock): time the
+# host lost all at once, INSIDE whichever stage was open, never beside them.
+HOST_CLOCK_FIELDS = ("freeze_ms", "freezes", "freeze_max_ms", "gc_ms", "gc_collections")
 
 
 class _Sink:
-    """What ``ServingMetrics.log_to`` writes to, kept."""
+    """What ``ServingMetrics.log_to`` writes to, kept; the host clock is the
+    engine's own monitor's."""
 
-    def __init__(self):
+    def __init__(self, monitor=None):
         self.records = []
+        if monitor is not None:
+            self.drain_host_clock = monitor.drain_host_clock
 
     def emit(self, kind, **fields):
         self.records.append({"kind": kind, **fields})
+
+
+def _assert_host_clock_fields(rec, wall_ms):
+    """The five fields are there, and what they count lies inside the wall
+    time of the record's interval (a loaded machine may freeze for real)."""
+    for k in HOST_CLOCK_FIELDS:
+        assert isinstance(rec[k], (int, float)) and rec[k] >= 0, (k, rec)
+    assert rec["freeze_max_ms"] <= rec["freeze_ms"] <= wall_ms
+    assert (rec["freezes"] == 0) == (rec["freeze_ms"] == 0.0)
 
 
 def _slow_stages(eng, flush_times, assemble_s=0.004, score_s=0.008):
@@ -82,11 +99,14 @@ def _drive(eng, rng, n_blocks, rows=4, per_flush=4):
 
 
 def test_serving_interval_records_tile_the_collectors_time(tmp_path):
-    cfg = _cfg(tmp_path, serve_buckets=(1, 4, 16), serve_flush_deadline_ms=1.0, serve_metrics_every_s=0.0)
+    cfg = _cfg(tmp_path, serve_buckets=(1, 4, 16), serve_flush_deadline_ms=1.0, serve_metrics_every_s=0.0,
+               metrics_path=str(tmp_path / "serve.jsonl"))  # a sink: the engine's monitor runs its clock
     _checkpoint(cfg)
     rng = np.random.default_rng(0)
-    sink, flush_times = _Sink(), []
+    flush_times = []
     with ServingEngine(cfg, log=lambda *_: None) as eng:
+        sink = _Sink(eng._monitor)
+        sink.drain_host_clock()
         _slow_stages(eng, flush_times)
         walls, seen = [], 0
         for n_blocks in (120, 160):
@@ -101,6 +121,7 @@ def test_serving_interval_records_tile_the_collectors_time(tmp_path):
             for k in INTERVAL_FIELDS:
                 assert isinstance(rec[k], (int, float)) and np.isfinite(rec[k]), (k, rec[k])
             assert rec["frame_in_ms"] is None and rec["interval_frames"] == 0  # no replica reader here
+            _assert_host_clock_fields(rec, 1e3 * walls[-1] + 50.0)
             # The four stages tile a flush: their means sum to the flush
             # time as timed from outside, within 2%.
             staged = sum(rec[k] for k in STAGES) * n / 1e3
@@ -125,6 +146,10 @@ def test_serving_interval_records_tile_the_collectors_time(tmp_path):
         # No flush since the last record: the next one carries no interval.
         eng.metrics.log_to(sink)
         assert "interval_s" not in sink.records[-1] and sink.records[-1]["flushes"] == snap["flushes"]
+    # The engine's own records (the close record here) carry the fields too.
+    own = [json.loads(l) for l in open(tmp_path / "serve.jsonl")]
+    own = [r for r in own if r.get("kind") == "serving"]
+    assert own and all(set(HOST_CLOCK_FIELDS) <= set(r) for r in own)
 
 
 def test_histogram_bins_by_arithmetic_as_by_search():
@@ -205,6 +230,9 @@ def test_train_records_split_the_wall_time_of_a_step(tmp_path):
         steps = r["step"] - prev["step"]
         total += steps * (r["wait_ms"] + r["dispatch_ms"] + r["host_ms"]) + r["sync_ms"]
         wall += 1e3 * (t1 - t0)
+        # A freeze is inside the four fields (it stretches whichever was
+        # open), so they still tile the window with the clock's beside them.
+        _assert_host_clock_fields(r, 1e3 * (t1 - t0) + 50.0)
     assert abs(total - wall) <= 0.05 * wall, (total, wall)
     inputs = [r for r in rows if r.get("kind") == "input"]
     assert inputs and all(isinstance(r["wait_ms"], float) and r["wait_ms"] >= 0.0 for r in inputs)
@@ -309,6 +337,20 @@ def test_spans_lie_on_the_profilers_host_plane(tmp_path):
                 got, statuses, _ = unpack_scores_frame(count, payload)
                 assert list(got) == list(req) and not statuses.any()
         assert list(prefetch(iter(range(5)), depth=2)) == list(range(5))
+        # What the host loses all at once, made for real: a full collection,
+        # and one C call that holds the interpreter lock past the clock's
+        # threshold by a period and more (the replica's monitor has a sink, so
+        # its clock runs).
+        gc.collect()
+        xs = list(np.random.default_rng(3).random(400_000))
+        t_end = time.perf_counter() + 20.0
+        while True:
+            t0 = time.perf_counter()
+            sorted(xs)
+            if time.perf_counter() - t0 >= FREEZE_S + 2 * CLOCK_PERIOD_S or time.perf_counter() > t_end:
+                break
+            xs = xs + xs
+        time.sleep(4 * CLOCK_PERIOD_S)
     finally:
         jax.profiler.stop_trace()
     with socket.create_connection(("127.0.0.1", ready.port), timeout=30) as sock:
@@ -320,7 +362,7 @@ def test_spans_lie_on_the_profilers_host_plane(tmp_path):
     lines = _host_events(trace_dir)
     names = {n for evs in lines.values() for n, _, _ in evs}
     for want in ("serve.frame_in", "serve.collect_wait", "serve.flush", "serve.assemble", "serve.dispatch",
-                 "serve.fetch", "serve.reply", "input.wait"):
+                 "serve.fetch", "serve.reply", "input.wait", "host.gc", "host.freeze"):
         assert want in names, (want, sorted(n for n in names if "." in n)[:40])
     collector = next(evs for evs in lines.values() if any(n == "serve.flush" for n, _, _ in evs))
     flushes = [(s, e) for n, s, e in collector if n == "serve.flush"]

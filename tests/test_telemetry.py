@@ -96,6 +96,13 @@ _DRIVER_PAYLOADS = {
         phase="steady", elapsed_s=61.2, ok=True, unanswered=0,
         freshness_scored_p99_ms=212.4, chain_len=5, disk_bytes=1048576,
     ),
+    # The host clock's event (ISSUE 39): the monitor writes it itself, but
+    # only when the interpreter really froze for 50 ms — the table cannot
+    # wait for that, so the row is the emitter's own payload shape.
+    "freeze": dict(
+        late_ms=312.4, freeze_ms=312.4, classification="gil", gc_ms=0.0,
+        cpu_ms=309.8, beats=0, os_delta={"nivcsw": 2}, stacks={},
+    ),
     # Tiered parameter store (ISSUE 12): the per-log-window residency
     # record the training loop drains from paramstore stats.
     "tiering": dict(

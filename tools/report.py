@@ -213,6 +213,24 @@ def summarize(records: list[dict]) -> dict:
         }
         for r in kinds.get("stall", [])
     ]
+    # The host clock's freezes (the summary's totals; kind=freeze events, at
+    # most 20 a run): counted apart from stalls,
+    # which --strict gates on — a freeze is the machine's or the
+    # interpreter's, a stall the program's.
+    summaries = kinds.get("summary", [])
+    s["freezes"] = summaries[-1].get("freezes") if summaries else None
+    s["freeze_ms"] = summaries[-1].get("freeze_ms") if summaries else None
+    s["freeze_events"] = [
+        {
+            "t": r.get("t"),
+            "late_ms": r.get("late_ms"),
+            "freeze_ms": r.get("freeze_ms"),
+            "classification": r.get("classification"),
+            "gc_ms": r.get("gc_ms"),
+            "cpu_ms": r.get("cpu_ms"),
+        }
+        for r in kinds.get("freeze", [])
+    ]
     s["anomalies"] = len(kinds.get("anomaly", []))
     s["anomaly_events"] = [
         {
@@ -691,6 +709,15 @@ def render(s: dict, title: str = "run") -> str:
             f"  - step {e['step']}: {e['classification']}, "
             f"{e['since_last_step_s']}s without a step, "
             f"queue depth {e['prefetch_queue_depth']}"
+        )
+    L.append(
+        f"- freezes: {_fmt(s['freezes'], 0)} ({_fmt(s['freeze_ms'])} ms in which "
+        "no Python thread ran; the first 20 below)"
+    )
+    for e in s["freeze_events"]:
+        L.append(
+            f"  - t={e['t']}s: {e['classification']}, clock {e['late_ms']} ms late, "
+            f"{e['freeze_ms']} ms frozen (gc {e['gc_ms']} ms, cpu {e['cpu_ms']} ms)"
         )
     L.append(f"- anomalies: {s['anomalies']}")
     for e in s["anomaly_events"]:
