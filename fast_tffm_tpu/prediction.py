@@ -125,6 +125,15 @@ def _run_predict(
     interaction = _say_interaction(
         log, model, cfg.batch_size // (mesh.size if mesh is not None else 1), backward=False,
     )
+    # So with the forward gather's form (trainer.gather_rows: the
+    # single-device rows layout; the packed and sharded gathers have one).
+    gather = {}
+    if mesh is None and cfg.table_layout != "packed":
+        from fast_tffm_tpu.trainer import describe_gather, gather_form, gather_profile
+
+        shape = (*state.table.shape[:1], cfg.batch_size * max_nnz, state.table.shape[1])
+        gather = gather_profile(*shape, gather_form(*shape))
+        log("forward gather: " + describe_gather(*shape, gather["gather_form"]))
     # Multi-host: the sharded predict step is ONE SPMD program over the
     # global mesh; replicated scores come back on every process and process
     # 0 writes them.  When the batch size divides evenly, the INPUT is also
@@ -216,7 +225,7 @@ def _run_predict(
                 ledger.stage(
                     "predict_step", predict_step, (state, b),
                     examples=int(getattr(b.labels, "shape", (0,))[0] or 0) or None,
-                    **interaction,
+                    **interaction, **gather,
                 )
             scores = np.asarray(predict_step(state, b))
             batches += 1

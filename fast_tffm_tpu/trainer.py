@@ -3,7 +3,10 @@
 The TPU-native analog of the reference's session step loop
 (`renyi533/fast_tffm` :: local trainer: sess.run(train_op) over the graph
 parser → gather → scorer → loss → Adagrad scatter-add).  Here one jitted
-function fuses gather → fused scorer (custom VJP) → loss → dedup (one sort
+function fuses gather (row by row, or, where a big batch reads a table of
+sub-tile rows on a TPU, one kernel sweep of its transposed view with the
+result sorted back to batch order: gather_form) → fused scorer (custom VJP)
+→ loss → dedup (one sort
 of the ids, the gradients brought to that order; ahead of row operations
 also a segment sum on tile-wide rows) → sparse Adagrad tail (row by row:
 accumulator gather and scatter-set, table scatter-add; or, where the batch
@@ -24,6 +27,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from fast_tffm_tpu.models.base import Batch, logistic_loss
 from fast_tffm_tpu.optim import (
@@ -31,12 +35,17 @@ from fast_tffm_tpu.optim import (
     dense_adagrad_update,
     init_adagrad,
     init_table_adagrad,
+    sort_ids,
     sparse_adagrad_update,
 )
 
 __all__ = [
     "TrainState",
     "init_state",
+    "gather_form",
+    "gather_profile",
+    "describe_gather",
+    "gather_rows",
     "train_step_body",
     "make_train_step",
     "make_decayed_body",
@@ -96,12 +105,125 @@ def init_state(
     )
 
 
-def gather_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
+# What the forward gather costs in its two forms on a TPU v5e (PERF.md §6,
+# PR 40: every piece alone, medians of eight calls, at 2^26 x 9 under
+# 2,555,904 uniform ids, 2^25 x 31 under 720,896 and 2^25 x 17 under
+# 2,555,904).  Row by row, a lane-major row is one single-lane read a float:
+# 22.3 / 30.8 / 39.0 ns a row of 9 / 17 / 31 floats, which is 5.6 ns and
+# 8.35 for every group of 8 sublanes the row spans.  As a sweep the table's
+# padded bytes pass through the kernel in 17.9 ms of its 22.1 and 16.2 at
+# the first two shapes (4.3 GB both: 240 GB/s, where the tail's sweep
+# streams 560; its grid and its contraction bind it, not the HBM), and an id
+# of a row of 9 pays 13.5 ns: 2.1 for its share of the sort with positions,
+# 3.6 for the work list and its chunk's grid step, 7.8 on the way back to
+# batch order as one of the ten operands of a sort.  That last cost is the
+# sort's, and a sort's grows faster than its operands (18 of them read 44.7
+# ms where ten read 20.0, PR 32); gathered row by row instead, the way back
+# lost at both shapes that were read (2^25 x 31: 30.3 ms in all against the
+# row gather's 28.1; 2^25 x 17 under 2,555,904 ids: 114 against 78.7).  So
+# the rule holds for rows up to ``_SWEEP_MAX_D`` floats, the widest whose way
+# back the chip has read, and says ``rows`` past it.
+_ROW_NS = 5.6
+_ROW_TILE_NS = 8.35
+_GATHER_SWEEP_BYTES_PER_S = 240e9
+_SWEEP_ID_NS = 13.5
+_SWEEP_MAX_D = 9
+
+
+def gather_form(num_rows: int, m: int, d: int, backend: str | None = None) -> str:
+    """Which form ``gather_rows`` takes for ``m`` ids on ``num_rows`` rows of
+    ``d`` when nobody says: ``"sweep"`` (ops.pallas_gather.sweep_gather, the
+    table read once through its transposed view, the result sorted back to
+    batch order) or ``"rows"`` (XLA's row gather).  A trace-time function of
+    the shapes and the backend, as ``optim.rows_tail_form`` is the tail's:
+
+      * only a TPU takes the sweep (anywhere else the kernel would run
+        interpreted inside every step);
+      * only rows of at most ``_SWEEP_MAX_D`` floats: those are held
+        lane-major (a row read is one single-lane access a float, the
+        transposed view the kernel takes is a bitcast), and their way back
+        to batch order is the one the chip has read.  A wider row keeps the
+        row gather, which won wherever the two were read;
+      * only where the sweep's work list fits the scalar memory
+        (ops.pallas_tail.sweep_fits);
+      * only where the table's bytes, once, at the rate the kernel reaches
+        plus what an id costs on its way through the sort, the kernel and
+        back take less time than the ids' row reads.  ``fm8_criteo`` (2^26
+        rows of 9, 65,536 x 39 ids): 17.9 + 34.5 ms against 57.0, the sweep
+        (the two cross at 2.03M ids); the same table under a serving flush
+        of at most 512 x 39 ids: 17.9 ms against 0.45, the rows.
+    """
+    if (backend or jax.default_backend()) != "tpu" or d > _SWEEP_MAX_D:
+        return "rows"
+    from fast_tffm_tpu.ops.pallas_tail import sweep_fits
+
+    if not sweep_fits(num_rows, d, m):
+        return "rows"
+    tiles = -(-d // 8)
+    sweep_s = num_rows * 4 * 8 * tiles / _GATHER_SWEEP_BYTES_PER_S + m * _SWEEP_ID_NS * 1e-9
+    return "sweep" if sweep_s < m * (_ROW_NS + _ROW_TILE_NS * tiles) * 1e-9 else "rows"
+
+
+def gather_profile(num_rows: int, m: int, d: int, form: str = "rows") -> dict:
+    """The forward gather's trace-time choice as a step's ``kind=profile``
+    record carries it: ``gather_form`` and, under the sweep,
+    ``gather_items`` = the kernel's grid length a step (static: every block
+    of the table once plus every chunk of the ids once); null under the
+    rows."""
+    items = None
+    if form == "sweep":
+        from fast_tffm_tpu.ops.pallas_gather import sweep_gather_items
+
+        items = sweep_gather_items(num_rows, d, m)
+    return dict(gather_form=form, gather_items=items)
+
+
+def describe_gather(num_rows: int, m: int, d: int, form: str = "rows") -> str:
+    """``gather_profile`` as one start-up line (a trace-time choice, so it
+    is said once)."""
+    if form == "sweep":
+        return (
+            f"pallas sweep of table.T ({gather_profile(num_rows, m, d, form)['gather_items']} "
+            f"grid items a step; ids sorted once, columns brought back to batch "
+            f"order as sort operands, row width {d})"
+        )
+    return f"xla row gather ({m} rows of {d} a step)"
+
+
+def gather_rows(table: jax.Array, ids: jax.Array, form: str | None = None) -> jax.Array:
     """``table[ids]``: the rows-layout gather of touched rows only, under
     the step's ``fm.gather`` scope (the packed and sharded gathers carry
-    the same name where they live)."""
+    the same name where they live), in one of two forms that ``gather_form``
+    chooses between from the shapes (``form``: a test names one to run it on
+    any backend).  ``rows``: XLA's gather, a row at a time.  ``sweep``: the
+    ids are sorted once with their positions (``optim.sort_ids``), the table
+    is read once through its transposed view, block by block, and every id
+    takes its row's values through a one-hot contraction of exact bfloat16
+    parts (ops.pallas_gather.sweep_gather: ``[D, M]`` in id order), and the
+    ``D`` columns go back to batch order as the operands of ONE sort keyed
+    by the positions.  Nothing is rounded: the result is ``table[ids]`` bit
+    for bit but for the kernel's three stated limits (-0.0 comes back as
+    0.0, a value under about 1e-33 loses its last part, a non-finite value
+    reaches its 128-row group's other rows that the same chunk reads), ids
+    outside ``[0, V)`` included (brought to the row XLA's gather reads,
+    before the sort)."""
+    v, d = table.shape
+    flat = ids.reshape(-1)
+    if form is None:
+        form = gather_form(v, flat.shape[0], d)
     with jax.named_scope("fm.gather"):
-        return table[ids]
+        if form != "sweep":
+            return table[ids]
+        from fast_tffm_tpu.ops.pallas_gather import sweep_gather
+
+        # What ``table[ids]`` reads for an id outside [0, V): a negative id
+        # counts from the end, then the nearest row.
+        flat = jnp.clip(jnp.where(flat < 0, flat + v, flat), 0, v - 1)
+        sid, order = sort_ids(flat, v)
+        cols = sweep_gather(table, sid)  # [D, M], in id order
+        # The positions are unique: an unstable sort loses nothing.
+        _, *cols = lax.sort((order, *cols), num_keys=1, is_stable=False)
+        return jnp.stack(cols, axis=-1).reshape(*ids.shape, d)
 
 
 def batch_loss(model, table_rows, dense, batch: Batch):
@@ -120,7 +242,8 @@ def train_step_body(
     decay: float = 1.0, gather=gather_rows,
 ):
     """The (unjitted) single-device step, by the scope its ops carry:
-    ``fm.gather`` (the batch's rows) → ``fm.interaction`` (fused scorer and
+    ``fm.gather`` (the batch's rows, in the form ``gather_form`` chooses) →
+    ``fm.interaction`` (fused scorer and
     its backward) → ``fm.loss`` → ``fm.dedup`` → ``fm.tail``, in one of two
     forms that optim.sparse_adagrad_update chooses between from the shapes
     BEFORE it dedups (``optim.rows_tail_form``).  ``rows``: ``fm.dedup`` is
@@ -274,8 +397,6 @@ def make_scanned_train_step(model, learning_rate: float, body=None):
     preserved: the scan carry aliases the donated input buffers, so the
     [V, D] table still updates in place across all K micro-steps.
     """
-    from jax import lax
-
     body = body or train_step_body
 
     @partial(jax.jit, donate_argnums=(0,))

@@ -639,8 +639,10 @@ def _run_training(
     the latter on rows ``segment_sum_lanes`` wide; ``tail_permutation``; the
     sweep's ``tail_block_lanes``; dist_train's ``tail_slots``, the exchanged
     slots a row shard's tail takes, ``parallel.train_step.shard_tail_ids``;
-    empty on every other layout) rides the step's ``kind=profile`` record
-    beside ``row_dim``.
+    empty on every other layout; and the forward gather's,
+    ``trainer.gather_profile``: ``gather_form`` ``sweep`` | ``rows`` and the
+    sweep kernel's ``gather_items``, its grid length a step) rides the
+    step's ``kind=profile`` record beside ``row_dim``.
     ``exchange_profile`` (dist_train's: ``mesh`` {data, row}, ``shard_rows``,
     ``lookup``, ``exchange_bytes_per_step`` = the payload bytes a chip sends
     and receives in the step's collectives, parallel/exchange.py) rides that
@@ -812,6 +814,7 @@ def _run_training(
                 "segment_sum_lanes": None, "tail_form": None,
                 "tail_duplicates": None, "tail_permutation": None,
                 "tail_block_lanes": None, "tail_slots": None,
+                "gather_form": None, "gather_items": None,
                 **(tail_profile or {}),
             },
             **(exchange_profile or {}),
@@ -1395,7 +1398,13 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
             rows_tail_form,
             rows_tail_profile,
         )
-        from fast_tffm_tpu.trainer import make_decayed_body, make_dedup_body
+        from fast_tffm_tpu.trainer import (
+            describe_gather,
+            gather_form,
+            gather_profile,
+            make_decayed_body,
+            make_dedup_body,
+        )
 
         if cfg.dedup_gather_rows > 0:
             # Device-side dedup-before-gather (ROADMAP D12): the
@@ -1409,13 +1418,21 @@ def train(cfg: Config, *, resume: bool = False, log=print, step_hook=None):
             step_body = None  # train_step_body
         # The form the step's tail takes is optim.sparse_adagrad_update's
         # choice at trace time; asked here only to say it once.
-        m_ids = cfg.batch_size * cfg.max_nnz
+        m_ids = cfg.batch_size * max_nnz
         num_rows, row_dim = state.table.shape
         tail_form = rows_tail_form(
             num_rows, m_ids, row_dim, state.table_opt.accum.shape[-1]
         )
         tail_profile = rows_tail_profile(num_rows, m_ids, row_dim, tail_form)
         log("sparse tail: " + describe_rows_tail(num_rows, m_ids, row_dim, tail_form))
+        # So is the forward gather's (trainer.gather_rows; the dedup body
+        # brings its own gather and asks nobody).
+        if cfg.dedup_gather_rows > 0:
+            log(f"forward gather: xla rows of the batch's unique ids (dedup_gather_rows = {cfg.dedup_gather_rows})")
+        else:
+            gather_kind = gather_form(num_rows, m_ids, row_dim)
+            tail_profile.update(gather_profile(num_rows, m_ids, row_dim, gather_kind))
+            log("forward gather: " + describe_gather(num_rows, m_ids, row_dim, gather_kind))
         step_fn = make_train_step(
             model, cfg.learning_rate, decay=decay, body=step_body
         )

@@ -501,3 +501,59 @@ def test_report_renders_and_gates_observability(tmp_path):
     assert r.returncode == 1 and "freshness p99" in r.stdout
     r = run(fat, "--compare", base, "--strict")
     assert r.returncode == 1 and "bytes/example" in r.stdout
+
+
+@pytest.mark.parametrize(
+    "kw, form",
+    [
+        (dict(model="fm", factor_num=4), "rows"),  # off a TPU nobody takes the sweep
+        (dict(model="fm", factor_num=4), "sweep"),  # the rule says so (patched): 200 rows, one block; 32 x 8 ids, one chunk
+        (dict(model="fm", factor_num=4, dedup_gather_rows=256), "dedup"),  # the dedup body brings its own gather
+        (dict(model="fm", factor_num=4, table_layout="packed"), None),  # no rows-layout gather
+    ],
+    ids=["rows", "sweep", "dedup_gather_rows", "packed"],
+)
+def test_the_profile_records_say_the_forward_gathers_form_and_grid(dataset, monkeypatch, kw, form):
+    """``gather_form`` and ``gather_items`` (the sweep kernel's grid length a
+    step; null under the rows) ride the step's ``kind=profile`` record beside
+    the tail's fields, and the predict program's; the start-up line ``forward
+    gather: ...`` says the same.  A trace-time choice (``trainer.gather_form``),
+    asked once for the record and once by ``gather_rows`` when the step is
+    traced: of the same shapes, so the record names the form the program took."""
+    from fast_tffm_tpu import trainer
+    from fast_tffm_tpu.prediction import predict
+
+    rule, asked = trainer.gather_form, []
+    monkeypatch.setattr(
+        trainer, "gather_form", lambda *a, **k: asked.append(a) or ("sweep" if form == "sweep" else rule(*a, **k))
+    )
+    extra = dict(predict_files=(str(dataset / "train.libsvm"),), score_path=str(dataset / "scores.txt"))
+    cfg = _cfg(dataset, tag="gather", epoch_num=1, **extra, **kw)
+    logs = []
+    train(cfg, log=lambda *a: logs.append(" ".join(map(str, a))))
+    (prof,) = [r for r in _read(cfg.metrics_path) if r["kind"] == "profile" and r["program"] == "train_step"]
+    said = [l for l in logs if l.startswith("forward gather: ")]
+    items = 1 + 1  # one block of 256 lanes, one chunk of 256 ids
+    if form == "sweep":
+        assert (prof["gather_form"], prof["gather_items"]) == ("sweep", items)
+        assert said == [
+            f"forward gather: pallas sweep of table.T ({items} grid items a step; ids sorted once, "
+            "columns brought back to batch order as sort operands, row width 5)"
+        ]
+    elif form == "rows":
+        assert (prof["gather_form"], prof["gather_items"]) == ("rows", None)
+        assert said == [f"forward gather: xla row gather ({32 * NNZ} rows of 5 a step)"]
+    else:
+        assert (prof["gather_form"], prof["gather_items"]) == (None, None)
+        assert len(said) == (1 if form == "dedup" else 0)
+    assert "tail_form" in prof and "row_dim" in prof
+    if form in ("rows", "sweep"):
+        assert len(asked) >= 2 and set(asked) == {(200, 32 * NNZ, 5)}  # the record's shapes are the traced step's
+        asked.clear()
+        pcfg = _cfg(dataset, tag="gather_p", model_file=cfg.model_file, metrics_path=str(dataset / "m_gather_p.jsonl"), **extra, **kw)
+        logs.clear()
+        predict(pcfg, log=lambda *a: logs.append(" ".join(map(str, a))))
+        (prof,) = [r for r in _read(pcfg.metrics_path) if r["kind"] == "profile" and r["program"] == "predict_step"]
+        assert (prof["gather_form"], prof["gather_items"]) == (form, items if form == "sweep" else None)
+        assert len([l for l in logs if l.startswith("forward gather: ")]) == 1
+        assert len(asked) >= 2 and set(asked) == {(200, 32 * NNZ, 5)}

@@ -8,7 +8,9 @@ of the whole step at the two train cells' shapes: which ops stand under
 ``fm.dedup`` and ``fm.tail`` in each form (ISSUE 32: the sweep's step sums
 no segments and sorts once; the rows' step is what it was), and of the
 SHARDED step at the four-chip cell's shapes (ISSUE 36: the shard's tail is
-the same sweep, under the same scopes).
+the same sweep, under the same scopes).  Since ISSUE 40 the forward gather's
+kernel too (ops/pallas_gather.py), alone at both one-chip FM cells' shapes
+and inside ``fm8_criteo``'s step, under ``fm.gather``.
 
 All of it in this one file and behind a fixture: one process may hold the
 TPU's library, so only the worker that runs this file loads it.
@@ -80,17 +82,20 @@ def test_the_sweep_compiles_for_the_chip_in_place(one_chip, v, d, a, m):
 def _step_ops(one_chip, monkeypatch, model, b, n):
     """(compiled text, {scope: Counter of HLO opcodes}, forms asked) of the
     train step as ``make_train_step`` builds it, compiled for the described
-    chip; ``rows_tail_form`` is told the backend is a TPU (it asks
-    ``jax.default_backend()``, which is the CPU here) and the kernel is
-    compiled, not interpreted."""
-    from fast_tffm_tpu import optim
+    chip; ``rows_tail_form`` and ``gather_form`` are told the backend is a
+    TPU (they ask ``jax.default_backend()``, which is the CPU here) and the
+    kernels are compiled, not interpreted."""
+    from fast_tffm_tpu import optim, trainer
     from fast_tffm_tpu.models.base import Batch
-    from fast_tffm_tpu.ops import pallas_tail
+    from fast_tffm_tpu.ops import pallas_gather, pallas_tail
     from fast_tffm_tpu.trainer import init_state, make_train_step
 
     rule, asked = optim.rows_tail_form, []
     monkeypatch.setattr(optim, "rows_tail_form", lambda *a, backend=None: asked.append(rule(*a, backend="tpu")) or asked[-1])
     monkeypatch.setattr(pallas_tail, "resolve_interpret", lambda interpret: False)
+    gather_rule = trainer.gather_form  # the forward gather's form is asked the same way
+    monkeypatch.setattr(trainer, "gather_form", lambda *a, backend=None: gather_rule(*a, backend="tpu"))
+    monkeypatch.setattr(pallas_gather, "resolve_interpret", lambda interpret: False)
     sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     state = jax.eval_shape(lambda: init_state(model, jax.random.key(0), 0.1, "element"))
     state = jax.tree.map(lambda x: sd(x.shape, x.dtype), state)
@@ -102,7 +107,7 @@ def _step_ops(one_chip, monkeypatch, model, b, n):
     ops = collections.defaultdict(collections.Counter)
     for line in text.splitlines():
         op = re.match(r"\s*(ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)  # a long tuple type holds /*index=5*/
-        scope = re.search(r'op_name="jit\(step\)/(fm\.(?:dedup|tail))/', line)
+        scope = re.search(r'op_name="jit\(step\)/(fm\.(?:gather|dedup|tail))/', line)
         if op and scope:
             ops[scope.group(1)][op.group(3)] += 1
     return text, ops, asked
@@ -123,6 +128,47 @@ def test_the_sweeps_step_sums_no_segments_and_sorts_once(one_chip, monkeypatch):
     assert "2555904,128]" not in text and "scatter-add" not in text
     assert ops["fm.dedup"]["gather"] == 0  # the permutation rides the sort
     assert ops["fm.tail"]["sort"] == 0 and ops["fm.tail"]["scatter"] == 0
+    # ISSUE 40: the forward gather is a sweep too.  Under ``fm.gather`` stand
+    # the sort of the ids with their positions, the kernel and the sort that
+    # brings the nine columns back to batch order; no row is gathered from
+    # the table (no ``gather`` producing ``f32[2555904,9]``), the table
+    # reaches both kernels as a bitcast, and no instruction stands under two
+    # of the three scopes (``harness/scopes.py`` would count it twice).
+    assert ops["fm.gather"]["sort"] == 2
+    kernels = re.findall(r'custom_call_target="tpu_custom_call".*?op_name="jit\(step\)/(fm\.\w+)/pallas_call"', text)
+    assert sorted(kernels) == ["fm.gather", "fm.tail"]
+    for line in text.splitlines():
+        assert not (" gather(" in line and "f32[2555904,9]" in line.split(" gather(")[0]), line
+        assert not re.search(r" copy\(.*f32\[67108864,9\]", line), line
+        assert sum(f"/{scope}/" in line for scope in ("fm.gather", "fm.dedup", "fm.tail")) <= 1, line
+
+
+@pytest.mark.parametrize(
+    "v, d, m",
+    [(2**26, 9, 65536 * 39), (2**25, 31, 65536 * 11)],  # fm8_criteo.train_fmb; fm3_k30_kdd12.train_fmb_order3
+    ids=["fm8", "fm3"],
+)
+def test_the_gather_kernel_compiles_for_the_chip_on_the_tables_own_buffer(one_chip, v, d, m):
+    """``ops.pallas_gather.sweep_gather`` at the two one-chip FM cells'
+    shapes: Mosaic takes it, its blocks and scratch fit VMEM, and the
+    transposed view of the lane-major table is a bitcast: nothing copies or
+    transposes a ``[V, D]`` buffer on the way in."""
+    from fast_tffm_tpu.ops.pallas_gather import sweep_gather
+
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    gather = jax.jit(lambda t, u: sweep_gather(t, u, interpret=False))
+    compiled = gather.lower(sd((v, d), jnp.float32), sd((m,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    whole = re.compile(rf"f32\[({v},{d}|{d},{v})\]")
+    ops = set()
+    for line in text.splitlines():
+        m_ = re.match(r"\s*(ROOT )?%?[\w.\-]+ = (\([^=]*\)|\S+) ([\w\-]+)\(", line)
+        if m_ and whole.search(line):
+            ops.add(m_.group(3))
+    assert ops == {"parameter", "bitcast", "custom-call"}, ops
+    assert f"f32[{v},{d}]{{0,1:T(8,128)}}" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20  # the work list and the result's padding, no table
 
 
 def test_the_rows_step_keeps_its_dedup_and_its_row_operations(one_chip, monkeypatch):
