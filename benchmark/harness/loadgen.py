@@ -5,10 +5,14 @@
 
 Frames of ``frame_rows`` x ``nnz`` over ``connections`` pipelined FMD1
 connections (the program's public ``FrameConnection``), Poisson arrivals at a
-rate fixed in the spec: ``round(rate * seconds)`` arrival times drawn from the
-seed and sorted, so every seed sends the same number of frames.  A frame is
-timed from when it was DUE to its last reply row.  Frames are drawn from a
-pool made from the seed, in an order drawn from the seed.
+rate fixed in the spec: ``round(rate * seconds)`` arrival times, sorted.  They
+are ONE stream for every seed: the seed draws only the order of the window's
+half-second blocks of it, so every seed sends the same arrivals in another
+order and the queue wait they make is the same work (two runs of one seed read
+``serve_p50_ms`` 0.05% apart where three seeds read 1.8% apart, PERF.md
+section 2).  A frame is timed from when it was DUE to its last reply row.
+Frames are drawn from a pool made from the seed, in an order drawn from the
+seed.
 
 The schedule is one; ``processes`` shards send it, shard p the frames
 i = p mod processes over its share of the connections, because one Python
@@ -30,15 +34,31 @@ sys.path.insert(0, os.path.dirname(HERE))
 sys.path.insert(0, os.path.dirname(os.path.dirname(HERE)))
 
 
+BLOCK_S = 0.5
+
+
+def _blocks_reordered(t, span: float, rng):
+    """The sorted times ``t`` in [0, span) with their whole blocks of BLOCK_S
+    in the order ``rng`` draws; what is left past the last whole block stays
+    last.  A time keeps its place inside its block."""
+    k = int(span // BLOCK_S)
+    if k < 2:
+        return t
+    block = np.minimum((t // BLOCK_S).astype(int), k)
+    place = np.arange(k + 1)
+    place[rng.permutation(k)] = np.arange(k)
+    return np.sort(place[block] * BLOCK_S + (t - block * BLOCK_S))
+
+
 def schedule(seed: int, rate_frames: float, warm_s: float, seconds: float, pool: int):
     """(due times [n], pool index [n], n_warm).  The warm-up's frames come
-    first, then the window's, one Poisson stream with a fixed count each."""
-    rng = np.random.default_rng([int(seed), 7])
+    first, then the window's, one Poisson stream with a fixed count each: the
+    same stream whatever the seed, which orders the window's blocks of it and
+    draws the frames sent."""
+    stream, rng = np.random.default_rng(7), np.random.default_rng([int(seed), 7])
     n_warm, n_win = int(round(rate_frames * warm_s)), int(round(rate_frames * seconds))
-    due = np.concatenate([
-        np.sort(rng.random(n_warm)) * warm_s,
-        warm_s + np.sort(rng.random(n_win)) * seconds,
-    ])
+    warm, window = np.sort(stream.random(n_warm)) * warm_s, np.sort(stream.random(n_win)) * seconds
+    due = np.concatenate([warm, warm_s + _blocks_reordered(window, seconds, rng)])
     return due, rng.integers(0, pool, size=due.size), n_warm
 
 

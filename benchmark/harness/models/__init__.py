@@ -15,13 +15,37 @@ module is plain ``jax.numpy`` in float32 and imports nothing of the program:
     .step_flops(rows, nnz, uniq)     FLOPs one train step must make
     .score_bytes(rows, nnz)          bytes that scoring ``rows`` rows must move
 
-The loss, the L2 term, autodiff, Adagrad and the planted faults are common
+A model with parameters OUTSIDE the table (dense leaves: an MLP's weights)
+also defines
+
+    .init_dense()                    {name: float32 array}, the initial leaves
+                                     by the recipe the program documents
+                                     (``trainer.init_state``: ``k1, k2 =
+                                     split(key(0))``, the dense leaves from k2)
+    .score(rows, vals, fields, dense)    the leaves are the FOURTH argument
+
+and both windows then carry them: the train window reads the program's
+``state.dense`` back beside the table's rows and holds it to the reference by
+two numbers of its own (``train.compare``), the serve window saves them in the
+model file it writes and scores the pool under them.  A module that defines no
+``init_dense`` has no dense leaves, its ``score`` takes three arguments and
+nothing of the above is done for it (``dense_leaves`` below is how a window
+asks).  ``fm2``, ``hofm``, ``ffm`` and ``ffm_f32`` have none; ``deepfm`` has.
+
+The loss, the L2 term (over the gathered rows alone), autodiff, Adagrad (the
+table's and the dense leaves' alike) and the planted faults are common
 (``reference.py``); the peaks and ``least_seconds`` are ``peaks.py``.
 """
 
 from __future__ import annotations
 
 import numpy as np
+
+
+def dense_leaves(model) -> dict:
+    """The model's initial dense leaves, ``{}`` for a module without any."""
+    init = getattr(model, "init_dense", None)
+    return dict(init()) if init else {}
 
 
 def uniform_factor_rows(vocab: int, cols: int, init_range: float, rows: np.ndarray):

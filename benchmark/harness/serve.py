@@ -7,7 +7,9 @@ them, with a ``launcher`` that runs ``serving.replica.run_replica`` on a thread
 (the duck type ``ReplicaProcess`` describes): only the process that holds the
 chip can trace it and read its memory, so both ``--trace`` modes use this one
 arrangement.  The model file is a checkpoint of a state made on the device
-from the seed, written by the program's own writer.
+from the seed, written by the program's own writer; a model's dense leaves
+(``models/__init__.py``) are saved in it as the model draws them, and the pool
+is scored under the same leaves.
 """
 
 from __future__ import annotations
@@ -24,6 +26,7 @@ import time
 import numpy as np
 
 from . import cells, common, gen, loadgen, peaks, readers, reference
+from .models import dense_leaves
 
 _POOL_BLOCK = 1 << 16
 
@@ -91,7 +94,9 @@ def served_model(cell):
     return model
 
 
-def write_model_file(cfg, seed: int, row_dim: int) -> None:
+def write_model_file(cfg, seed: int, row_dim: int, dense=None) -> None:
+    """The served state: the table from the seed, the dense leaves ``dense``
+    (none: ``{}``) as the model draws them, every accumulator at its start."""
     import jax.numpy as jnp
 
     from fast_tffm_tpu.checkpoint import save_checkpoint
@@ -101,7 +106,9 @@ def write_model_file(cfg, seed: int, row_dim: int) -> None:
     table = seed_table(seed, cfg.vocabulary_size, row_dim)
     cols = table.shape[1] if cfg.adagrad_accumulator == "element" else 1
     accum = np.broadcast_to(np.float32(cfg.init_accumulator_value), (table.shape[0], cols))
-    state = TrainState(table, AdagradState(accum), {}, AdagradState({}), jnp.zeros((), jnp.int32))
+    dense = dict(dense or {})
+    dense_accum = {k: jnp.full_like(v, cfg.init_accumulator_value) for k, v in dense.items()}
+    state = TrainState(table, AdagradState(accum), dense, AdagradState(dense_accum), jnp.zeros((), jnp.int32))
     save_checkpoint(cfg.model_file, state, "npz")
 
 
@@ -112,8 +119,9 @@ def pool_reference(seed, spec, model, dtype=None):
     _, ids, vals = loadgen.pool_rows(seed, spec)
     fields = gen.column_fields(ids)
     table = seed_table(seed, spec["vocab"], model.row_dim)
+    dense = dense_leaves(model)
     out = [
-        np.asarray(reference.score_rows(model.score, table, *(a[i : i + _POOL_BLOCK] for a in (ids, vals, fields)), dtype or jnp.float32))
+        np.asarray(reference.score_rows(model.score, table, *(a[i : i + _POOL_BLOCK] for a in (ids, vals, fields)), dtype or jnp.float32, dense))
         for i in range(0, ids.shape[0], _POOL_BLOCK)
     ]
     return np.concatenate(out).reshape(spec["pool_frames"], spec["frame_rows"])
@@ -265,7 +273,7 @@ def _model_and_config(cell, model, seed, name, workroot, phase):
     """An emptied work directory with the cell's INI file and the model file
     made from the seed; (directory, loaded Config)."""
     work, cfg = common.configured(cell, name, workroot)
-    write_model_file(cfg, seed, model.row_dim)
+    write_model_file(cfg, seed, model.row_dim, dense_leaves(model))
     phase("model file written")
     # The model file's dirty pages go to disk now, not under the window, and
     # what this process has built so far is kept out of later collections:

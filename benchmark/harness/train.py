@@ -7,6 +7,12 @@ device, and then calls ``log``.  Those calls are the sync boundaries: the
 window opens at the first one at or after ``warm_steps`` and closes at the
 first one ``--seconds`` later, so it holds whole steps only.  The same compiled
 step and state serve steps 1-3, which the reference follows, and the window.
+
+Where the cell's model has dense leaves (``models/__init__.py``) the check
+reads ``state.dense`` back too and compares it apart from the table's two
+leaves: a perceptron through the MXU and a gathered row through the VPU do not
+agree with float32 to the same digits, and one worst-leaf number would force
+the table's limit up to the perceptron's.
 """
 
 from __future__ import annotations
@@ -20,8 +26,10 @@ import traceback
 import numpy as np
 
 from . import cells, common, gen, readers, reference
+from .models import dense_leaves
 
 CHECK_STEPS = 3
+DENSE_NUMBERS = ("dense_grad1_norm_gap", "dense_delta3_norm_gap")
 
 
 class _WindowClosed(Exception):
@@ -71,6 +79,7 @@ def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cell
     tr, ini = cell["traffic"], cell["ini"]
     batch, nnz = int(ini["Train"]["batch_size"]), int(ini["Train"]["max_nnz"])
     hyper, model = _hyper(ini), cell["model"]
+    has_dense = _dense_limits_stated(cell)
     vocab = hyper["vocab"]
     log_every = int(ini["Train"]["log_every"])
     n_batches = int(tr["file_batches"])
@@ -87,6 +96,8 @@ def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cell
     u = np.unique(first)
     u1 = np.unique(first[0])
     take = jax.jit(lambda t, i: t[i])
+    # The next step donates its state: a dense leaf is kept as a copy.
+    kept = lambda tree: {k: jax.numpy.copy(v) for k, v in tree.items()}
     u_dev, u1_dev = _pad(u, first.size), _pad(u1, first[0].size)
     phase("check rows found")
 
@@ -104,11 +115,16 @@ def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cell
             frame = sys._getframe(1).f_locals
             state = frame["state"]
             cap["losses"].append(frame["loss"])
+            dense = getattr(state, "dense", None) if has_dense else None
             if step_num == 1:
                 cap["t1"] = take(state.table, u1_dev)
                 cap["a1"] = take(state.table_opt.accum, u1_dev)
+                if dense:
+                    cap["d1"], cap["da1"] = kept(dense), kept(state.dense_opt.accum)
             if step_num == CHECK_STEPS:
                 cap["t3"] = take(state.table, u_dev)
+                if dense:
+                    cap["d3"] = kept(dense)
 
     def log(msg):
         msg = str(msg)
@@ -141,6 +157,7 @@ def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cell
         "t1": np.asarray(cap["t1"])[: u1.size],
         "a1": np.asarray(cap["a1"])[: u1.size],
         "t3": np.asarray(cap["t3"])[: u.size],
+        **{k: {n: np.asarray(a) for n, a in cap[k].items()} for k in ("d1", "da1", "d3") if k in cap},
     }
     cap.clear()
     gc.collect()
@@ -192,28 +209,34 @@ def _pad(rows, n):
     return np.concatenate([rows, np.full(n - rows.size, rows[0], rows.dtype)])
 
 
-def followed(h, model, first, vals, fields, labels, u, u1, dtype=None, shards=0):
+def followed(h, model, first, vals, fields, labels, u, u1, dtype=None, shards=0, dense_frozen=False):
     """The reference over the first three batches (``first`` ids [3, B, N],
     ``vals`` and ``fields`` [3, B, N], ``labels`` [3, B]), on the compact table
-    of the rows ``u`` they touch.  Returns what ``compare`` reads, as numpy."""
+    of the rows ``u`` they touch and on the model's dense leaves, where it has
+    any.  Returns what ``compare`` reads, as numpy."""
     import jax.numpy as jnp
 
     t0 = model.init_rows(_pad(u, CHECK_STEPS * h["rows_per_step"]))
+    d0 = dense_leaves(model)
     idx = np.searchsorted(u, first).astype(np.int32)
     # Padding rows of the compact table are never read; shard 0 may own them.
     owner = _pad(u // (h["vocab"] // shards), t0.shape[0]).astype(np.int32) if shards else None
     outs = reference.train_steps(
         model.score, t0, list(zip(idx, vals, fields, labels)), h["lr"], h["accum0"], h["bias_lambda"], h["factor_lambda"],
-        dtype=dtype or jnp.float32, owner=owner,
+        dtype=dtype or jnp.float32, owner=owner, dense0=d0, dense_frozen=dense_frozen,
     )
     at1 = np.searchsorted(u, u1)
     f32 = lambda a: np.asarray(a.astype(jnp.float32))[: u.size]
-    return {
+    out = {
         "losses": [float(o[0]) for o in outs],
         "t0": f32(t0), "at1": at1,
         "t1": f32(outs[0][1])[at1], "a1": f32(outs[0][2])[at1],
         "t3": f32(outs[-1][1]),
     }
+    if d0:
+        leaves = lambda tree: {k: np.asarray(v.astype(jnp.float32)) for k, v in tree.items()}
+        out.update(d0=leaves(d0), d1=leaves(outs[0][3]), da1=leaves(outs[0][4]), d3=leaves(outs[-1][3]))
+    return out
 
 
 def planted(cell, seed, what):
@@ -221,8 +244,10 @@ def planted(cell, seed, what):
     place, at the cell's own size: ``control`` follows the three steps in
     bfloat16; ``half_batch`` leaves half of each batch out and takes the mean
     over the rest; ``no_exchange`` (cells on several chips) leaves out the
-    exchange of gradients between the row shards.  Returns the numbers ``compare`` gives against the sound
-    float32 reference."""
+    exchange of gradients between the row shards; ``dense_frozen`` (models
+    with dense leaves) never updates them, what a step that drops their
+    gradient computes.  Returns the numbers ``compare`` gives against the
+    sound float32 reference."""
     import jax.numpy as jnp
 
     ini = cell["ini"]
@@ -240,6 +265,10 @@ def planted(cell, seed, what):
         bad = followed(h, model, first[:, :half], vals[:, :half], fields[:, :half], labels[:, :half], u, u1)
     elif what == "no_exchange":
         bad = followed(h, model, first, vals, fields, labels, u, u1, shards=cell["chips"])
+    elif what == "dense_frozen":
+        if "d0" not in ref:
+            raise SystemExit(f"{cell['name']}: the configuration's model has no dense leaves to freeze")
+        bad = followed(h, model, first, vals, fields, labels, u, u1, dense_frozen=True)
     else:
         raise ValueError(what)
     return compare(bad, ref, h["lr"])
@@ -257,6 +286,22 @@ def _hyper(ini):
     }
 
 
+def _dense_limits_stated(cell) -> bool:
+    """Whether the cell's model has dense leaves; one that has, under a mix
+    that states no limit for a number over them, exits: ``common.decide``
+    skips a number without a limit, and the cell would read ``correct`` with
+    its dense leaves held to nothing."""
+    if not hasattr(cell["model"], "init_dense"):
+        return False
+    lacking = [n for n in DENSE_NUMBERS if cell["traffic"].get("limits", {}).get(n) is None]
+    if lacking:
+        raise SystemExit(
+            f"{cell['name']}: the configuration's model has dense leaves and the mix states no limit for "
+            f"{' or '.join(lacking)}: it would read correct with those leaves held to nothing"
+        )
+    return True
+
+
 def _leaf_norms(rows):
     """The two leaves of a row table: biases (column 0), factors (the rest)."""
     rows = rows.astype(np.float64)
@@ -268,14 +313,46 @@ def compare(got, ref, lr):
     norm of the first gradient, worked out on both sides from the state after
     one step (g = (p0 - p1) * sqrt(accum1) / lr); gap of the norm of the
     parameters' change after three steps.  Norms by leaf, the worst leaf
-    counts, each against the reference's norm of that leaf."""
+    counts, each against the reference's norm of that leaf.  Those three are
+    over the table's two leaves alone; where the reference has dense leaves
+    the two norms are taken over them too, by the same formulas, as two
+    numbers of their own (``_dense_gaps``)."""
     t0_1 = ref["t0"][ref["at1"]]
     grad = lambda s: _leaf_norms((t0_1 - s["t1"]) * np.sqrt(s["a1"]) / lr)
     delta = lambda s: _leaf_norms(s["t3"] - ref["t0"])
     worst = lambda p, r: float(np.max(np.abs(p - r) / r))
     lp, lr_ = np.array(got["losses"]), np.array(ref["losses"])
-    return {
+    numbers = {
         "loss_gap": float(np.max(np.abs(lp - lr_) / np.abs(lr_))) if lp.shape == lr_.shape else float("inf"),
         "grad1_norm_gap": worst(grad(got), grad(ref)),
         "delta3_norm_gap": worst(delta(got), delta(ref)),
     }
+    if "d0" in ref:
+        numbers.update(zip(DENSE_NUMBERS, _dense_gaps(got, ref, lr)))
+    return numbers
+
+
+def _dense_gaps(got, ref, lr):
+    """(gap of the first gradient's norm, gap of the three steps' change's
+    norm) over the dense leaves, the worst leaf counting, each leaf against
+    the reference's norm of it or of the median dense leaf, whichever is
+    larger (a small leaf beside large ones rounds to a large share of
+    itself).  A leaf the program lacks or shapes otherwise reads infinity; a
+    leaf whose reference norm is 0 cannot be compared and is an error."""
+    norm = lambda a: float(np.sqrt((np.asarray(a, np.float64) ** 2).sum()))
+
+    def norms(s, name, p0):
+        if not all(name in s.get(k, {}) and s[k][name].shape == p0.shape for k in ("d1", "da1", "d3")):
+            return None
+        return norm((p0 - s["d1"][name]) * np.sqrt(s["da1"][name]) / lr), norm(s["d3"][name] - p0)
+
+    theirs = {name: norms(ref, name, p0) for name, p0 in ref["d0"].items()}
+    median = [float(m) for m in np.median(list(theirs.values()), axis=0)]
+    gaps = [0.0, 0.0]
+    for name, r in theirs.items():
+        p = norms(got, name, ref["d0"][name])
+        for i, what in enumerate(("first gradient", "change after three steps")):
+            if not r[i]:
+                raise SystemExit(f"the reference's {what} of the dense leaf {name!r} has norm 0: the leaf cannot be compared")
+            gaps[i] = max(gaps[i], abs(p[i] - r[i]) / max(r[i], median[i]) if p else float("inf"))
+    return gaps

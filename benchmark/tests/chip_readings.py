@@ -6,6 +6,7 @@ seeds in one process (the benchmark's own runs never run this):
     ... --what control     the reference in bfloat16 put in the program's place
     ... --what half_batch  the reference with half of each batch left out, the mean over the rest
     ... --what no_exchange the reference of a row-sharded cell with the gradients' exchange between shards left out
+    ... --what dense_frozen the reference of a model with dense leaves, those leaves never updated
     ... --what trace --out chiprun_out/recorded_trace.json   one short traced run, its events kept
     ... --what sweep --rates 50000,100000 --seconds 5        serve mixes: one server, a window a rate
 
@@ -26,7 +27,7 @@ sys.path.insert(0, os.path.dirname(HERE))
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
-    ap.add_argument("--what", choices=("program", "control", "half_batch", "no_exchange", "trace", "sweep"), required=True)
+    ap.add_argument("--what", choices=("program", "control", "half_batch", "no_exchange", "dense_frozen", "trace", "sweep"), required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, default=1.0)
     ap.add_argument("--out", default="")

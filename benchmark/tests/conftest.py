@@ -46,3 +46,32 @@ def toy_bench(tmp_path):
             t[k] = v
         json.dump(t, open(root / "traffic" / fn, "w"))
     return str(root)
+
+
+# The one model with parameters outside the table, named by no shipped
+# configuration: a toy cell of it, made of new files alone.  The mix is
+# ``train_fmb`` key for key with limits for the two numbers over dense leaves;
+# every limit is set from readings at THIS size on the CPU (test_dense_seam.py
+# says which), not from a chip.
+DEEPFM_TOY = {
+    "name": "deepfm_toy", "harness_model": "deepfm", "chips": 1, "reduced": [],
+    "source": "a toy for the harness's own tests: DeepFM at 39 fields, k = 10, a perceptron of 16-16-16",
+    "ini": {
+        "General": {"model": "deepfm", "factor_num": 10, "num_fields": 39, "hidden_dims": "16 16 16", "compute_dtype": "float32",
+                    "vocabulary_size": 1 << 14, "hash_feature_id": "false"},
+        "Train": {"batch_size": 512, "max_nnz": 39, "learning_rate": 0.05, "factor_lambda": 1e-7, "bias_lambda": 1e-7,
+                  "init_accumulator_value": 0.1, "thread_num": 2, "queue_size": 8},
+    },
+}
+DENSE_LIMITS = {"dense_grad1_norm_gap": 1e-4, "dense_delta3_norm_gap": 1e-4}
+
+
+@pytest.fixture
+def dense_bench(toy_bench):
+    """``toy_bench`` with the toy DeepFM configuration and the mix
+    ``train_fmb_dense`` beside the shipped ones."""
+    json.dump(DEEPFM_TOY, open(os.path.join(toy_bench, "configs", "deepfm_toy.json"), "w"))
+    mix = json.load(open(os.path.join(toy_bench, "traffic", "train_fmb.json")))
+    mix["limits"] = dict(mix["limits"], **DENSE_LIMITS)
+    json.dump(mix, open(os.path.join(toy_bench, "traffic", "train_fmb_dense.json"), "w"))
+    return toy_bench

@@ -65,6 +65,14 @@ def test_a_shipped_configurations_module_has_the_programs_row_width(config, tmp_
     program = build_model(load_config(cells.write_ini(str(tmp_path / "cell.cfg"), cell["ini"])))
     assert cell["model"].row_dim == program.row_dim
     assert cell["model"].reads_fields == bool(getattr(program, "uses_fields", False))
+    # ... and its dense leaves: none on either side (shapes alone: the table is not drawn).
+    import jax
+
+    from fast_tffm_tpu.trainer import init_state
+    from harness.models import dense_leaves
+
+    state = jax.eval_shape(lambda: init_state(program, jax.random.key(0)))
+    assert dense_leaves(cell["model"]) == {} and state.dense == {} and state.dense_opt.accum == {}
 
 
 FFM_TOY = {
@@ -167,7 +175,7 @@ def test_the_field_aware_work_model_is_the_programs_at_its_width_and_the_fields(
 
 
 def test_a_configuration_that_names_no_harness_model_or_a_missing_one_is_an_error(ffm_bench):
-    for name, sentence in ((None, "names no harness_model"), ("fm3", "there is no harness/models/fm3.py")):
+    for name, sentence in ((None, "names no harness_model"), ("no_such_model", "there is no harness/models/no_such_model.py")):
         c = dict(FFM_TOY, harness_model=name)
         json.dump(c, open(os.path.join(ffm_bench, "configs", "ffm4_toy.json"), "w"))
         with pytest.raises(SystemExit, match=sentence):

@@ -44,6 +44,22 @@ def test_every_seed_sends_the_same_number_of_frames(seed):
     assert due[n_warm - 1] < 2.0 <= due[n_warm] and due[-1] < 7.0 and which.max() < 64
 
 
+def test_every_seed_sends_the_same_arrivals_in_another_order():
+    """The seed must not change the work: one Poisson stream, the window's
+    half-second blocks of it in the seed's order, a last partial block last."""
+    (a, _, n), (b, _, _) = (loadgen.schedule(s, 100.0, 2.0, 5.2, 64) for s in (1, 3000000019))
+    assert (a[:n] == b[:n]).all() and (a[n:] != b[n:]).any()
+
+    def blocks(due):
+        w = due[n:] - 2.0
+        k = (w // loadgen.BLOCK_S).astype(int)
+        return [tuple(np.round(w[k == i] - i * loadgen.BLOCK_S, 9)) for i in range(11)]
+
+    ba, bb = blocks(a), blocks(b)
+    assert sorted(ba[:10]) == sorted(bb[:10]) and ba[:10] != bb[:10] and ba[10] == bb[10]
+    assert sum(map(len, ba)) == 520
+
+
 def _run(toy_bench, tmp_path, seed=5):
     cell = cells.load_cell("fm8_criteo.serve_steady", toy_bench)
     return serve.run(cell, seed, 1.0, False, time.time(), require_chip=False, workroot=str(tmp_path))
