@@ -243,10 +243,10 @@ def train_step_body(
 ):
     """The (unjitted) single-device step, by the scope its ops carry:
     ``fm.gather`` (the batch's rows, in the form ``gather_form`` chooses) →
-    ``fm.interaction`` (fused scorer and
-    its backward) → ``fm.loss`` → ``fm.dedup`` → ``fm.tail``, in one of two
-    forms that optim.sparse_adagrad_update chooses between from the shapes
-    BEFORE it dedups (``optim.rows_tail_form``).  ``rows``: ``fm.dedup`` is
+    ``fm.interaction`` (fused scorer and its backward; DeepFM's perceptron:
+    ``deepfm.feed``, ``deepfm.mlp``) → ``fm.loss`` → ``fm.dedup`` → ``fm.tail``
+    → ``deepfm.dense_update`` (a model with dense leaves).  The tail takes one
+    of two forms (``optim.rows_tail_form``, before it dedups).  ``rows``: ``fm.dedup`` is
     one sort for ids and order, the permutation gather, a segment sum on
     128-lane rows and the unique ids by a second sort; ``fm.tail`` one
     gather and one scatter-set of the accumulator and one scatter-add into
@@ -276,9 +276,9 @@ def train_step_body(
     )
     dense, dense_opt = state.dense, state.dense_opt
     if jax.tree.leaves(state.dense):
-        dense, dense_opt = dense_adagrad_update(
-            state.dense, state.dense_opt, g_dense, learning_rate, decay=decay
-        )
+        with jax.named_scope("deepfm.dense_update"):
+            leaves = (state.dense, state.dense_opt, g_dense, learning_rate)
+            dense, dense_opt = dense_adagrad_update(*leaves, decay=decay)
     return (
         TrainState(table, table_opt, dense, dense_opt, state.step + 1),
         data_loss,
@@ -534,9 +534,9 @@ def packed_train_step_body(
             )
     dense, dense_opt = state.dense, state.dense_opt
     if jax.tree.leaves(state.dense):
-        dense, dense_opt = dense_adagrad_update(
-            state.dense, state.dense_opt, g_dense, learning_rate
-        )
+        with jax.named_scope("deepfm.dense_update"):
+            leaves = (state.dense, state.dense_opt, g_dense, learning_rate)
+            dense, dense_opt = dense_adagrad_update(*leaves)
     return (
         TrainState(table, AdagradState(accum), dense, dense_opt, state.step + 1),
         data_loss,

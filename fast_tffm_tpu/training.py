@@ -566,12 +566,18 @@ def _say_interaction(log, model, batch_rows: int, backward: bool = True) -> dict
     share of a step), said once at start-up and returned as the step's
     ``kind=profile`` fields.  ``fm_score``'s choice at trace time
     (``ops.fm.interaction_form``); every model but the FM of order 3 and up
-    scores by a closed form of order 2."""
+    scores by a closed form of order 2.  A model with a perceptron over the
+    gathered rows (DeepFM) says that too, and the record carries its
+    ``models.deepfm.PERCEPTRON_FIELDS`` (null for every other model)."""
+    from fast_tffm_tpu.models.deepfm import describe_perceptron, perceptron_profile
     from fast_tffm_tpu.ops.fm import describe_interaction, interaction_profile
 
     shape = (getattr(model, "order", 2), batch_rows, model.factor_num)
     log("interaction: " + describe_interaction(*shape, backward=backward))
-    return interaction_profile(*shape, backward=backward)
+    head = perceptron_profile(model, batch_rows, backward=backward)
+    if head["dense_params"] is not None:
+        log("perceptron: " + describe_perceptron(model))
+    return {**interaction_profile(*shape, backward=backward), **head}
 
 
 def _run_training(

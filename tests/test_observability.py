@@ -557,3 +557,111 @@ def test_the_profile_records_say_the_forward_gathers_form_and_grid(dataset, monk
         assert (prof["gather_form"], prof["gather_items"]) == (form, items if form == "sweep" else None)
         assert len([l for l in logs if l.startswith("forward gather: ")]) == 1
         assert len(asked) >= 2 and set(asked) == {(200, 32 * NNZ, 5)}
+
+
+# --- the dense head (DeepFM's perceptron): its scopes and its profile fields ---
+
+_HEAD_SCOPES = ("deepfm.feed", "deepfm.mlp", "deepfm.dense_update")
+
+
+@pytest.mark.parametrize(
+    "kw, dense_params, hidden, dtype",
+    [
+        (dict(model="deepfm", factor_num=4, num_fields=NNZ, max_nnz=NNZ, hidden_dims=(16, 8), compute_dtype="bfloat16"), 673, [16, 8], "bfloat16"),
+        (dict(model="deepfm", factor_num=4, num_fields=NNZ, max_nnz=NNZ, hidden_dims=(12,), compute_dtype="float32"), 409, [12], "float32"),
+        (dict(model="fm", factor_num=4), None, None, None),
+        (dict(model="ffm", factor_num=4, num_fields=39), None, None, None),
+    ],
+    ids=["deepfm_16_8_bf16", "deepfm_12_f32", "fm", "ffm"],
+)
+def test_the_profile_records_say_the_perceptron(dataset, kw, dense_params, hidden, dtype):
+    """``dense_params``, ``hidden_dims``, ``compute_dtype`` and
+    ``mlp_flops_per_step`` (6 a weight a row for the train step, 2 for the
+    predict step) ride the step's ``kind=profile`` record beside the
+    interaction's fields; null for a model without a perceptron.  The
+    start-up line ``perceptron: ...`` says the same, and only such a model
+    says it."""
+    from fast_tffm_tpu.prediction import predict
+
+    extra = dict(predict_files=(str(dataset / "train.libsvm"),), score_path=str(dataset / "scores.txt"))
+    cfg = _cfg(dataset, tag="head", epoch_num=1, **extra, **kw)
+    logs = []
+    train(cfg, log=lambda *a: logs.append(" ".join(map(str, a))))
+    (prof,) = [r for r in _read(cfg.metrics_path) if r["kind"] == "profile" and r["program"] == "train_step"]
+    weights = None if dense_params is None else dense_params - sum(hidden) - 1
+    flops = lambda per_weight: None if weights is None else per_weight * weights * cfg.batch_size
+    assert (prof["dense_params"], prof["hidden_dims"], prof["compute_dtype"], prof["mlp_flops_per_step"]) == (dense_params, hidden, dtype, flops(6))
+    assert "interaction_form" in prof and "tail_form" in prof  # beside the fields that were there
+    said = [l for l in logs if l.startswith("perceptron: ")]
+    if dense_params is None:
+        assert not said
+    else:
+        dims = "-".join(map(str, [NNZ * 4, *hidden, 1]))
+        assert said == [f"perceptron: {dims}, {dense_params:,} parameters, {dtype} operands"]
+
+    pcfg = _cfg(dataset, tag="head_p", model_file=cfg.model_file, metrics_path=str(dataset / "m_head_p.jsonl"), **extra, **kw)
+    logs.clear()
+    predict(pcfg, log=lambda *a: logs.append(" ".join(map(str, a))))
+    (prof,) = [r for r in _read(pcfg.metrics_path) if r["kind"] == "profile" and r["program"] == "predict_step"]
+    assert (prof["dense_params"], prof["hidden_dims"], prof["compute_dtype"], prof["mlp_flops_per_step"]) == (dense_params, hidden, dtype, flops(2))
+    assert len([l for l in logs if l.startswith("perceptron: ")]) == (0 if dense_params is None else 1)
+
+
+def _step_op_names(model, packed=False):
+    """The ``jax.named_scope`` paths in the ``op_name``s of the lowered train
+    step and of the lowered predict step, at a toy size."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from fast_tffm_tpu.models.base import Batch
+    from fast_tffm_tpu.trainer import (
+        init_packed_state, init_state, make_packed_predict_step, make_packed_train_step, make_predict_step, make_train_step,
+    )
+
+    b, n = 32, NNZ
+    batch = Batch(labels=jnp.zeros((b,)), ids=jnp.zeros((b, n), jnp.int32), vals=jnp.ones((b, n)),
+                  fields=jnp.zeros((b, n if getattr(model, "uses_fields", False) else 0), jnp.int32), weights=jnp.ones((b,)))
+    if packed:
+        state, step, predict = init_packed_state(model, jax.random.key(0)), make_packed_train_step(model, 0.05), make_packed_predict_step(model)
+    else:
+        state, step, predict = init_state(model, jax.random.key(0)), make_train_step(model, 0.05), make_predict_step(model)
+    names = lambda fn, *args: set(re.findall(r'op_name="jit\(\w+\)/([^"]*)"', fn.lower(*args).as_text(dialect="hlo", debug_info=True)))
+    return names(step, state, batch), names(predict, state, batch)
+
+
+@pytest.mark.parametrize("body", ["rows", "packed"])
+def test_a_deepfm_step_names_its_feed_its_perceptron_and_its_dense_update(body):
+    """In the compiled step's ``op_name``s: ``deepfm.feed`` and ``deepfm.mlp``
+    forward (``jvp(...)``) and backward (``transpose(jvp(...))``), the
+    leaves' Adagrad under ``deepfm.dense_update``, in both step bodies; no op
+    under two of them, none of the perceptron's matmuls outside
+    ``deepfm.mlp``, and the FM half still under ``fm.interaction``.  The
+    predict step holds the first two, forward only."""
+    from fast_tffm_tpu.models import DeepFMModel
+
+    model = DeepFMModel(vocabulary_size=V, num_fields=NNZ, factor_num=4, hidden_dims=(16, 8))
+    step, predict = _step_op_names(model, packed=body == "packed")
+    for scope in ("deepfm.feed", "deepfm.mlp"):
+        assert any(n.startswith(f"jvp({scope})/") for n in step), scope
+        assert any(n.startswith(f"transpose(jvp({scope}))/") for n in step), scope
+        assert any(n.startswith(f"{scope}/") for n in predict), scope
+    assert any(n.startswith("deepfm.dense_update/") for n in step)
+    assert not any("deepfm.dense_update" in n or "jvp(" in n for n in predict)
+    assert all(sum(s in n for s in _HEAD_SCOPES) <= 1 for n in step | predict)  # nothing under two
+    assert all("deepfm.mlp" in n for n in step | predict if n.endswith("/dot_general"))  # every matmul is the perceptron's
+    assert any(n.startswith("jvp(fm.interaction)/") for n in step) and not any("fm.interaction" in n and "deepfm." in n for n in step)
+
+
+@pytest.mark.parametrize("kind", ["fm", "fm_order3", "ffm"])
+def test_a_step_without_a_perceptron_names_none_of_the_three(kind):
+    from fast_tffm_tpu.models import FFMModel, FMModel
+
+    model = {
+        "fm": lambda: FMModel(vocabulary_size=V, factor_num=4, order=2),
+        "fm_order3": lambda: FMModel(vocabulary_size=V, factor_num=4, order=3),
+        "ffm": lambda: FFMModel(vocabulary_size=V, num_fields=NNZ, factor_num=4),
+    }[kind]()
+    step, predict = _step_op_names(model)
+    assert step and predict and not any(s in n for n in step | predict for s in _HEAD_SCOPES)
