@@ -565,19 +565,26 @@ def _say_interaction(log, model, batch_rows: int, backward: bool = True) -> dict
     """The form the model's interaction takes on ``batch_rows`` rows (a chip's
     share of a step), said once at start-up and returned as the step's
     ``kind=profile`` fields.  ``fm_score``'s choice at trace time
-    (``ops.fm.interaction_form``); every model but the FM of order 3 and up
-    scores by a closed form of order 2.  A model with a perceptron over the
-    gathered rows (DeepFM) says that too, and the record carries its
+    (``ops.fm.interaction_form``), or the field-aware model's one form, the
+    pair tensor of ``models/ffm.py`` (``interaction_form = ffm_pair_tensor``,
+    ``order`` 2); the plain FM of order 2 and DeepFM's FM half score by the
+    closed form of order 2.  A model with a perceptron over the gathered rows
+    (DeepFM) says that too, and the record carries its
     ``models.deepfm.PERCEPTRON_FIELDS`` (null for every other model)."""
     from fast_tffm_tpu.models.deepfm import describe_perceptron, perceptron_profile
     from fast_tffm_tpu.ops.fm import describe_interaction, interaction_profile
 
     shape = (getattr(model, "order", 2), batch_rows, model.factor_num)
-    log("interaction: " + describe_interaction(*shape, backward=backward))
+    form = getattr(model, "interaction_form", None)  # a model with a form of its own says it
+    if form is None:
+        said = describe_interaction(*shape, backward=backward)
+    else:
+        said = model.describe_interaction(backward=backward)
+    log("interaction: " + said)
     head = perceptron_profile(model, batch_rows, backward=backward)
     if head["dense_params"] is not None:
         log("perceptron: " + describe_perceptron(model))
-    return {**interaction_profile(*shape, backward=backward), **head}
+    return {**interaction_profile(*shape, backward=backward, form=form), **head}
 
 
 def _run_training(
@@ -654,9 +661,9 @@ def _run_training(
     and receives in the step's collectives, parallel/exchange.py) rides that
     record too, and its byte count every ``kind=train`` record.
     ``interaction_profile`` (``ops.fm.interaction_profile``: ``order``,
-    ``interaction_form`` ``order2`` | ``pallas_anova`` | ``scan``, and the
-    kernel's ``anova_programs_per_step``, null for the other forms) rides it
-    as well.
+    ``interaction_form`` ``order2`` | ``pallas_anova`` | ``scan``, or the
+    field-aware model's ``ffm_pair_tensor``, and the kernel's
+    ``anova_programs_per_step``, null for the other forms) rides it as well.
 
     ``datastats_ids`` (optional ``batch -> device ids``) lets the sampled
     id-statistics collector read a device-cache batch's ids straight off

@@ -161,7 +161,7 @@ def test_the_steps_profile_record_says_the_row_width_and_the_tails_lanes(dataset
     "kw, form, programs",
     [
         (dict(model="fm", factor_num=4, order=2), "order2", None),
-        (dict(model="ffm", factor_num=4, num_fields=39), "order2", None),  # a closed form of order 2 as well
+        (dict(model="ffm", factor_num=4, num_fields=39), "ffm_pair_tensor", None),  # models/ffm.py's one form
         (dict(model="fm", factor_num=30, order=3), "scan", None),  # off a TPU the lax.scan
         (dict(model="fm", factor_num=30, order=3), "pallas_anova", 2 * 30),  # made to say the kernel: one tile of 128 rows x 30 factors
     ],
@@ -187,7 +187,8 @@ def test_the_profile_records_say_the_interactions_order_form_and_grid(dataset, m
     assert (prof["order"], prof["interaction_form"], prof["anova_programs_per_step"]) == (order, form, programs)
     assert "tail_form" in prof and "row_dim" in prof  # beside the tail's fields, which keep their names
     (said,) = [l for l in logs if l.startswith("interaction: ")]
-    assert said.startswith(f"interaction: order {order}, ")
+    # (a model with a form of its own words its own line: the lowering test below holds the field-aware model's)
+    assert said.startswith(f"interaction: order {order}, ") == (form != "ffm_pair_tensor")
     assert (f"{programs} grid programs a step, forward and backward" in said) == (programs is not None)
 
     pcfg = _cfg(dataset, tag="inter_p", model_file=cfg.model_file, metrics_path=str(dataset / "m_inter_p.jsonl"), **extra, **kw)
@@ -652,6 +653,51 @@ def test_a_deepfm_step_names_its_feed_its_perceptron_and_its_dense_update(body):
     assert all(sum(s in n for s in _HEAD_SCOPES) <= 1 for n in step | predict)  # nothing under two
     assert all("deepfm.mlp" in n for n in step | predict if n.endswith("/dot_general"))  # every matmul is the perceptron's
     assert any(n.startswith("jvp(fm.interaction)/") for n in step) and not any("fm.interaction" in n and "deepfm." in n for n in step)
+
+
+@pytest.mark.parametrize("num_fields, row_dim", [(39, 157), (22, 89)], ids=["criteo_39x4", "avazu_22x4"])
+def test_the_ffm_step_keeps_its_pair_interaction_under_its_own_scopes(num_fields, row_dim):
+    """The lowered field-aware train step at libffm's Criteo row (39 fields,
+    k = 4) and at Avazu's (22): every op of ``fm.interaction`` stands under
+    one of ``ffm.fieldsum``, ``ffm.pairdot``, ``ffm.diag``, forward
+    (``jvp``) and backward (``transpose(jvp)``), the hand-written backward
+    among them; the row gradient leaves 1 + F·k lanes wide, so the backward
+    pads no F·k-wide array to that; and the form says its name."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from fast_tffm_tpu.models import FFMModel
+    from fast_tffm_tpu.models.base import Batch
+    from fast_tffm_tpu.trainer import init_state, make_train_step
+    from fast_tffm_tpu.training import _say_interaction
+
+    model = FFMModel(vocabulary_size=V, num_fields=num_fields, factor_num=4)
+    assert model.row_dim == row_dim
+    b, n = 16, num_fields
+    batch = Batch(labels=jnp.zeros((b,)), ids=jnp.zeros((b, n), jnp.int32), vals=jnp.ones((b, n)),
+                  fields=jnp.zeros((b, n), jnp.int32), weights=jnp.ones((b,)))
+    text = make_train_step(model, 0.05).lower(init_state(model, jax.random.key(0)), batch).as_text(dialect="hlo", debug_info=True)
+    ops = [(m.group(1), line) for line in text.splitlines() if (m := re.search(r'op_name="jit\(step\)/([^"]*)"', line))]
+    # (``add_any`` is autodiff's sum of the rows' two cotangents, the score's and the L2 term's.)
+    inside = [(name, line) for name, line in ops if "fm.interaction" in name and not name.endswith("/add_any")]
+    assert inside and all("/ffm." in name for name, _ in inside), [n for n, _ in inside if "/ffm." not in n][:5]
+    for way in ("jvp(fm.interaction)", "transpose(jvp(fm.interaction))"):
+        for scope in ("ffm.fieldsum", "ffm.pairdot", "ffm.diag"):
+            assert any(name.startswith(f"{way}/{scope}/") for name, _ in inside), (way, scope)
+    backward = [line for name, line in inside if name.startswith("transpose(")]
+    assert sum(" dot(" in line for line in backward) == 1  # the one backward matmul
+    assert not any(re.search(r"\[\d+,\d+,%d\]\S* pad\(" % row_dim, line) for line in backward)
+    assert not any("ffm." in name and "fm.interaction" not in name for name, _ in ops)  # and none of it outside
+
+    # The model says its form itself (``interaction_form``, ``describe_interaction``), as ``order`` and ``mlp_dims``
+    # are said: the launcher reads it and asks for no class.
+    for backward, passes in ((True, "hand-written backward"), (False, "forward only")):
+        logs = []
+        profile = _say_interaction(logs.append, model, b, backward=backward)
+        assert profile["interaction_form"] == model.interaction_form == "ffm_pair_tensor" and profile["order"] == 2
+        assert logs == [f"interaction: field-aware pair tensor ({num_fields} fields x 4 factors, one block transpose a step, {passes})"]
 
 
 @pytest.mark.parametrize("kind", ["fm", "fm_order3", "ffm"])
