@@ -30,7 +30,8 @@ two numbers of its own (``train.compare``), the serve window saves them in the
 model file it writes and scores the pool under them.  A module that defines no
 ``init_dense`` has no dense leaves, its ``score`` takes three arguments and
 nothing of the above is done for it (``dense_leaves`` below is how a window
-asks).  ``fm2``, ``hofm``, ``ffm`` and ``ffm_f32`` have none; ``deepfm`` has.
+asks).  ``fm2``, ``fm2_hashed``, ``hofm``, ``ffm`` and ``ffm_f32`` have none;
+``deepfm`` has.
 
 The loss, the L2 term (over the gathered rows alone), autodiff, Adagrad (the
 table's and the dense leaves' alike) and the planted faults are common

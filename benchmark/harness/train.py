@@ -13,6 +13,12 @@ reads ``state.dense`` back too and compares it apart from the table's two
 leaves: a perceptron through the MXU and a gathered row through the VPU do not
 agree with float32 to the same digits, and one worst-leaf number would force
 the table's limit up to the perceptron's.
+
+The check reads each of steps 1-3 through a ``StepView``: the state, the loss
+and the LOGICAL rows of logical ids, read as the run lays out and tiers its
+table (``row_reader``): a resident or row-sharded ``[V, D]`` table, the packed
+``[V/P, 128]`` tiles, or the tiered store's device hot tier over its host cold
+store.
 """
 
 from __future__ import annotations
@@ -22,6 +28,8 @@ import os
 import sys
 import time
 import traceback
+from functools import partial
+from typing import Callable, NamedTuple
 
 import numpy as np
 
@@ -95,7 +103,7 @@ def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cell
     first = ids[: CHECK_STEPS * batch].reshape(CHECK_STEPS, batch, nnz)
     u = np.unique(first)
     u1 = np.unique(first[0])
-    take = jax.jit(lambda t, i: t[i])
+    read = row_reader(cfg.table_layout, model.row_dim)
     # The next step donates its state: a dense leaf is kept as a copy.
     kept = lambda tree: {k: jax.numpy.copy(v) for k, v in tree.items()}
     u_dev, u1_dev = _pad(u, first.size), _pad(u1, first[0].size)
@@ -106,25 +114,23 @@ def run(cell, seed, seconds, do_trace, t_start, require_chip=True, workroot=cell
     wall_open = [None]
     trace_dir = os.path.join(work, "trace")
 
+    def check(view: StepView):
+        cap["losses"].append(view.loss)
+        dense = getattr(view.state, "dense", None) if has_dense else None
+        if view.step == 1:
+            cap["t1"], cap["a1"] = view.rows(u1_dev)
+            if dense:
+                cap["d1"], cap["da1"] = kept(dense), kept(view.state.dense_opt.accum)
+        if view.step == CHECK_STEPS:
+            cap["t3"], _ = view.rows(u_dev, accum=False)
+            if dense:
+                cap["d3"] = kept(dense)
+
     def hook(step_num):
         if win.close is not None:
             raise _WindowClosed
         if step_num <= CHECK_STEPS:
-            # ``step_hook`` is handed the step number only; the state and the
-            # loss of that step are the caller's locals.
-            frame = sys._getframe(1).f_locals
-            state = frame["state"]
-            cap["losses"].append(frame["loss"])
-            dense = getattr(state, "dense", None) if has_dense else None
-            if step_num == 1:
-                cap["t1"] = take(state.table, u1_dev)
-                cap["a1"] = take(state.table_opt.accum, u1_dev)
-                if dense:
-                    cap["d1"], cap["da1"] = kept(dense), kept(state.dense_opt.accum)
-            if step_num == CHECK_STEPS:
-                cap["t3"] = take(state.table, u_dev)
-                if dense:
-                    cap["d3"] = kept(dense)
+            check(_loop_view(sys._getframe(1).f_locals, step_num, read))
 
     def log(msg):
         msg = str(msg)
@@ -207,6 +213,86 @@ def _pad(rows, n):
     """``rows`` padded to the most rows the batches can touch, so that every
     seed compiles the same shapes; the padding repeats a row and is never read."""
     return np.concatenate([rows, np.full(n - rows.size, rows[0], rows.dtype)])
+
+
+class StepView(NamedTuple):
+    """The step ``step_hook`` follows, as the check reads it: its number, the
+    state after it, its loss (a device value) and ``rows(ids, accum=True)``,
+    the LOGICAL table rows of logical ``ids`` with their accumulator rows
+    (None without ``accum``).  Only ``rows`` reads the device."""
+
+    step: int
+    state: object
+    loss: object
+    rows: Callable
+
+
+def _loop_view(frame: dict, step: int, read) -> StepView:
+    """The view from the locals of the loop that calls ``step_hook``
+    (``training._run_training``), which hands its hook the step number alone:
+    ``state``, ``loss`` and ``paramstore``, the tiered store's server or None."""
+    state = frame["state"]
+    return StepView(step, state, frame["loss"], partial(read, state, server=frame.get("paramstore")))
+
+
+def row_reader(layout: str, row_dim: int):
+    """``read(state, ids, accum=True, server=None) -> (table rows, accumulator
+    rows or None)`` for logical ``ids`` (a host int array):
+
+    - ``rows`` layout, on one chip or row-sharded (``dist_train``'s global
+      array): ``state.table[ids]`` and ``state.table_opt.accum[ids]``, one
+      jitted gather;
+    - ``packed``: the program's own gathers of logical rows from the
+      ``[V/P, 128]`` tiles, the accumulator packed alike or fused into the
+      table's tiles (an empty ``table_opt.accum`` marks it), as its
+      checkpoint's delta writer reads them (``checkpoint_async.make_row_gather``);
+    - the tiered store (``server``): the state is the compact ``[C, D]`` table
+      of hot rows and staging slots, indexed by slot; hot ids are read from
+      their slots, the others from the pending overlay over the cold store once
+      the last step's staged rows are fetched into it (``flush_writeback``,
+      which the next step calls first: fetching them earlier stages the same
+      rows)."""
+    import jax
+
+    take = jax.jit(lambda t, i: t[i])
+    if layout == "packed":
+        from fast_tffm_tpu.ops import packed_table as pt
+
+        jit = lambda gather: jax.jit(partial(gather, d=row_dim))
+        packed = jit(pt.packed_gather), jit(pt.packed_accum_gather_any)
+        fused = jit(pt.fused_gather), jit(pt.fused_accum_gather)
+
+        def leaves(state):
+            if state.table_opt.accum.size == 0:  # the accumulator fused into the table's tiles
+                return fused, (state.table, state.table)
+            return packed, (state.table, state.table_opt.accum)
+
+    else:
+
+        def leaves(state):
+            return (take, take), (state.table, state.table_opt.accum)
+
+    def read(state, ids, accum=True, server=None):
+        if server is not None:
+            return _tiered_rows(server, state, ids, accum, take)
+        (gt, ga), (t, a) = leaves(state)
+        return gt(t, ids), (ga(a, ids) if accum else None)
+
+    return read
+
+
+def _tiered_rows(server, state, ids, accum, take):
+    ids = np.asarray(ids, np.int64)
+    server.flush_writeback(state)
+    hit, slot = server.residency.lookup(ids)
+    table, acc, _ = server.read_latest(ids[~hit])
+
+    def rows(leaf, cold):
+        out = np.array(take(leaf, slot))
+        out[~hit] = cold
+        return out
+
+    return rows(state.table, table), (rows(state.table_opt.accum, acc) if accum else None)
 
 
 def followed(h, model, first, vals, fields, labels, u, u1, dtype=None, shards=0, dense_frozen=False):

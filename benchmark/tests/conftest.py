@@ -69,9 +69,10 @@ DENSE_LIMITS = {"dense_grad1_norm_gap": 1e-4, "dense_delta3_norm_gap": 1e-4}
 @pytest.fixture
 def dense_bench(toy_bench):
     """``toy_bench`` with the toy DeepFM configuration and the mix
-    ``train_fmb_dense`` beside the shipped ones."""
+    ``train_fmb_dense_toy`` beside the shipped ones (a name of its own: the
+    shipped ``train_fmb_dense`` keeps its limits)."""
     json.dump(DEEPFM_TOY, open(os.path.join(toy_bench, "configs", "deepfm_toy.json"), "w"))
     mix = json.load(open(os.path.join(toy_bench, "traffic", "train_fmb.json")))
     mix["limits"] = dict(mix["limits"], **DENSE_LIMITS)
-    json.dump(mix, open(os.path.join(toy_bench, "traffic", "train_fmb_dense.json"), "w"))
+    json.dump(mix, open(os.path.join(toy_bench, "traffic", "train_fmb_dense_toy.json"), "w"))
     return toy_bench

@@ -150,7 +150,7 @@ def test_the_five_metric_files_name_the_cell_and_the_layer_and_benchmark_json_sa
     bench = json.load(open(os.path.join(cells.CHECKOUT, "BENCHMARK.json")))
     listed = {m["name"]: m for m in bench["per_layer"]}
     names = ["deepfm.mlp_ms", "deepfm.feed_ms", "deepfm.dense_update_ms", "deepfm.dense_params", "deepfm.mlp_roofline"]
-    assert [m["name"] for m in bench["per_layer"][-5:]] == names
+    assert set(names) <= set(listed)
     for name in names:
         m = _metric(name)
         assert m["workloads"] == [CELL] and m["layer"] == "dense head (models/deepfm)" and m["kinds"] == ["train"]

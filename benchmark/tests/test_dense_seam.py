@@ -20,7 +20,7 @@ import pytest
 from harness import cells, common, gen, loadgen, reference, serve, train
 from harness.models import deepfm, dense_leaves, fm2
 
-CELL = "deepfm_toy.train_fmb_dense"
+CELL = "deepfm_toy.train_fmb_dense_toy"
 FIVE = {"loss_gap", "grad1_norm_gap", "delta3_norm_gap", "dense_grad1_norm_gap", "dense_delta3_norm_gap"}
 
 
@@ -199,7 +199,7 @@ def test_frozen_dense_leaves_cannot_be_planted_in_a_model_that_has_none(toy_benc
 
 @pytest.mark.parametrize("lacking", [("dense_grad1_norm_gap",), ("dense_delta3_norm_gap",), train.DENSE_NUMBERS])
 def test_a_dense_model_under_a_mix_without_dense_limits_exits(dense_bench, tmp_path, lacking):
-    path = os.path.join(dense_bench, "traffic", "train_fmb_dense.json")
+    path = os.path.join(dense_bench, "traffic", "train_fmb_dense_toy.json")
     mix = json.load(open(path))
     for name in lacking:
         del mix["limits"][name]

@@ -148,8 +148,12 @@ CHIP_READINGS = {
 def test_the_mix_is_train_fmb_under_limits_that_see_the_third_order_term():
     mix = json.load(open(os.path.join(cells.BENCH_DIR, "traffic", "train_fmb_order3.json")))
     plain = json.load(open(os.path.join(cells.BENCH_DIR, "traffic", "train_fmb.json")))
-    same = lambda m: {k: v for k, v in m.items() if k not in ("what", "limits", "limits_from")}
+    # Key for key but for the loss fetch's cadence: 8 steps, so that a host stall finds the chip fed (PERF.md section 2).
+    cadence = lambda m: m["ini"]["Train"]["log_every"]
+    same = lambda m: {k: v for k, v in m.items() if k not in ("what", "limits", "limits_from", "ini")} | {
+        "ini": {**m["ini"], "Train": {k: v for k, v in m["ini"]["Train"].items() if k != "log_every"}}}
     assert same(mix) == same(plain) and set(mix["limits"]) == set(plain["limits"]) and mix["limits_from"]
+    assert (cadence(mix), cadence(plain)) == (8, 4)
     decide = lambda reading, limits: common.decide(dict(zip(NAMES, reading)), limits)
     for what, readings in CHIP_READINGS.items():
         for reading in readings:

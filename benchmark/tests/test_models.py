@@ -65,14 +65,15 @@ def test_a_shipped_configurations_module_has_the_programs_row_width(config, tmp_
     program = build_model(load_config(cells.write_ini(str(tmp_path / "cell.cfg"), cell["ini"])))
     assert cell["model"].row_dim == program.row_dim
     assert cell["model"].reads_fields == bool(getattr(program, "uses_fields", False))
-    # ... and its dense leaves: none on either side (shapes alone: the table is not drawn).
+    # ... and its dense leaves, the same names and shapes on both sides (the program's by shape alone: the table is not drawn).
     import jax
 
     from fast_tffm_tpu.trainer import init_state
     from harness.models import dense_leaves
 
     state = jax.eval_shape(lambda: init_state(program, jax.random.key(0)))
-    assert dense_leaves(cell["model"]) == {} and state.dense == {} and state.dense_opt.accum == {}
+    shapes = lambda tree: {name: tuple(leaf.shape) for name, leaf in tree.items()}
+    assert shapes(dense_leaves(cell["model"])) == shapes(state.dense) == shapes(state.dense_opt.accum)
 
 
 FFM_TOY = {
