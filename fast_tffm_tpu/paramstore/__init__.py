@@ -20,6 +20,7 @@ TrainState, resume cursor) for training.py's tiered branch."""
 from __future__ import annotations
 
 import os
+from functools import partial
 
 import numpy as np
 
@@ -126,15 +127,20 @@ def open_tiered_run(cfg, model, max_nnz: int, *, resume: bool, log=print):
                 f"{cfg.paramstore_hot_rows} ignored for this run)"
             )
         server = TieredParamServer(
-            store, hot_ids, miss_rows, model, init_accum=init_acc
+            store, hot_ids, miss_rows, model, init_accum=init_acc,
+            residency_policy="the checkpoint's",
         )
         dense = jax.tree.unflatten(treedef, [jnp.asarray(x) for x in rec["dense"]])
         dense_acc = jax.tree.unflatten(
             treedef, [jnp.asarray(x) for x in rec["dense_accum"]]
         )
+
+        def hot(t, a):
+            t[:], a[:] = rec["hot_t"], rec["hot_a"]
+
         state = _compact_state(
-            server, rec["hot_t"], rec["hot_a"], dense,
-            AdagradState(dense_acc), int(rec["step"]), init_acc,
+            server, hot, dense, AdagradState(dense_acc), int(rec["step"]),
+            init_acc,
         )
         log(
             f"resumed tiered run from {cfg.model_file} at step "
@@ -188,35 +194,71 @@ def open_tiered_run(cfg, model, max_nnz: int, *, resume: bool, log=print):
         ),
     )
     server = TieredParamServer(
-        store, hot_ids, miss_rows, model, init_accum=init_acc
+        store, hot_ids, miss_rows, model, init_accum=init_acc,
+        residency_policy=(
+            f"sample of {cfg.paramstore_sample_batches} batches"
+            if policy == "sample" else policy
+        ),
     )
-    hot_t, hot_a = store.read_rows(server.residency.hot_ids)
     state = _compact_state(
-        server, hot_t, hot_a, dense, dense_opt, step0, init_acc
+        server, partial(_read_hot, store, server.residency.hot_ids), dense,
+        dense_opt, step0, init_acc,
     )
+    row_bytes = 4 * (model.row_dim + accum_width)
     log(
-        f"paramstore: hot tier {server.hot_rows} rows + staging "
-        f"{server.miss_rows} rows on device "
-        f"({server.capacity * (model.row_dim + accum_width) * 4 / 2**20:.1f} "
-        f"MiB), cold store {vocab} rows at {store_dir}"
+        f"paramstore: {vocab} logical rows on the host "
+        f"({vocab * row_bytes / 2**30:.2f} GiB of table and accumulator, "
+        f"{'materialized' if materialize else 'lazy'} cold store at "
+        f"{store_dir}); on the device {server.hot_rows} hot + "
+        f"{server.miss_rows} staging slots "
+        f"({server.capacity * row_bytes / 2**30:.2f} GiB); residency "
+        f"{server.residency_policy}"
     )
     return server, state, None
 
 
-def _compact_state(server, hot_t, hot_a, dense, dense_opt, step, init_acc):
+# Rows a set-up read takes at a time: its uint64 hash temporaries stay a
+# few tens of MB per worker, whatever the hot tier's size.
+_HOT_READ_CHUNK = 1 << 18
+
+
+def _read_hot(store, hot_ids, t, a):
+    """Fill ``t`` [H, D] and ``a`` [H, A] with the store's rows of
+    ``hot_ids``, a chunk at a time over a few threads (numpy releases the
+    interpreter lock in the hash and the copies)."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    def one(lo):
+        hi = min(hot_ids.size, lo + _HOT_READ_CHUNK)
+        t[lo:hi], a[lo:hi] = store.read_rows(hot_ids[lo:hi])
+
+    starts = range(0, hot_ids.size, _HOT_READ_CHUNK)
+    with ThreadPoolExecutor(max_workers=min(8, os.cpu_count() or 1)) as pool:
+        list(pool.map(one, starts))
+
+
+def _compact_state(server, fill_hot, dense, dense_opt, step, init_acc):
+    """The device ``TrainState`` over the compact ``[C, D]`` table: the hot
+    rows that ``fill_hot(table[:H], accum[:H])`` writes in place, then
+    the staging slots (zero rows, the initial accumulator)."""
     import jax.numpy as jnp
 
     from fast_tffm_tpu.optim import AdagradState
     from fast_tffm_tpu.trainer import TrainState
 
-    c, d, a = server.capacity, server.row_dim, server.accum_width
-    table = np.zeros((c, d), np.float32)
-    table[: server.hot_rows] = hot_t
-    accum = np.full((c, a), np.float32(init_acc), np.float32)
-    accum[: server.hot_rows] = hot_a
+    c, d, a, h = server.capacity, server.row_dim, server.accum_width, server.hot_rows
+    table = np.empty((c, d), np.float32)
+    accum = np.empty((c, a), np.float32)
+    fill_hot(table[:h], accum[:h])
+    table[h:] = 0.0
+    accum[h:] = np.float32(init_acc)
+    table_d = jnp.asarray(table)
+    del table
+    accum_d = jnp.asarray(accum)
+    del accum
     return TrainState(
-        table=jnp.asarray(table),
-        table_opt=AdagradState(jnp.asarray(accum)),
+        table=table_d,
+        table_opt=AdagradState(accum_d),
         dense=dense,
         dense_opt=dense_opt,
         step=jnp.asarray(np.int32(step)),

@@ -16,10 +16,13 @@ sequence, are identical to the uninterrupted run's):
   * ``file:PATH`` — an id array (.npy, or one id per line) exported from
     telemetry; the first K ids win.
 
-Resolution (``ResidencyMap.resolve``) is pure numpy over sorted hot ids:
-hot id -> its rank (= its device slot), miss id -> a per-superbatch
-staging slot.  Slots are ranks in SORTED order, so the mapping is a pure
-function of the hot set — no insertion-order state to drift."""
+Resolution (``ResidencyMap.resolve``) is one gather per id from a dense
+id-to-slot map (``int32[V + 1]``, slot + 1 per hot id, 0 for the rest: 4 B a
+logical row, built once; the zeros are never-touched pages until a hot id
+lands on them): hot id -> its rank (= its device slot), miss id -> a
+per-superbatch staging slot.  Slots are ranks in SORTED order, so the
+mapping is a pure function of the hot set — no insertion-order state to
+drift."""
 
 from __future__ import annotations
 
@@ -38,27 +41,33 @@ class Resolved(NamedTuple):
     miss_ids: np.ndarray  # unique missed LOGICAL ids (sorted), [m]
     hit_slots: int  # gather slots that hit the hot tier
     total_slots: int  # all gather slots (B*N per micro batch)
-    unique_ids: int  # unique logical ids across the superbatch
 
 
 class ResidencyMap:
-    def __init__(self, hot_ids: np.ndarray):
+    """The hot set and its id-to-slot map.  ``vocab`` sizes the map (the
+    logical rows); without it the map covers the largest hot id.  An id at
+    or past the map's end reads its last entry, which no hot id holds, so
+    it misses."""
+
+    def __init__(self, hot_ids: np.ndarray, vocab: int | None = None):
         hot = np.unique(np.asarray(hot_ids, np.int64))
         if hot.size != np.asarray(hot_ids).size:
             raise ValueError("hot_ids must be unique")
         self.hot_ids = hot  # sorted; slot of hot_ids[i] is i
         self.hot_rows = int(hot.size)
+        top = int(hot[-1]) + 1 if hot.size else 0
+        self._slot1 = np.zeros(max(int(vocab or 0), top) + 1, np.int32)
+        self._slot1[hot] = np.arange(1, hot.size + 1, dtype=np.int32)
+
+    def _slots1(self, ids) -> np.ndarray:
+        """slot + 1 per id, 0 for a miss: one gather."""
+        return self._slot1.take(ids, mode="clip")
 
     def lookup(self, ids: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        """(hit mask, hot slot per id) for flat logical ``ids``."""
-        pos = np.searchsorted(self.hot_ids, ids)
-        pos_c = np.minimum(pos, max(0, self.hot_rows - 1))
-        hit = (
-            (pos < self.hot_rows) & (self.hot_ids[pos_c] == ids)
-            if self.hot_rows
-            else np.zeros(ids.shape, bool)
-        )
-        return hit, pos_c.astype(np.int64)
+        """(hit mask, slot per id) for flat logical ``ids``: a hit's hot
+        slot, slot 0 for a miss, so that every slot is in range."""
+        s = self._slots1(np.asarray(ids))
+        return s > 0, np.maximum(s - 1, 0)
 
     def resolve(self, ids_seq: list[np.ndarray], miss_capacity: int) -> Resolved:
         """Remap a superbatch's logical ids to device slots.
@@ -67,11 +76,14 @@ class ResidencyMap:
         staging slot ``hot_rows + rank`` (rank within the sorted unique
         miss set of THIS superbatch).  Dedup-before-gather falls out for
         free: a miss row is staged (and its bytes cross the wire) once
-        per superbatch no matter how many slots repeat it."""
+        per superbatch no matter how many slots repeat it.  One map read
+        per id; the only sort is over the missed ids."""
+        shapes = [np.shape(a) for a in ids_seq]
         flats = [np.asarray(a).reshape(-1) for a in ids_seq]
         all_flat = np.concatenate(flats) if len(flats) > 1 else flats[0]
-        hit_all, _ = self.lookup(all_flat)
-        miss_ids = np.unique(all_flat[~hit_all])
+        local = self._slots1(all_flat)
+        miss_at = np.flatnonzero(local == 0)
+        miss_ids, rank = np.unique(all_flat[miss_at], return_inverse=True)
         if miss_ids.size > miss_capacity:
             raise ValueError(
                 f"paramstore: a superbatch touches {miss_ids.size} unique "
@@ -79,21 +91,14 @@ class ResidencyMap:
                 f"{miss_capacity} — raise [ParamStore] miss_rows (or "
                 "hot_rows), or lower batch_size/steps_per_call"
             )
-        remapped = []
-        for a, flat in zip(ids_seq, flats):
-            hit, slot = self.lookup(flat)
-            miss_rank = np.searchsorted(miss_ids, flat)
-            local = np.where(
-                hit, slot, self.hot_rows + np.minimum(miss_rank, max(0, miss_ids.size - 1))
-            )
-            remapped.append(local.astype(np.int32).reshape(np.asarray(a).shape))
-        uniq = int(np.unique(all_flat).size)
+        local -= 1
+        local[miss_at] = self.hot_rows + rank.reshape(-1)
+        bounds = np.cumsum([f.size for f in flats])[:-1]
         return Resolved(
-            remapped=remapped,
+            remapped=[p.reshape(sh) for p, sh in zip(np.split(local, bounds), shapes)],
             miss_ids=miss_ids,
-            hit_slots=int(hit_all.sum()),
+            hit_slots=int(all_flat.size - miss_at.size),
             total_slots=int(all_flat.size),
-            unique_ids=uniq,
         )
 
 
@@ -130,36 +135,36 @@ def choose_hot_ids(
                 f"distinct in-range ids, fewer than hot_rows = {k}"
             )
         # Preserve the file's ranking: first K distinct ids in file order.
-        seen: set = set()
-        out = []
-        for i in ids.tolist():
-            if i not in seen:
-                seen.add(i)
-                out.append(i)
-                if len(out) == k:
-                    break
-        return np.array(out, np.int64)
+        _, first = np.unique(ids, return_index=True)
+        return ids[np.sort(first)[:k]]
     if policy != "sample":
         raise ValueError(
             f"unknown [ParamStore] residency policy {policy!r} "
             "(sample | first | file:PATH)"
         )
-    counts: dict = {}
-    ids_all = []
-    n = 0
-    for arr in sample_batches or ():
-        ids_all.append(np.asarray(arr, np.int64).reshape(-1))
-        n += 1
+    ids_all = [np.asarray(a, np.int64).reshape(-1) for a in sample_batches or ()]
     if not ids_all:
         # No sample available (empty stream): fall back to the first-K
         # deterministic set rather than failing a run that would work.
         return np.arange(k, dtype=np.int64)
     flat = np.concatenate(ids_all)
-    uniq, cnt = np.unique(flat, return_counts=True)
+    if int(vocab) <= 4 * flat.size:
+        # A dense count: one pass, no sort of the sample.
+        counts = np.bincount(flat, minlength=int(vocab))
+        uniq = np.flatnonzero(counts)
+        cnt = counts[uniq]
+        del counts
+    else:
+        uniq, cnt = np.unique(flat, return_counts=True)
     # Top-K by (count desc, id asc) — a full deterministic order, so ties
-    # cannot reshuffle residency between runs.
-    order = np.lexsort((uniq, -cnt))
-    top = uniq[order[:k]]
+    # cannot reshuffle residency between runs.  The cut is the largest
+    # count c with at least K ids seen c times or more: every id above it
+    # is in, and its ties are taken in id order (``uniq`` is sorted).
+    of_count = np.bincount(cnt)
+    at_least = np.cumsum(of_count[::-1])[::-1]
+    cut = max(1, int(np.flatnonzero(at_least >= k)[-1])) if at_least[0] >= k else 1
+    top = uniq[cnt > cut]
+    top = np.concatenate([top, uniq[cnt == cut][: k - top.size]])
     if top.size < k:
         # Fewer distinct ids than hot_rows in the sample: fill with the
         # smallest unseen ids (deterministic).

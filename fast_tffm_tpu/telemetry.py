@@ -198,16 +198,22 @@ SCHEMAS: dict[str, tuple[str, ...]] = {
     "soak": ("phase", "elapsed_s", "ok"),
     # Tiered parameter store (ISSUE 12; paramstore/): one record per log
     # window — hot-tier hit rate over gather slots, staged miss rows and
-    # their wire bytes, writeback (staging D2H -> pending overlay) and
-    # resolve costs, coherency restages, and the pending-overlay depth
-    # (rows awaiting their post-publish store apply).
+    # their wire bytes, the three host spans as ms per step of the
+    # window's ``steps`` (``tier.resolve``: residency lookup and the miss
+    # set; ``tier.read``: the missed rows through the overlay and the
+    # store; ``tier.writeback``: staging D2H -> pending overlay),
+    # coherency restages, and the pending-overlay depth (rows awaiting
+    # their post-publish store apply).
     "tiering": (
+        "steps",
         "hit_rate",
         "miss_rows",
+        "miss_rows_per_step",
         "miss_bytes_per_step",
         "writeback_rows",
         "writeback_ms",
         "resolve_ms",
+        "read_ms",
         "restages",
         "pending_rows",
     ),

@@ -614,6 +614,7 @@ def _run_training(
     accum_restart=None,
     stream_stop=None,
     paramstore=None,
+    tier_profile=None,
 ):
     """Shared step loop.  ``train_stream(epoch)`` overrides the per-epoch
     input stream, ``to_batch(parsed, w)`` the host→device batch assembly,
@@ -663,7 +664,9 @@ def _run_training(
     ``interaction_profile`` (``ops.fm.interaction_profile``: ``order``,
     ``interaction_form`` ``order2`` | ``pallas_anova`` | ``scan``, or the
     field-aware model's ``ffm_pair_tensor``, and the kernel's
-    ``anova_programs_per_step``, null for the other forms) rides it as well.
+    ``anova_programs_per_step``, null for the other forms) rides it as well,
+    and so does ``tier_profile`` (the tiered store's ``hot_rows``,
+    ``miss_rows`` and ``residency``, ``TieredParamServer.profile``).
 
     ``datastats_ids`` (optional ``batch -> device ids``) lets the sampled
     id-statistics collector read a device-cache batch's ids straight off
@@ -832,6 +835,7 @@ def _run_training(
             },
             **(exchange_profile or {}),
             **(interaction_profile or {}),
+            **(tier_profile or {}),
         )
 
     # Pod liveness: this host's heartbeat (armed at bring-up) starts
@@ -1589,6 +1593,19 @@ def _tiered_train(cfg: Config, *, resume: bool, log=print, step_hook=None):
     else:
         inner = make_train_step(model, cfg.learning_rate, decay=decay, body=body)
     step_fn = server.wrap_step(inner)
+    # The inner step's forms over the compact table, said as train() says
+    # them for a resident one.
+    from fast_tffm_tpu.optim import describe_rows_tail, rows_tail_form, rows_tail_profile
+    from fast_tffm_tpu.trainer import describe_gather, gather_form, gather_profile
+
+    m_ids = cfg.batch_size * max_nnz
+    num_rows, row_dim = state.table.shape
+    tail_form = rows_tail_form(num_rows, m_ids, row_dim, state.table_opt.accum.shape[-1])
+    tail_profile = rows_tail_profile(num_rows, m_ids, row_dim, tail_form)
+    log("sparse tail: " + describe_rows_tail(num_rows, m_ids, row_dim, tail_form))
+    gather_kind = gather_form(num_rows, m_ids, row_dim)
+    tail_profile.update(gather_profile(num_rows, m_ids, row_dim, gather_kind))
+    log("forward gather: " + describe_gather(num_rows, m_ids, row_dim, gather_kind))
     # The wire spec lives at the COMPACT capacity: ids narrow to the
     # local slot range (e.g. 3 bytes for a 2^30 logical vocab whose
     # compact tier holds < 2^24 slots).
@@ -1623,12 +1640,18 @@ def _tiered_train(cfg: Config, *, resume: bool, log=print, step_hook=None):
             "tiered runs score through the residency-aware evaluate path"
         )
 
-    return _run_training(
-        cfg, state, step_fn, predict_step, max_nnz, log,
-        train_stream=train_stream, to_batch=to_batch, evaluate=evaluate,
-        step_hook=step_hook, row_dim=model.row_dim,
-        start_cursor=start_cursor, paramstore=server,
-    )
+    try:
+        return _run_training(
+            cfg, state, step_fn, predict_step, max_nnz, log,
+            train_stream=train_stream, to_batch=to_batch, evaluate=evaluate,
+            step_hook=step_hook, row_dim=model.row_dim,
+            start_cursor=start_cursor, paramstore=server,
+            tail_profile=tail_profile,
+            interaction_profile=_say_interaction(log, model, cfg.batch_size),
+            tier_profile=server.profile(),
+        )
+    finally:
+        to_batch.close()
 
 
 def _device_cached_input(cfg: Config, model, max_nnz: int, log, body=None):

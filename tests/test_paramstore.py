@@ -165,7 +165,7 @@ def test_residency_resolve_remaps_and_dedups():
     h = m.hot_rows
     expect = np.array([[0, h + 1, 1], [h + 1, h + 0, 0]])
     assert np.array_equal(res.remapped[0], expect)
-    assert res.hit_slots == 3 and res.total_slots == 6 and res.unique_ids == 4
+    assert res.hit_slots == 3 and res.total_slots == 6 and res.miss_ids.size == 2
     with pytest.raises(ValueError, match="miss_rows"):
         m.resolve(ids, miss_capacity=1)
 
@@ -228,6 +228,39 @@ def test_tiered_row_accumulator(ds):
         _cfg(ds, "tier_row", paramstore=True, paramstore_hot_rows=32, **kw)
     )
     assert _losses(res_logs) == _losses(tier_logs)
+
+
+def test_a_long_tiered_run_holds_no_more_once_every_miss_is_pending(ds):
+    """Once every missed row is pending, a step leaves nothing behind: the
+    live device arrays, their bytes, the threads and the overlay's pool
+    stay where they were epochs before."""
+    import threading
+
+    import jax
+
+    epochs = 12
+    seen = []
+
+    def hook(step):
+        srv = sys._getframe(1).f_locals["paramstore"]
+        live = jax.live_arrays()
+        seen.append((
+            len(live), sum(a.nbytes for a in live), threading.active_count(),
+            srv.pending_rows, srv._overlay._pool.shape[0],
+        ))
+
+    cfg = _cfg(
+        ds, "t_long", paramstore=True, paramstore_hot_rows=48,
+        epoch_num=epochs, save_every_epochs=0, queue_size=2,
+    )
+    _run(cfg, step_hook=hook)
+    n = len(seen) // epochs
+    early, late = seen[3 * n : 4 * n], seen[(epochs - 1) * n :]
+    assert {s[3:] for s in early} == {s[3:] for s in late} and len({s[3] for s in late}) == 1
+    # The prefetch queue holds up to its depth of batches, whenever a hook
+    # looks: the late epoch may hold as much as the early one, no more.
+    for k in range(3):
+        assert max(s[k] for s in late) <= max(s[k] for s in early), (k, early, late)
 
 
 def test_tiered_coherency_restage_stays_exact(ds, tmp_path):
@@ -515,3 +548,438 @@ def test_paramstore_config_rejections():
     with pytest.raises(ValueError, match="rows"):
         mk(dedup_gather_rows=8, table_layout="packed").validate()
     mk(paramstore=True).validate()  # the plain enablement is legal
+
+
+# -- the host path's arrays (resolve, overlay, shipped rows) --------------
+
+
+class _RowModel:
+    """What the server asks of a model: its row width, whether it reads
+    fields."""
+
+    uses_fields = False
+
+    def __init__(self, row_dim):
+        self.row_dim = row_dim
+
+
+def _server(tmp_path, vocab=256, d=5, hot=np.arange(0, 256, 7), miss_rows=13):
+    from fast_tffm_tpu.paramstore import TieredParamServer
+
+    store = ColdStore.create(
+        str(tmp_path / "store"), vocab=vocab, row_dim=d, accum_width=d,
+        seed=1, init_range=0.5, init_accum=0.1,
+    )
+    return TieredParamServer(store, hot, miss_rows, _RowModel(d), init_accum=0.1)
+
+
+def _stage_and_fetch(srv, ids, rows):
+    """What a step does with its misses: ``rows`` [n, D + A] in the staging
+    slots of a state, fetched for the writeback after the next dispatch."""
+    import jax.numpy as jnp
+
+    from fast_tffm_tpu.optim import AdagradState
+    from fast_tffm_tpu.trainer import TrainState
+
+    d, h = srv.row_dim, srv.hot_rows
+    table = np.zeros((srv.capacity, d), np.float32)
+    accum = np.full((srv.capacity, srv.accum_width), 0.1, np.float32)
+    table[h : h + ids.size], accum[h : h + ids.size] = rows[:, :d], rows[:, d:]
+    state = TrainState(
+        table=jnp.asarray(table), table_opt=AdagradState(jnp.asarray(accum)),
+        dense={}, dense_opt=AdagradState({}), step=jnp.asarray(np.int32(0)),
+    )
+    srv._build_jits()
+    srv._fetch_staged(state, ids)
+
+
+def _searchsorted_lookup(hot, ids):
+    """The lookup the dense map replaced: a binary search of the sorted hot
+    ids."""
+    pos = np.searchsorted(hot, ids)
+    pos_c = np.minimum(pos, max(0, hot.size - 1))
+    hit = (pos < hot.size) & (hot[pos_c] == ids) if hot.size else np.zeros(ids.shape, bool)
+    return hit, pos_c
+
+
+def _searchsorted_resolve(hot, ids_seq):
+    flat = np.concatenate([a.reshape(-1) for a in ids_seq])
+    hit_all, _ = _searchsorted_lookup(hot, flat)
+    miss = np.unique(flat[~hit_all])
+    out = []
+    for a in ids_seq:
+        hit, slot = _searchsorted_lookup(hot, a.reshape(-1))
+        rank = np.minimum(np.searchsorted(miss, a.reshape(-1)), max(0, miss.size - 1))
+        out.append(np.where(hit, slot, hot.size + rank).reshape(a.shape))
+    return out, miss, int(hit_all.sum())
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_the_id_map_resolves_as_the_search_of_the_hot_set_did(seed):
+    rng = np.random.default_rng(seed)
+    vocab = int(rng.integers(50, 5000))
+    hot = rng.choice(vocab, size=int(rng.integers(0, vocab // 2)), replace=False)
+    m = ResidencyMap(hot, vocab)
+    ids = rng.integers(0, vocab, size=(int(rng.integers(1, 4)), 17, 5))  # repeats, hits and misses
+    hit, slot = m.lookup(ids.reshape(-1))
+    want_hit, want_slot = _searchsorted_lookup(np.sort(hot), ids.reshape(-1))
+    assert np.array_equal(hit, want_hit) and np.array_equal(slot[hit], want_slot[hit])
+    assert ((0 <= slot) & (slot < max(1, hot.size))).all()
+    res = m.resolve(list(ids), miss_capacity=ids.size)
+    remapped, miss, hits = _searchsorted_resolve(np.sort(hot), list(ids))
+    assert np.array_equal(res.miss_ids, miss) and res.hit_slots == hits
+    assert all(np.array_equal(a, b) and a.dtype == np.int32 for a, b in zip(res.remapped, remapped))
+    # A map sized by its hot ids alone: an id past its end misses.
+    small = ResidencyMap(hot)
+    assert np.array_equal(small.lookup(np.array([vocab + 5]))[0], [False])
+
+
+def test_the_sample_policy_counts_densely_as_it_sorted():
+    """Top-K by (count desc, id asc), whichever way the sample is counted:
+    a dense count (a vocabulary within four times the sample) and a sort."""
+    rng = np.random.default_rng(7)
+    flat = rng.zipf(1.3, size=4000) % 900
+    for vocab in (900, 100_000):
+        for k in (1, 17, 250, 899):  # 899: more than the sample's distinct ids, the smallest unseen fill
+            uniq, cnt = np.unique(flat, return_counts=True)
+            want = uniq[np.lexsort((uniq, -cnt))][:k]
+            fill = np.setdiff1d(np.arange(min(vocab, 2 * k)), want)[: k - want.size]
+            got = choose_hot_ids("sample", k, vocab, sample_batches=iter([flat]))
+            assert np.array_equal(got, np.sort(np.concatenate([want, fill])))
+
+
+def test_the_shipped_rows_round_trip_bit_for_bit(tmp_path):
+    """The converter's staged rows reach the device as the overlay holds
+    them, bit for bit: negative zero, subnormals, an odd staging capacity,
+    a single batch and a superbatch of two (``k > 0``)."""
+    import jax
+
+    from fast_tffm_tpu.data.libsvm import ParsedBatch
+    from fast_tffm_tpu.data.wire import make_spec
+    from fast_tffm_tpu.paramstore import TieredConverter
+
+    srv = _server(tmp_path)
+    d = srv.row_dim
+    special = np.array([-0.0, 1e-45, -1.4e-40, 3.4e38, 1.17e-38], np.float32)
+    odd = np.array([3, 5, 200, 201, 255], np.int64)  # none of them hot
+    rows = np.concatenate([np.tile(special, (5, 2))[:, :d], np.tile(special[::-1], (5, 2))[:, :d]], axis=1)
+    _stage_and_fetch(srv, odd, rows)
+    srv.flush_writeback(None)
+    spec = make_spec(srv.capacity, 4, with_vals=True, with_fields=False, with_weights=True)
+    conv = TieredConverter(srv, spec)
+
+    def parsed(ids):
+        ids = np.asarray(ids, np.int32).reshape(2, 4)
+        return ParsedBatch(
+            labels=np.array([0, 1], np.float32), ids=ids, vals=np.ones(ids.shape, np.float32),
+            fields=np.zeros(ids.shape, np.int32), nnz=np.full(2, 4, np.int32),
+        )
+
+    a, b = parsed([3, 7, 5, 200, 14, 201, 255, 3]), parsed([255, 5, 0, 1, 2, 9, 3, 10])
+    for given, weights in ((a, np.ones(2, np.float32)), ([a, b], [np.ones(2, np.float32)] * 2)):
+        tb = conv(given, weights)
+        n = tb.miss_ids.size
+        s = srv.staged_rows(n)
+        staged = np.asarray(tb.staged.result()[0]).reshape(2 * d, -1).T
+        mt, ma = staged[:, :d], staged[:, d:]
+        assert staged.shape == (s, 2 * d) and s <= srv.miss_rows == 13  # an odd staging capacity
+        t, acc, _ = srv.read_latest(tb.miss_ids)
+        assert np.array_equal(mt[:n].view(np.uint32), t.view(np.uint32))
+        assert np.array_equal(ma[:n].view(np.uint32), acc.view(np.uint32))
+        assert (mt[n:] == 0).all() and (ma[n:] == np.float32(0.1)).all()
+        at = np.searchsorted(tb.miss_ids, odd)
+        assert np.array_equal(mt[at].view(np.uint32), rows[:, :d].view(np.uint32))
+        seq = given if isinstance(given, list) else [given]
+        res = srv.residency.resolve([p.ids for p in seq], srv.miss_rows)
+        got_ids = np.asarray(jax.device_get(tb.batch.ids))
+        assert np.array_equal(got_ids, np.stack(res.remapped) if isinstance(given, list) else res.remapped[0])
+    # Payloads of one staged size made in a row keep each its own rows on
+    # the device.
+    cold = np.setdiff1d(np.arange(256), srv.residency.hot_ids)
+    made = [conv(parsed(np.resize(cold[6 * i : 6 * i + 6], 8)), np.ones(2, np.float32)) for i in range(6)]
+    for tb in made:
+        t, acc, _ = srv.read_latest(tb.miss_ids)
+        assert tb.miss_ids.size == 6
+        assert np.array_equal(np.asarray(tb.staged.result()[0]).reshape(2 * d, -1).T[:6], np.concatenate([t, acc], axis=1))
+    conv.close()
+
+
+class _DictOverlay:
+    """The pending overlay as the dict it replaced, with the store beneath
+    it: what ``read_latest``, ``_stale`` and ``apply_pending`` must agree
+    with."""
+
+    def __init__(self, d, store_seed=1, init_range=0.5):
+        self.d, self.seed, self.r = d, store_seed, init_range
+        self.pending, self.store, self.log, self.version = {}, {}, [], 0
+
+    def write(self, ids, rows):
+        self.version += 1
+        self.log.append((self.version, ids))
+        for i, row in zip(ids.tolist(), rows):
+            self.pending[i] = row.copy()
+
+    def read(self, ids):
+        lazy = np.concatenate(
+            [hashed_uniform_rows(ids, self.d, self.seed, self.r), np.full((ids.size, self.d), 0.1, np.float32)], axis=1
+        )
+        return np.stack([self.pending.get(i, self.store.get(i, lazy[j])) for j, i in enumerate(ids.tolist())])
+
+    def stale(self, miss, version, in_flight):
+        return any(np.intersect1d(miss, ids).size for v, ids in self.log if v > version) or bool(
+            in_flight is not None and np.intersect1d(miss, in_flight).size
+        )
+
+    def apply(self):
+        self.store.update(self.pending)
+        self.pending.clear()
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_the_array_overlay_agrees_with_a_dict(tmp_path, monkeypatch, seed):
+    """Seeded sequences of writebacks, reads with repeats, staleness checks
+    and applies, one of them killed between two chunks by the chaos hook:
+    the overlay reads what a dict over the store reads, and a kill
+    mid-apply loses no row and leaves none stale."""
+    from fast_tffm_tpu import resilience
+    from fast_tffm_tpu.paramstore import tiered
+
+    rng = np.random.default_rng(seed)
+    srv = _server(tmp_path, miss_rows=40)
+    d, vocab = srv.row_dim, srv.store.vocab
+    model = _DictOverlay(d)
+    monkeypatch.setattr(tiered, "APPLY_CHUNK_BYTES", 3 * d * 4)  # three rows a chunk
+    kill_at = int(rng.integers(1, 4))
+
+    def fault(ordinal):
+        if ordinal == kill_at:
+            raise RuntimeError("killed between chunks")
+
+    monkeypatch.setattr(resilience, "maybe_writeback_fault", fault)
+    versions = [0]
+    for _ in range(50):
+        op = rng.choice(["write", "read", "stale", "in_flight", "apply"], p=[0.35, 0.3, 0.15, 0.1, 0.1])
+        if op in ("write", "in_flight"):
+            ids = np.sort(rng.choice(vocab, size=int(rng.integers(1, 30)), replace=False))
+            rows = rng.standard_normal((ids.size, 2 * d)).astype(np.float32)
+            rows[rng.random(rows.shape) < 0.1] = -0.0
+            _stage_and_fetch(srv, ids, rows)
+            if op == "in_flight":
+                miss = np.unique(rng.choice(vocab, size=20))
+                assert srv._stale(miss, srv._version) == model.stale(miss, srv._version, ids)
+            srv.flush_writeback(None)
+            model.write(ids, rows)
+            versions.append(srv._version)
+            assert srv._version == model.version
+        elif op == "read":
+            ids = rng.integers(0, vocab, size=int(rng.integers(1, 60)))
+            t, a, v = srv.read_latest(ids)
+            assert v == model.version
+            assert np.array_equal(np.concatenate([t, a], axis=1).view(np.uint32), model.read(ids).view(np.uint32))
+        elif op == "stale":
+            miss = np.unique(rng.choice(vocab, size=int(rng.integers(1, 30))))
+            v = int(rng.choice(versions))
+            assert srv._stale(miss, v) == model.stale(miss, v, None)
+        else:
+            try:
+                srv.apply_pending(f"save-{rng.integers(1 << 30)}")
+            except RuntimeError:
+                # Killed mid-apply: every pending row is still pending, and
+                # what reached the store is the same rows (a redo).
+                ids = np.array(sorted(model.pending), np.int64)
+                assert srv.pending_rows == ids.size
+                if ids.size:
+                    t, a = srv.store.read_rows(ids[:3])
+                    assert np.array_equal(np.concatenate([t, a], axis=1), model.read(ids[:3]))
+                    t, a, _ = srv.read_latest(ids)
+                    assert np.array_equal(np.concatenate([t, a], axis=1), model.read(ids))
+                continue
+            model.apply()
+            assert srv.pending_rows == 0
+        ids, t, a = srv.pending_snapshot()
+        assert list(ids) == sorted(model.pending)
+        if ids.size:
+            assert np.array_equal(np.concatenate([t, a], axis=1), np.stack([model.pending[i] for i in ids.tolist()]))
+    every = np.arange(vocab)
+    t, a, _ = srv.read_latest(every)
+    assert np.array_equal(np.concatenate([t, a], axis=1), model.read(every))
+
+
+def test_a_large_read_of_pending_rows_is_split_over_threads_and_reads_the_same():
+    from fast_tffm_tpu.paramstore import tiered
+
+    rng = np.random.default_rng(5)
+    ov = tiered._Overlay(1 << 20, 6, capacity=1024)
+    ids = np.unique(rng.integers(0, 1 << 20, size=400_000))
+    rows = rng.standard_normal((ids.size, 6)).astype(np.float32)
+    ov.write(ids, rows)
+    # Several parts of pending ids, with ids not pending among them.
+    absent = np.setdiff1d(np.arange(1 << 20), ids)[:5000]
+    ask = rng.permutation(np.concatenate([ids[: 3 * tiered._PART_ROWS + 17], absent]))
+    out = np.full((ask.size, 6), np.nan, np.float32)
+    cold, version = ov.read_into(ask, out)
+    assert version == 1
+    assert np.array_equal(np.sort(ask[cold]), absent)
+    found = np.setdiff1d(np.arange(ask.size), cold)
+    assert np.array_equal(out[found], rows[np.searchsorted(ids, ask[found])])
+    assert np.isnan(out[cold]).all()
+
+
+def test_overlay_readers_beside_its_writer_see_the_latest_rows_or_a_stale_version():
+    """The prefetch thread reads the overlay while the loop thread writes
+    it.  Under a short switch interval, with more readers than cores: every
+    row a reader gets is the latest of its id at the version it was handed,
+    unless a LATER writeback holds that id (torn or newer: what ``_stale``
+    restages)."""
+    import threading
+
+    from fast_tffm_tpu.paramstore.tiered import _Overlay
+
+    vocab, width, writes = 4096, 8, 150
+    ov = _Overlay(vocab, width, capacity=16)  # small: the pool grows under the readers
+    log = {}  # version -> (ids, value)
+    seen, stop = [], threading.Event()
+
+    def reader(seed):
+        rng = np.random.default_rng(seed)
+        while not stop.is_set():
+            ids = rng.integers(0, vocab, size=64)
+            rows = np.empty((ids.size, width), np.float32)
+            cold, version = ov.read_into(ids, rows)
+            found = np.setdiff1d(np.arange(ids.size), cold)
+            seen.append((ids[found], rows[found], version))
+
+    def writer():
+        rng = np.random.default_rng(99)
+        for k in range(1, writes + 1):
+            ids = np.unique(rng.integers(0, vocab, size=int(rng.integers(1, 200))))
+            log[k] = ids
+            assert ov.write(ids, np.full((ids.size, width), float(k), np.float32)) == k
+        stop.set()
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=reader, args=(i,)) for i in range((os.cpu_count() or 1) + 1)]
+        threads.append(threading.Thread(target=writer))
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert ov.version == writes and len(seen) > 0
+    last = {}  # id -> [(version, value)] in order
+    for k in range(1, writes + 1):
+        for i in log[k].tolist():
+            last.setdefault(i, []).append(k)
+    checked = 0
+    for ids, rows, version in seen:
+        for i, row in zip(ids.tolist(), rows):
+            wrote = last[i]
+            latest = max((k for k in wrote if k <= version), default=None)
+            if any(k > version for k in wrote) and not (row == latest).all():
+                continue  # a later writeback holds it: the payload restages
+            assert latest is not None and (row == latest).all(), (i, version, row)
+            checked += 1
+    assert checked > 0
+
+
+@pytest.fixture
+def harness():
+    """The benchmark's harness (a script's package, not an installed one)."""
+    bench = os.path.join(REPO, "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        from harness import cells, gen, train
+
+        yield cells, gen, train
+    finally:
+        sys.path.remove(bench)
+
+
+def test_a_lazy_tiered_run_follows_the_float32_reference_and_fails_it_without_its_writeback(tmp_path, monkeypatch, harness):
+    """The tiered cell's own check at a toy size past the materialize bound
+    (the lazy store, ``fm2_hashed``'s initial rows): about a fifth of a
+    step's slots miss the hot tier, and ids that miss in one step miss
+    again in the next ones, so that payloads resolved ahead go stale and
+    restage.  The run matches the float32 reference after three steps
+    within ``train_fmb``'s limits; dropping the writeback fails them."""
+    import time
+
+    from fast_tffm_tpu.paramstore import TieredParamServer, tiered
+
+    cells, gen, train = harness
+    seed, vocab, batch, n_batches = 11, 1 << 22, 512, 8
+    cell = cells.load_cell("fm16_criteo_tiered.train_fmb_tiered")
+    cell["ini"]["General"]["vocabulary_size"] = vocab
+    cell["ini"]["Train"].update(batch_size=batch, thread_num=2)
+    cell["traffic"].update(file_batches=n_batches, warm_steps=8)
+    cell["model"] = cells.harness_model(cell["config"], cell["ini"])
+    _, ids, _ = gen.rows_from_seed(seed, n_batches * batch, 39, vocab)
+    # The hot set: every id seen twice or more but every 16th of them, then
+    # singletons up to four fifths of the slots.
+    uniq, cnt = np.unique(ids, return_counts=True)
+    repeated = uniq[cnt > 1]
+    hot = np.concatenate([np.setdiff1d(repeated, repeated[3::16]), uniq[cnt == 1][:107_000]])
+    (tmp_path / "hot.txt").write_text("\n".join(map(str, hot)))
+    cell["ini"]["ParamStore"].update(hot_rows=hot.size, miss_rows=8192, residency=f"file:{tmp_path / 'hot.txt'}")
+    seen = {"hit": 0, "slots": 0, "restages": 0}
+    resolve, restage = tiered._TierStats.note_resolve, tiered._TierStats.note_restage
+
+    def note_resolve(self, res, *a):
+        seen["hit"] += res.hit_slots
+        seen["slots"] += res.total_slots
+        return resolve(self, res, *a)
+
+    def note_restage(self, *a):
+        seen["restages"] += 1
+        return restage(self, *a)
+
+    monkeypatch.setattr(tiered._TierStats, "note_resolve", note_resolve)
+    monkeypatch.setattr(tiered._TierStats, "note_restage", note_restage)
+    run = lambda: train.run(cell, seed, 0.2, False, time.time(), require_chip=False, workroot=str(tmp_path / "w"))
+    r = run()
+    assert r["correct"] is True and r["failed"] == 0, r["compared"]
+    assert 0.75 < seen["hit"] / seen["slots"] < 0.85 and seen["restages"] > 0, seen
+
+    def dropped(self, state):
+        self._last_staged = self._fetched = None
+
+    monkeypatch.setattr(TieredParamServer, "flush_writeback", dropped)
+    r = run()
+    assert r["correct"] is False and min(v["value"] / v["limit"] for k, v in r["compared"].items() if k != "loss_gap") > 1
+
+
+def test_the_tiered_cell_stops_at_once_a_program_whose_store_searches_its_hot_set(monkeypatch, harness):
+    """The tiered cell's harness model loads over this program's store (its
+    residency map is built over the vocabulary) and stops a program whose
+    map searches the sorted hot set, with a message and before the runtime
+    starts: such a program's set-up alone outlasts a run."""
+    from fast_tffm_tpu.paramstore import residency
+
+    cells, _, _ = harness
+    cell = cells.load_cell("fm16_criteo_tiered.train_fmb_tiered")
+    assert cell["model"].row_dim == 17 and cell["config"]["harness_model"] == "fm2_tiered"
+
+    class SearchedMap:
+        def __init__(self, hot_ids):
+            self.hot_ids = np.sort(hot_ids)
+
+    monkeypatch.setattr(residency, "ResidencyMap", SearchedMap)
+    with pytest.raises(SystemExit, match="searching the sorted hot set"):
+        cells.load_cell("fm16_criteo_tiered.train_fmb_tiered")
+
+
+@pytest.mark.parametrize("shape", [(3, 5), (5, 3), (20_011, 34), (34, 70_001)])
+def test_the_blocked_transpose_is_the_transpose(shape, monkeypatch):
+    """The staged rows' way to the chip and back: a block of the long axis
+    at a time, on threads, across blocks and parts of odd sizes."""
+    from fast_tffm_tpu.paramstore import tiered
+
+    monkeypatch.setattr(tiered, "_T_BLOCK", 1000)
+    monkeypatch.setattr(tiered, "_PART_ROWS", 4096)
+    a = np.random.default_rng(5).standard_normal(shape).astype(np.float32)
+    got = tiered._transposed(a)
+    assert got.flags.c_contiguous and np.array_equal(got, a.T)

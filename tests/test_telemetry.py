@@ -106,10 +106,10 @@ _DRIVER_PAYLOADS = {
     # Tiered parameter store (ISSUE 12): the per-log-window residency
     # record the training loop drains from paramstore stats.
     "tiering": dict(
-        hit_rate=0.6103, miss_rows=812, miss_rows_per_step=203.0,
+        steps=4, hit_rate=0.6103, miss_rows=812, miss_rows_per_step=203.0,
         miss_bytes_per_step=58464, wire_bytes_per_step=23040,
-        dedup_ratio=0.2954, writeback_rows=812, writeback_ms=1.9,
-        resolve_ms=3.2, restages=0, pending_rows=812, hot_rows=4096,
+        writeback_rows=812, writeback_ms=0.475, resolve_ms=0.8,
+        read_ms=0.4, restages=0, pending_rows=812, hot_rows=4096,
         apply_rows=0, apply_ms=0.0,
     ),
 }

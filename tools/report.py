@@ -573,8 +573,11 @@ def summarize(records: list[dict]) -> dict:
     s["tier_writeback_rows"] = (
         sum(r.get("writeback_rows") or 0 for r in tier) if tier else None
     )
+    # writeback_ms is a mean a writeback, and a step makes one.
     wb_ms = sum(
-        (r.get("writeback_ms") or 0) + (r.get("apply_ms") or 0) for r in tier
+        (r.get("writeback_ms") or 0) * (r.get("steps") or 1)
+        + (r.get("apply_ms") or 0)
+        for r in tier
     )
     s["tier_writeback_ms_total"] = round(wb_ms, 1) if tier else None
     # Writeback stall share: staging D2H + store applies as a fraction of
