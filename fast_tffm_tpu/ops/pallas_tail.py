@@ -8,7 +8,14 @@ touched rows.  The kernel here takes the place of everything after the
 FIRST sort (optim.sort_ids, which both forms share): it is handed the
 batch's occurrences in id order (optim.occurrences_by_id), duplicates and
 all, and sums a row's occurrences itself (PR 32; until then it ran after
-optim.dedup_rows' segment sum and saw every row once).
+optim.dedup_rows' segment sum and saw every row once).  The gradient
+columns reach that order as operands of the ids' sort up to 16 of them;
+wider rows are gathered in the sort's order, padded to one 128-lane tile
+first where their lane-major buffer is too large for the VMEM (a row-major
+row, where a 17-float row is 17 lanes of that buffer in HBM: the narrow
+gather took 78 ms of ``fm16_criteo_tiered``'s step on a TPU v5e), as they
+are where it fits or where a row shard keeps a prefix of the order
+(optim.occurrences_permutation).
 
 ``rows_tail_adagrad_update`` / ``sweep_adagrad_update`` — the **rows sweep**
 (PR 30) — serve a plain ``[V, D]`` table with a separate ``[V, D]``

@@ -191,15 +191,42 @@ _SWEEP_BYTES_PER_S = 560e9
 # tile (2.6 GB of temporaries).  Seventeen: 44.7 against 87.0.  But the sort
 # COMPILES in 19 s with 2 operands, 115 s with 10 (72 unstable) and 284 s
 # with 18, once for every batch shape, so it carries up to one 16-row group
-# of the kernel's operand and wider rows keep the gather.
+# of the kernel's operand.
 _SORT_OPERAND_COLUMNS = 16
+# Wider rows are gathered in the order of one sort of the ids with their
+# positions.  A ``[M, D]`` float32 buffer narrower than a tile is held
+# lane-major, ``D`` rounded up to sublanes of 8 by ``M`` lanes, so a row is
+# ``D`` single lanes: cheap where XLA keeps the buffer in the VMEM
+# (``fm3_k30_kdd12``'s 720,896 rows of 31, 92 MB, compiled for a v5e into
+# memory space 1: 9.8 ns a row in its step) and not where it is past the
+# VMEM's 128 MiB and stays in HBM (``fm16_criteo_tiered``'s 2,555,904 rows
+# of 17, 245 MB: 30.4 ns a row).  There the rows are padded to one 128-lane
+# tile, held row-major, and a gather moves each as one 512-byte row.  Read
+# standalone on a TPU v5e (PERF.md §6; medians of eight): 2,555,904
+# rows of 17 in 42.1 ms with the sort, against 83.0 narrow; the first
+# 1,284,384 of them (a row shard's bounded tail) 19.1 against 27.2; but
+# 720,896 rows of 31 12.5 against 9.0.  Sorts that carried the 17 columns
+# ran in 40.9-41.1 ms whether one, two of 9 and 8 or three of 6: XLA merges
+# sorts on one key into one, which compiles in 282-304 s.
+_VMEM_BYTES = 128 * 2**20
 
 
-def occurrences_permutation(d: int) -> str:
-    """How ``occurrences_by_id`` brings gradients ``d`` wide to id order:
-    ``"sort operands"`` or ``"row gather"`` (what ``dedup_rows`` does at
-    every width: its segment sum wants rows)."""
-    return "sort operands" if d <= _SORT_OPERAND_COLUMNS else "row gather"
+def occurrences_permutation(d: int, m: int) -> str:
+    """How ``occurrences_by_id`` brings the ``m`` gradients ``d`` wide that
+    one sort of the ids orders to id order: ``"sort operands"`` (the columns
+    ride that sort), ``"tile-wide row gather"`` (the rows, padded to one
+    128-lane tile, gathered in its order) or ``"row gather"`` (the rows as
+    they are, which ``dedup_rows`` does at every width: its segment sum
+    wants rows).  A trace-time function of the shapes, as ``rows_tail_form``
+    is: ``fm8_criteo`` (9) and ``deepfm10_criteo`` (11) take the sort
+    operands, ``fm16_criteo_tiered`` and ``fm16_criteo_row4``'s shard tails
+    (17 by 2,555,904: 245 MB) the tile-wide gather, ``fm3_k30_kdd12`` (31
+    by 720,896: 92 MB) the row gather."""
+    if d <= _SORT_OPERAND_COLUMNS:
+        return "sort operands"
+    if d < _LANES and -(-d // 8) * 8 * m * 4 > _VMEM_BYTES:
+        return "tile-wide row gather"
+    return "row gather"
 
 
 def rows_tail_form(
@@ -236,20 +263,25 @@ def rows_tail_form(
     return "sweep" if sweep_s < m * _ROWS_OVER_SWEEP_NS * 1e-9 else "rows"
 
 
-def rows_tail_profile(num_rows: int, m: int, d: int, form: str = "rows") -> dict:
+def rows_tail_profile(
+    num_rows: int, m: int, d: int, form: str = "rows", sorted_ids: int | None = None
+) -> dict:
     """The tail's trace-time choices at these shapes, as the step's
     ``kind=profile`` record carries them: ``tail_form``; how a row's
     duplicates are summed (``tail_duplicates``: ``segment_sum`` on rows
     ``segment_sum_lanes`` wide ahead of the row operations, or ``kernel``:
     the sweep's own contraction, and then no segment sum runs and its lanes
-    are null); how the gradients reach id order (``tail_permutation``); the
-    sweep's ``tail_block_lanes``."""
+    are null); how the gradients reach id order (``tail_permutation``:
+    ``occurrences_permutation``); the sweep's ``tail_block_lanes``.
+    ``sorted_ids``: the ids sorted, where the tail keeps the first ``m`` of
+    them (a row shard's bounded tail, ``sparse_adagrad_update``'s
+    ``keep``); ``None``: ``m``."""
     if form == "sweep":
         from fast_tffm_tpu.ops.pallas_tail import sweep_block_lanes
 
         return dict(
             tail_form="sweep", tail_duplicates="kernel", segment_sum_lanes=None,
-            tail_permutation=occurrences_permutation(d),
+            tail_permutation=occurrences_permutation(d, m if sorted_ids is None else sorted_ids),
             tail_block_lanes=sweep_block_lanes(num_rows, d),
         )
     return dict(
@@ -259,10 +291,12 @@ def rows_tail_profile(num_rows: int, m: int, d: int, form: str = "rows") -> dict
     )
 
 
-def describe_rows_tail(num_rows: int, m: int, d: int, form: str = "rows") -> str:
+def describe_rows_tail(
+    num_rows: int, m: int, d: int, form: str = "rows", sorted_ids: int | None = None
+) -> str:
     """The form ``sparse_adagrad_update`` takes at these shapes, for the
     trainer's start-up log (it is a trace-time choice, so it is said once)."""
-    p = rows_tail_profile(num_rows, m, d, form)
+    p = rows_tail_profile(num_rows, m, d, form, sorted_ids)
     if form == "sweep":
         block = p["tail_block_lanes"]
         return (
@@ -369,19 +403,26 @@ def occurrences_by_id(
     order, COLUMN BY COLUMN along the lanes — the layout the kernel reads
     and the one a ``[M, D]`` float32 buffer with ``D`` under a tile has on
     the TPU anyway.  So nothing here needs an ``[M, D]`` row, and the
-    permutation has two forms (``occurrences_permutation``): the ``D``
-    columns ride the ONE stable sort of the ids as its operands, or they are
-    gathered row by row in the order that sort returns.  ``keep``
-    (``sort_ids``): ``M`` is ``keep``, the gather stops there (the sorted
-    columns are cut there)."""
+    permutation has three forms (``occurrences_permutation``): the ``D``
+    columns ride the ONE stable sort of the ids as its operands, or the rows
+    are gathered in the order that sort returns, padded to one whole tile
+    first or as they are.  ``keep`` (``sort_ids``): ``M`` is ``keep``, the
+    gather stops there (the sorted columns are cut there)."""
+    d = row_grads.shape[1]
+    form = occurrences_permutation(d, ids.shape[0])
     with jax.named_scope("fm.dedup"):
-        if occurrences_permutation(row_grads.shape[1]) == "sort operands":
+        if form == "sort operands":
             sid, *cols = lax.sort(
                 (jnp.minimum(ids, num_rows), *row_grads.T), num_keys=1, is_stable=True
             )
             gt = jnp.stack(cols)
             return (sid, gt) if keep is None else (sid[:keep], gt[:, :keep])
         sid, order = sort_ids(ids, num_rows, keep)
+        if form == "tile-wide row gather":
+            # XLA folds a pad, the gather and the slice back into the narrow
+            # gather; the barrier keeps the tile-wide rows it is to read.
+            wide = lax.optimization_barrier(jnp.pad(row_grads, ((0, 0), (0, _LANES - d))))
+            return sid, wide[order][:, :d].T
         return sid, row_grads[order].T
 
 

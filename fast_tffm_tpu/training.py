@@ -2057,12 +2057,12 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
             shard_rows, m_ids, model.row_dim, state.table_opt.accum.shape[-1]
         )
         tail_profile = dict(
-            rows_tail_profile(shard_rows, m_ids, model.row_dim, tail_form),
+            rows_tail_profile(shard_rows, m_ids, model.row_dim, tail_form, handed),
             tail_slots=m_ids,
         )
         log(
             "sparse tail: "
-            + describe_rows_tail(shard_rows, m_ids, model.row_dim, tail_form)
+            + describe_rows_tail(shard_rows, m_ids, model.row_dim, tail_form, handed)
             + f"; the shard's first {m_ids} of {handed} exchanged slots"
         )
     dist_saveable = None

@@ -372,23 +372,44 @@ def test_sweep_sums_the_occurrences_of_a_row(name, acc_kind, decay):
             _assert_accum_few_ulp(ka[look], decay * np.asarray(acc)[look])
 
 
-@pytest.mark.parametrize("d, form", [(9, "sort operands"), (16, "sort operands"), (17, "row gather"), (31, "row gather"), (89, "row gather")])
-def test_occurrences_reach_id_order_the_same_way_in_both_forms(d, form):
+@pytest.mark.parametrize(
+    "d, keep, form",
+    [
+        (9, None, "sort operands"),
+        (16, None, "sort operands"),
+        (17, None, "tile-wide row gather"),
+        (17, 300, "tile-wide row gather"),  # half of the 600 kept: the gather of those alone
+        (17, 300, "row gather"),
+        (18, None, "row gather"),
+        (31, None, "tile-wide row gather"),
+        (31, None, "row gather"),
+        (89, None, "row gather"),
+    ],
+    ids=["9", "16", "17", "17_keep_half", "17_keep_half_narrow", "18", "31_tile_wide", "31", "89"],
+)
+def test_occurrences_reach_id_order_the_same_way_in_both_forms(monkeypatch, d, keep, form):
     """``occurrences_by_id``: the ids ascending with drop ids clamped to V,
     the gradients column by column in that order, ties in the batch's order
-    (a stable sort), whether the columns ride the sort or are gathered by
-    its order (``occurrences_permutation``, by row width)."""
-    from fast_tffm_tpu.optim import occurrences_by_id, occurrences_permutation
+    (a stable sort), whether the columns ride the sort or the rows are
+    gathered by its order, padded to a tile or not: every form is
+    ``row_grads[order].T`` bit for bit, ``-0.0``, a NaN and an infinity
+    among the values.  The rows here are few, so the tile-wide gather, which
+    ``occurrences_permutation`` takes for buffers past the VMEM, is forced."""
+    from fast_tffm_tpu import optim
 
-    assert occurrences_permutation(d) == form
+    m, v = 600, 50
+    if form == "tile-wide row gather":
+        assert optim.occurrences_permutation(d, 2_555_904) == form
+        monkeypatch.setattr(optim, "occurrences_permutation", lambda *a: form)
+    assert optim.occurrences_permutation(d, m) == form
     rng = np.random.default_rng(d)
-    v = 50
-    ids = rng.integers(0, v + 8, 600).astype(np.int32)  # repeats, and ids past V
-    g = rng.standard_normal((600, d)).astype(np.float32)
-    sid, gt = jax.jit(lambda i, r: occurrences_by_id(i, r, v))(ids, g)
-    order = np.argsort(np.minimum(ids, v), kind="stable")
+    ids = rng.integers(0, v + 8, m).astype(np.int32)  # repeats, and ids past V
+    g = rng.standard_normal((m, d)).astype(np.float32)
+    g[3, d - 1], g[7, 0], g[11, d // 2] = -0.0, np.nan, np.float32(np.inf)
+    sid, gt = jax.jit(lambda i, r: optim.occurrences_by_id(i, r, v, keep))(ids, g)
+    order = np.argsort(np.minimum(ids, v), kind="stable")[:keep]
     np.testing.assert_array_equal(np.asarray(sid), np.minimum(ids, v)[order])
-    np.testing.assert_array_equal(np.asarray(gt), g[order].T)
+    np.testing.assert_array_equal(np.asarray(gt).view(np.uint32), g[order].T.view(np.uint32))
 
 
 def _cell_shapes(config, shards=1):
@@ -436,6 +457,74 @@ def test_auto_chooses_the_form_from_shapes_and_backend(shapes, backend, form):
     assert rows_tail_form(*shapes, backend=backend) == form
     if backend == "cpu":  # what this suite's train steps get when nobody says
         assert rows_tail_form(*shapes) == "rows"
+
+
+@pytest.mark.parametrize(
+    "config, keep, form",
+    [
+        ("fm8_criteo", None, "sort operands"),  # nine columns ride the sort, as before
+        ("deepfm10_criteo", None, "sort operands"),  # eleven
+        ("fm16_criteo_tiered", None, "tile-wide row gather"),  # 17 by 2,555,904: 245 MB lane-major
+        ("fm16_criteo_row4", None, "tile-wide row gather"),  # the row shard's whole-list branch: the same
+        ("fm16_criteo_row4", 1284384, "tile-wide row gather"),  # its bounded branch: the first half of them
+        ("fm3_k30_kdd12", None, "row gather"),  # 31 by 720,896: 92 MB, kept in the VMEM
+    ],
+    ids=["fm8_criteo", "deepfm10_criteo", "fm16_criteo_tiered", "fm16_criteo_row4_whole", "fm16_criteo_row4",
+         "fm3_k30_kdd12"],
+)
+def test_the_permutation_is_chosen_from_the_shapes(config, keep, form):
+    """``occurrences_permutation`` at the shipped cells' shapes (from their
+    files; a row shard's tail sorts every chip's 2,555,904 slots and may
+    keep the first 1,284,384, ``parallel.train_step.shard_tail_ids``), and
+    what the step's profile record and start-up line say of it."""
+    from fast_tffm_tpu.optim import describe_rows_tail, occurrences_permutation, rows_tail_profile
+
+    v, m, d, _cols = _cell_shapes(config)
+    assert occurrences_permutation(d, m) == form
+    said = rows_tail_profile(v, keep or m, d, "sweep", m)["tail_permutation"]
+    assert said == form
+    assert f"occurrences brought to id order as {said}, row width {d})" in describe_rows_tail(v, keep or m, d, "sweep", m)
+
+
+def test_the_tile_wide_gather_starts_past_the_vmem():
+    """The lane-major buffer of ``m`` rows of 17 is 24 sublanes x ``m``
+    floats: past 128 MiB at 1,398,102 rows, the tile-wide gather."""
+    from fast_tffm_tpu.optim import occurrences_permutation
+
+    assert occurrences_permutation(17, 1_398_101) == "row gather"
+    assert occurrences_permutation(17, 1_398_102) == "tile-wide row gather"
+    assert occurrences_permutation(127, 2_555_904) == "tile-wide row gather"
+    assert occurrences_permutation(128, 2_555_904) == "row gather"  # a whole tile already
+
+
+@pytest.mark.parametrize("form", ["tile-wide row gather", "sort operands"])
+def test_train_step_at_row_width_17_is_the_same_in_every_permutation(monkeypatch, sweep_form, form):
+    """A toy FM of row width 17 (k = 16) through the sweep, its gradients
+    brought to id order by the narrow row gather and by another form: three
+    steps, and the losses, table and accumulator are the same bits (the
+    permutation moves values and computes nothing)."""
+    from fast_tffm_tpu import optim
+
+    model = FMModel(vocabulary_size=100, factor_num=16, order=2)
+    batches = _batches()
+
+    def run(forced):
+        monkeypatch.setattr(optim, "occurrences_permutation", lambda *a: forced)
+        state, losses = tr.init_state(model, jax.random.key(0), 0.1, "element"), []
+        step = tr.make_train_step(model, 0.05)
+        for b in batches:
+            state, loss = step(state, b)
+            losses.append(float(loss))
+        return state, losses
+
+    s0, l0 = run("row gather")
+    s1, l1 = run(form)
+    assert sweep_form == [(100, 16 * 6, 17, 17)] * 2
+    assert l1 == l0
+    np.testing.assert_array_equal(np.asarray(s1.table).view(np.uint32), np.asarray(s0.table).view(np.uint32))
+    np.testing.assert_array_equal(
+        np.asarray(s1.table_opt.accum).view(np.uint32), np.asarray(s0.table_opt.accum).view(np.uint32)
+    )
 
 
 def test_remainder_tail_small_blocks():

@@ -10,7 +10,8 @@ no segments and sorts once; the rows' step is what it was), and of the
 SHARDED step at the four-chip cell's shapes (ISSUE 36: the shard's tail is
 the same sweep, under the same scopes).  Since ISSUE 40 the forward gather's
 kernel too (ops/pallas_gather.py), alone at both one-chip FM cells' shapes
-and inside ``fm8_criteo``'s step, under ``fm.gather``.
+and inside ``fm8_criteo``'s step, under ``fm.gather``.  And the tiered
+cell's inner step, whose gradients of 17 reach id order as tile-wide rows.
 
 All of it in this one file and behind a fixture: one process may hold the
 TPU's library, so only the worker that runs this file loads it.
@@ -143,6 +144,25 @@ def test_the_sweeps_step_sums_no_segments_and_sorts_once(one_chip, monkeypatch):
         assert sum(f"/{scope}/" in line for scope in ("fm.gather", "fm.dedup", "fm.tail")) <= 1, line
 
 
+def test_the_tiered_step_gathers_its_17_wide_gradients_as_tile_wide_rows(one_chip, monkeypatch):
+    """``fm16_criteo_tiered.train_fmb_tiered``'s inner step (the compact table
+    of 2^25 + 2^20 rows of 17, 65,536 x 39 ids): the tail is the sweep, and
+    under ``fm.dedup`` stand ONE sort (the ids with their positions) and the
+    gather of the gradients padded to ``f32[2555904,128]`` rows, held
+    row-major, in its order; no gather there makes ``f32[2555904,17]`` rows
+    (the narrow gather reads 17 single lanes a row)."""
+    from fast_tffm_tpu.models import FMModel
+
+    model = FMModel(vocabulary_size=2**25 + 2**20, factor_num=16, order=2)
+    text, ops, asked = _step_ops(one_chip, monkeypatch, model, 65536, 39)
+    assert asked == ["sweep"] and "tpu_custom_call" in text
+    assert ops["fm.dedup"]["sort"] == 1
+    gathers = [l for l in text.splitlines() if re.search(r"= \S+ gather\(", l)]
+    made = lambda l: l.split(" gather(")[0]
+    dedup = [l for l in gathers if "/fm.dedup/" in l]
+    assert dedup and all("f32[2555904,128]{1,0" in made(l) for l in dedup), dedup
+
+
 @pytest.mark.parametrize(
     "v, d, m",
     [(2**26, 9, 65536 * 39), (2**25, 31, 65536 * 11)],  # fm8_criteo.train_fmb; fm3_k30_kdd12.train_fmb_order3
@@ -198,11 +218,12 @@ def test_the_sharded_step_takes_the_sweep_on_the_ids_the_shard_owns(four_chips, 
     slots the tail keeps of all four chips' 2,555,904, and at those in the
     whole list's branch; each branch of the ONE conditional holds one kernel,
     in place: nothing but the kernels, and no copy, is on a
-    ``f32[33554432,17]`` shard; the bounded branch's permutation gather is
-    ``f32[1284384,17]`` under ``fm.dedup``; the global dedup is gone (one
-    segment sum, the local one, and its ``[638976,128]`` rows; none on
-    ``[2555904,128]``); and no instruction stands under both ``fm.tail`` and
-    ``fm.dedup`` (``harness/scopes.py`` would count it twice)."""
+    ``f32[33554432,17]`` shard; both branches' permutation gathers read the
+    gradients padded to ``f32[2555904,128]`` rows under ``fm.dedup``, the
+    bounded one ``f32[1284384,128]`` of them; the global dedup is gone (one
+    segment sum, the local one, and its ``[638976,128]`` rows); and no
+    instruction stands under both ``fm.tail`` and ``fm.dedup``
+    (``harness/scopes.py`` would count it twice)."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -238,6 +259,7 @@ def test_the_sharded_step_takes_the_sweep_on_the_ids_the_shard_owns(four_chips, 
     assert sorted(asked) == [((2**25, bound, 17, 17), "sweep"), ((2**25, 2555904, 17, 17), "sweep")]
     shard = re.compile(r"f32\[(33554432,17|17,33554432)\]")
     on_shard, both, tail_calls, segment_sums, bounded_gathers = set(), [], collections.Counter(), [], 0
+    branch_gathers, wide = [], []
     computation = None
     for line in text.splitlines():
         head = re.match(r"(ENTRY )?%([\w.\-]+) \(", line)
@@ -258,12 +280,27 @@ def test_the_sharded_step_takes_the_sweep_on_the_ids_the_shard_owns(four_chips, 
             on_shard.add(op.group(3))
         if op.group(3) == "scatter":
             segment_sums.append(op.group(2))
-        bounded_gathers += op.group(2).startswith(f"f32[{bound},17]") and "fm.dedup" in path and path[-1] == "gather"
+        bounded_gathers += op.group(2).startswith(f"f32[{bound},128]") and "fm.dedup" in path and path[-1] == "gather"
+        if op.group(3) == "gather" and "fm.dedup" in path and "cond" in path:
+            branch_gathers.append(op.group(2).split("{")[0])
+        if "2555904,128]" in op.group(2) and op.group(3) != "parameter":
+            wide.append((op.group(3), "/".join(path)))
     assert text.count(" conditional(") == 1 and sorted(tail_calls.values()) == [1, 1]  # one kernel a branch
     assert on_shard <= {"parameter", "bitcast", "custom-call", "get-tuple-element", "tuple", "conditional"}, on_shard
     assert bounded_gathers >= 1  # the fusion and the gather inside it
+    # Both branches gather the seventeen-wide gradients padded to tile-wide
+    # rows: the bounded one the rows it keeps, the whole list's all of them.
+    assert sorted(set(branch_gathers)) == [f"f32[{bound},128]", "f32[2555904,128]"], branch_gathers
     assert not both, both
-    assert "2555904,128]" not in text and len(segment_sums) == 1 and "638976,128]" in segment_sums[0], segment_sums
+    # One segment sum, the local one on [638976,128] rows.  Rows of
+    # [2555904,128] are made only in the branches, under fm.dedup, by the
+    # pad and the whole list's gather of the tile-wide rows (a fusion, and
+    # the gather, transpose and reshape inside it): no global dedup.
+    assert len(segment_sums) == 1 and "638976,128]" in segment_sums[0], segment_sums
+    made = {(opcode, where.split("/")[-1]) for opcode, where in wide}
+    assert wide and all(re.search(r"/cond/branch_[01]_fun/fm\.dedup/", where) for _, where in wide), wide
+    assert made <= {("pad", "pad"), ("fusion", "gather"), ("gather", "gather"), ("transpose", "gather"),
+                    ("reshape", "gather")}, made
 
 
 def test_the_anova_kernel_compiles_for_the_chip_at_the_order_3_cells_shape(one_chip):

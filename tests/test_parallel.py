@@ -755,7 +755,7 @@ def test_dist_train_says_the_form_its_shard_tail_took(tmp_path, monkeypatch, for
     assert set(asked) <= {(24, slots, 17, 17), (24, 4 * 16 * 8, 17, 17)}
     records = [json.loads(line) for line in (tmp_path / "metrics.jsonl").read_text().splitlines()]
     profile = next(r for r in records if r["kind"] == "profile" and r["program"] == "train_step")
-    want = optim.rows_tail_profile(24, slots, 17, form)
+    want = optim.rows_tail_profile(24, slots, 17, form, handed)
     assert want["tail_form"] == form and profile["row_dim"] == 17
     assert {k: profile[k] for k in want} == want and profile["tail_slots"] == slots
     trains = [r for r in records if r["kind"] == "train"]

@@ -382,7 +382,7 @@ def test_keep_cuts_the_sorted_list_and_nothing_else(d, what):
         np.testing.assert_array_equal(sk[:n], s[:n])
         assert np.all(np.asarray(uk[n:]) >= rows) and np.all(np.diff(np.asarray(uk)) > 0)
         return
-    assert optim.occurrences_permutation(d) == what
+    assert optim.occurrences_permutation(d, m) == what
     (s0, g0), (sk, gk) = optim.occurrences_by_id(ids, g, rows), optim.occurrences_by_id(ids, g, rows, keep)
     np.testing.assert_array_equal(sk, s0[:keep])
     np.testing.assert_array_equal(gk, g0[:, :keep])
