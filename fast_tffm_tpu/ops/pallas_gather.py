@@ -47,18 +47,18 @@ no MXU, compiles and reads 54.2.  What binds the kernel is its grid (0.6 us
 an item, 18,176 items) and the contraction (7.8 ms), not the table's 4.3 GB
 (more DMA streams a block changed nothing).
 
-STATUS ON THE CHIP (TPU v5 lite, jax 0.9.0, libtpu 0.0.34; each piece
-alone, medians of eight calls).  2^26 rows of 9 under 2,555,904 uniform ids:
-``table[ids]`` 56.9 ms; the sort with positions 5.4, this kernel with its
-work list 26.7, the nine columns back to batch order as operands of one
-sort 20.0: 52.1.  2^25 rows of 31 under 720,896 ids: 28.1 against 1.9 +
-20.0 + 8.5 = 30.4 with the rows gathered back through the inverse
-permutation, and 2^25 rows of 17 under 2,555,904: 78.7 against 5.5 + 26.7 +
-81.9; that way back is not in the tree, and ``trainer.gather_form`` keeps
-``table[ids]`` for rows over 9 floats.
+STATUS ON THE CHIP (TPU v5 lite, jax 0.9.0, libtpu 0.0.34; each piece alone,
+medians of eight calls).  2^26 x 9 under 2,555,904 uniform ids: ``table[ids]``
+56.9 ms; sort with positions 5.4, kernel and work list 26.7, nine columns back
+as operands of one sort 20.0: 52.1.  2^25 x 31 under 720,896: 28.1 against
+1.9 + 20.0 + 8.5 (rows gathered back by the inverse permutation); 2^25 x 17
+under 2,555,904: 78.7 against 5.5 + 26.7 + 81.9, so ``trainer.gather_form``
+keeps ``table[ids]`` past 9 floats.  A row SHARD's lookup has a tile-wide way
+back (parallel/embedding.sharded_gather): a [2^25, 17] shard owning 639,628 of
+2,555,904 slots reads them all masked in 105.7 ms; sort 4.9, the owned prefix
+swept 19.9, laid out as 128-lane rows 3.9, scattered to its slots 21.8: 45.3.
 tests/test_pallas_tail_chip_compile.py compiles the kernel for a described
-v5e at both shapes.  It runs under ``interpret=`` for CPU tier-1
-(ops.pallas_common resolves the flag).
+v5e at both shapes; under ``interpret=`` it runs on the CPU (pallas_common).
 """
 
 from __future__ import annotations

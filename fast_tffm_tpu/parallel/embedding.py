@@ -14,7 +14,12 @@ inside `shard_map`:
            `psum_scatter` over ROW_AXIS returns each requesting chip
            exactly its own rows — every parameter row crosses ICI once,
            and the heavy [*, N, D] float traffic rides the same
-           reduce-scatter that a dense sharded matmul would use.
+           reduce-scatter that a dense sharded matmul would use.  On a TPU
+           a shard with lane-major rows reads only its own share: the
+           slots sorted once, the prefix it owns swept from its
+           ``table.T`` and scattered back as tile-wide rows, under the
+           same bound as the tail's, with the masked gather of every slot
+           as the counted fallback (``sharded_gather``).
   update:  per-occurrence row gradients are deduped locally (sort +
            segment-sum, static shapes), all_gathered over BOTH axes
            (replacing Hogwild's racy async scatter with a deterministic
@@ -41,12 +46,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from fast_tffm_tpu.optim import AdagradState, dedup_rows, sparse_adagrad_update
+from fast_tffm_tpu.optim import _LANES, AdagradState, dedup_rows, sparse_adagrad_update
 from fast_tffm_tpu.parallel.exchange import exchange_scope
 from fast_tffm_tpu.parallel.mesh import DATA_AXIS, ROW_AXIS, axis_size
 
 __all__ = [
     "sharded_gather",
+    "shard_lookup_form",
     "sharded_sparse_adagrad_update",
     "apply_shard_adagrad",
     "packed_sharded_gather",
@@ -135,16 +141,134 @@ def apply_shard_adagrad(
     return table_shard, accum_shard, whole.astype(jnp.int32)
 
 
+# What a row shard's lookup costs in its two forms on a TPU v5e (PERF.md §6:
+# each piece alone, medians of eight calls, on a [2^25, 17] shard under the
+# 2,555,904 slots of fm16_criteo_row4's four chips, 639,628 of them its own).
+# The masked row gather of every slot reads a row whatever it holds: 105.7 ms
+# (87.6 inside the step), as ``trainer``'s row cost says of a row of 17.  The
+# sweep: the sort of (local id, slot) 4.9 ms, 1.9 ns a slot; the kernel with
+# its work list over the first 1,284,384 of them 19.9, the shard's padded
+# bytes at the rate the one-chip gather's sweep reaches plus 5 ns a kept id;
+# those columns laid out as 128-lane rows 3.9 and scattered to their slots
+# 21.8, 20 ns a kept id more: 45.3 in one program.
+_LOOKUP_SLOT_NS = 1.9
+_LOOKUP_KEPT_NS = 25.0
+
+
+def shard_lookup_form(
+    shard_rows: int, m: int, d: int, bound: int | None, backend: str | None = None
+) -> str:
+    """Which form ``sharded_gather`` takes on a row shard of ``shard_rows``
+    rows of ``d`` handed ``m`` slots by its row peers, of which it may own
+    ``bound`` before the step reads the whole list (``None``: no bound).  A
+    trace-time function of the shapes and the backend, as
+    ``trainer.gather_form`` is the one-chip gather's: ``"sweep"`` (the kept
+    prefix of the sorted slots swept from the shard's ``table.T``, the rows
+    brought back tile-wide) or ``"rows"`` (the masked row gather of every
+    slot).
+
+      * only a TPU takes the sweep (anywhere else the kernel would run
+        interpreted inside every step);
+      * only rows under one 128-lane tile: those are held lane-major, the
+        transposed view the kernel takes is a bitcast, and a row read is
+        one single-lane access a float;
+      * only where ``bound`` cuts the list (one row shard, or a factor that
+        bounds nothing, leaves every slot to read) and the sweep's work list
+        over the kept prefix fits the scalar memory
+        (ops.pallas_tail.sweep_fits);
+      * only where the shard's bytes once, plus what a slot and a kept id
+        cost on their way through the sort, the kernel and back, take less
+        time than the row reads of every slot.  ``fm16_criteo_row4`` (2^25
+        rows of 17 a shard, 2,555,904 slots, 1,284,384 kept): 50 ms against
+        78, the sweep; the same shard under a few thousand slots: 13 ms
+        against a tenth of one, the rows.
+    Callers ask it for the rows layout alone; the packed layouts keep their
+    own gathers."""
+    if bound is None or bound >= m or d >= _LANES:
+        return "rows"
+    if (backend or jax.default_backend()) != "tpu":
+        return "rows"
+    from fast_tffm_tpu.ops.pallas_tail import sweep_fits
+    from fast_tffm_tpu.trainer import _GATHER_SWEEP_BYTES_PER_S, _ROW_NS, _ROW_TILE_NS
+
+    if not sweep_fits(shard_rows, d, bound):
+        return "rows"
+    tiles = -(-d // 8)
+    sweep_s = (
+        shard_rows * 4 * 8 * tiles / _GATHER_SWEEP_BYTES_PER_S
+        + (m * _LOOKUP_SLOT_NS + bound * _LOOKUP_KEPT_NS) * 1e-9
+    )
+    return "sweep" if sweep_s < m * (_ROW_NS + _ROW_TILE_NS * tiles) * 1e-9 else "rows"
+
+
+def _swept_rows(table_shard: jax.Array, local: jax.Array, bound: int) -> jax.Array:
+    """``table_shard[local]`` with every slot at or past ``shard_rows`` (the
+    sentinel of the slots the shard does not own) read as zeros, where at
+    most ``bound`` slots are under it: ONE unstable sort of (local id, slot)
+    (the slots are unique), the first ``bound`` ids swept from the shard's
+    transposed view (ops.pallas_gather.sweep_gather: ``[D, bound]`` in id
+    order; what it writes in the columns of kept sentinels is never read),
+    those columns laid out as row-major ``[bound, 128]`` rows and scattered
+    to their slots in a zeroed ``[M, 128]`` buffer (the kept sentinels to
+    distinct places past its end, where they drop), then cut to ``D``."""
+    from fast_tffm_tpu.ops.pallas_gather import sweep_gather
+
+    shard_rows, d = table_shard.shape
+    flat = local.reshape(-1)
+    m = flat.shape[0]
+    sid, slot = lax.sort(
+        (flat, jnp.arange(m, dtype=jnp.int32)), num_keys=1, is_stable=False
+    )
+    sid, slot = sid[:bound], slot[:bound]
+    cols = sweep_gather(table_shard, sid)  # [D, bound]
+    wide = jnp.pad(cols, ((0, _LANES - d), (0, 0))).T  # [bound, 128] rows
+    to = jnp.where(sid < shard_rows, slot, m + jnp.arange(bound, dtype=jnp.int32))
+    rows = jnp.zeros((m, _LANES), wide.dtype).at[to].set(
+        wide, mode="drop", unique_indices=True
+    )
+    return rows[:, :d].reshape(*local.shape, d)
+
+
 @jax.named_scope("fm.gather")
-def sharded_gather(table_shard: jax.Array, ids: jax.Array) -> jax.Array:
+def sharded_gather(
+    table_shard: jax.Array, ids: jax.Array, bound: int | None = None,
+    form: str | None = None,
+):
     """Assemble this chip's parameter rows from the row-sharded table.
 
     table_shard: [V/R, D] this shard's contiguous rows.
     ids:         [B_local, N] global row ids for THIS chip's micro-batch
                  (batch is sharded over data AND row axes).
-    Returns:     [B_local, N, D] rows for this chip's ids.
+    bound:       (static; ``train_step.shard_lookup_ids``) how many of the
+                 ``R * B_local * N`` slots the row peers hand this shard it
+                 may own before the lookup reads them all.
+    form:        ``shard_lookup_form``'s answer, asked at these shapes where
+                 ``None`` (a test names one to run it on the CPU).
+    Returns:     ``(rows, took_whole)``: ``[B_local, N, D]`` rows for this
+                 chip's ids, and an int32 scalar, 1 where this shard owned
+                 more than ``bound`` of the slots and read the whole list,
+                 ``None`` where no ``cond`` was traced.
+
+    Every row peer's ids are all-gathered, the shard serves the rows it owns
+    (zeros for the others' slots) and a ``psum_scatter`` returns each peer its
+    answers.  The rows it serves come in one of two forms:
+
+      * ``rows``: every slot read from the shard, the ones it does not own
+        at local row 0, multiplied by zero afterwards (a masked row gather of
+        all ``R * B_local * N`` slots);
+      * ``sweep``: where the shard owns at most ``bound`` slots (the shard
+        tail's own bound, ``lookup_capacity_factor`` over the uniform share),
+        the sorted prefix of them alone is read, by one sweep of the shard's
+        ``table.T``, and the rows reach slot order as tile-wide rows
+        scattered into zeros (``_swept_rows``); where it owns more, under
+        ``lax.cond``, the masked row gather of the whole list.  A skewed
+        shard is slower, never wrong, and the branches hold no collective,
+        so each shard decides for itself.  The rows are the masked gather's bit for bit but for
+        the sweep kernel's limits (ops.pallas_gather): the slots a shard
+        does not own read +0.0 where the masked gather reads row 0 times
+        zero, and the sum the ``psum_scatter`` makes is the same.
     """
-    shard_rows = table_shard.shape[0]
+    shard_rows, d = table_shard.shape
     if axis_size(ROW_AXIS) == 1:
         # One row shard: every id is local and the gather/scatter
         # collectives are identities — skip them (axis_size is static, so
@@ -158,19 +282,32 @@ def sharded_gather(table_shard: jax.Array, ids: jax.Array) -> jax.Array:
         # skipped.
         in_range = (ids >= 0) & (ids < shard_rows)
         rows = table_shard[jnp.where(in_range, ids, 0)]
-        return rows * in_range[..., None].astype(rows.dtype)
-    base = lax.axis_index(ROW_AXIS) * shard_rows
+        return rows * in_range[..., None].astype(rows.dtype), None
     # Ids are int32 and tiny next to D-wide rows; gather all ROW peers' ids,
     # serve the rows we own, and reduce-scatter each peer its answers (each
     # row is owned by exactly one shard, so the sum IS the row).
     with exchange_scope():
         all_ids = lax.all_gather(ids, ROW_AXIS, tiled=True)  # [R*B_local, N]
-    local = all_ids - base
-    owned = (local >= 0) & (local < shard_rows)
-    local = jnp.where(owned, local, 0)
-    rows = table_shard[local] * owned[..., None].astype(table_shard.dtype)
+    if form is None:
+        form = shard_lookup_form(shard_rows, all_ids.size, d, bound)
+    local, owned = owned_local_ids(all_ids, shard_rows, sentinel=0)
+
+    def row_gather():
+        return table_shard[local] * owned[..., None].astype(table_shard.dtype)
+
+    took_whole = None
+    if form == "sweep":
+        whole = jnp.sum(owned, dtype=jnp.int32) > bound
+        rows = lax.cond(
+            whole, row_gather,
+            lambda: _swept_rows(table_shard, jnp.where(owned, local, shard_rows), bound),
+        )
+        took_whole = whole.astype(jnp.int32)
+    else:
+        rows = row_gather()
     with exchange_scope():
-        return lax.psum_scatter(rows, ROW_AXIS, scatter_dimension=0, tiled=True)
+        rows = lax.psum_scatter(rows, ROW_AXIS, scatter_dimension=0, tiled=True)
+    return rows, took_whole
 
 
 def sharded_sparse_adagrad_update(

@@ -30,7 +30,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fast_tffm_tpu.models.base import Batch
 from fast_tffm_tpu.optim import AdagradState, dense_adagrad_update
-from fast_tffm_tpu.parallel.embedding import sharded_gather, sharded_sparse_adagrad_update
+from fast_tffm_tpu.parallel.embedding import (
+    shard_lookup_form,
+    sharded_gather,
+    sharded_sparse_adagrad_update,
+)
 from fast_tffm_tpu.parallel.exchange import exchange_scope
 from fast_tffm_tpu.parallel.mesh import (
     DATA_AXIS,
@@ -49,6 +53,7 @@ __all__ = [
     "unpack_sharded_on_device",
     "make_sharded_train_step",
     "shard_tail_ids",
+    "shard_lookup_ids",
     "make_sharded_predict_step",
     "make_global_batch",
     "make_global_superbatch",
@@ -542,7 +547,7 @@ def _make_gather(
             d_row, slr = packed_meta
             g = fused_sharded_gather if fused else packed_sharded_gather
             return (lambda table, ids: g(table, ids, d_row, slr)), None, False
-        return sharded_gather, None, False
+        return (lambda table, ids: sharded_gather(table, ids)[0]), None, False
     if lookup != "alltoall":
         raise ValueError(f"unknown lookup {lookup!r} (allgather | alltoall)")
     from fast_tffm_tpu.parallel.alltoall import capacity_for, routed_gather
@@ -560,6 +565,19 @@ def _make_gather(
     return (lambda table, ids: routed_gather(table, ids, cap)), cap, cap < m
 
 
+def shard_lookup_ids(mesh: Mesh, ids_per_chip: int, capacity_factor: float) -> int:
+    """How many of the slots its row peers hand it a row shard's lookup
+    (``embedding.sharded_gather``) may own before it reads the whole list,
+    where a chip's micro-batch holds ``ids_per_chip`` ids: from each row peer
+    the ``capacity`` slots one row peer may send another
+    (``alltoall.capacity_for``), the bound ``shard_tail_ids`` takes from every
+    chip.  The same config key, ``lookup_capacity_factor``, and never more
+    than the ``row * ids_per_chip`` slots the lookup is handed."""
+    from fast_tffm_tpu.parallel.alltoall import capacity_for
+
+    return mesh.shape[ROW_AXIS] * capacity_for(ids_per_chip, mesh.shape[ROW_AXIS], capacity_factor)
+
+
 def shard_tail_ids(mesh: Mesh, ids_per_chip: int, capacity_factor: float) -> int:
     """How many (id, gradient) slots the rows layout's shard tail
     (``embedding.apply_shard_adagrad``) takes a step, where a chip's
@@ -575,10 +593,7 @@ def shard_tail_ids(mesh: Mesh, ids_per_chip: int, capacity_factor: float) -> int
     shard, or a factor of ``row`` and up, bound nothing).  It is the ``m``
     ``optim.rows_tail_form`` sees at the shard, for whoever says the tail's
     form aloud (training.dist_train)."""
-    from fast_tffm_tpu.parallel.alltoall import capacity_for
-
-    slots = capacity_for(ids_per_chip, mesh.shape[ROW_AXIS], capacity_factor)
-    return mesh.shape[DATA_AXIS] * mesh.shape[ROW_AXIS] * slots
+    return mesh.shape[DATA_AXIS] * shard_lookup_ids(mesh, ids_per_chip, capacity_factor)
 
 
 def make_sharded_train_step(
@@ -587,7 +602,7 @@ def make_sharded_train_step(
     table_layout: str = "rows", packed_update: str = "auto",
     accumulator: str = "element", compact_cap: int = 0,
     steps_per_call: int = 1, adagrad_decay: float = 1.0,
-    count_full_tails: bool = False,
+    count_full_tails: bool = False, count_full_lookups: bool = False,
 ):
     """Returns jitted SPMD ``step(state, batch) -> (state, global mean loss)``.
 
@@ -631,6 +646,15 @@ def make_sharded_train_step(
     the row shards that took the whole list this step (0 where the shapes
     make that impossible; summed over the K steps of a fused call), for the
     driver to count (``shard_tail_full_steps``).
+
+    The rows layout's lookup (``embedding.sharded_gather``) is bounded the
+    same way where ``embedding.shard_lookup_form`` says ``sweep``: a shard
+    reads the first ``shard_lookup_ids`` slots of the sorted list its row
+    peers hand it, and the whole list where it owns more.
+    ``count_full_lookups`` appends after the tail's count another replicated
+    int32: the chips whose lookup read the whole list this step (a chip's
+    lookup serves its own row peers, so data replicas count apart; 0 where
+    the form is the masked row gather), ``shard_lookup_full_steps``.
 
     Note the defaults differ by layer on purpose: the CONFIG default
     (``lookup_overflow = fallback``, what the train/predict drivers pass)
@@ -696,10 +720,17 @@ def make_sharded_train_step(
         # update's slots; whether that is fewer than it is handed is a
         # trace-time fact, as ``can_overflow`` is.
         tail_bound, may_fill = None, False
+        lookup_bound, lookup_form = None, "rows"
         if not packed and (lookup == "allgather" or (fallback and can_overflow)):
             ids_per_chip = batch.ids.shape[0] * batch.ids.shape[1]
             tail_bound = shard_tail_ids(mesh, ids_per_chip, capacity_factor)
             may_fill = tail_bound < mesh.size * ids_per_chip
+            if mesh.shape[ROW_AXIS] > 1:
+                # So is the lookup's form, from the shard's shapes.
+                lookup_bound = shard_lookup_ids(mesh, ids_per_chip, capacity_factor)
+                lookup_form = shard_lookup_form(
+                    table.shape[0], mesh.shape[ROW_AXIS] * ids_per_chip, d_row, lookup_bound
+                )
 
         def allgather_branch():
             if fused:
@@ -719,7 +750,7 @@ def make_sharded_train_step(
                         table, batch.ids, g_rows, learning_rate,
                         shard_logical_rows, mode=fmode, k_cap=compact_cap,
                     )
-                return t2, accum, g_dense, dl, no_flag
+                return t2, accum, g_dense, dl, no_flag, no_flag
             if packed:
                 from fast_tffm_tpu.ops.packed_table import resolve_packed_update
                 from fast_tffm_tpu.parallel.embedding import (
@@ -746,14 +777,19 @@ def make_sharded_train_step(
                             table, accum, batch.ids, g_rows, learning_rate,
                             num_rows_global, shard_logical_rows,
                         )
-                return t2, a2, g_dense, dl, no_flag
-            rows = sharded_gather(table, batch.ids)
+                return t2, a2, g_dense, dl, no_flag, no_flag
+            rows, read_whole = sharded_gather(
+                table, batch.ids, bound=lookup_bound, form=lookup_form
+            )
             (_, dl), (g_rows, g_dense) = grad_fn(rows, dense)
             t2, a2, whole = sharded_sparse_adagrad_update(
                 table, accum, batch.ids, g_rows, learning_rate,
                 num_rows_global, decay=decay, bound=tail_bound,
             )
-            return t2, a2, g_dense, dl, whole if may_fill else no_flag
+            return (
+                t2, a2, g_dense, dl, whole if may_fill else no_flag,
+                no_flag if read_whole is None else read_whole,
+            )
 
         if lookup == "alltoall":
             from fast_tffm_tpu.parallel.alltoall import routed_update, routing_overflow
@@ -792,7 +828,7 @@ def make_sharded_train_step(
                     # NaN the loss so the training loop aborts before
                     # checkpointing.
                     dl = jnp.where(overflow, jnp.nan, dl)
-                return t2, a2, g_dense, dl, no_flag
+                return t2, a2, g_dense, dl, no_flag, no_flag
 
             # When overflow is statically impossible, emit the routed branch
             # alone — no bincount, no dual compile (HLO-pinned by
@@ -802,14 +838,14 @@ def make_sharded_train_step(
                 # for packed shards the table's leading dim is PHYSICAL, so
                 # the closure's logical count is the correct one either way.
                 overflowed = routing_overflow(batch.ids, shard_logical_rows, cap)
-                table, accum, g_dense, data_loss_local, whole = lax.cond(
+                table, accum, g_dense, data_loss_local, whole, read_whole = lax.cond(
                     overflowed, allgather_branch, routed_branch
                 )
             else:
-                table, accum, g_dense, data_loss_local, whole = routed_branch()
+                table, accum, g_dense, data_loss_local, whole, read_whole = routed_branch()
                 overflowed = jnp.asarray(False)
         else:
-            table, accum, g_dense, data_loss_local, whole = allgather_branch()
+            table, accum, g_dense, data_loss_local, whole, read_whole = allgather_branch()
             overflowed = jnp.asarray(False)
         if jax.tree.leaves(dense):
             with exchange_scope("fm.tail"):
@@ -819,15 +855,22 @@ def make_sharded_train_step(
                 decay=decay,
             )
             dense_acc = dense_acc.accum
+        # The flags ride the loss's all-reduce.  A row shard's data replicas
+        # are handed the same slots by the update and decide alike; each
+        # chip's lookup serves its own row peers.
+        counted = {}
+        if count_full_tails and may_fill:
+            counted["tail"] = whole
+        if count_full_lookups and lookup_form == "sweep":
+            counted["lookup"] = read_whole
         with exchange_scope("fm.loss"):
-            if count_full_tails and may_fill:
-                # The flag rides the loss's all-reduce; a row shard's data
-                # replicas are handed the same slots and decide alike.
-                data_loss, whole = lax.psum((data_loss_local, whole), _BOTH)
-                whole = whole // mesh.shape[DATA_AXIS]
-            else:
-                data_loss, whole = lax.psum(data_loss_local, _BOTH), no_flag
-        return table, accum, dense, dense_acc, data_loss, overflowed.astype(jnp.int32), whole
+            data_loss, counted = lax.psum((data_loss_local, counted), _BOTH)
+        whole = counted["tail"] // mesh.shape[DATA_AXIS] if "tail" in counted else no_flag
+        read_whole = counted.get("lookup", no_flag)
+        return (
+            table, accum, dense, dense_acc, data_loss, overflowed.astype(jnp.int32),
+            whole, read_whole,
+        )
 
     dense_spec = jax.tree.map(lambda _: P(), model.init_dense(jax.random.key(0)))
     mapped = shard_map(
@@ -841,20 +884,23 @@ def make_sharded_train_step(
             _batch_specs(),
         ),
         out_specs=(
-            P(ROW_AXIS, None), P(ROW_AXIS, None), dense_spec, dense_spec, P(), P(), P(),
+            P(ROW_AXIS, None), P(ROW_AXIS, None), dense_spec, dense_spec, P(), P(), P(), P(),
         ),
         check_vma=False,
     )
 
     def _apply(state: TrainState, batch: Batch):
-        table, accum, dense, dense_acc, loss, overflowed, whole = mapped(
+        table, accum, dense, dense_acc, loss, overflowed, whole, read_whole = mapped(
             state.table, state.table_opt.accum, state.dense, state.dense_opt.accum, batch
         )
         new = TrainState(
             table, AdagradState(accum), dense, AdagradState(dense_acc), state.step + 1
         )
         # The counters the caller asked for, in the order the return names them.
-        return new, loss, (overflowed,) * fallback + (whole,) * count_full_tails
+        return new, loss, (
+            (overflowed,) * fallback + (whole,) * count_full_tails
+            + (read_whole,) * count_full_lookups
+        )
 
     if steps_per_call <= 1:
 

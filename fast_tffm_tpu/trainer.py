@@ -116,13 +116,13 @@ def init_state(
 # streams 560; its grid and its contraction bind it, not the HBM), and an id
 # of a row of 9 pays 13.5 ns: 2.1 for its share of the sort with positions,
 # 3.6 for the work list and its chunk's grid step, 7.8 on the way back to
-# batch order as one of the ten operands of a sort.  That last cost is the
-# sort's, and a sort's grows faster than its operands (18 of them read 44.7
-# ms where ten read 20.0, PR 32); gathered row by row instead, the way back
-# lost at both shapes that were read (2^25 x 31: 30.3 ms in all against the
-# row gather's 28.1; 2^25 x 17 under 2,555,904 ids: 114 against 78.7).  So
-# the rule holds for rows up to ``_SWEEP_MAX_D`` floats, the widest whose way
-# back the chip has read, and says ``rows`` past it.
+# batch order as one of the ten operands of a sort: the sort's cost, which
+# grows faster than its operands (18 read 44.7 ms where ten read 20.0, PR
+# 32); gathered row by row instead, the way back lost at both shapes read
+# (2^25 x 31: 30.3 ms against 28.1; 2^25 x 17, 2,555,904 ids: 114 against
+# 78.7).  So the rule says ``rows`` past ``_SWEEP_MAX_D`` floats, the widest
+# way back read.  A row shard's lookup has a tile-wide way back of its own
+# (parallel/embedding.shard_lookup_form; it wins at 17 floats there).
 _ROW_NS = 5.6
 _ROW_TILE_NS = 8.35
 _GATHER_SWEEP_BYTES_PER_S = 240e9

@@ -639,7 +639,8 @@ def _run_training(
     ``extra_metrics()`` (optional) is drained at every log point and its
     dict merged into the stdout line and the JSONL record (dist_train uses
     it to report alltoall overflow-fallback step counts and the row shards
-    whose tail took the whole exchanged list, ``shard_tail_full_steps``).  ``saveable``
+    whose tail took the whole exchanged list, ``shard_tail_full_steps``, and
+    the chips whose lookup read the whole list, ``shard_lookup_full_steps``).  ``saveable``
     (optional) converts the live state to its checkpoint form before
     every save — the packed table layout uses it to store LOGICAL [V, D]
     arrays, keeping packed and rows checkpoints interchangeable.
@@ -652,7 +653,10 @@ def _run_training(
     duplicates are summed, ``tail_duplicates`` ``kernel`` | ``segment_sum``,
     the latter on rows ``segment_sum_lanes`` wide; ``tail_permutation``; the
     sweep's ``tail_block_lanes``; dist_train's ``tail_slots``, the exchanged
-    slots a row shard's tail takes, ``parallel.train_step.shard_tail_ids``;
+    slots a row shard's tail takes, ``parallel.train_step.shard_tail_ids``,
+    and its lookup's ``lookup_form`` ``sweep`` | ``rows``
+    (``parallel.embedding.shard_lookup_form``) with ``lookup_slots``, the
+    slots the shard's local gather reads a step;
     empty on every other layout; and the forward gather's,
     ``trainer.gather_profile``: ``gather_form`` ``sweep`` | ``rows`` and the
     sweep kernel's ``gather_items``, its grid length a step) rides the
@@ -831,6 +835,7 @@ def _run_training(
                 "tail_duplicates": None, "tail_permutation": None,
                 "tail_block_lanes": None, "tail_slots": None,
                 "gather_form": None, "gather_items": None,
+                "lookup_form": None, "lookup_slots": None,
                 **(tail_profile or {}),
             },
             **(exchange_profile or {}),
@@ -2020,6 +2025,7 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
         steps_per_call=step_k,
         adagrad_decay=cfg.online_adagrad_decay,
         count_full_tails=cfg.table_layout == "rows",
+        count_full_lookups=cfg.table_layout == "rows",
     )
     predict_step = make_sharded_predict_step(
         model, mesh, lookup=cfg.lookup, capacity_factor=cfg.lookup_capacity_factor,
@@ -2045,7 +2051,9 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
             rows_tail_form,
             rows_tail_profile,
         )
-        from fast_tffm_tpu.parallel.train_step import shard_tail_ids
+        from fast_tffm_tpu.parallel.embedding import shard_lookup_form
+        from fast_tffm_tpu.parallel.mesh import ROW_AXIS
+        from fast_tffm_tpu.parallel.train_step import shard_lookup_ids, shard_tail_ids
 
         shard_rows = exchange_profile["shard_rows"]
         ids_per_chip = cfg.batch_size // mesh.size * max_nnz
@@ -2065,6 +2073,24 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
             + describe_rows_tail(shard_rows, m_ids, model.row_dim, tail_form, handed)
             + f"; the shard's first {m_ids} of {handed} exchanged slots"
         )
+        if cfg.lookup == "allgather":
+            # So is the lookup's (embedding.sharded_gather at the shard's
+            # shapes), and the slots its local gather reads a step.
+            slots = mesh.shape[ROW_AXIS] * ids_per_chip
+            bound = shard_lookup_ids(mesh, ids_per_chip, cfg.lookup_capacity_factor)
+            lookup_form = "rows"
+            if mesh.shape[ROW_AXIS] > 1:
+                lookup_form = shard_lookup_form(shard_rows, slots, model.row_dim, bound)
+            tail_profile.update(
+                lookup_form=lookup_form, lookup_slots=bound if lookup_form == "sweep" else slots
+            )
+            log("shard lookup: " + (
+                f"pallas sweep of the shard's table.T over the first {bound} of {slots} "
+                f"sorted slots, rows back tile-wide (row width {model.row_dim}); the "
+                "whole list where a shard owns more"
+                if lookup_form == "sweep" else
+                f"xla masked row gather ({slots} slots of {model.row_dim} a step)"
+            ))
     dist_saveable = None
     if cfg.table_layout == "packed":
         # Checkpoints hold LOGICAL [V, D] arrays.  Multi-process: unpack
@@ -2161,10 +2187,11 @@ def dist_train(cfg: Config, *, resume: bool = False, log=print, mesh=None, step_
 
     # The counters the step returns after its loss, in its order: the routed
     # lookup's fallback steps, and (rows layout) the row shards whose tail
-    # took the whole exchanged list and not its bounded prefix.
+    # took the whole exchanged list and not its bounded prefix, and the
+    # chips whose lookup read the whole list its row peers handed it.
     counted = ["lookup_overflow_steps"] * (
         cfg.lookup == "alltoall" and cfg.lookup_overflow == "fallback"
-    ) + ["shard_tail_full_steps"] * (cfg.table_layout == "rows")
+    ) + ["shard_tail_full_steps", "shard_lookup_full_steps"] * (cfg.table_layout == "rows")
     extra_metrics = None
     if counted:
         # Fold each into ONE running device scalar (no host sync, no per-step

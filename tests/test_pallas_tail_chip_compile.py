@@ -210,7 +210,60 @@ def test_the_rows_step_keeps_its_dedup_and_its_row_operations(one_chip, monkeypa
     assert tail == {"sort": 0, "gather": 1, "scatter": 2}
 
 
-def test_the_sharded_step_takes_the_sweep_on_the_ids_the_shard_owns(four_chips, monkeypatch):
+_SHARDED = {}  # lookup form -> the compiled four-chip step: one compile a form a process
+
+
+def _sharded_step(four_chips, lookup_form):
+    """(compiled text, its ``memory_analysis()``, the tail rule's questions
+    and answers) of ``fm16_criteo_row4.dist_train_fmb``'s step (2^27 rows of 17
+    over ``{data: 1, row: 4}``, 65,536 x 39 ids, allgather lookup) compiled
+    for the described ``v5e:2x2``, its tail's form asked as a TPU's rule
+    answers and the kernels compiled, not interpreted.  ``lookup_form``
+    ``rows``: the lookup's rule left to the CPU it runs on, which says the
+    masked row gather (the program before the lookup had another form);
+    ``sweep``: the rule asked as a TPU's."""
+    if lookup_form in _SHARDED:
+        return _SHARDED[lookup_form]
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from fast_tffm_tpu import optim
+    from fast_tffm_tpu.models import FMModel
+    from fast_tffm_tpu.models.base import Batch
+    from fast_tffm_tpu.ops import pallas_gather, pallas_tail
+    from fast_tffm_tpu.parallel import make_sharded_train_step
+    from fast_tffm_tpu.parallel import train_step as ts
+    from fast_tffm_tpu.trainer import init_state
+
+    mesh = Mesh(np.array(four_chips).reshape(1, 4), ("data", "row"))
+    rule, asked = optim.rows_tail_form, []
+    lookup_rule = ts.shard_lookup_form
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(optim, "rows_tail_form", lambda *a, backend=None: asked.append((a, rule(*a, backend="tpu"))) or asked[-1][1])
+        mp.setattr(pallas_tail, "resolve_interpret", lambda interpret: False)
+        if lookup_form == "sweep":
+            mp.setattr(ts, "shard_lookup_form", lambda *a, backend=None: lookup_rule(*a, backend="tpu"))
+            mp.setattr(pallas_gather, "resolve_interpret", lambda interpret: False)
+        model, b, n = FMModel(vocabulary_size=2**27, factor_num=16, order=2), 65536, 39
+        ns = lambda spec: NamedSharding(mesh, spec)
+        sd = lambda x, spec: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=ns(spec))
+        state = jax.eval_shape(lambda: init_state(model, jax.random.key(0), 0.1, "element"))
+        state = state._replace(
+            table=sd(state.table, P("row", None)), table_opt=type(state.table_opt)(sd(state.table_opt.accum, P("row", None))),
+            step=sd(state.step, P()),
+        )
+        batch = Batch(
+            labels=jax.ShapeDtypeStruct((b,), jnp.float32), ids=jax.ShapeDtypeStruct((b, n), jnp.int32),
+            vals=jax.ShapeDtypeStruct((b, n), jnp.float32), fields=jax.ShapeDtypeStruct((b, 0), jnp.int32),
+            weights=jax.ShapeDtypeStruct((b,), jnp.float32),
+        )
+        batch = jax.tree.map(sd, batch, ts._batch_specs())
+        compiled = make_sharded_train_step(model, 0.05, mesh).lower(state, batch).compile()
+    _SHARDED[lookup_form] = compiled.as_text(), compiled.memory_analysis(), asked
+    return _SHARDED[lookup_form]
+
+
+def test_the_sharded_step_takes_the_sweep_on_the_ids_the_shard_owns(four_chips):
     """``fm16_criteo_row4.dist_train_fmb``'s step (2^27 rows of 17 over
     ``{data: 1, row: 4}``, 65,536 x 39 ids, allgather lookup) compiled for the
     described ``v5e:2x2``: the shard's tail is the Pallas sweep under
@@ -225,35 +278,14 @@ def test_the_sharded_step_takes_the_sweep_on_the_ids_the_shard_owns(four_chips, 
     instruction stands under both ``fm.tail`` and ``fm.dedup``
     (``harness/scopes.py`` would count it twice)."""
     import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import Mesh
 
-    from fast_tffm_tpu import optim
-    from fast_tffm_tpu.models import FMModel
-    from fast_tffm_tpu.models.base import Batch
-    from fast_tffm_tpu.ops import pallas_tail
-    from fast_tffm_tpu.parallel import make_sharded_train_step
-    from fast_tffm_tpu.parallel.train_step import _batch_specs, shard_tail_ids
-    from fast_tffm_tpu.trainer import init_state
+    from fast_tffm_tpu.parallel.train_step import shard_tail_ids
 
+    text, memory, asked = _sharded_step(four_chips, "rows")
+    print(f"temporaries a chip with the masked row gather: {memory.temp_size_in_bytes} bytes")
     mesh = Mesh(np.array(four_chips).reshape(1, 4), ("data", "row"))
-    rule, asked = optim.rows_tail_form, []
-    monkeypatch.setattr(optim, "rows_tail_form", lambda *a, backend=None: asked.append((a, rule(*a, backend="tpu"))) or asked[-1][1])
-    monkeypatch.setattr(pallas_tail, "resolve_interpret", lambda interpret: False)
-    model, b, n = FMModel(vocabulary_size=2**27, factor_num=16, order=2), 65536, 39
-    ns = lambda spec: NamedSharding(mesh, spec)
-    sd = lambda x, spec: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=ns(spec))
-    state = jax.eval_shape(lambda: init_state(model, jax.random.key(0), 0.1, "element"))
-    state = state._replace(
-        table=sd(state.table, P("row", None)), table_opt=type(state.table_opt)(sd(state.table_opt.accum, P("row", None))),
-        step=sd(state.step, P()),
-    )
-    batch = Batch(
-        labels=jax.ShapeDtypeStruct((b,), jnp.float32), ids=jax.ShapeDtypeStruct((b, n), jnp.int32),
-        vals=jax.ShapeDtypeStruct((b, n), jnp.float32), fields=jax.ShapeDtypeStruct((b, 0), jnp.int32),
-        weights=jax.ShapeDtypeStruct((b,), jnp.float32),
-    )
-    batch = jax.tree.map(sd, batch, _batch_specs())
-    text = make_sharded_train_step(model, 0.05, mesh).lower(state, batch).compile().as_text()
+    b, n = 65536, 39
     bound = shard_tail_ids(mesh, b // 4 * n, 2.0)
     assert bound == 1284384
     assert sorted(asked) == [((2**25, bound, 17, 17), "sweep"), ((2**25, 2555904, 17, 17), "sweep")]

@@ -703,6 +703,11 @@ def test_dist_train_records_what_its_exchange_moves(tmp_path, steps_per_call):
         {"data": 1, "row": 4}, 24, "allgather", by_hand)
     assert "tail_form" in profile and "row_dim" in profile  # beside the tail's fields
     assert any(str(s).startswith("exchange: allgather lookup, 24 rows a shard") and f"{by_hand} bytes" in str(s) for s in said)
+    # The lookup's form off a TPU: the masked row gather of the 4 x 16 x 8
+    # slots the row peers hand a shard, and no chip counted as reading them all.
+    assert (profile["lookup_form"], profile["lookup_slots"]) == ("rows", 512)
+    assert "shard lookup: xla masked row gather (512 slots of 5 a step)" in said
+    assert all(r["shard_lookup_full_steps"] == 0 for r in trains)
 
 
 # --- PR 36: the shard's tail is the single-device step's, and says which form it took --

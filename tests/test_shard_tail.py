@@ -386,3 +386,143 @@ def test_keep_cuts_the_sorted_list_and_nothing_else(d, what):
     (s0, g0), (sk, gk) = optim.occurrences_by_id(ids, g, rows), optim.occurrences_by_id(ids, g, rows, keep)
     np.testing.assert_array_equal(sk, s0[:keep])
     np.testing.assert_array_equal(gk, g0[:, :keep])
+
+
+# --- the lookup reads the shard's own share of the slots ------------------
+#
+# ``embedding.sharded_gather`` in its ``sweep`` form sorts the slots its row
+# peers hand it once, sweeps the kept prefix from the shard's ``table.T`` (the
+# kernel interpreted here) and brings the rows back through a zero row; where
+# a shard owns more than its bound it reads the whole list as the masked row
+# gather does.  ``shard_lookup_form`` says ``rows`` on the CPU, so the tests
+# name the form.
+
+V_LOOK, B_LOOK, N_LOOK = 4 * 3000, 64, 10  # 3,000 rows a shard: a block cut short by the end
+
+
+def _lookup(mesh, table, ids, form, bound):
+    """(rows every chip gets back, [row] the flag each shard returned)."""
+    from fast_tffm_tpu.parallel.embedding import sharded_gather
+
+    def body(t, i):
+        rows, whole = sharded_gather(t, i, bound=bound, form=form)
+        return rows, jnp.reshape(jnp.zeros((), jnp.int32) if whole is None else whole, (1,))
+
+    both = P(("data", "row"))
+    fn = shard_map(body, mesh=mesh, in_specs=(P("row", None), both), out_specs=(both, P(("data", "row"))), check_vma=False)
+    rows, flags = jax.jit(fn)(table, ids)
+    return np.asarray(rows), np.asarray(flags)
+
+
+def _look_ids(rng, high=V_LOOK):
+    ids = rng.integers(0, high, size=(B_LOOK, N_LOOK)).astype(np.int32)
+    # Ids outside [0, V) read zeros in every form, and the first and last
+    # row of every shard.
+    ids.reshape(-1)[:12] = [-1, -(2**31), V_LOOK, 2**31 - 1, 0, 2999, 3000, 5999, 6000, 8999, 9000, V_LOOK - 1]
+    return ids
+
+
+@pytest.mark.parametrize("d", [9, 17])
+def test_the_swept_lookup_is_the_masked_gather_bit_for_bit(d):
+    """A 1 x 4 row mesh, uniform ids, a bound of half the 640 slots a shard is
+    handed: every shard takes the bounded branch, and the rows every chip
+    gets back are the masked row gather's bit for bit (values of sixteen
+    decades, every bfloat16 part of them non-zero)."""
+    rng = np.random.default_rng(d)
+    x = rng.standard_normal((V_LOOK, d)) * 10.0 ** rng.integers(-8, 8, (V_LOOK, d))
+    table = jnp.asarray(x.astype(np.float32))
+    ids = jnp.asarray(_look_ids(rng))
+    mesh = make_mesh(1, 4)
+    want, no_flags = _lookup(mesh, table, ids, "rows", None)
+    got, flags = _lookup(mesh, table, ids, "sweep", 320)
+    assert want.shape == (B_LOOK, N_LOOK, d)
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    np.testing.assert_array_equal(want.reshape(-1, d)[:4], 0.0)  # the ids outside [0, V)
+    np.testing.assert_array_equal(want.reshape(-1, d)[4:], np.asarray(table)[np.asarray(ids).reshape(-1)[4:]])
+    assert flags.tolist() == [0, 0, 0, 0] and no_flags.tolist() == [0, 0, 0, 0]
+
+
+def test_a_shard_that_owns_more_than_its_lookup_bound_reads_the_whole_list():
+    """Every id in shard 0's range: shard 0 owns all 640 slots, over its bound
+    of 320, and reads the whole list (counted: its flag alone is 1); the
+    others own none and take the bounded branch.  The rows are the same."""
+    rng = np.random.default_rng(4)
+    table = jnp.asarray(rng.standard_normal((V_LOOK, 17)).astype(np.float32))
+    ids = jnp.asarray(rng.integers(0, 3000, size=(B_LOOK, N_LOOK)).astype(np.int32))
+    mesh = make_mesh(1, 4)
+    want, _ = _lookup(mesh, table, ids, "rows", None)
+    got, flags = _lookup(mesh, table, ids, "sweep", 320)
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    assert flags.tolist() == [1, 0, 0, 0]
+
+
+@pytest.mark.parametrize("skewed", [False, True], ids=["uniform", "skewed"])
+def test_the_step_counts_the_chips_whose_lookup_read_the_whole_list(monkeypatch, skewed):
+    """Through ``make_sharded_train_step`` with the rule saying ``sweep`` (as
+    a TPU's does at ``fm16_criteo_row4``'s shapes): the step's state and loss
+    are the masked lookup's bit for bit, and ``count_full_lookups`` returns
+    after the tail's count the chips whose lookup took the whole list: 0
+    under uniform ids, 1 a step (shard 0) where every id is shard 0's."""
+    import fast_tffm_tpu.parallel.train_step as ts
+
+    model = FMModel(vocabulary_size=V_BIG, factor_num=16, order=2, factor_lambda=1e-4, bias_lambda=1e-4)
+    mesh, batches = make_mesh(1, 4), _big_batches(9, high=4096 if skewed else V_BIG)
+
+    def run():
+        state = init_sharded_state(model, mesh, jax.random.key(11), 0.1, "element")
+        step = make_sharded_train_step(model, 0.1, mesh, count_full_tails=True, count_full_lookups=True)
+        out = []
+        for b in batches:
+            state, loss, tail, lookup = step(state, b)
+            out.append((float(loss), int(tail), int(lookup)))
+        return np.asarray(state.table), np.asarray(state.table_opt.accum), out
+
+    want_t, want_a, want = run()
+    asked = []
+    monkeypatch.setattr(ts, "shard_lookup_form", lambda *a, **k: asked.append(a) or "sweep")
+    got_t, got_a, got = run()
+    assert asked == [(V_BIG // 4, B_BIG * N_BIG, 17, 672)]  # the shard's rows, its row peers' slots, the tail's bound
+    assert [(l, t) for l, t, _ in got] == [(l, t) for l, t, _ in want]
+    assert [n for *_, n in want] == [0, 0] and [n for *_, n in got] == ([1, 1] if skewed else [0, 0])
+    np.testing.assert_array_equal(got_t, want_t)
+    np.testing.assert_array_equal(got_a, want_a)
+
+
+@pytest.mark.parametrize(
+    "mesh_shape, kw",
+    [((4, 1), {}), ((1, 1), {}), ((1, 4), dict(table_layout="packed")),
+     ((1, 4), dict(table_layout="packed", accumulator="fused"))],
+    ids=["one-row-shard", "one-chip-mesh", "packed", "fused"],
+)
+def test_one_row_shard_and_the_packed_layouts_keep_the_row_gather(monkeypatch, mesh_shape, kw):
+    """Where the rule would say ``sweep`` for every shape it is asked at, a
+    mesh of one row shard and the packed layouts never ask it: their steps
+    hold no kernel and no ``cond`` from the lookup, and no flag beside the
+    loss."""
+    import fast_tffm_tpu.parallel.train_step as ts
+
+    asked = []
+    monkeypatch.setattr(ts, "shard_lookup_form", lambda *a, **k: asked.append(a) or "sweep")
+    model = FMModel(vocabulary_size=V_BIG, factor_num=16, order=2)
+    mesh, (b,) = make_mesh(*mesh_shape), _big_batches(7, n=1)
+    state = init_sharded_state(
+        model, mesh, jax.random.key(0), 0.1, kw.get("accumulator", "element"), kw.get("table_layout", "rows")
+    )
+    step = make_sharded_train_step(model, 0.1, mesh, count_full_lookups=True, **kw)
+    names = [n for n, _ in _primitives(jax.make_jaxpr(step)(state, b).jaxpr)]
+    assert asked == [] and "pallas_call" not in names
+    assert int(step(state, b)[2]) == 0
+
+
+def test_the_lookup_takes_the_sweep_on_a_tpu_where_the_bound_cuts_the_list():
+    """``shard_lookup_form`` at ``fm16_criteo_row4``'s shard (2^25 rows of 17,
+    2,555,904 slots, bound 1,284,384) and around it."""
+    from fast_tffm_tpu.parallel.embedding import shard_lookup_form
+
+    shard, m, bound = 2**25, 65536 * 39, 1284384
+    assert shard_lookup_form(shard, m, 17, bound, backend="tpu") == "sweep"
+    assert shard_lookup_form(shard, m, 17, bound, backend="cpu") == "rows"
+    assert shard_lookup_form(shard, m, 17, m, backend="tpu") == "rows"  # the bound cuts nothing
+    assert shard_lookup_form(shard, m, 17, None, backend="tpu") == "rows"
+    assert shard_lookup_form(shard, m, 128, bound, backend="tpu") == "rows"  # a row-major table
+    assert shard_lookup_form(shard, 40 * m, 17, 20 * m, backend="tpu") == "rows"  # past the scalar memory
